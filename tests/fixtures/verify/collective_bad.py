@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from tools.xtpuverify.contracts import ProgramContract
-from xgboost_tpu.context import shard_map
 from xgboost_tpu.programs import ProgramSpec, RoundPlan, _abstract
 
 CONTRACT = ProgramContract("fx.collective", dispatch_budget=2,
@@ -34,12 +33,12 @@ def asymmetric_cond_body(x):  # VERIFY[collective-symmetry]
 
 
 def plan():
-    stray = jax.jit(shard_map(stray_axis_body, mesh=_mesh("model"),
-                              in_specs=P("model"), out_specs=P(),
-                              check_vma=False))
-    asym = jax.jit(shard_map(asymmetric_cond_body, mesh=_mesh("data"),
-                             in_specs=P("data"), out_specs=P("data"),
-                             check_vma=False))
+    stray = jax.jit(jax.shard_map(stray_axis_body, mesh=_mesh("model"),
+                                  in_specs=P("model"), out_specs=P(),
+                                  check_vma=False))
+    asym = jax.jit(jax.shard_map(asymmetric_cond_body, mesh=_mesh("data"),
+                                 in_specs=P("data"), out_specs=P("data"),
+                                 check_vma=False))
     return RoundPlan(handle="fx.collective", unit="tree", dispatches=[
         ProgramSpec(name="stray", fn=stray,
                     args=(_abstract((8,), "float32"),),

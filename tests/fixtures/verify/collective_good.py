@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from tools.xtpuverify.contracts import ProgramContract
-from xgboost_tpu.context import shard_map
 from xgboost_tpu.programs import ProgramSpec, RoundPlan, _abstract
 
 CONTRACT = ProgramContract("fx.collective", dispatch_budget=1,
@@ -27,9 +26,9 @@ def symmetric_body(x):
 
 def plan():
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("data",))
-    fn = jax.jit(shard_map(symmetric_body, mesh=mesh,
-                           in_specs=P("data"), out_specs=P(),
-                           check_vma=False))
+    fn = jax.jit(jax.shard_map(symmetric_body, mesh=mesh,
+                               in_specs=P("data"), out_specs=P(),
+                               check_vma=False))
     return RoundPlan(handle="fx.collective", unit="tree", dispatches=[
         ProgramSpec(name="sym", fn=fn,
                     args=(_abstract((8,), "float32"),),

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 import xgboost_tpu as xgb
-from xgboost_tpu.context import DATA_AXIS, shard_map
+from xgboost_tpu.context import DATA_AXIS
 from xgboost_tpu.ops.partition import counting_sort_by_node
 
 P = jax.sharding.PartitionSpec
@@ -276,9 +276,9 @@ def test_counting_sort_single_node_under_shard_map(mesh):
         rel = jnp.zeros(x.shape[0], jnp.int32)
         return counting_sort_by_node(rel, 1)
 
-    fn = jax.jit(shard_map(root_perm, mesh=mesh,
-                           in_specs=(P(DATA_AXIS),),
-                           out_specs=P(DATA_AXIS)))
+    fn = jax.jit(jax.shard_map(root_perm, mesh=mesh,
+                               in_specs=(P(DATA_AXIS),),
+                               out_specs=P(DATA_AXIS)))
     out = np.asarray(fn(jnp.arange(n, dtype=jnp.float32)))
     local = n // ndev
     expect = np.tile(np.arange(local, dtype=np.int32), ndev)
@@ -291,9 +291,9 @@ def test_counting_sort_single_node_under_shard_map(mesh):
     def perm_of(rel):
         return counting_sort_by_node(rel, 1)
 
-    fn2 = jax.jit(shard_map(perm_of, mesh=mesh,
-                            in_specs=(P(DATA_AXIS),),
-                            out_specs=P(DATA_AXIS)))
+    fn2 = jax.jit(jax.shard_map(perm_of, mesh=mesh,
+                                in_specs=(P(DATA_AXIS),),
+                                out_specs=P(DATA_AXIS)))
     out2 = np.asarray(fn2(jnp.asarray(rel_np)))
     for d in range(ndev):
         lo = d * local

@@ -29,9 +29,6 @@ _WORKER = textwrap.dedent("""
 
     import jax
     jax.config.update("jax_platforms", "cpu")
-    from jax._src import xla_bridge as _xb
-    for _n in list(_xb._backend_factories):
-        if _n != "cpu": _xb._backend_factories.pop(_n)
     jax.distributed.initialize(coordinator_address=coord,
                                num_processes=world, process_id=rank)
     assert jax.process_count() == world

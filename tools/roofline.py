@@ -53,7 +53,8 @@ Peaks and their provenance:
   are therefore upper bounds).
 
 Pure shape math — runs anywhere (no TPU needed). The measured s/round it
-compares against defaults to BENCH_r05's HIGGS-11M steady 5.7183 r/s and
+compares against defaults to HIGGS-11M steady 5.7183 r/s (an older
+figure from a removed record, to be re-measured on the attached chip) and
 is overridable: ``python tools/roofline.py --measured-ms 174.8``.
 Output: a markdown table (pasted into BASELINE.md) + one JSON line.
 """
@@ -206,7 +207,8 @@ def main():
     ap.add_argument("--depth", type=int, default=6)
     ap.add_argument("--measured-ms", type=float, default=174.9,
                     help="measured ms/round to score utilisation against "
-                         "(default: BENCH_r05 higgs11m steady 5.7183 r/s)")
+                         "(default: 5.7183 r/s, an older figure from a "
+                         "removed record)")
     args = ap.parse_args()
     n, F, depth = args.rows, args.features, args.depth
 

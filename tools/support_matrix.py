@@ -35,11 +35,6 @@ def _force_cpu():
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from jax._src import xla_bridge as _xb
-
-    for _n in list(getattr(_xb, "_backend_factories", {})):
-        if _n != "cpu":
-            _xb._backend_factories.pop(_n, None)
 
 
 # feature rows: name -> extra params (tiny shapes; numeric binary data)

@@ -41,7 +41,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 TRACE_WRAPPERS = {
     "jax.jit", "jit", "pjit", "jax.pmap", "pmap", "jax.vmap", "vmap",
     "jax.grad", "jax.value_and_grad", "jax.remat", "jax.checkpoint",
-    "shard_map", "_shard_map", "jax.experimental.shard_map.shard_map",
+    "jax.shard_map", "shard_map",
     "pl.pallas_call", "pallas_call",
     "jax.lax.scan", "lax.scan", "jax.lax.while_loop", "lax.while_loop",
     "jax.lax.cond", "lax.cond", "jax.lax.switch", "lax.switch",

@@ -93,11 +93,11 @@ def finalize_findings(findings: List[Finding]) -> List[Finding]:
 # cond: "branches", pjit: "jaxpr", custom_*: "call_jaxpr"/"fun_jaxpr").
 
 def _sub_jaxprs(value) -> Iterator[Any]:
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, Jaxpr):
         yield value
     elif isinstance(value, (tuple, list)):
         for v in value:
@@ -106,9 +106,9 @@ def _sub_jaxprs(value) -> Iterator[Any]:
 
 def iter_eqns(jaxpr) -> Iterator[Any]:
     """Every eqn in a (Closed)Jaxpr, recursing into sub-jaxprs."""
-    import jax
+    from jax.extend.core import ClosedJaxpr
 
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
@@ -121,16 +121,16 @@ def iter_eqns(jaxpr) -> Iterator[Any]:
 def iter_closed_jaxprs(closed) -> Iterator[Any]:
     """Every ClosedJaxpr in the tree (top level + nested) — the consts of
     inner pjit closures live on these, not on the top-level jaxpr."""
-    import jax
+    from jax.extend.core import ClosedJaxpr
 
     yield closed
     for eqn in iter_eqns(closed):
         for v in eqn.params.values():
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, ClosedJaxpr):
                 yield v
             elif isinstance(v, (tuple, list)):
                 for x in v:
-                    if isinstance(x, jax.core.ClosedJaxpr):
+                    if isinstance(x, ClosedJaxpr):
                         yield x
 
 

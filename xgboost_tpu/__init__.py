@@ -7,29 +7,26 @@ row partitioning as static-shape gathers under ``jit``, and the rabit/NCCL
 collective layer replaced by ``jax.lax.psum`` over the ICI/DCN device mesh.
 """
 
-def _enable_jax_compile_cache() -> None:
-    """Persistent XLA compilation cache: compiles cost ~50 s each on a
-    single-core host, and the training programs are identical across
-    processes/runs. Opt out with XTPU_JAX_CACHE=0; an explicit user-set
-    JAX_COMPILATION_CACHE_DIR always wins."""
+def _place_compile_cache() -> None:
+    """Persistent XLA compilation cache. ``JAX_COMPILATION_CACHE_DIR`` set
+    from outside is the whole configuration: jax reads it itself and nothing
+    here sets a directory. Unset, the cache is ``<checkout>/.jax_cache`` —
+    one fixed, gitignored path, because the directory is part of what a
+    later process must find again (a home, temp, pid or time-derived path
+    never hits from the next machine or the next process)."""
     import os
 
-    if os.environ.get("XTPU_JAX_CACHE", "1") != "1" \
-            or os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
-    try:
-        import jax
+    import jax
 
-        path = os.path.join(os.path.expanduser("~"), ".cache",
-                            "xgboost_tpu", "jax_cache")
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
-    except Exception:  # pragma: no cover - cache is best-effort
-        pass
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache"))
 
 
-_enable_jax_compile_cache()
+_place_compile_cache()
 
 from . import callback  # noqa: E402
 from .config import config_context, get_config, set_config  # noqa: E402

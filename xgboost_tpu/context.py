@@ -22,22 +22,6 @@ DATA_AXIS = "data"
 FEATURE_AXIS = "feat"
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``: newer jax exposes it as
-    ``jax.shard_map`` (replication checker flag ``check_vma``), older
-    releases only under ``jax.experimental.shard_map`` with the flag
-    spelled ``check_rep``. Every grower routes through here so the mesh
-    tiers run on both."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-
-
 @functools.lru_cache(maxsize=None)
 def default_device(platform: Optional[str] = None):
     if platform is None or platform == "auto":

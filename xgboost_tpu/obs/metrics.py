@@ -26,7 +26,8 @@ import weakref
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 __all__ = ["Sample", "Family", "HistogramData", "MetricsRegistry",
-           "get_registry", "render_families"]
+           "get_registry", "render_families", "count_degrade",
+           "degrade_counts"]
 
 LabelSet = Tuple[Tuple[str, str], ...]
 
@@ -268,3 +269,25 @@ _registry = MetricsRegistry()
 def get_registry() -> MetricsRegistry:
     """The process-wide registry every source registers into."""
     return _registry
+
+
+_DEGRADES = "xtpu_degrades_total"
+DEGRADE_PATHS = ("insight_disarm", "paged_collapse")
+
+
+def count_degrade(path: str) -> None:
+    """Count one run that caught a failure and kept going on a slower
+    tier. These are the only two such handlers on the training path (the
+    insight-armed round disarming, the paged resident collapse dropping to
+    streaming); anything that reports on the chip asserts both stay zero
+    (``chip_smoke.py``)."""
+    if path not in DEGRADE_PATHS:
+        raise ValueError(f"unknown degrade path {path!r}")
+    _registry.inc(_DEGRADES, labels=(("path", path),),
+                  help="failures caught by a handler that continued on a "
+                       "slower tier, by path")
+
+
+def degrade_counts() -> Dict[str, int]:
+    return {p: int(_registry.get(_DEGRADES, (("path", p),)))
+            for p in DEGRADE_PATHS}

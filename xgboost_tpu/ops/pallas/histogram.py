@@ -224,7 +224,11 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
     SMEM block (safe feature id, threshold bin, default_left, can_split);
     each previous node's split-feature row is pulled from the tile with
     one dynamic sublane slice — n_prev <= 64, so this is a short scalar
-    loop, not a gather.
+    loop, not a gather. The slice reads an int32 copy of the tile staged
+    once per row block in a VMEM scratch (``[F, R]`` i32, 224 KiB at
+    F=28, R=2048): Mosaic refuses a dynamic one-row slice of the packed
+    uint8 tile itself (four rows share a sublane, so the row index would
+    have to be provably aligned), and accepts it on 32-bit rows.
 
     Histogram math is IDENTICAL to ``_make_int8_kernel(packed=True)`` at
     ``B = coarse_b``: same per-feature loop, same PT4 node-scatter, same
@@ -232,12 +236,15 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
     bit-identical to the unfused one."""
     B, N, R, F = coarse_b, n_nodes, block_rows, n_feat
 
-    def kernel(split_ref, bins_ref, q_ref, pos_ref, hist_ref, pos_out_ref):
+    def kernel(split_ref, bins_ref, q_ref, pos_ref, hist_ref, pos_out_ref,
+               bins32):
         i = pl.program_id(0)
 
         @pl.when(i == 0)
         def _():
             hist_ref[:] = jnp.zeros_like(hist_ref)
+
+        bins32[:] = bins_ref[:].astype(jnp.int32)          # [F, R] i32
 
         # ---- advance: route rows below the previous level's splits ----
         pos_row = pos_ref[:]                               # [1, R] i32
@@ -250,10 +257,14 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
             tj = split_ref[1, j]
             dj = split_ref[2, j]
             cj = split_ref[3, j]
-            bj = bins_ref[pl.ds(fj, 1), :].astype(jnp.int32)   # [1, R]
-            gr = jnp.where(bj == missing_bin, dj == 0, bj > tj)
-            child = 2 * pos_row + 1 + gr.astype(jnp.int32)
-            new_pos = jnp.where((rel_prev == j) & (cj > 0), child, new_pos)
+            bj = bins32[pl.ds(fj, 1), :]                   # [1, R]
+            # the SMEM scalars enter as int32 operands only: a scalar
+            # bool broadcast against a vector does not lower
+            gr = jnp.where(bj == missing_bin, 1 - dj,
+                           (bj > tj).astype(jnp.int32))
+            child = 2 * pos_row + 1 + gr
+            take = jnp.where(rel_prev == j, cj, 0)
+            new_pos = jnp.where(take > 0, child, new_pos)
         pos_out_ref[:] = new_pos
         rel = jnp.where((new_pos >= lo) & (new_pos < lo + N),
                         new_pos - lo, N)                   # [1, R]
@@ -278,7 +289,7 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
               + jnp.uint32(0x03020100))
         M7F = jnp.uint32(0x7F7F7F7F)
         for f in range(F):
-            row = bins_ref[f:f + 1, :].astype(jnp.int32)   # [1, R]
+            row = bins32[f:f + 1, :]                       # [1, R]
             cb = jnp.where(row == missing_bin, B - 1, row >> shift)
             x = K4 ^ (cb.astype(jnp.uint32) * jnp.uint32(0x01010101))
             y = (~(((x & M7F) + M7F) | x | M7F)) >> jnp.uint32(7)
@@ -359,6 +370,7 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                                 memory_space=pltpu.VMEM),
                    pl.BlockSpec((1, R), lambda i: (0, i),
                                 memory_space=pltpu.VMEM)],
+        scratch_shapes=[pltpu.VMEM((F, R), jnp.int32)],
         interpret=interpret,
     )(splits, bins_t, q, pos_t)
     inv = jnp.repeat(1.0 / scale, N)[None, None, :]      # [1, 1, 2N]
@@ -377,6 +389,10 @@ def _make_scan_kernel(n_feat: int, n_bins: int, block_rows: int):
     carry between them never touches HBM (the decoupled look-back of the
     segmented scan, expressed through Pallas' revisit semantics).
 
+    ``n_feat`` is the FEATURE BLOCK the kernel owns: the grid is
+    (feature blocks, row blocks) with the row sweep innermost, so each
+    (node, feature block) tile's visits stay contiguous.
+
     What the sorted layout buys over ``_make_int8_kernel``: the block's
     node is fixed, so the ``[4N, R]`` node-scatter plane and the N-wide
     MXU columns vanish — the gradient operand is a node-free ``[4, R]``
@@ -390,7 +406,7 @@ def _make_scan_kernel(n_feat: int, n_bins: int, block_rows: int):
     B, R, F = n_bins, block_rows, n_feat
 
     def kernel(bn_ref, bins_ref, q_ref, out_ref):
-        i = pl.program_id(0)
+        i = pl.program_id(1)
         # first block of a node: zero its accumulator tile (block_node is
         # nondecreasing, so each output row's visits are contiguous)
         first = jnp.logical_or(
@@ -456,8 +472,25 @@ def scan_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     R = min(block_rows, max(_round_up(n, 128), 128))
     perm, block_node = counting_sort_by_node(rel_pos, n_nodes, block=R)
     nb = perm.shape[0] // R
+    # Whole-F accumulator tile when it fits: the [F_blk, B, 4] int32 tile
+    # pads its 4-wide minor axis to 128 lanes and Pallas double-buffers
+    # it, so 4 MiB per buffer is what the 16 MiB scoped-VMEM limit leaves
+    # beside the input blocks (F=28 x 256 bins: 3.5 MiB; F=136 whole was
+    # refused at 34.5 MiB). Past it, split F into the fewest fitting
+    # blocks of a multiple of 8 features, as build_hist_pallas does.
+    budget = 4 * 2 ** 20
+    per_feat = _round_up(B, 8) * 128 * 4
+    if F * per_feat <= budget:
+        F_blk = F
+    else:
+        cap = max(8, (budget // per_feat) // 8 * 8)
+        n_blocks = -(-F // cap)
+        F_blk = min(cap, _round_up(-(-F // n_blocks), 8))
+    F_pad = _round_up(F, F_blk)
     # pad slots carry the sentinel row id n -> bins 0 / q 0: zero payload
     bins_p = jnp.take(bins_t, perm, axis=1, mode="fill", fill_value=0)
+    if F_pad != F:
+        bins_p = jnp.pad(bins_p, ((0, F_pad - F), (0, 0)))
     gpair_t = gpair.T                                    # [2, n]
     max_abs = jnp.max(jnp.abs(gpair_t), axis=1)
     if axis_name is not None:
@@ -468,19 +501,20 @@ def scan_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((F, R), lambda i, bn: (0, i)),
-                  pl.BlockSpec((2, R), lambda i, bn: (0, i))],
+        grid=(F_pad // F_blk, nb),
+        in_specs=[pl.BlockSpec((F_blk, R), lambda j, i, bn: (j, i)),
+                  pl.BlockSpec((2, R), lambda j, i, bn: (0, i))],
         # the scalar-prefetched block_node drives the output row: pad /
         # stray blocks land on the trash row n_nodes, dropped below
-        out_specs=pl.BlockSpec((1, F, B, 4),
-                               lambda i, bn: (bn[i], 0, 0, 0)))
+        out_specs=pl.BlockSpec((1, F_blk, B, 4),
+                               lambda j, i, bn: (bn[i], j, 0, 0)))
     acc = pl.pallas_call(
-        _make_scan_kernel(F, B, R),
-        out_shape=jax.ShapeDtypeStruct((n_nodes + 1, F, B, 4), jnp.int32),
+        _make_scan_kernel(F_blk, B, R),
+        out_shape=jax.ShapeDtypeStruct((n_nodes + 1, F_pad, B, 4),
+                                       jnp.int32),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(block_node, bins_p, q_p)[:n_nodes]                 # [N, F, B, 4]
+    )(block_node, bins_p, q_p)[:n_nodes, :F]             # [N, F, B, 4]
 
     inv = (1.0 / scale)[None, None, None, :]             # [1, 1, 1, 2]
 

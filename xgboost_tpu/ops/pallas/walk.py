@@ -1,4 +1,4 @@
-"""Pallas TPU variant of the packed-forest walk (experimental, opt-in).
+"""Pallas TPU variant of the packed-forest walk (experimental, no caller).
 
 ``ops/walk.py`` lets XLA schedule the level-synchronous walk; this
 kernel instead pins the whole packed node pool (words + value plane)
@@ -8,17 +8,18 @@ levels, which is the same residency argument the histogram kernel
 makes for its accumulator. The leaf→group reduction stays a single
 ``[R, T] @ [T, G]`` MXU dot per block.
 
-Scope (why it is opt-in, ``XTPU_PALLAS_WALK=1``):
+Scope (why nothing routes to it; ROADMAP C3 decides whether it stays):
 
 - **no categorical splits** — the bitset gather would need a second
   VMEM-resident pool; callers with ``has_cat`` packs must stay on
   ``walk_packed`` (the wrapper enforces this);
 - the node pool must FIT in VMEM (~16 MB ⇒ ≲1M nodes for the two f32
   planes); the wrapper raises past that rather than silently spilling;
-- CPU CI exercises it in interpret mode (``interpret=True``); Mosaic
-  lowering of the per-level dynamic gathers is TPU-generation
-  dependent, which is exactly why the stock XLA walk stays the
-  default.
+- it does not compile: on jax 0.9.0 the per-level ``words[idx]`` gather
+  fails Pallas' TPU lowering with ``NotImplementedError: Only 2D gather
+  is supported`` (tried compiled for v5e, PR 21). Only interpret mode
+  runs, and callers ask for it explicitly (``interpret=True``,
+  tests/test_packed.py).
 
 Parity: same node-word layout (``serve/packed.py`` constants), same
 NaN→default routing, same HIGHEST-precision leaf dot as the reference
@@ -102,7 +103,7 @@ def _walk_pallas(words, values, tree_offsets, tree_weight, group_onehot,
     return out[:n]
 
 
-def walk_packed_pallas(pf, X, base, *, interpret: bool = True,
+def walk_packed_pallas(pf, X, base, *, interpret: bool = False,
                        block_rows: int = BLOCK_ROWS):
     """Margin of a packed forest via the Pallas kernel. ``pf`` is a
     :class:`~...serve.packed.PackedForest`; raises for categorical

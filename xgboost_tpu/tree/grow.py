@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..context import shard_map as _shard_map
 from ..obs import trace as _trace
 from ..ops.histogram import (build_hist, build_hist_prehot,
                              build_onehot_plane, fused_advance_coarse,
@@ -172,6 +171,149 @@ def exchange_best_split(res, axis_name, F: int, *, with_cat: bool = False):
     return res._replace(**repl), mine
 
 
+# The gather-free level ops materialise [n, n_level] intermediates; past
+# this level width the memory cost outweighs the gather cost, so deeper
+# levels fall back to the per-row gather walk.
+DENSE_LEVEL_MAX = 64
+
+
+class Schedule(NamedTuple):
+    """What ``hist_method`` resolves to for one grow program — every
+    field is a trace-time constant of ``_grow``."""
+
+    kernel: str        # hist_method with the "+sub"/"+nosub" suffix cut
+    compaction: bool   # smaller-child build + sibling subtraction
+    prehot: bool       # pre-materialised one-hot plane
+    coarse: bool       # two-level coarse->refine search space
+    fused: bool        # ... scheduled as the cross-level fused sweep
+    scan: bool         # ... scheduled as the segmented scan
+    mega: bool         # ... with the level loop rolled into one fori_loop
+
+    @property
+    def name(self) -> str:
+        """The schedule that runs: ``mega``/``scan``/``fused``/``coarse``,
+        or the one-pass build ``kernel`` names."""
+        for flag in ("mega", "scan", "fused", "coarse"):
+            if getattr(self, flag):
+                return flag
+        return self.kernel
+
+
+def resolve_schedule(hist_method: str, n: int, max_nbins: int,
+                     has_missing: bool, param: TrainParam, *, numeric: bool,
+                     col_split: bool = False,
+                     sharded: bool = False) -> Schedule:
+    """The histogram schedule ``_grow`` runs for ``hist_method`` at this
+    shape: ``n`` local rows, ``numeric`` = no categorical feature,
+    ``sharded`` = under a mesh axis. Reads the backend
+    (``auto_selects_coarse``) and the import-time ``AUTO_SCAN_PROMOTE`` /
+    ``AUTO_MEGA`` switches, nothing else."""
+    # Smaller-child build + sibling subtraction (reference
+    # src/tree/hist/histogram.h:192-207, updater_gpu_hist.cu:558): per split
+    # parent only the child with FEWER rows is built — the built rows are
+    # compacted into a fixed n//2-capacity buffer (sum over parents of
+    # min(left, right) can never exceed n/2) — and the sibling is the
+    # parent-minus-child difference. OPT-IN via "<kernel>+sub": measured
+    # SLOWER on TPU v5e (the nonzero-compaction + row gathers cost more
+    # than the halved one-hot build they save; interleaved A/B 2.7-2.9 vs
+    # 3.3-4.3 rounds/s at 1M x 28 depth 6), so the default is a full build
+    # per level — kept for revisiting with a gather-fused kernel.
+    # "+nosub" is accepted as the explicit spelling of the default. Never
+    # used under a mesh: the count-based choice bounds GLOBAL rows, but one
+    # shard's share of the built children can exceed its local half, so a
+    # static per-shard compaction capacity cannot be guaranteed.
+    hist_kernel = hist_method
+    use_compaction = False
+    for _suffix, _enable in (("+sub", True), ("+nosub", False)):
+        if hist_kernel.endswith(_suffix):
+            hist_kernel = hist_kernel[: -len(_suffix)]
+            use_compaction = _enable
+    use_compaction &= not sharded and not col_split and n >= 8
+    # Pre-materialised one-hot plane (ops/histogram.py build_onehot_plane):
+    # one [F*B, n] int8 plane in HBM turns every level's histogram into a
+    # single int8 MXU contraction. EXPLICIT opt-in only since round 2: with
+    # the hi/lo byte planes fused into one [4N]-column matmul the Pallas
+    # kernel (VMEM one-hot, ~28 MB/level HBM traffic) measures faster at
+    # every level width (8.3 ms flat vs 9.7-37 ms at 1M x 28 x 256 on v5e)
+    # and costs no plane memory, so "auto" routes to it via build_hist.
+    use_prehot = (not use_compaction and n * 128 < 2 ** 31
+                  and hist_kernel == "prehot")
+
+    # Two-level coarse->refine histogram (hist_method="coarse"): a 20-slot
+    # pass over bins >> 4, a span choice per (node, feature) from the
+    # coarse boundary gains, a 16-bin refine pass over the chosen span,
+    # and an exact evaluate_splits over the order-preserving synthetic
+    # layout — 2.8x cheaper per level than the 256-wide one-pass kernel
+    # (docs/performance.md round-4 section). Exactness: every coarse
+    # boundary is scored exactly; in-span fine boundaries exactly; fine
+    # splits OUTSIDE the chosen span are not searched.
+    #
+    # Round 5: "auto" promotes to coarse where its preconditions hold and
+    # it measured faster (TPU, numeric, wide bins, enough rows) — the
+    # eval-set validation table in docs/performance.md is the quality
+    # justification. All sizes below the thresholds keep the exact kernel.
+    use_coarse = hist_kernel in ("coarse", "fused")
+    if hist_kernel == "auto":
+        use_coarse = auto_selects_coarse(
+            n, max_nbins, has_missing, numeric=numeric,
+            col_split=col_split)
+    # Round 6: the cross-level FUSED sweep is a rescheduling of the coarse
+    # scheme, not a new search space — per level boundary the row advance
+    # below level L's decoded splits and level L+1's coarse accumulation
+    # share one read of the bin tile (ops/histogram.py
+    # fused_advance_coarse), where the unfused path streams a persistent
+    # [n, F] f32 copy for the advance matmul plus the coarse-id copy.
+    # Bit-exact with "coarse" (tests/test_fused_hist.py), so "auto"
+    # promotes straight to the fused scheduling wherever it promoted to
+    # coarse; explicit "coarse" keeps the two-pass scheduling so the A/B
+    # stays measurable.
+    use_fused = hist_kernel == "fused" or (hist_kernel == "auto"
+                                           and use_coarse)
+    # Round 12: the segmented-scan formulation replaces the fused schedule's
+    # coarse+refine data passes with ONE sorted pass per level — rows are
+    # counting-sorted by node (ops/partition.py counting_sort_by_node), the
+    # fine histogram is a contiguous segment sum over the sorted runs, and
+    # the coarse + refine histograms are derived from it (integral
+    # slice-diffs on TPU, direct sorted builds on XLA) instead of being
+    # re-accumulated from the data. Search space and models are
+    # bit-identical to fused (tools/validate_scan.py pins the grid), so
+    # "auto" promotes scan wherever it promoted fused; explicit "fused"
+    # keeps the old schedule so the A/B stays measurable.
+    use_scan = (hist_kernel in ("scan", "mega")
+                or (hist_kernel == "auto"
+                    and use_coarse and AUTO_SCAN_PROMOTE))
+    use_coarse = use_coarse or use_scan
+    use_fused = use_fused and not use_scan
+    # Round 14 megakernel (hist_method="mega"): the scan stage chain, but
+    # the Python depth loop becomes one ``lax.fori_loop`` with level
+    # bounds as traced carries and node arrays padded to the static
+    # capacity N_cap = 2^(max_depth-1). Engages for explicit "mega" and
+    # for "auto" wherever scan promoted (XTPU_MEGA=0 opts out); outside
+    # its gates it falls back to the unrolled scan loop, which is
+    # bit-identical, so a fallback is never a correctness event:
+    # - numeric features only (scan's own restriction);
+    # - every level dense (2^max_depth <= DENSE_LEVEL_MAX): the loop body
+    #   is ONE program, so the dense/walk advance switch cannot vary by
+    #   depth;
+    # - colsample_bynode == 1: per-node subsampling draws
+    #   ``jax.random.split(key, n_level)`` whose RESULTS depend on the
+    #   level width, which is traced here — jax's split is not
+    #   prefix-stable, so the padded draw would change sampled features
+    #   (colsample_bylevel is safe: fold_in of the traced depth is
+    #   value-identical to the unrolled fold_in);
+    # - no smaller-child compaction (static per-level capacities).
+    use_mega = (use_scan
+                and (hist_kernel == "mega"
+                     or (hist_kernel == "auto" and AUTO_MEGA))
+                and numeric and not use_compaction
+                and param.max_depth >= 1
+                and 2 ** param.max_depth <= DENSE_LEVEL_MAX
+                and param.colsample_bynode >= 1.0)
+    return Schedule(kernel=hist_kernel, compaction=use_compaction,
+                    prehot=use_prehot, coarse=use_coarse, fused=use_fused,
+                    scan=use_scan, mega=use_mega)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("param", "max_nbins", "hist_method", "axis_name",
@@ -253,10 +395,6 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
         feat_off = None
         mono_loc, cat_loc = monotone, cat
 
-    # The gather-free level ops materialise [n, n_level] intermediates; past
-    # this level width the memory cost outweighs the gather cost, so deeper
-    # levels fall back to the per-row gather walk.
-    DENSE_LEVEL_MAX = 64
     # per-level delta accumulation touches the deepest level (2^max_depth
     # nodes); all levels must be dense for it to cover every row exactly once
     dense_delta = 2 ** max_depth <= DENSE_LEVEL_MAX
@@ -273,110 +411,16 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                          node_upper[lo:lo + n_level])
         return w * param.eta
 
-    # Smaller-child build + sibling subtraction (reference
-    # src/tree/hist/histogram.h:192-207, updater_gpu_hist.cu:558): per split
-    # parent only the child with FEWER rows is built — the built rows are
-    # compacted into a fixed n//2-capacity buffer (sum over parents of
-    # min(left, right) can never exceed n/2) — and the sibling is the
-    # parent-minus-child difference. OPT-IN via "<kernel>+sub": measured
-    # SLOWER on TPU v5e (the nonzero-compaction + row gathers cost more
-    # than the halved one-hot build they save; interleaved A/B 2.7-2.9 vs
-    # 3.3-4.3 rounds/s at 1M x 28 depth 6), so the default is a full build
-    # per level — kept for revisiting with a gather-fused kernel.
-    # "+nosub" is accepted as the explicit spelling of the default. Never
-    # used under a mesh: the count-based choice bounds GLOBAL rows, but one
-    # shard's share of the built children can exceed its local half, so a
-    # static per-shard compaction capacity cannot be guaranteed.
-    hist_kernel = hist_method
-    use_compaction = False
-    for _suffix, _enable in (("+sub", True), ("+nosub", False)):
-        if hist_kernel.endswith(_suffix):
-            hist_kernel = hist_kernel[: -len(_suffix)]
-            use_compaction = _enable
-    use_compaction &= axis_name is None and not col_split and n >= 8
+    sched = resolve_schedule(hist_method, n, max_nbins, has_missing, param,
+                             numeric=cat is None, col_split=col_split,
+                             sharded=axis_name is not None)
+    hist_kernel, use_compaction, use_prehot = (
+        sched.kernel, sched.compaction, sched.prehot)
+    use_coarse, use_fused, use_scan, use_mega = (
+        sched.coarse, sched.fused, sched.scan, sched.mega)
     prev_hist = None
     built_is_left = None
-
-    # Pre-materialised one-hot plane (ops/histogram.py build_onehot_plane):
-    # one [F*B, n] int8 plane in HBM turns every level's histogram into a
-    # single int8 MXU contraction. EXPLICIT opt-in only since round 2: with
-    # the hi/lo byte planes fused into one [4N]-column matmul the Pallas
-    # kernel (VMEM one-hot, ~28 MB/level HBM traffic) measures faster at
-    # every level width (8.3 ms flat vs 9.7-37 ms at 1M x 28 x 256 on v5e)
-    # and costs no plane memory, so "auto" routes to it via build_hist.
-    use_prehot = (not use_compaction and n * 128 < 2 ** 31
-                  and hist_kernel == "prehot")
     oh_pre = (build_onehot_plane(bins_t, max_nbins) if use_prehot else None)
-
-    # Two-level coarse->refine histogram (hist_method="coarse"): a 20-slot
-    # pass over bins >> 4, a span choice per (node, feature) from the
-    # coarse boundary gains, a 16-bin refine pass over the chosen span,
-    # and an exact evaluate_splits over the order-preserving synthetic
-    # layout — 2.8x cheaper per level than the 256-wide one-pass kernel
-    # (docs/performance.md round-4 section). Exactness: every coarse
-    # boundary is scored exactly; in-span fine boundaries exactly; fine
-    # splits OUTSIDE the chosen span are not searched.
-    #
-    # Round 5: "auto" promotes to coarse where its preconditions hold and
-    # it measured faster (TPU, numeric, wide bins, enough rows) — the
-    # eval-set validation table in docs/performance.md is the quality
-    # justification. All sizes below the thresholds keep the exact kernel.
-    use_coarse = hist_kernel in ("coarse", "fused")
-    if hist_kernel == "auto":
-        use_coarse = auto_selects_coarse(
-            n, max_nbins, has_missing, numeric=cat is None,
-            col_split=col_split)
-    # Round 6: the cross-level FUSED sweep is a rescheduling of the coarse
-    # scheme, not a new search space — per level boundary the row advance
-    # below level L's decoded splits and level L+1's coarse accumulation
-    # share one read of the bin tile (ops/histogram.py
-    # fused_advance_coarse), where the unfused path streams a persistent
-    # [n, F] f32 copy for the advance matmul plus the coarse-id copy.
-    # Bit-exact with "coarse" (tests/test_fused_hist.py), so "auto"
-    # promotes straight to the fused scheduling wherever it promoted to
-    # coarse; explicit "coarse" keeps the two-pass scheduling so the A/B
-    # stays measurable.
-    use_fused = hist_kernel == "fused" or (hist_kernel == "auto"
-                                           and use_coarse)
-    # Round 12: the segmented-scan formulation replaces the fused schedule's
-    # coarse+refine data passes with ONE sorted pass per level — rows are
-    # counting-sorted by node (ops/partition.py counting_sort_by_node), the
-    # fine histogram is a contiguous segment sum over the sorted runs, and
-    # the coarse + refine histograms are derived from it (integral
-    # slice-diffs on TPU, direct sorted builds on XLA) instead of being
-    # re-accumulated from the data. Search space and models are
-    # bit-identical to fused (tools/validate_scan.py pins the grid), so
-    # "auto" promotes scan wherever it promoted fused; explicit "fused"
-    # keeps the old schedule so the A/B stays measurable.
-    use_scan = (hist_kernel in ("scan", "mega")
-                or (hist_kernel == "auto"
-                    and use_coarse and AUTO_SCAN_PROMOTE))
-    use_coarse = use_coarse or use_scan
-    use_fused = use_fused and not use_scan
-    # Round 14 megakernel (hist_method="mega"): the scan stage chain, but
-    # the Python depth loop becomes one ``lax.fori_loop`` with level
-    # bounds as traced carries and node arrays padded to the static
-    # capacity N_cap = 2^(max_depth-1). Engages for explicit "mega" and
-    # for "auto" wherever scan promoted (XTPU_MEGA=0 opts out); outside
-    # its gates it falls back to the unrolled scan loop, which is
-    # bit-identical, so a fallback is never a correctness event:
-    # - numeric features only (scan's own restriction);
-    # - every level dense (2^max_depth <= DENSE_LEVEL_MAX): the loop body
-    #   is ONE program, so the dense/walk advance switch cannot vary by
-    #   depth;
-    # - colsample_bynode == 1: per-node subsampling draws
-    #   ``jax.random.split(key, n_level)`` whose RESULTS depend on the
-    #   level width, which is traced here — jax's split is not
-    #   prefix-stable, so the padded draw would change sampled features
-    #   (colsample_bylevel is safe: fold_in of the traced depth is
-    #   value-identical to the unrolled fold_in);
-    # - no smaller-child compaction (static per-level capacities).
-    use_mega = (use_scan
-                and (hist_kernel == "mega"
-                     or (hist_kernel == "auto" and AUTO_MEGA))
-                and cat is None and not use_compaction
-                and max_depth >= 1 and dense_delta
-                and param.colsample_bynode >= 1.0)
     if use_coarse:
         if cat is not None or max_nbins > 256 + int(has_missing):
             raise NotImplementedError(
@@ -1286,7 +1330,7 @@ class TreeGrower:
             mega_possible = (self.hist_method == "mega"
                              or (self.hist_method == "auto" and AUTO_MEGA
                                  and jax.default_backend() == "tpu"))
-            self._sharded_fn = jax.jit(_shard_map(
+            self._sharded_fn = jax.jit(jax.shard_map(
                 inner, mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,

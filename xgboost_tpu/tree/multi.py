@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..context import shard_map as _shard_map
 from ..ops.histogram import build_hist_multi
 from ..ops.partition import advance_positions_level, update_positions
 from ..ops.split import evaluate_splits_multi
@@ -527,7 +526,7 @@ class MultiTargetGrower:
                 pos = jax.lax.fori_loop(0, max_depth, body, pos)
                 return pos, lv[pos]
 
-            self._repark_fn = jax.jit(_shard_map(
+            self._repark_fn = jax.jit(jax.shard_map(
                 repark, mesh=self.mesh,
                 in_specs=(P(DATA_AXIS), P(), P()),
                 out_specs=(P(DATA_AXIS), P(DATA_AXIS, None))))
@@ -569,7 +568,7 @@ class MultiTargetGrower:
                     gain=P(), positions=P(DATA_AXIS),
                     delta=P(DATA_AXIS, None), base_weight=P())
                 check_vma = True
-            self._sharded_fn = jax.jit(_shard_map(
+            self._sharded_fn = jax.jit(jax.shard_map(
                 inner, mesh=self.mesh,
                 in_specs=in_specs, out_specs=out_specs,
                 check_vma=check_vma))
@@ -706,13 +705,13 @@ class MultiLossguideGrower:
 
                 ev = functools.partial(_eval2_multi_col,
                                        axis_name=DATA_AXIS, **kw)
-                sharded_eval = jax.jit(_shard_map(
+                sharded_eval = jax.jit(jax.shard_map(
                     ev, mesh=self.mesh,
                     in_specs=(P(None, DATA_AXIS), P(), P(), P(), P(),
                               P(), P(None, DATA_AXIS), P(DATA_AXIS),
                               P(DATA_AXIS, None)),
                     out_specs=P(), check_vma=False))
-                sharded_apply = jax.jit(_shard_map(
+                sharded_apply = jax.jit(jax.shard_map(
                     functools.partial(_apply1_col, axis_name=DATA_AXIS),
                     mesh=self.mesh,
                     in_specs=(P(None, DATA_AXIS), P()) + (P(),) * 9,
@@ -732,22 +731,22 @@ class MultiLossguideGrower:
 
                 ev = functools.partial(_eval2_multi, axis_name=DATA_AXIS,
                                        **kw)
-                sharded_eval = jax.jit(_shard_map(
+                sharded_eval = jax.jit(jax.shard_map(
                     ev, mesh=self.mesh,
                     in_specs=(P(DATA_AXIS, None), P(DATA_AXIS, None, None),
                               P(DATA_AXIS), P(), P(), P(), P(), P(),
                               P(None, DATA_AXIS)),
                     out_specs=P()))
-                sharded_apply = jax.jit(_shard_map(
+                sharded_apply = jax.jit(jax.shard_map(
                     _apply1, mesh=self.mesh,
                     in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(), P(),
                               P(), P(), P(), P(), P(), P(), P()),
                     out_specs=P(DATA_AXIS)))
-                sharded_root = jax.jit(_shard_map(
+                sharded_root = jax.jit(jax.shard_map(
                     functools.partial(_root_sum, axis_name=DATA_AXIS),
                     mesh=self.mesh,
                     in_specs=(P(DATA_AXIS, None, None),), out_specs=P()))
-                sharded_gather = jax.jit(_shard_map(
+                sharded_gather = jax.jit(jax.shard_map(
                     lambda lv, pos: lv[pos], mesh=self.mesh,
                     in_specs=(P(), P(DATA_AXIS)),
                     out_specs=P(DATA_AXIS, None)))
