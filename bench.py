@@ -18,9 +18,8 @@ headline metric stays the 1M config for round-over-round comparability.
 Env knobs: BENCH_ROWS (default 1e6), BENCH_ROUNDS (default 20),
 BENCH_SKIP_BASELINE=1 to reuse the last stored baseline time,
 BENCH_11M=0 to skip the north-star shape, BENCH_OBS=0 to skip the
-xtpuobs tracing-overhead + stage-drift keys (tools/perf_report.py) and
-the xtpuflight keys (overlap_hidden_pct, straggler_skew_pct,
-hbm_peak_bytes_per_round, postmortem_write_ms).
+xtpuflight keys (straggler_skew_pct, hbm_peak_bytes_per_round,
+postmortem_write_ms) and the xtpuinsight keys.
 """
 
 from __future__ import annotations
@@ -456,16 +455,14 @@ def bench_checkpoint_overhead(X, y):
 
 
 def bench_flight():
-    """xtpuflight keys (BENCH_OBS): aggregate compute-hidden fraction of
-    the streamed tier's ``ring/upload`` spans, per-stage rank skew of a
-    small virtual multi-rank world (merged, clock-aligned rings), the
-    per-round HBM peak watermark, and the black-box bundle write cost."""
+    """xtpuflight keys (BENCH_OBS): per-stage rank skew of a small virtual
+    multi-rank world (merged, clock-aligned rings), the per-round HBM
+    peak watermark, and the black-box bundle write cost."""
     import tempfile
     import threading
 
     import xgboost_tpu as xgb
     from xgboost_tpu.obs import flight, memory
-    from xgboost_tpu.obs import trace as tr
     from xgboost_tpu.obs.trace import Tracer
     from xgboost_tpu.parallel.collective import InMemoryCommunicator
     from xgboost_tpu.parallel.resilience import (ResilientCommunicator,
@@ -473,38 +470,10 @@ def bench_flight():
 
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), "tools"))
-    from perf_report import _train_paged
-    from trace_analyze import overlap_hidden_pct, straggler_report
+    from trace_analyze import straggler_report
 
     out = {}
     rows = int(os.environ.get("BENCH_OBS_ROWS", 200_000))
-
-    # ---- overlap_hidden_pct: streamed paged run, ASYNC tracing (the
-    # spans time real dispatch/blocking, not forced sync), ring/upload
-    # spans scored against other-thread compute spans
-    env_keep = {k: os.environ.get(k) for k in
-                ("XTPU_PAGE_ROWS", "XTPU_PAGED_COLLAPSE",
-                 "XTPU_PAGE_CACHE_BYTES")}
-    os.environ["XTPU_PAGE_ROWS"] = str(max(rows // 4, 1))
-    os.environ["XTPU_PAGED_COLLAPSE"] = "0"
-    os.environ["XTPU_PAGE_CACHE_BYTES"] = "0"
-    was_traced = tr.enabled()
-    try:
-        with tempfile.TemporaryDirectory(prefix="xtpu_bench_flight_") as d:
-            tr.enable()
-            _train_paged(rows, COLS, DEPTH, 2, 4, d, "w")  # compile
-            tr.reset()
-            _train_paged(rows, COLS, DEPTH, 3, 4, d, "m")
-            rec = flight.FlightRecorder(rank=0, world=1)
-            out["overlap_hidden_pct"] = overlap_hidden_pct([rec.ring_doc()])
-    finally:
-        if not was_traced:
-            tr.disable()
-        for k, v in env_keep.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
 
     # ---- straggler_skew_pct: 4 virtual ranks, resilient allreduces
     # under per-rank rings, clocks aligned, merged timeline built
@@ -514,7 +483,7 @@ def bench_flight():
     def run_rank(rank):
         comm = ResilientCommunicator(world[rank])
         rec = flight.FlightRecorder(
-            comm=comm, tracer=Tracer(capacity=4096, annotate_device=False))
+            comm=comm, tracer=Tracer(capacity=4096))
         rec.sync_clocks(pings=4)
         for _ in range(8):
             with rec.span("hist/allreduce"):
@@ -695,22 +664,7 @@ def main():
         result["pipeline_rounds_behind"] = behind
         result["pipeline_replay_byte_equal"] = byte_equal
     if os.environ.get("BENCH_OBS", "1") != "0":
-        # xtpuobs drift report (tools/perf_report.py): whole-round cost
-        # of enabled tracing on the resident hot path (bar: <= 1.0%),
-        # plus per-stage measured ms/round from the streamed paged proxy
-        # joined against the roofline floors
-        sys.path.insert(0, os.path.join(os.path.dirname(
-            os.path.abspath(__file__)), "tools"))
-        from perf_report import measure_overhead, stage_report
-
-        result["obs_overhead_pct"] = round(
-            measure_overhead(ROWS, COLS, DEPTH, rounds=10), 3)
-        rep = stage_report(
-            rows=int(os.environ.get("BENCH_OBS_ROWS", 200_000)),
-            features=COLS, depth=DEPTH, rounds=3)
-        result.update(rep["keys"])
-        # xtpuflight keys: overlap_hidden_pct (ROADMAP item 2's async
-        # psum signal), straggler_skew_pct over a 4-rank virtual world,
+        # xtpuflight keys: straggler_skew_pct over a 4-rank virtual world,
         # the per-round HBM peak watermark, and the black-box write cost
         result.update(bench_flight())
         # xtpuinsight keys: armed-telemetry round cost (bar <= 1.0%),

@@ -135,9 +135,7 @@ def test_multi_rank_rings_merge_into_one_aligned_timeline(tmp_path):
     WORLD = 4
 
     def body(rank, comm):
-        rec = FlightRecorder(comm=comm,
-                             tracer=trace.Tracer(1024,
-                                                 annotate_device=False))
+        rec = FlightRecorder(comm=comm, tracer=trace.Tracer(1024))
         clk = rec.sync_clocks(pings=4)
         for i in range(3):
             with rec.span("hist/build", "train", {"i": i}):

@@ -2,12 +2,11 @@
 
 The three load-bearing promises (docs/observability.md):
 
-1. disabled tracing is FREE — zero allocations per span site on the
-   training hot path (the ``round/fused`` span in ``core.py``);
+1. with the ring off a span site is one inert profiler annotation: no
+   ring record, no growth of the ring (its cost without a profiler
+   session is bounded in ``tests/test_trace_program.py``);
 2. tracing NEVER changes the model — traced and untraced training
-   produce byte-identical ``save_raw`` artifacts (enabled-path overhead
-   at the bench shape is pinned by the slow-marked test + bench.py's
-   ``obs_overhead_pct``);
+   produce byte-identical ``save_raw`` artifacts;
 3. exports round-trip — Perfetto JSON loads back with the spans, names,
    and nesting the recorder saw.
 
@@ -30,7 +29,6 @@ from xgboost_tpu.obs import trace as tr
 @pytest.fixture(autouse=True)
 def _trace_off_after():
     yield
-    tr.set_sync(False)
     tr.disable()
 
 
@@ -50,34 +48,27 @@ def _train(X, y, **params):
 
 # ------------------------------------------------------------ span tracer
 
-def test_disabled_span_is_shared_and_allocation_free():
+def test_disabled_span_leaves_no_ring_record():
+    """Ring off: a span site is a bare profiler annotation. Nothing is
+    recorded, and a ring enabled afterwards starts empty."""
     tr.disable()
-    s1 = tr.span("round/fused")
-    s2 = tr.span("paged/hist", "train")
-    assert s1 is s2  # the shared _NULL singleton, not a fresh object
-    # zero allocations attributable to trace.py across many span sites —
-    # the per-round cost of XTPU_TRACE=0 on the hot path. Warm past
-    # CPython's lazy per-code-object caches (3.10 mallocs an opcache on
-    # a call-count threshold, attributed to the function's first line)
-    # so the measured window sees only true per-call allocations.
-    for _ in range(2000):
-        tr.span("round/fused")
+    assert tr.tracer() is None
+    for _ in range(100):
+        with tr.span("round/fused", "train", {"iteration": 3}):
+            pass
+        with tr.span("round", "train", {"rounds": 4}, step=3):
+            pass
         tr.instant("collective/retry")
-    flt = tracemalloc.Filter(True, tr.__file__)
-    tracemalloc.start()
-    try:
-        gc.collect()
-        base = tracemalloc.take_snapshot().filter_traces([flt])
-        for _ in range(1000):
-            with tr.span("round/fused"):
-                pass
-            tr.instant("collective/retry")
-        after = tracemalloc.take_snapshot().filter_traces([flt])
-    finally:
-        tracemalloc.stop()
-    diff = after.compare_to(base, "lineno")
-    grown = [d for d in diff if d.size_diff > 0]
-    assert not grown, [str(d) for d in grown]
+    assert tr.tracer() is None and tr.export() == 0
+    t = tr.enable(capacity=16)
+    assert len(t) == 0 and t.dropped == 0
+    with tr.span("round/fused", "train", {"iteration": 4}):
+        pass
+    assert [s.name for s in t.spans()] == ["round/fused"]
+    tr.disable()
+    with tr.span("round/fused"):
+        pass
+    assert len(t) == 1          # the ring that was live did not grow
 
 
 def test_disabled_memory_hooks_are_allocation_free():
@@ -217,16 +208,6 @@ def test_trace_spans_cover_paged_level_structure(tmp_path, monkeypatch):
     assert {"paged/exchange", "paged/eval", "paged/fetch"} <= names
 
 
-def test_sync_mode_blocks_only_when_armed():
-    tr.disable()
-    x = np.arange(8.0)
-    assert tr.sync(x) is x          # disabled: pure pass-through
-    tr.enable()
-    assert tr.sync(x) is x          # enabled, sync off: still free
-    tr.set_sync(True)
-    assert tr.sync(x) is x          # armed: blocks (numpy: no-op) then returns
-
-
 # ------------------------------------------------------- metrics registry
 
 def _fam(name, kind="counter", value=1, labels=()):
@@ -327,16 +308,3 @@ def test_collective_counters_registered():
     gc.collect()
     text = om.get_registry().render_prometheus()
     assert 'kind="retry"' not in text
-
-
-@pytest.mark.slow
-def test_tracing_overhead_under_one_percent_at_bench_shape():
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "tools"))
-    from perf_report import measure_overhead
-
-    pct = measure_overhead(rows=1_000_000, features=28, depth=6,
-                           rounds=20)
-    assert pct <= 1.0, f"enabled tracing cost {pct:.2f}% per round"

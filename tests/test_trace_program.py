@@ -1,0 +1,370 @@
+"""Names that reach a profiler trace: the round driver's host spans on the
+profiler's clock, the ``xtpu.<stage>`` scopes inside the round programs,
+and the compile counters by program (docs/observability.md).
+
+The switch is the profiler session: nothing here sets an ``XTPU_*``
+variable but the byte-identity test, which arms the ring to show that it
+changes nothing either."""
+
+import glob
+import os
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import xgboost_tpu as xgb
+from xgboost_tpu.obs import metrics as om
+from xgboost_tpu.obs import trace as tr
+
+
+def _data(n=1500, f=9, seed=0):
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, f).astype(np.float32)
+    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
+    return X, y
+
+
+PARAMS = {"objective": "binary:logistic", "max_depth": 3, "max_bin": 32,
+          "eval_metric": "logloss"}
+
+
+def _train(rounds=4, evals=False, **params):
+    X, y = _data()
+    kw = {}
+    if evals:
+        kw["evals"] = [(xgb.DMatrix(X[:300], label=y[:300]), "test")]
+    return xgb.train({**PARAMS, **params}, xgb.DMatrix(X, label=y), rounds,
+                     verbose_eval=False, **kw)
+
+
+class _Session:
+    """A ``jax.profiler`` session on CPU; ``spans()`` gives the program's
+    host spans as (name, start, end, stats) in time order."""
+
+    def __init__(self, tmp_path):
+        self.dir = str(tmp_path / "trace")
+
+    def __enter__(self):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+
+    def spans(self):
+        from jax.profiler import ProfileData
+
+        (path,) = glob.glob(os.path.join(self.dir, "plugins", "profile",
+                                         "*", "*.xplane.pb"))
+        out = []
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(("round", "train/")):
+                        out.append((e.name, e.start_ns,
+                                    e.start_ns + e.duration_ns,
+                                    dict(e.stats)))
+        return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+def _parent(spans, child):
+    """The tightest span that covers ``child`` (the enclosing span on the
+    thread), or None."""
+    covering = [s for s in spans if s is not child
+                and s[1] <= child[1] and child[2] <= s[2]]
+    return min(covering, key=lambda s: s[2] - s[1])[0] if covering else None
+
+
+@pytest.mark.parametrize("evals", [False, True], ids=["batched", "evals"])
+def test_profiler_session_sees_the_round_driver(tmp_path, evals):
+    _train(2, evals)                     # compile outside the session
+    with _Session(tmp_path) as session:
+        bst = _train(4, evals)
+        bst.get_dump()                   # forces the tree flush, if pending
+    spans = session.spans()
+    names = [s[0] for s in spans]
+    parents = {s[0]: _parent(spans, s) for s in spans}
+    assert names[0] == "train/call" and spans[0][3]["rounds"] == 4
+    assert parents["round"] == "train/call"
+    assert parents["round/guard"] == "round"
+    assert "round/flush" in names
+    for name, _s, _e, stats in spans:
+        assert "iteration" in stats, (name, stats)
+    rounds = [s for s in spans if s[0] == "round"]
+    if evals:
+        assert [s[3]["step_num"] for s in rounds] == [0, 1, 2, 3]
+        assert parents["round/fused"] == "round"
+        assert parents["round/eval"] == "round"
+        assert parents["round/eval/pull"] == "round/eval"
+        assert parents["round/callbacks"] == "round"
+        assert parents["round/flush"] == "round/eval"
+        assert "round/batch" not in names
+        assert names.count("round/eval") == 4
+    else:
+        assert [(s[3]["step_num"], s[3]["rounds"]) for s in rounds] == \
+            [(0, 4)]
+        assert parents["round/batch"] == "round"
+        assert "round/fused" not in names and "round/eval" not in names
+
+
+def test_general_path_and_bootstrap_have_spans(tmp_path):
+    X, y = _data()
+    dm = xgb.DMatrix(X, label=y)
+
+    def fobj(margin, dtrain):            # a custom objective: unfused path
+        p = 1.0 / (1.0 + np.exp(-margin))
+        return p - y, p * (1.0 - p)
+
+    bst = _train(2)
+    with _Session(tmp_path) as session:
+        xgb.train(PARAMS, dm, 2, obj=fobj, verbose_eval=False,
+                  xgb_model=bst)
+    names = [s[0] for s in session.spans()]
+    assert names.count("round/general") == 2
+    assert names.count("train/bootstrap") == 1      # a fresh DMatrix cache
+
+
+@pytest.mark.parametrize("mode", ["session", "ring"])
+def test_models_are_byte_identical_under_tracing(tmp_path, mode):
+    plain = [bytes(_train(4, e).save_raw()) for e in (False, True)]
+    if mode == "session":
+        with _Session(tmp_path):
+            traced = [bytes(_train(4, e).save_raw()) for e in (False, True)]
+    else:
+        tr.enable()
+        try:
+            traced = [bytes(_train(4, e).save_raw()) for e in (False, True)]
+            assert "round/batch" in {s.name for s in tr.tracer().spans()}
+        finally:
+            tr.disable()
+    assert traced == plain
+
+
+def test_span_site_without_a_session_costs_under_20us():
+    tr.disable()
+    for _ in range(2000):
+        with tr.span("round/fused", "train", {"iteration": 1}):
+            pass
+    n = 20000
+    t0 = time.perf_counter()
+    for i in range(n):
+        with tr.span("round/fused", "train", {"iteration": i}):
+            pass
+    per_site = (time.perf_counter() - t0) / n
+    assert per_site < 20e-6, f"{per_site * 1e6:.2f} us a span site"
+
+
+def test_round_counters_count_at_the_spans_boundaries():
+    reg = om.get_registry()
+    keys = [("xtpu_rounds_total", ()),
+            ("xtpu_round_dispatches_total",
+             (("program", "_fused_multi_round_fn"),)),
+            ("xtpu_round_dispatches_total",
+             (("program", "_fused_round_fn"),)),
+            ("xtpu_tree_flushes_total", ())]
+    before = [reg.get(*k) for k in keys]
+    _train(4).get_dump()
+    _train(3, evals=True)
+    after = [reg.get(*k) for k in keys]
+    assert [a - b for a, b in zip(after, before)] == [7, 1, 3, 4]
+    text = reg.render_prometheus()
+    assert "# TYPE xtpu_rounds_total counter" in text
+    assert 'xtpu_round_dispatches_total{program="_fused_round_fn"}' in text
+
+
+# ------------------------------------------------------------ stage scopes
+
+def test_unknown_stage_raises():
+    with pytest.raises(ValueError, match="nonsense"):
+        tr.stage("nonsense")
+    assert set(tr.ROUND_ROOTS) <= set(tr.STAGES)
+    assert tr.opened_stages() <= set(tr.STAGES)
+    assert "permute" in tr.STAGES and "kernel.scan_hist" in tr.STAGES
+    assert {"kernel." + k for k in tr.KERNELS} <= set(tr.STAGES)
+
+
+SCOPE = re.compile(r"xtpu\.[A-Za-z0-9_.]+")
+HEAVY = re.compile(r"= \S+ (gather|sort|custom-call)\(")
+
+
+def _check_scopes(hlo_text: str):
+    """Every scope in a compiled program's op names is in STAGES, and every
+    gather, sort and custom call sits under one; every scoped op names
+    only stages this process has opened and, where its path is whole (a
+    reducer's body carries a cut one), starts with one of ROUND_ROOTS: what
+    a trace reader holds a cache-served executable to. Returns the scopes
+    seen."""
+    seen, heavy = set(), 0
+    opened = {"xtpu." + s for s in tr.opened_stages()}
+    roots = {"xtpu." + s for s in tr.ROUND_ROOTS}
+    for line in hlo_text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        scopes = SCOPE.findall(m.group(1)) if m else []
+        seen.update(scopes)
+        assert set(scopes) <= opened, scopes
+        if scopes and m.group(1).startswith("jit("):
+            assert scopes[0] in roots, m.group(1)
+        if HEAVY.search(line):
+            heavy += 1
+            assert scopes, f"outside every stage: {line.strip()[:200]}"
+    unknown = {s for s in seen if s[len("xtpu."):] not in tr.STAGES}
+    assert not unknown, unknown
+    assert heavy, "no gather, sort or custom call in the program at all"
+    return seen
+
+
+def _round_program_text(hist_method: str, batched: bool, **params) -> str:
+    """Compiled text of the round program ``train`` runs under
+    ``hist_method`` (the batched driver's, or the per-round driver's)."""
+    from xgboost_tpu import core
+
+    fn = core._fused_multi_round_fn if batched else core._fused_round_fn
+    X, y = _data(n=700)
+    dm = xgb.DMatrix(X, label=y)
+    bst = xgb.Booster({**PARAMS, "hist_method": hist_method, **params})
+    bst._configure(dm)
+    state = bst._state_of(dm, is_train=True)
+    obj_params, grower, labels, weights, n_real = bst._fused_binding(state)
+    it = np.arange(2, dtype=np.int32) if batched else np.int32(0)
+    seed = it.astype(np.uint32) if batched else bst.ctx.raw_seed(0)
+    return fn.lower(
+        state["binned"].bins, state["margin"], labels, weights, n_real,
+        seed, it, grower.monotone, grower.constraint_sets, grower.cat,
+        obj_cls=type(bst.obj), obj_params=obj_params, param=grower.param,
+        max_nbins=grower.max_nbins, hist_method=grower.hist_method,
+        has_missing=grower.has_missing).compile().as_text()
+
+
+@pytest.mark.parametrize("hist_method, batched, want", [
+    ("auto", True, {"xtpu.grow", "xtpu.gradient", "xtpu.margin",
+                    "xtpu.leaf", "xtpu.eval", "xtpu.advance"}),
+    ("mega", True, {"xtpu.grow", "xtpu.sort", "xtpu.advance",
+                    "xtpu.count_sort", "xtpu.permute", "xtpu.delta"}),
+    ("scan", False, {"xtpu.sort", "xtpu.count_sort", "xtpu.permute",
+                     "xtpu.refine", "xtpu.window"}),
+    ("fused", False, {"xtpu.advance_hist", "xtpu.advance", "xtpu.hist"}),
+    ("coarse", False, {"xtpu.hist", "xtpu.refine", "xtpu.advance"}),
+    ("onehot", False, {"xtpu.hist", "xtpu.advance"}),
+])
+def test_round_programs_carry_only_known_scopes(hist_method, batched, want):
+    seen = _check_scopes(_round_program_text(hist_method, batched))
+    assert want <= seen, want - seen
+
+
+@pytest.mark.parametrize("hist_method", ["auto", "mega"])
+def test_lossguide_programs_open_known_stages_only(hist_method):
+    """The lossguide programs are not round programs of their own (the
+    host replays the pops); ``stage`` refusing an unknown name at trace
+    time is what holds their scopes to STAGES."""
+    bst = _train(2, grow_policy="lossguide", max_leaves=6, max_depth=0,
+                 hist_method=hist_method)
+    assert bst.num_boosted_rounds() == 2
+
+
+@pytest.mark.parametrize("kernel", ["build_hist_int8", "build_hist",
+                                    "fused_advance_coarse", "scan_hist"])
+def test_pallas_kernels_are_named_and_scoped(kernel):
+    """Interpret mode (no Mosaic on CPU): the kernel's name and its
+    ``xtpu.kernel.<name>`` scope are on the traced program, with the
+    quantise / permute / fold stages around it."""
+    from xgboost_tpu.ops.pallas import histogram as ph
+
+    rng = np.random.RandomState(0)
+    n, F, B, N = 256, 4, 32, 2
+    bins_t = jnp.asarray(rng.randint(0, B, (F, n)), jnp.uint8)
+    gpair = jnp.asarray(rng.randn(n, 2), jnp.float32)
+    pos = jnp.asarray(rng.randint(0, N, n), jnp.int32)
+    if kernel == "scan_hist":
+        fn = jax.jit(lambda b, g, p: ph.scan_hist_pallas(
+            b, g, p, N, B, missing_bin=B - 1, with_coarse=True,
+            interpret=True))
+        want = {"permute", "quantise", "count_sort", "fold"}
+    elif kernel == "fused_advance_coarse":
+        split = (jnp.zeros(1, jnp.int32), jnp.full(1, 7, jnp.int32),
+                 jnp.zeros(1, bool), jnp.ones(1, bool))
+        fn = jax.jit(lambda b, g, p: ph.fused_advance_coarse_pallas(
+            b, g, p * 0, *split, lo_prev=0, n_prev=1, lo=1, n_level=2,
+            missing_bin=B - 1, interpret=True))
+        want = {"quantise", "fold"}
+    else:
+        precision = "int8x2" if kernel == "build_hist_int8" else "f32"
+        fn = jax.jit(lambda b, g, p: ph.build_hist_pallas(
+            b, g, p, N, B, precision=precision, interpret=True))
+        want = {"fold"} | ({"quantise"} if precision == "int8x2" else set())
+    text = fn.lower(bins_t, gpair, pos).as_text(debug_info=True)
+    scopes = set(SCOPE.findall(text))
+    assert {"xtpu." + w for w in want} | {"xtpu.kernel." + kernel} <= scopes
+    assert not {s for s in scopes if s[len("xtpu."):] not in tr.STAGES}
+    assert _pallas_names(jax.make_jaxpr(fn)(bins_t, gpair, pos).jaxpr) == \
+        [kernel]
+
+
+def _pallas_names(jaxpr) -> list:
+    """``name=`` of every ``pallas_call`` in a jaxpr, nested ones too."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                out.extend(_pallas_names(inner))
+    return out
+
+
+# -------------------------------------------------------- compile counters
+
+def test_compile_counters_by_program():
+    """1 for a round program trained twice at one shape, 2 after a second
+    shape; the jitted helpers traced inside it (``_grow``, ``take``, ...)
+    are not booked beside it, and its trace+lower seconds hold theirs."""
+    def counts():
+        return om.program_compile_counts()
+
+    def delta(after, before, program, field):
+        zero = {"compiles": 0, "compile_s": 0.0, "cache_hits": 0,
+                "trace_lower_s": 0.0}
+        return (after.get(program, zero)[field]
+                - before.get(program, zero)[field])
+
+    prog = "_fused_multi_round_fn"
+    c0 = counts()
+    _train(4, max_bin=29)                # a shape no other test compiles
+    c1 = counts()
+    _train(4, max_bin=29)
+    c2 = counts()
+    assert delta(c1, c0, prog, "compiles") == 1
+    assert delta(c2, c1, prog, "compiles") == 0
+    assert delta(c1, c0, prog, "trace_lower_s") > 0
+    assert delta(c1, c0, prog, "compile_s") > 0
+    assert delta(c2, c1, prog, "trace_lower_s") == 0
+    # nested traces: _grow is jitted and traced inside the round program;
+    # only the outermost program is booked
+    assert delta(c1, c0, "_grow", "trace_lower_s") == 0
+    assert delta(c1, c0, "_grow", "compiles") == 0
+    _train(4, max_bin=27)
+    c3 = counts()
+    assert delta(c3, c0, prog, "compiles") == 2
+    assert delta(c3, c0, prog, "cache_hits") == 0
+    # the same program again with jax's in-memory caches dropped: traced
+    # anew and served by the persistent cache (conftest: every compile
+    # lands there), which jax's own event counts as a compile too
+    jax.clear_caches()
+    _train(4, max_bin=27)
+    c4 = counts()
+    assert delta(c4, c3, prog, "compiles") == 1
+    assert delta(c4, c3, prog, "cache_hits") == 1
+    assert delta(c4, c3, prog, "trace_lower_s") > 0
+    text = om.get_registry().render_prometheus()
+    assert f'xtpu_program_compiles_total{{program="{prog}"}}' in text
+    assert "# TYPE xtpu_program_trace_lower_seconds_total counter" in text

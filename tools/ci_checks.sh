@@ -40,15 +40,6 @@ echo "== validate_fleet (kill-one-replica, atomic fan-out, ring churn) =="
 JAX_PLATFORMS=cpu VALIDATE_FLEET_REQS="${VALIDATE_FLEET_REQS:-60}" \
     python tools/validate_fleet.py || exit $?
 
-echo "== perf_report smoke (--json path + budget gate wiring) =="
-# tiny shape: this checks the CI-wirable surface (json output parses,
-# budget comparison runs), not the drift numbers — CPU drift vs v5e
-# floors runs orders of magnitude above 1x, hence the proxy budget
-JAX_PLATFORMS=cpu python tools/perf_report.py --rows 20000 --rounds 1 \
-    --pages 2 --depth 4 --skip-overhead --skip-mega --json \
-    --budget 1e9 | python -c "import json,sys; d=json.load(sys.stdin); \
-assert 'rows' in d and 'stage_drift_max' in d['keys'], d" || exit $?
-
 echo "== tier-1 tests =="
 timeout -k 10 870 env JAX_PLATFORMS=cpu \
     python -m pytest tests/ -q -m 'not slow' \

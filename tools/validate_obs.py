@@ -1,9 +1,9 @@
 """Observability promotion gate: tracing must be invisible to training.
 
-xtpuobs instruments the hot paths in-line (host spans in the paged and
-lossguide drivers, ``jax.named_scope`` labels inside the fused dispatch,
-``obs.trace.sync`` barriers that are armed only in measurement mode), so
-the load-bearing contract is that NONE of it perturbs numerics: training
+xtpuobs instruments the hot paths in-line (host spans in the round,
+paged and lossguide drivers, ``obs.trace.stage`` scopes inside the fused
+dispatch), so the load-bearing contract is that NONE of it perturbs
+numerics: training
 with ``XTPU_TRACE=1`` must produce **byte-identical** ``save_raw``
 artifacts to an untraced run, in every tier whose driver the tracer
 touches. This gate trains each cell twice — tracing off, then on — and
@@ -90,8 +90,8 @@ def _cell_mega(X, y, rounds):
 
 def _train_paged(X, y, rounds):
     """Genuinely streamed paged training: iterator + cache prefix, page
-    cache off, collapse off — the driver whose stage spans + sync
-    barriers perf_report times is exactly the one under test here."""
+    cache off, collapse off — the driver that carries the per-level
+    ``paged/*`` stage spans is exactly the one under test here."""
     from xgboost_tpu.data.dmatrix import DataIter
 
     n_pages = 3

@@ -15,8 +15,7 @@ contains a call to a name visibly bound to ``jax.jit`` (assignment,
 synchronization between the LAST jitted call and the timer stop.
 Recognized syncs: ``block_until_ready`` / ``jax.device_get`` /
 ``np.asarray``/``np.array`` / ``float``/``int``/``bool`` coercion /
-``.item()`` / the repo's ``fetch_struct``/``fetch_packed`` helpers /
-``obs.trace.sync``.
+``.item()`` / the repo's ``fetch_struct``/``fetch_packed`` helpers.
 
 Only names *visibly* jit-bound in the same module are considered, so
 timers around opaque callables (kernels stashed in caches or passed in
@@ -44,7 +43,7 @@ _SYNC_CALLS = {"jax.block_until_ready", "block_until_ready",
                "np.asarray", "np.array", "numpy.asarray", "numpy.array",
                "float", "int", "bool",
                "fetch_struct", "fetch_packed"}
-_SYNC_ATTRS = {"item", "block_until_ready", "sync", "sync_on"}
+_SYNC_ATTRS = {"item", "block_until_ready", "sync_on"}
 _PARTIALS = {"functools.partial", "partial"}
 
 

@@ -8,6 +8,7 @@ ids in ``tree_info`` and per-iteration offsets in ``iteration_indptr``.
 
 from __future__ import annotations
 
+import bisect
 from typing import List, Optional
 
 import os
@@ -17,6 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..data.binned import BinnedMatrix
+from ..obs import trace as _trace
+from ..obs.metrics import count_tree_flush
 from ..registry import BOOSTERS
 from ..tree.grow import GrownTree, TreeGrower
 from ..tree.param import TrainParam
@@ -192,18 +195,23 @@ class GBTree:
                    if isinstance(t, _PendingTree)]
         if not pending:
             return
-        # round-batched trees share one stacked-array dict — fetch each
-        # distinct dict once, then slice host-side
-        unique: dict = {}
-        for _, t in pending:
-            unique.setdefault(id(t.arrays), t.arrays)
-        fetched = dict(zip(unique.keys(),
-                           _fetch_packed(list(unique.values()))))
-        for i, t in pending:
-            arrs = fetched[id(t.arrays)]
-            if t.index is not None:
-                arrs = {k: v[t.index] for k, v in arrs.items()}
-            self._trees[i] = t.grower.to_tree_model(_HostGrown(arrs))
+        # the round the oldest pending tree was boosted in
+        first = bisect.bisect_right(self.iteration_indptr, pending[0][0]) - 1
+        with _trace.span("round/flush", "train",
+                         {"iteration": first, "trees": len(pending)}):
+            # round-batched trees share one stacked-array dict — fetch each
+            # distinct dict once, then slice host-side
+            unique: dict = {}
+            for _, t in pending:
+                unique.setdefault(id(t.arrays), t.arrays)
+            fetched = dict(zip(unique.keys(),
+                               _fetch_packed(list(unique.values()))))
+            for i, t in pending:
+                arrs = fetched[id(t.arrays)]
+                if t.index is not None:
+                    arrs = {k: v[t.index] for k, v in arrs.items()}
+                self._trees[i] = t.grower.to_tree_model(_HostGrown(arrs))
+        count_tree_flush()
 
     def _vertical_federated(self) -> bool:
         from ..parallel import collective

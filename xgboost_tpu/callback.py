@@ -16,6 +16,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from .logging_utils import console
+from .obs import trace as _trace
 from .obs.insight import TrainingLog
 
 EvalsLog = Dict[str, Dict[str, List[float]]]
@@ -59,8 +60,9 @@ class CallbackContainer:
         return model
 
     def before_iteration(self, model, epoch: int) -> bool:
-        return any(cb.before_iteration(model, epoch, self.history)
-                   for cb in self.callbacks)
+        with _trace.span("round/callbacks", "train", {"iteration": epoch}):
+            return any(cb.before_iteration(model, epoch, self.history)
+                       for cb in self.callbacks)
 
     def after_iteration(self, model, epoch: int, evals) -> bool:
         if evals:
@@ -75,8 +77,10 @@ class CallbackContainer:
                     self.history.setdefault(
                         data_name, collections.OrderedDict()).setdefault(
                             metric_name, []).append(score)
-        return any(cb.after_iteration(model, epoch, self.history)
-                   for cb in self.callbacks)
+        # the eval above carries its own span (``round/eval``)
+        with _trace.span("round/callbacks", "train", {"iteration": epoch}):
+            return any(cb.after_iteration(model, epoch, self.history)
+                       for cb in self.callbacks)
 
 
 def _parse_eval_str(msg: str):

@@ -30,8 +30,9 @@ from .objective.base import _nan_policy
 from .tree.param import TrainParam
 from .utils import observer
 from .obs import memory as obs_memory
-from .obs.metrics import count_degrade
+from .obs.metrics import count_degrade, count_round_dispatch
 from .obs import trace as obs_trace
+from .obs.trace import stage
 from .utils.timer import Monitor
 
 _VERSION = (0, 1, 0)
@@ -85,7 +86,10 @@ def _check_margin_finite(margin, n_valid: int, objective: str,
     # insight-armed rounds pass the guard scalar in (they pull it once and
     # reuse it as the telemetry NaN-guard count — still exactly one guard
     # dispatch per round)
-    bad = int(bad if bad is not None else _margin_bad_rows(margin, n_valid))
+    # the blocking pull: the host waits here for the round program
+    with obs_trace.span("round/guard", "train", {"iteration": first_round}):
+        bad = int(bad if bad is not None
+                  else _margin_bad_rows(margin, n_valid))
     if not bad:
         return
     where = (f"round {first_round}" if n_rounds == 1 else
@@ -124,28 +128,34 @@ def _fused_round_body(margin, seed, iteration, bins, labels, weights,
 
     obj = obj_cls(dict(obj_params))
     sinfo = types.SimpleNamespace(labels=labels, weights=weights)
-    gpair = obj.get_gradient(margin, sinfo, 0)
+    with stage("gradient"):
+        gpair = obj.get_gradient(margin, sinfo, 0)
     K = gpair.shape[1]
 
     if K == 1:
         # general path key discipline: tkey = fold_in(key, k * npt + p),
         # npt == 1, p == 0, k == 0 on this path
         tkey = jax.random.fold_in(key, 0)
-        gp = sample_gradients(gpair[:, 0, :], tkey, param)
+        with stage("gradient"):
+            gp = sample_gradients(gpair[:, 0, :], tkey, param)
         tree_mask = _sample_features(jax.random.fold_in(tkey, 0xC0),
                                      n_real > 0, param.colsample_bytree)
         gkey = jax.random.fold_in(tkey, 0x5EED)
-        grown = _grow(bins, gp, n_real, tree_mask, gkey, monotone,
-                      constraint_sets, cat, param=param, max_nbins=max_nbins,
-                      hist_method=hist_method, axis_name=None,
-                      has_missing=has_missing)
-        return margin + grown.delta[:, None], grown
+        with stage("grow"):     # outermost: nothing inside a tree falls out
+            grown = _grow(bins, gp, n_real, tree_mask, gkey, monotone,
+                          constraint_sets, cat, param=param,
+                          max_nbins=max_nbins, hist_method=hist_method,
+                          axis_name=None, has_missing=has_missing)
+        with stage("margin"):
+            return margin + grown.delta[:, None], grown
 
-    stacked, delta = _grow_classes_scan(
-        bins, gpair, n_real, key, monotone, constraint_sets, cat,
-        param=param, max_nbins=max_nbins, hist_method=hist_method,
-        has_missing=has_missing)
-    return margin + delta, stacked
+    with stage("grow"):
+        stacked, delta = _grow_classes_scan(
+            bins, gpair, n_real, key, monotone, constraint_sets, cat,
+            param=param, max_nbins=max_nbins, hist_method=hist_method,
+            has_missing=has_missing)
+    with stage("margin"):
+        return margin + delta, stacked
 
 
 @_functools.partial(
@@ -927,16 +937,21 @@ class Booster:
         # with the version cache, src/gbm/gbtree.cc:506-544)
         total = self.gbm.version()
         if state["n_trees"] < total:
-            if self.gbm.supports_margin_cache:
-                # raw-threshold walk, NOT the binned fast path: loaded trees
-                # may have been grown against different quantile cuts, so
-                # their split_bin indices are meaningless here (same reason
-                # the eval path falls back to raw for loaded models)
-                delta = self.gbm.margin_delta_raw(
-                    np.asarray(state["dm"].values()), state["n_trees"], total)
-                state["margin"] = state["margin"] + jnp.asarray(delta)
-            else:
-                state["margin"] = self.gbm.compute_margin(state)
+            with obs_trace.span("train/bootstrap", "train",
+                                {"iteration": iteration,
+                                 "trees": total - state["n_trees"]}):
+                if self.gbm.supports_margin_cache:
+                    # raw-threshold walk, NOT the binned fast path: loaded
+                    # trees may have been grown against different quantile
+                    # cuts, so their split_bin indices are meaningless here
+                    # (same reason the eval path falls back to raw for
+                    # loaded models)
+                    delta = self.gbm.margin_delta_raw(
+                        np.asarray(state["dm"].values()), state["n_trees"],
+                        total)
+                    state["margin"] = state["margin"] + jnp.asarray(delta)
+                else:
+                    state["margin"] = self.gbm.compute_margin(state)
             state["n_trees"] = total
         if fobj is None and self._fused_step(state, iteration):
             if obs_memory.enabled():
@@ -982,10 +997,14 @@ class Booster:
             observer.observe("gpair", gpair, iteration)
         key = self.ctx.make_key(iteration)
         _prior_trees = len(getattr(self.gbm, "_trees", ()))
-        with self._monitor.section("BoostOneIter"):
+        # the unfused path has its span too, so a degrade shows in a trace
+        with obs_trace.span("round/general", "train",
+                            {"iteration": iteration}), \
+                self._monitor.section("BoostOneIter"):
             delta = self.gbm.do_boost(state, gpair, iteration,
                                       jax.random.fold_in(key, iteration),
                                       obj=self.obj, margin=margin)
+        count_round_dispatch("general")
         with self._monitor.section("UpdateCache"):
             if self.gbm.supports_margin_cache:
                 state["margin"] = state["margin"] + delta
@@ -1032,7 +1051,8 @@ class Booster:
             ins = self._insight_binding(state, obj_params)
         if ins is not None:
             try:
-                with obs_trace.span("round/fused"):
+                with obs_trace.span("round/fused", "train",
+                                    {"iteration": iteration}):
                     (new_margin, grown, telem, new_ems,
                      partials) = _fused_round_insight_fn(
                         binned.bins, state["margin"], labels, weights,
@@ -1060,6 +1080,7 @@ class Booster:
                 count_degrade("insight_disarm")
                 self._recover_donated_margin(state)
                 return self._fused_step(state, iteration)
+            count_round_dispatch("_fused_round_insight_fn")
             # the guard reduction doubles as the NaN-guard telemetry
             # counter — still exactly the budgeted 2 dispatches per round
             bad = _margin_bad_rows(new_margin, state["n_valid"])
@@ -1080,11 +1101,12 @@ class Booster:
                                      partials, bad)
             return True
 
-        # hot path: obs_trace.span returns a shared no-op when tracing
-        # is off — tests/test_obs.py pins this to zero allocations. An
-        # error of this program propagates: a general-path round standing
-        # in for it would go unnoticed.
-        with obs_trace.span("round/fused"):
+        # hot path: with no profiler session the span is one inert
+        # annotation (tests/test_obs.py bounds its cost). An error of this
+        # program propagates: a general-path round standing in for it
+        # would go unnoticed.
+        with obs_trace.span("round/fused", "train",
+                            {"iteration": iteration}):
             new_margin, grown = _fused_round_fn(
                 binned.bins, state["margin"], labels, weights, n_real,
                 self.ctx.raw_seed(iteration), np.int32(iteration),
@@ -1094,6 +1116,7 @@ class Booster:
                 hist_method=grower.hist_method,
                 has_missing=grower.has_missing,
                 nan_policy=_nan_policy())
+        count_round_dispatch("_fused_round_fn")
         _check_margin_finite(new_margin, state["n_valid"], self.obj.name,
                              iteration)
         if isinstance(grown, dict):     # multiclass: stacked [K] class axis
@@ -1333,15 +1356,19 @@ class Booster:
         seeds = np.asarray([self.ctx.raw_seed(i) for i in iterations],
                            np.uint32)
         iters = np.asarray(list(iterations), np.int32)
-        new_margin, growns = _fused_multi_round_fn(
-            binned.bins, state["margin"], labels, weights, n_real,
-            seeds, iters,
-            grower.monotone, grower.constraint_sets, grower.cat,
-            obj_cls=type(self.obj), obj_params=obj_params,
-            param=grower.param, max_nbins=grower.max_nbins,
-            hist_method=grower.hist_method,
-            has_missing=grower.has_missing,
-            nan_policy=_nan_policy())
+        with obs_trace.span("round/batch", "train",
+                            {"iteration": int(iters[0]),
+                             "rounds": len(iters)}):
+            new_margin, growns = _fused_multi_round_fn(
+                binned.bins, state["margin"], labels, weights, n_real,
+                seeds, iters,
+                grower.monotone, grower.constraint_sets, grower.cat,
+                obj_cls=type(self.obj), obj_params=obj_params,
+                param=grower.param, max_nbins=grower.max_nbins,
+                hist_method=grower.hist_method,
+                has_missing=grower.has_missing,
+                nan_policy=_nan_policy())
+        count_round_dispatch("_fused_multi_round_fn", len(iters))
         _check_margin_finite(new_margin, state["n_valid"], self.obj.name,
                              int(iters[0]), len(iters))
         # all R x Kc trees share ONE stacked-array dict; _flush fetches it
@@ -1666,7 +1693,12 @@ class Booster:
                  feval: Optional[Callable] = None,
                  output_margin: bool = True) -> str:
         """Evaluate on a list of (DMatrix, name); returns the reference-format
-        line ``[i]\\tname-metric:value...`` (``src/learner.cc:1307-1342``).
+        line ``[i]\\tname-metric:value...`` (``src/learner.cc:1307-1342``)."""
+        with obs_trace.span("round/eval", "train", {"iteration": iteration}):
+            return self._eval_set(evals, iteration, feval, output_margin)
+
+    def _eval_set(self, evals, iteration, feval, output_margin) -> str:
+        """``eval_set`` under its span.
 
         Three tiers, cheapest first: (1) scores the insight-armed fused
         round already computed IN-CARRY for this iteration (obs/insight.py
@@ -1687,7 +1719,7 @@ class Booster:
                         score = ins["scores"][(name, metric.full_name)]
                         msg += f"\t{name}-{metric.full_name}:{score:.6f}"
                 return msg
-            scores = self._batched_eval_scores(evals)
+            scores = self._batched_eval_scores(evals, iteration)
             if scores is not None:
                 msg = f"[{iteration}]"
                 for _, name in evals:
@@ -1734,7 +1766,8 @@ class Booster:
                     msg += f"\t{name}-{mname}:{val:.6f}"
         return msg
 
-    def _batched_eval_scores(self, evals: Sequence[Tuple[DMatrix, str]]
+    def _batched_eval_scores(self, evals: Sequence[Tuple[DMatrix, str]],
+                             iteration: int
                              ) -> Optional[Dict[Tuple[str, str], float]]:
         """Score every (DMatrix, metric) pair through ONE
         ``_eval_partials_fn`` dispatch; None -> caller uses the host loop.
@@ -1786,7 +1819,9 @@ class Booster:
             tuple(margins), tuple(labels), tuple(weights),
             obj_cls=type(self.obj), obj_params=obj_params,
             specs=specs, rows=tuple(rows))
-        host = jax.device_get(parts)
+        with obs_trace.span("round/eval/pull", "train",
+                            {"iteration": iteration}):
+            host = jax.device_get(parts)
         out: Dict[Tuple[str, str], float] = {}
         for di, (dm, name) in enumerate(evals):
             for mi, metric in enumerate(self._eval_metrics):
@@ -2292,7 +2327,6 @@ def train(params: Dict[str, Any], dtrain: DMatrix,
     bit-exactly (``tools/validate_resume.py`` gates this)."""
     from .callback import (CallbackContainer, EarlyStopping,
                            EvaluationMonitor)
-    from .parallel import collective
 
     from .obs import insight as obs_insight
 
@@ -2352,6 +2386,26 @@ def train(params: Dict[str, Any], dtrain: DMatrix,
         # margin update + metric partials into the fused round program
         bst._insight_evals = list(evals)
 
+    with obs_trace.span("train/call", "train",
+                        {"iteration": bst.num_boosted_rounds(),
+                         "rounds": num_boost_round}):
+        bst = _train_rounds(bst, dtrain, num_boost_round, container, evals,
+                            obj, batchable, ck, resumed)
+    bst._monitor.maybe_print()  # one cumulative table (reference: destructor)
+
+    if evals_result is not None:
+        evals_result.update(container.history)
+    return bst
+
+
+def _train_rounds(bst: Booster, dtrain: DMatrix, num_boost_round: int,
+                  container, evals, obj, batchable: bool, ck,
+                  resumed) -> Booster:
+    """``train``'s round loop, under its ``train/call`` span. Each iteration
+    is one ``round`` step span (``step_num`` = its first round, ``rounds`` =
+    how many it boosts), so a profiler trace reads in rounds."""
+    from .parallel import collective
+
     bst = container.before_training(bst)
     start = bst.num_boosted_rounds()
     # Largest power-of-two chunks <= XTPU_BATCH_ROUNDS: each chunk is one
@@ -2373,17 +2427,22 @@ def train(params: Dict[str, Any], dtrain: DMatrix,
                 lim = min(lim, ck.rounds_to_boundary(i))
             if batchable and lim >= 2:
                 k = 1 << (lim.bit_length() - 1)
-                if bst.update_batch(dtrain, list(range(i, i + k))):
+                with obs_trace.span("round", "train",
+                                    {"iteration": i, "rounds": k}, step=i):
+                    batched = bst.update_batch(dtrain, list(range(i, i + k)))
+                if batched:
                     i += k
                     if ck is not None:
                         ck.maybe_save(bst, dtrain, i, force=(i == end))
                     continue
                 # config needs the per-round path (or a continuation
                 # bootstrap round) — fall through; retried next iteration
-            if container.before_iteration(bst, i):
-                break
-            bst.update(dtrain, i, fobj=obj)
-            stop = container.after_iteration(bst, i, list(evals))
+            with obs_trace.span("round", "train",
+                                {"iteration": i, "rounds": 1}, step=i):
+                if container.before_iteration(bst, i):
+                    break
+                bst.update(dtrain, i, fobj=obj)
+                stop = container.after_iteration(bst, i, list(evals))
             i += 1
             if ck is not None:
                 ck.maybe_save(bst, dtrain, i, force=(stop or i == end))
@@ -2402,9 +2461,4 @@ def train(params: Dict[str, Any], dtrain: DMatrix,
         # newest snapshot stale, so here write failures DO surface
         if ck is not None:
             ck.close(raise_errors=True)
-    bst = container.after_training(bst)
-    bst._monitor.maybe_print()  # one cumulative table (reference: destructor)
-
-    if evals_result is not None:
-        evals_result.update(container.history)
-    return bst
+    return container.after_training(bst)

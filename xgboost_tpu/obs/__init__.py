@@ -2,9 +2,10 @@
 
 Five instruments, one taxonomy:
 
-- :mod:`~xgboost_tpu.obs.trace` — ring-buffered host spans paired with
-  device-timeline annotations; ``XTPU_TRACE=1`` turns it on, export is
-  Chrome/Perfetto JSON or jsonl.
+- :mod:`~xgboost_tpu.obs.trace` — program spans as profiler annotations
+  and ``xtpu.<stage>`` scopes inside the compiled programs (the switch
+  is the ``jax.profiler`` session); ``XTPU_TRACE=1`` also records the
+  spans into a ring exported as Chrome/Perfetto JSON or jsonl.
 - :mod:`~xgboost_tpu.obs.metrics` — the process-wide
   :class:`MetricsRegistry` every counting subsystem registers into;
   rendered as Prometheus text exposition on serve's ``GET /metrics``.
@@ -25,10 +26,9 @@ Five instruments, one taxonomy:
   :class:`TrainingLog`, and the model inspector / diff backing
   ``tools/model_report.py`` and the pipeline's gate-rejection reports.
 
-``tools/perf_report.py`` joins the measured spans against
-``tools/roofline.py`` floors into the stage-drift table;
 ``tools/trace_analyze.py`` computes overlap/straggler reports from
-exported rings.
+exported rings; ``benchmark/lib/program_trace.py`` reads the spans and
+scopes out of a profiler trace.
 """
 
 from . import flight, insight, memory, metrics, trace

@@ -213,7 +213,8 @@ class FlightRecorder:
     def span(self, name: str, cat: str = "",
              args: Optional[Dict[str, Any]] = None):
         t = self.tracer
-        return _trace._NULL if t is None else t.span(name, cat, args)
+        return _trace.span(name, cat, args) if t is None \
+            else t.span(name, cat, args)
 
     def adopt_current_thread(self) -> None:
         """Attribute the calling thread's spans in the SHARED global ring

@@ -1,26 +1,31 @@
-"""Low-overhead span tracing: ring-buffered host spans + device pairing.
+"""Program spans and stage scopes: names that reach the profiler's trace.
 
-One process-wide :class:`Tracer` records host-side spans — stage names
-like ``paged/hist`` or ``serve/compute`` with wall-clock start/end —
-into a fixed-capacity ring, and pairs every span with a
-``jax.profiler.TraceAnnotation`` so the same stage names show up on the
-device timeline when a ``jax.profiler`` capture is running. Host spans
-around a *jitted* region measure dispatch + any sync the caller already
-does (see docs/observability.md for which stages are device-synced);
-stages *inside* one jitted program are labeled with ``jax.named_scope``
-at trace time instead (``tree/grow.py``) and only appear in device
-profiles.
+**The switch is the profiler session.** Every ``span()`` site opens a
+``jax.profiler.TraceAnnotation`` (a ``StepTraceAnnotation`` when ``step=``
+is given): with no ``jax.profiler`` session running that is one inert
+object, about a third of a microsecond a site; with one running, the span
+lands in the trace's host plane on the profiler's own clock, beside the
+device lines, with its ``args`` as stats. Nothing has to be set before
+import for that.
 
-Tracing is OFF by default and the disabled path is free: ``span()``
-returns a shared no-op context manager without allocating, so the
-resident hot loop (one ``_fused_step`` dispatch per round) pays one
-predicate per span site and nothing else — ``tests/test_obs.py``
-pins this to literally zero allocations.
+Stages *inside* one jitted program are named at trace time with
+:func:`stage` (``jax.named_scope("xtpu.<stage>")``): HLO metadata only,
+no value changes. On a TPU the scope path arrives in the trace as the
+``tf_op`` stat of each op's event metadata; a device op belongs to the
+innermost ``xtpu.`` scope on that path. :data:`STAGES` lists every stage
+the program may emit, with its nesting.
 
-Knobs (read at import; flip programmatically with
+The **ring** is the operator's offline exporter, on the process clock
+(``time.perf_counter``): one process-wide :class:`Tracer` that, when
+enabled, also records every span into a fixed-capacity ring for export as
+Chrome/Perfetto JSON or jsonl, for the flight recorder's merged timelines
+and the crash black box (``obs/flight.py``). It is OFF by default; a span
+then leaves no ring record.
+
+Knobs of the ring (read at import; flip programmatically with
 :func:`enable` / :func:`disable` mid-process):
 
-- ``XTPU_TRACE``      — ``1`` enables tracing (default ``0``).
+- ``XTPU_TRACE``      — ``1`` enables the ring (default ``0``).
 - ``XTPU_TRACE_BUF``  — ring capacity in spans (default ``65536``);
   the ring keeps the newest spans when it wraps.
 - ``XTPU_TRACE_OUT``  — path to auto-export on process exit;
@@ -30,15 +35,73 @@ Knobs (read at import; flip programmatically with
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 __all__ = ["Span", "Tracer", "enable", "disable", "enabled", "tracer",
-           "span", "instant", "export", "reset", "sync", "set_sync",
-           "set_identity"]
+           "span", "instant", "export", "reset", "set_identity",
+           "STAGES", "ROUND_ROOTS", "stage", "opened_stages"]
+
+# Every ``xtpu.<stage>`` scope a compiled program may carry. A device op
+# belongs to the INNERMOST one on its path. Nesting, outermost first:
+#
+#   gradient | grow | leaf | margin          the round programs (core.py)
+#     grow >  sort | advance_hist | hist     one data sweep of a level
+#               > advance | count_sort | permute | quantise
+#                 | kernel.<name> | fold
+#             exchange | window | refine | eval | delta | advance
+#     grow >  root | pop | apply | push | finalize     (lossguide), with
+#             hist / eval and the sweep's stages inside
+KERNELS = ("build_hist", "build_hist_int8", "fused_advance_coarse",
+           "scan_hist")         # the round programs' kernels, by ``name=``
+STAGES = (
+    "gradient", "grow", "leaf", "margin",
+    "sort", "advance_hist", "hist",
+    "advance", "count_sort", "permute", "quantise", "fold",
+    "exchange", "window", "refine", "eval", "delta",
+    "root", "pop", "apply", "push", "finalize",
+) + tuple("kernel." + k for k in KERNELS)
+_STAGE_SET = frozenset(STAGES)
+
+# What a reader of a device trace holds an executable's scopes to. jax's
+# persistent compile cache leaves metadata out of its key, so an executable
+# it serves carries the scopes of the source that WROTE the entry. Every
+# scoped op of a round program starts its path with one of ROUND_ROOTS
+# (``core._fused_round_body`` opens nothing else at its top), and names
+# only stages this process has opened: an op that does neither proves an
+# executable of other source.
+ROUND_ROOTS = ("gradient", "grow", "leaf", "margin")
+_opened: set = set()
+
+
+@contextlib.contextmanager
+def _stage(name: str):
+    _opened.add(name)
+    with jax.named_scope("xtpu." + name):
+        yield
+
+
+def stage(name: str):
+    """``jax.named_scope("xtpu.<name>")`` for a stage in :data:`STAGES`
+    (a context manager, or a decorator); any other name raises at trace
+    time, so a scope no reader knows cannot be added by accident."""
+    if name not in _STAGE_SET:
+        raise ValueError(f"unknown stage {name!r}: add it to "
+                         "xgboost_tpu.obs.trace.STAGES (and to the readers "
+                         "under benchmark/lib/program_trace.py)")
+    return _stage(name)
+
+
+def opened_stages() -> frozenset:
+    """The stages this process has opened while tracing, so far."""
+    return frozenset(_opened)
 
 
 class Span:
@@ -70,43 +133,21 @@ class Span:
         return d
 
 
-class _NullSpan:
-    """Shared no-op context manager — the entire disabled path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL = _NullSpan()
-
-
 class _LiveSpan:
     """Enabled-path context manager: one per ``with span(...)`` block."""
 
     __slots__ = ("_tr", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
-                 args: Optional[Dict[str, Any]]):
+                 args: Optional[Dict[str, Any]], ann):
         self._tr = tr
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = ann
 
     def __enter__(self):
-        ann_cls = self._tr._ann_cls
-        if ann_cls is not None:
-            try:
-                self._ann = ann_cls(self.name)
-                self._ann.__enter__()
-            except Exception:  # pragma: no cover - profiler unavailable
-                self._ann = None
-        else:
-            self._ann = None
+        self._ann.__enter__()
         tl = self._tr._tl
         tl.depth = getattr(tl, "depth", 0) + 1
         self._t0 = time.perf_counter()
@@ -117,8 +158,7 @@ class _LiveSpan:
         tl = self._tr._tl
         depth = getattr(tl, "depth", 1)
         tl.depth = depth - 1
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
+        self._ann.__exit__(*exc)
         self._tr._record(Span(self.name, self.cat, self._t0, t1,
                               depth - 1, threading.get_ident(), self.args))
         return False
@@ -127,8 +167,7 @@ class _LiveSpan:
 class Tracer:
     """Fixed-capacity ring of :class:`Span` records."""
 
-    def __init__(self, capacity: int = 65536,
-                 annotate_device: bool = True) -> None:
+    def __init__(self, capacity: int = 65536) -> None:
         self.capacity = max(int(capacity), 1)
         self._buf: List[Optional[Span]] = [None] * self.capacity
         self._n = 0                       # total spans ever recorded
@@ -137,13 +176,6 @@ class Tracer:
         self._epoch = time.perf_counter()  # export time base
         self.rank: Optional[int] = None    # distributed identity (flight)
         self.world: Optional[int] = None
-        self._ann_cls = None
-        if annotate_device:
-            try:
-                import jax.profiler
-                self._ann_cls = jax.profiler.TraceAnnotation
-            except Exception:  # pragma: no cover - jax-less analysis use
-                self._ann_cls = None
 
     def set_identity(self, rank: int, world: int) -> None:
         """Tag this ring with its ``(rank, world)`` — exported spans and
@@ -154,8 +186,9 @@ class Tracer:
 
     # ------------------------------------------------------------ recording
     def span(self, name: str, cat: str = "",
-             args: Optional[Dict[str, Any]] = None) -> _LiveSpan:
-        return _LiveSpan(self, name, cat, args)
+             args: Optional[Dict[str, Any]] = None,
+             step: Optional[int] = None) -> _LiveSpan:
+        return _LiveSpan(self, name, cat, args, _annotation(name, args, step))
 
     def instant(self, name: str, cat: str = "",
                 args: Optional[Dict[str, Any]] = None) -> None:
@@ -264,14 +297,25 @@ def tracer() -> Optional[Tracer]:
     return _tracer
 
 
-def span(name: str, cat: str = "", args: Optional[Dict[str, Any]] = None):
-    """The one instrumentation entry point. Disabled: returns a shared
-    no-op context manager (no allocation). Enabled: records a host span
-    and mirrors it onto the device timeline."""
+def _annotation(name: str, args: Optional[Dict[str, Any]],
+                step: Optional[int]):
+    if step is not None:
+        return StepTraceAnnotation(name, step_num=step, **(args or {}))
+    return TraceAnnotation(name, **args) if args else TraceAnnotation(name)
+
+
+def span(name: str, cat: str = "", args: Optional[Dict[str, Any]] = None,
+         step: Optional[int] = None):
+    """The one instrumentation entry point: a context manager that puts
+    ``name`` (with ``args`` as stats) on the profiler's host timeline when
+    a ``jax.profiler`` session is running, and is one inert annotation
+    when none is. ``step``: make it a ``StepTraceAnnotation`` with that
+    ``step_num`` (the ``round`` span). With the ring enabled the span is
+    also recorded there, on the process clock."""
     t = _tracer
     if t is None:
-        return _NULL
-    return t.span(name, cat, args)
+        return _annotation(name, args, step)
+    return t.span(name, cat, args, step)
 
 
 def instant(name: str, cat: str = "",
@@ -304,34 +348,6 @@ def set_identity(rank: int, world: int) -> None:
     t = _tracer
     if t is not None:
         t.set_identity(rank, world)
-
-
-_SYNC = os.environ.get("XTPU_TRACE_SYNC", "0") not in ("0", "")
-
-
-def set_sync(on: bool) -> None:
-    """Toggle measurement-sync mode (see :func:`sync`)."""
-    global _SYNC
-    _SYNC = bool(on)
-
-
-def sync(x):
-    """Measurement barrier: block on ``x`` before the enclosing span
-    closes — but ONLY when tracing is enabled AND sync mode is on
-    (``XTPU_TRACE_SYNC=1`` or :func:`set_sync`). The paged/lossguide
-    drivers dispatch stages asynchronously, so their host spans normally
-    time the *dispatch*; ``tools/perf_report.py`` flips sync mode on so
-    those same spans time the *stage* against the roofline floors.
-    Returns ``x`` unchanged; a no-op on both the disabled and the
-    enabled-but-async paths."""
-    if _tracer is not None and _SYNC:
-        try:
-            import jax
-
-            jax.block_until_ready(x)
-        except Exception:  # pragma: no cover - non-array payloads
-            pass
-    return x
 
 
 def _default_capacity() -> int:

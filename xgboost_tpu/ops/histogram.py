@@ -27,6 +27,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import stage
+
 
 def unpack_u4(packed: jnp.ndarray, n_features: int) -> jnp.ndarray:
     """Decode a u4-packed bin page (compressed page transport,
@@ -171,9 +173,10 @@ def build_hist_scan(bins: jnp.ndarray, gpair: jnp.ndarray,
         return fine
     if order is None:
         order = counting_sort_by_node(rel_pos, n_nodes)
-    bins_s = jnp.take(bins, order, axis=0)
-    gp_s = jnp.take(gpair, order, axis=0)
-    rel_s = jnp.take(rel_pos, order)
+    with stage("permute"):
+        bins_s = jnp.take(bins, order, axis=0)
+        gp_s = jnp.take(gpair, order, axis=0)
+        rel_s = jnp.take(rel_pos, order)
     return _segment_hist_acc(bins_s, gp_s, rel_s, n_nodes, max_nbins, acc)
 
 
@@ -401,6 +404,33 @@ def build_hist_multi(bins: jnp.ndarray, gpair3: jnp.ndarray,
 # the f32 advance operand and the coarse ids in-trace, so neither copy is
 # ever materialised in HBM.
 
+@stage("advance")
+def _advance_below(bins: jnp.ndarray, positions: jnp.ndarray, prev: dict,
+                   missing_bin: int, decision_axis) -> jnp.ndarray:
+    """The row decision of a boundary sweep: advance rows below the
+    PREVIOUS level's decoded splits (``prev``: ``fused_advance_coarse``
+    docstring). Pure integer routing, shared by the fused and scan
+    sweeps, so their positions are bit-identical."""
+    from .partition import advance_positions_level, update_positions
+
+    lo_prev, nl_prev = prev["lo"], prev["n_level"]
+    if prev["kind"] == "dense":
+        feat, thr, dleft, cs = prev["arrs"]
+        rel_prev = jnp.where(
+            (positions >= lo_prev) & (positions < lo_prev + nl_prev),
+            positions - lo_prev, nl_prev).astype(jnp.int32)
+        # f32 operand computed IN the trace: XLA fuses the upcast into the
+        # matmul read — no materialised [n, F] f32 copy
+        return advance_positions_level(
+            bins.astype(jnp.float32), positions, rel_prev, feat, thr,
+            dleft, cs, missing_bin, decision_axis=decision_axis)
+    sf, sb, dl, isf = prev["arrs"]
+    return update_positions(
+        bins, positions, sf, sb, dl, isf, missing_bin,
+        decision_axis=decision_axis,
+        feat_offset=prev.get("feat_offset"))
+
+
 def fused_advance_coarse(bins: jnp.ndarray, gpair: jnp.ndarray,
                          positions: jnp.ndarray, prev: dict, lo: int,
                          n_level: int, missing_bin: int, *,
@@ -425,7 +455,6 @@ def fused_advance_coarse(bins: jnp.ndarray, gpair: jnp.ndarray,
     kernel's block shapes and accumulation order, so the histograms are
     bit-identical, level by level.
     """
-    from .partition import advance_positions_level, update_positions
     from .split import COARSE_B, coarse_bin_ids
 
     kind = prev["kind"]
@@ -453,22 +482,8 @@ def fused_advance_coarse(bins: jnp.ndarray, gpair: jnp.ndarray,
             lo_prev=lo_prev, n_prev=nl_prev, lo=lo, n_level=n_level,
             missing_bin=missing_bin, axis_name=axis_name,
             interpret=interpret)
-    if kind == "dense":
-        feat, thr, dleft, cs = prev["arrs"]
-        rel_prev = jnp.where(
-            (positions >= lo_prev) & (positions < lo_prev + nl_prev),
-            positions - lo_prev, nl_prev).astype(jnp.int32)
-        # f32 operand computed IN the trace: XLA fuses the upcast into the
-        # matmul read — no materialised [n, F] f32 copy
-        positions = advance_positions_level(
-            bins.astype(jnp.float32), positions, rel_prev, feat, thr,
-            dleft, cs, missing_bin, decision_axis=decision_axis)
-    else:
-        sf, sb, dl, isf = prev["arrs"]
-        positions = update_positions(
-            bins, positions, sf, sb, dl, isf, missing_bin,
-            decision_axis=decision_axis,
-            feat_offset=prev.get("feat_offset"))
+    positions = _advance_below(bins, positions, prev, missing_bin,
+                               decision_axis)
     rel = jnp.where((positions >= lo) & (positions < lo + n_level),
                     positions - lo, n_level).astype(jnp.int32)
     cb = coarse_bin_ids(bins.astype(jnp.int32), missing_bin)
@@ -520,9 +535,10 @@ def scan_level_hists(bins: jnp.ndarray, gpair: jnp.ndarray,
                                 missing_bin=missing_bin,
                                 with_coarse=True, axis_name=axis_name)
     order = counting_sort_by_node(rel, n_level)
-    bins_s = jnp.take(bins, order, axis=0)
-    gp_s = jnp.take(gpair, order, axis=0)
-    rel_s = jnp.take(rel, order)
+    with stage("permute"):
+        bins_s = jnp.take(bins, order, axis=0)
+        gp_s = jnp.take(gpair, order, axis=0)
+        rel_s = jnp.take(rel, order)
     fine = _segment_hist_acc(bins_s, gp_s, rel_s, n_level, max_nbins, acc)
     cb_s = coarse_bin_ids(bins_s.astype(jnp.int32), missing_bin)
     from .split import COARSE_B
@@ -554,24 +570,8 @@ def scan_advance_level(bins: jnp.ndarray, gpair: jnp.ndarray,
     to the uncapped build — the stable counting sort produces the same
     permutation either way (the sentinel is the unique maximum key in
     both), and ``segment_sum`` only gains trailing empty segments."""
-    from .partition import advance_positions_level, update_positions
-
-    kind = prev["kind"]
-    lo_prev, nl_prev = prev["lo"], prev["n_level"]
-    if kind == "dense":
-        feat, thr, dleft, cs = prev["arrs"]
-        rel_prev = jnp.where(
-            (positions >= lo_prev) & (positions < lo_prev + nl_prev),
-            positions - lo_prev, nl_prev).astype(jnp.int32)
-        positions = advance_positions_level(
-            bins.astype(jnp.float32), positions, rel_prev, feat, thr,
-            dleft, cs, missing_bin, decision_axis=decision_axis)
-    else:
-        sf, sb, dl, isf = prev["arrs"]
-        positions = update_positions(
-            bins, positions, sf, sb, dl, isf, missing_bin,
-            decision_axis=decision_axis,
-            feat_offset=prev.get("feat_offset"))
+    positions = _advance_below(bins, positions, prev, missing_bin,
+                               decision_axis)
     cap = n_level if n_cap is None else n_cap
     rel = jnp.where((positions >= lo) & (positions < lo + n_level),
                     positions - lo, cap).astype(jnp.int32)
@@ -581,6 +581,7 @@ def scan_advance_level(bins: jnp.ndarray, gpair: jnp.ndarray,
     return positions, fine, coarse
 
 
+@stage("fold")
 def subtract_siblings(parent_hist: jnp.ndarray, child_hist: jnp.ndarray,
                       built_is_left: jnp.ndarray) -> jnp.ndarray:
     """Sibling subtraction trick (reference ``src/tree/hist/histogram.h:192-207``):

@@ -51,6 +51,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...obs.trace import stage
+
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
@@ -339,12 +341,13 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
 
     # identical 15-bit fixed-point quantisation to build_hist_pallas's
     # int8x2 path (global per-component scale, pmax'd across row shards)
-    gpair_t = gpair.T                                    # [2, n]
-    max_abs = jnp.max(jnp.abs(gpair_t), axis=1)
-    if axis_name is not None:
-        max_abs = jax.lax.pmax(max_abs, axis_name)
-    scale = 32512.0 / jnp.maximum(max_abs, 1e-30)
-    q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
+    with stage("quantise"):
+        gpair_t = gpair.T                                # [2, n]
+        max_abs = jnp.max(jnp.abs(gpair_t), axis=1)
+        if axis_name is not None:
+            max_abs = jax.lax.pmax(max_abs, axis_name)
+        scale = 32512.0 / jnp.maximum(max_abs, 1e-30)
+        q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
     pos_t = positions.astype(jnp.int32)[None, :]         # [1, n]
     splits = jnp.stack([jnp.maximum(feat, 0).astype(jnp.int32),
                         thr.astype(jnp.int32),
@@ -352,31 +355,34 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                         can_split.astype(jnp.int32)])    # [4, n_prev]
 
     grid = (n_pad // R,)
-    hist, pos_out = pl.pallas_call(
-        _make_fused_kernel(F, n_prev, N, R, lo_prev, lo, missing_bin, B,
-                           shift),
-        out_shape=[jax.ShapeDtypeStruct((F, B, 2 * N), jnp.float32),
-                   jax.ShapeDtypeStruct((1, n_pad), jnp.int32)],
-        grid=grid,
-        in_specs=[pl.BlockSpec((4, n_prev), lambda i: (0, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((F, R), lambda i: (0, i),
-                               memory_space=pltpu.VMEM),
-                  pl.BlockSpec((2, R), lambda i: (0, i),
-                               memory_space=pltpu.VMEM),
-                  pl.BlockSpec((1, R), lambda i: (0, i),
-                               memory_space=pltpu.VMEM)],
-        out_specs=[pl.BlockSpec((F, B, 2 * N), lambda i: (0, 0, 0),
-                                memory_space=pltpu.VMEM),
-                   pl.BlockSpec((1, R), lambda i: (0, i),
-                                memory_space=pltpu.VMEM)],
-        scratch_shapes=[pltpu.VMEM((F, R), jnp.int32)],
-        interpret=interpret,
-    )(splits, bins_t, q, pos_t)
-    inv = jnp.repeat(1.0 / scale, N)[None, None, :]      # [1, 1, 2N]
-    hist = hist * inv
-    gh = hist.reshape(F, B, 2, N)
-    return pos_out[0, :n], gh.transpose(3, 0, 1, 2)      # [N, F, B, 2]
+    with stage("kernel.fused_advance_coarse"):
+        hist, pos_out = pl.pallas_call(
+            _make_fused_kernel(F, n_prev, N, R, lo_prev, lo, missing_bin, B,
+                               shift),
+            out_shape=[jax.ShapeDtypeStruct((F, B, 2 * N), jnp.float32),
+                       jax.ShapeDtypeStruct((1, n_pad), jnp.int32)],
+            grid=grid,
+            in_specs=[pl.BlockSpec((4, n_prev), lambda i: (0, 0),
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec((F, R), lambda i: (0, i),
+                                   memory_space=pltpu.VMEM),
+                      pl.BlockSpec((2, R), lambda i: (0, i),
+                                   memory_space=pltpu.VMEM),
+                      pl.BlockSpec((1, R), lambda i: (0, i),
+                                   memory_space=pltpu.VMEM)],
+            out_specs=[pl.BlockSpec((F, B, 2 * N), lambda i: (0, 0, 0),
+                                    memory_space=pltpu.VMEM),
+                       pl.BlockSpec((1, R), lambda i: (0, i),
+                                    memory_space=pltpu.VMEM)],
+            scratch_shapes=[pltpu.VMEM((F, R), jnp.int32)],
+            interpret=interpret,
+            name="fused_advance_coarse",
+        )(splits, bins_t, q, pos_t)
+    with stage("fold"):
+        inv = jnp.repeat(1.0 / scale, N)[None, None, :]  # [1, 1, 2N]
+        hist = hist * inv
+        gh = hist.reshape(F, B, 2, N)
+        return pos_out[0, :n], gh.transpose(3, 0, 1, 2)  # [N, F, B, 2]
 
 
 def _make_scan_kernel(n_feat: int, n_bins: int, block_rows: int):
@@ -465,7 +471,6 @@ def scan_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     -> (fine [n_nodes, F, max_nbins, 2] f32, coarse or None)
     """
     from ..partition import counting_sort_by_node
-    from ..split import COARSE_B, COARSE_SPAN
 
     F, n = bins_t.shape
     B = max_nbins
@@ -488,16 +493,19 @@ def scan_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         F_blk = min(cap, _round_up(-(-F // n_blocks), 8))
     F_pad = _round_up(F, F_blk)
     # pad slots carry the sentinel row id n -> bins 0 / q 0: zero payload
-    bins_p = jnp.take(bins_t, perm, axis=1, mode="fill", fill_value=0)
-    if F_pad != F:
-        bins_p = jnp.pad(bins_p, ((0, F_pad - F), (0, 0)))
-    gpair_t = gpair.T                                    # [2, n]
-    max_abs = jnp.max(jnp.abs(gpair_t), axis=1)
-    if axis_name is not None:
-        max_abs = jax.lax.pmax(max_abs, axis_name)       # global scale
-    scale = 32512.0 / jnp.maximum(max_abs, 1e-30)
-    q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
-    q_p = jnp.take(q, perm, axis=1, mode="fill", fill_value=0)
+    with stage("permute"):
+        bins_p = jnp.take(bins_t, perm, axis=1, mode="fill", fill_value=0)
+        if F_pad != F:
+            bins_p = jnp.pad(bins_p, ((0, F_pad - F), (0, 0)))
+    with stage("quantise"):
+        gpair_t = gpair.T                                # [2, n]
+        max_abs = jnp.max(jnp.abs(gpair_t), axis=1)
+        if axis_name is not None:
+            max_abs = jax.lax.pmax(max_abs, axis_name)   # global scale
+        scale = 32512.0 / jnp.maximum(max_abs, 1e-30)
+        q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
+    with stage("permute"):
+        q_p = jnp.take(q, perm, axis=1, mode="fill", fill_value=0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -508,13 +516,25 @@ def scan_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         # stray blocks land on the trash row n_nodes, dropped below
         out_specs=pl.BlockSpec((1, F_blk, B, 4),
                                lambda j, i, bn: (bn[i], j, 0, 0)))
-    acc = pl.pallas_call(
-        _make_scan_kernel(F_blk, B, R),
-        out_shape=jax.ShapeDtypeStruct((n_nodes + 1, F_pad, B, 4),
-                                       jnp.int32),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(block_node, bins_p, q_p)[:n_nodes, :F]             # [N, F, B, 4]
+    with stage("kernel.scan_hist"):
+        acc = pl.pallas_call(
+            _make_scan_kernel(F_blk, B, R),
+            out_shape=jax.ShapeDtypeStruct((n_nodes + 1, F_pad, B, 4),
+                                           jnp.int32),
+            grid_spec=grid_spec,
+            interpret=interpret,
+            name="scan_hist",
+        )(block_node, bins_p, q_p)
+    with stage("fold"):
+        return _scan_fold(acc[:n_nodes, :F], scale, B, missing_bin,
+                          with_coarse)
+
+
+def _scan_fold(acc, scale, B: int, missing_bin, with_coarse: bool):
+    """Dequantise the scan kernel's byte-plane sums ``[N, F, B, 4]`` and,
+    ``with_coarse``, fold the coarse histogram from them in the integer
+    domain (``scan_hist_pallas`` docstring)."""
+    from ..split import COARSE_B, COARSE_SPAN
 
     inv = (1.0 / scale)[None, None, None, :]             # [1, 1, 1, 2]
 
@@ -643,40 +663,47 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     if precision == "int8x2":
         # 15-bit fixed-point with a global per-component scale (reference
         # GradientQuantiser, src/tree/gpu_hist/histogram.cu:55-100)
-        max_abs = jnp.max(jnp.abs(gpair_t), axis=1)      # [2]
-        if axis_name is not None:
-            max_abs = jax.lax.pmax(max_abs, axis_name)   # global scale
-        scale = 32512.0 / jnp.maximum(max_abs, 1e-30)    # headroom vs 32767
-        q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
+        with stage("quantise"):
+            max_abs = jnp.max(jnp.abs(gpair_t), axis=1)      # [2]
+            if axis_name is not None:
+                max_abs = jax.lax.pmax(max_abs, axis_name)   # global scale
+            scale = 32512.0 / jnp.maximum(max_abs, 1e-30)    # vs 32767
+            q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
         # SWAR one-hot needs every bin id to fit a byte and whole words:
         # matrices with a missing slot (B = 257) or tiny max_bin fall back
         # to the compare build
         packed = B % 4 == 0 and B <= 256
-        out = pl.pallas_call(
-            _make_int8_kernel(F_blk, B, N, R, packed=packed, u4=u4),
-            out_shape=out_shape,
-            grid=grid,
-            in_specs=[bins_spec, vec2_spec, pos_spec],
-            out_specs=out_spec,
-            scratch_shapes=[],
-            interpret=interpret,
-        )(bins_t, q, pos_t)
+        with stage("kernel.build_hist_int8"):
+            out = pl.pallas_call(
+                _make_int8_kernel(F_blk, B, N, R, packed=packed, u4=u4),
+                out_shape=out_shape,
+                grid=grid,
+                in_specs=[bins_spec, vec2_spec, pos_spec],
+                out_specs=out_spec,
+                scratch_shapes=[],
+                interpret=interpret,
+                name="build_hist_int8",
+            )(bins_t, q, pos_t)
         # columns [0:N] hold g-sums, [N:2N] h-sums -> per-component dequant
-        inv = jnp.repeat(1.0 / scale, N)[None, None, :]  # [1, 1, 2N]
-        out = out * inv
+        with stage("fold"):
+            inv = jnp.repeat(1.0 / scale, N)[None, None, :]  # [1, 1, 2N]
+            out = out * inv
     else:
-        out = pl.pallas_call(
-            _make_kernel(F_blk, B, N, R, precision, u4=u4),
-            out_shape=out_shape,
-            grid=grid,
-            in_specs=[bins_spec, vec2_spec, pos_spec],
-            out_specs=out_spec,
-            scratch_shapes=[pltpu.VMEM(
-                (F_blk * B, R),
-                jnp.float32 if precision == "f32" else jnp.bfloat16)],
-            interpret=interpret,
-        )(bins_t, gpair_t, pos_t)
+        with stage("kernel.build_hist"):
+            out = pl.pallas_call(
+                _make_kernel(F_blk, B, N, R, precision, u4=u4),
+                out_shape=out_shape,
+                grid=grid,
+                in_specs=[bins_spec, vec2_spec, pos_spec],
+                out_specs=out_spec,
+                scratch_shapes=[pltpu.VMEM(
+                    (F_blk * B, R),
+                    jnp.float32 if precision == "f32" else jnp.bfloat16)],
+                interpret=interpret,
+                name="build_hist",
+            )(bins_t, gpair_t, pos_t)
 
-    out = out[:F]                                    # [F, B, 2N]
-    gh = out.reshape(F, B, 2, N)                     # split g-part / h-part
-    return gh.transpose(3, 0, 1, 2)                  # [N, F, B, 2]
+    with stage("fold"):
+        out = out[:F]                                # [F, B, 2N]
+        gh = out.reshape(F, B, 2, N)                 # split g-part / h-part
+        return gh.transpose(3, 0, 1, 2)              # [N, F, B, 2]

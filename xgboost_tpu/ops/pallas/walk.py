@@ -86,6 +86,7 @@ def _walk_pallas(words, values, tree_offsets, tree_weight, group_onehot,
                              lay=_field_layout())
     out = pl.pallas_call(
         kern,
+        name="forest_walk",
         grid=grid,
         in_specs=[
             pl.BlockSpec(words.shape, lambda i: (0,)),     # resident

@@ -15,6 +15,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import stage
+
 
 def cat_goes_right(b: jnp.ndarray, words: jnp.ndarray) -> jnp.ndarray:
     """b: [n] bin/category ids; words: [n, W] uint32 left-set bitmasks ->
@@ -27,6 +29,7 @@ def cat_goes_right(b: jnp.ndarray, words: jnp.ndarray) -> jnp.ndarray:
     return bit == 0
 
 
+@stage("advance")
 def advance_positions_level(bins_f32: jnp.ndarray, positions: jnp.ndarray,
                             rel: jnp.ndarray,
                             feat: jnp.ndarray, thr: jnp.ndarray,
@@ -83,6 +86,7 @@ def advance_positions_level(bins_f32: jnp.ndarray, positions: jnp.ndarray,
                      positions)
 
 
+@stage("count_sort")
 def counting_sort_by_node(rel_pos: jnp.ndarray, n_nodes: int,
                           block: Optional[int] = None):
     """Stable counting-sort permutation grouping rows by level node id —
@@ -158,6 +162,7 @@ def counting_sort_by_node(rel_pos: jnp.ndarray, n_nodes: int,
     return perm, block_node
 
 
+@stage("advance")
 def update_positions(bins: jnp.ndarray, positions: jnp.ndarray,
                      split_feature: jnp.ndarray, split_bin: jnp.ndarray,
                      default_left: jnp.ndarray, is_split: jnp.ndarray,

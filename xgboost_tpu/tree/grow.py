@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import trace as _trace
+from ..obs.trace import stage
 from ..ops.histogram import (build_hist, build_hist_prehot,
                              build_onehot_plane, fused_advance_coarse,
                              scan_advance_level, scan_level_hists,
@@ -477,14 +478,14 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
             prev = {"kind": "dense", "lo": lo_prev, "n_level": nl_prev,
                     "arrs": (carry["feat_p"], carry["bin_p"],
                              carry["dl_p"], carry["cs_p"])}
-            with jax.named_scope("xtpu.sort"):
+            with stage("sort"):
                 positions, hist_f, hist_c = scan_advance_level(
                     bins, gpair, positions, prev, lo, n_level,
                     missing_bin, max_nbins=max_nbins, bins_t=bins_t,
                     method="auto", axis_name=mega_row_axis,
                     decision_axis=mega_dec_axis, acc=scan_acc,
                     n_cap=N_cap)
-            with jax.named_scope("xtpu.exchange"):
+            with stage("exchange"):
                 hist_f = allreduce(hist_f)
                 hist_c = allreduce(hist_c)
             node_sum_l = jax.lax.dynamic_slice(
@@ -496,11 +497,11 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                                                (N_cap,))
                 nupp_l = jax.lax.dynamic_slice(carry["node_upper"], (lo,),
                                                (N_cap,))
-            with jax.named_scope("xtpu.window"):
+            with stage("window"):
                 span = choose_refine_window(hist_c, node_sum_l,
                                             n_real_bins, param,
                                             has_missing)          # [N, F]
-            with jax.named_scope("xtpu.refine"):
+            with stage("refine"):
                 hist_r = refine_from_fine(hist_f, span, missing_bin)
             hist, n_real_eval = assemble_two_level(
                 hist_c, hist_r, span, n_real_bins, has_missing)
@@ -520,7 +521,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                         allowed, (jnp.int32(0), feat_off), (N_cap, F))
                 fmask = fmask & allowed
 
-            with jax.named_scope("xtpu.eval"):
+            with stage("eval"):
                 res = evaluate_splits(
                     hist, node_sum_l, n_real_eval, param,
                     feature_mask=fmask, monotone=mono_loc,
@@ -533,7 +534,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
             if col_split:
                 local_feat, local_bin = res.feature, res.bin
                 local_dl = res.default_left
-                with jax.named_scope("xtpu.exchange"):
+                with stage("exchange"):
                     res, mine = exchange_best_split(res, axis_name, F)
 
             can_split = (valid & active_l
@@ -600,7 +601,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                     .at[li_d].set(child_path, mode="drop")
                     .at[ri_d].set(child_path, mode="drop"))
 
-            with jax.named_scope("xtpu.delta"):
+            with stage("delta"):
                 # rows whose node just became a terminal leaf take its
                 # value now (the unrolled loop's dense_delta block)
                 leaf_now = active_l & ~can_split
@@ -661,7 +662,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
         # level is exactly N_cap wide, so the pending arrays are unpadded
         # and the static-bound advance matches the unrolled epilogue
         lo_p = 2 ** (max_depth - 1) - 1
-        with jax.named_scope("xtpu.advance"):
+        with stage("advance"):
             rel_p = jnp.where(
                 (positions >= lo_p) & (positions < lo_p + N_cap),
                 positions - lo_p, N_cap).astype(jnp.int32)
@@ -687,14 +688,14 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
             # named_scope: stage labels on the device timeline — _grow is
             # ONE jitted dispatch, so in-trace scopes (not host spans) are
             # what aligns its stages with jax.profiler captures
-            with jax.named_scope("xtpu.sort"):
+            with stage("sort"):
                 positions, hist_f, hist_c = scan_advance_level(
                     bins, gpair, positions, pending_adv, lo, n_level,
                     missing_bin, max_nbins=max_nbins, bins_t=bins_t,
                     method="auto", axis_name=row_axis,
                     decision_axis=axis_name if col_split else None,
                     acc=scan_acc)
-            with jax.named_scope("xtpu.exchange"):
+            with stage("exchange"):
                 hist_f = allreduce(hist_f)
                 hist_c = allreduce(hist_c)
             pending_adv = None
@@ -703,13 +704,13 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
             # level's decoded splits AND build this level's coarse
             # histogram from the same bin-tile read
             row_axis = axis_name if not col_split else None
-            with jax.named_scope("xtpu.advance_hist"):
+            with stage("advance_hist"):
                 positions, hist_c = fused_advance_coarse(
                     bins, gpair, positions, pending_adv, lo, n_level,
                     missing_bin, bins_t=bins_t, method="auto",
                     axis_name=row_axis,
                     decision_axis=axis_name if col_split else None)
-            with jax.named_scope("xtpu.exchange"):
+            with stage("exchange"):
                 hist_c = allreduce(hist_c)
             pending_adv = None
 
@@ -721,20 +722,20 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
             if use_scan and hist_f is None:
                 # root level (and any level not fed by a boundary sweep):
                 # one sorted pass builds fine + coarse together
-                with jax.named_scope("xtpu.sort"):
+                with stage("sort"):
                     hist_f, hist_c = scan_level_hists(
                         bins, gpair, rel, n_level, max_nbins, missing_bin,
                         bins_t=bins_t, method="auto", axis_name=row_axis,
                         acc=scan_acc)
-                with jax.named_scope("xtpu.exchange"):
+                with stage("exchange"):
                     hist_f = allreduce(hist_f)
                     hist_c = allreduce(hist_c)
             if hist_c is None:
-                with jax.named_scope("xtpu.hist"):
+                with stage("hist"):
                     hist_c = allreduce(build_hist(
                         cb, gpair, rel, n_level, 20, method="auto",
                         bins_t=cb_t, axis_name=row_axis))
-            with jax.named_scope("xtpu.window"):
+            with stage("window"):
                 span = choose_refine_window(hist_c,
                                             node_sum[lo:lo + n_level],
                                             n_real_bins, param,
@@ -745,13 +746,13 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                 # bit-equal to the direct refine build of the same rows
                 # (ops/split.py refine_from_fine docstring) — so the
                 # level needs NO second data sweep
-                with jax.named_scope("xtpu.refine"):
+                with stage("refine"):
                     hist_r = refine_from_fine(hist_f, span, missing_bin)
             else:
                 # per-row window of the row's node, via one [F,N+1]@[N+1,n]
                 # MXU matmul (rows outside the level hit the zero pad row;
                 # their kernel contribution is dropped by rel == n_level)
-                with jax.named_scope("xtpu.refine"):
+                with stage("refine"):
                     span_pad = jnp.concatenate(
                         [span.astype(jnp.float32),
                          jnp.zeros((1, F), jnp.float32)]).T  # [F, N+1]
@@ -777,7 +778,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
             hist, n_real_eval = assemble_two_level(
                 hist_c, hist_r, span, n_real_bins, has_missing)
         elif depth == 0 or not use_compaction:
-            with jax.named_scope("xtpu.hist"):
+            with stage("hist"):
                 if use_prehot:
                     hist = build_hist_prehot(
                         oh_pre, gpair, rel, n_level, max_nbins,
@@ -791,7 +792,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                         # (col split replicates rows — local scale is
                         # already global)
                         axis_name=axis_name if not col_split else None)
-            with jax.named_scope("xtpu.exchange"):
+            with stage("exchange"):
                 hist = allreduce(hist)
         else:
             n_parents = n_level // 2
@@ -802,18 +803,24 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                 is_left_child == built_is_left[
                     jnp.clip(par, 0, n_parents - 1)])
             cap = max(n // 2, 1)
-            idxr = jnp.nonzero(built_mask, size=cap, fill_value=n)[0]
-            bins_c = jnp.take(bins, idxr, axis=0, mode="fill", fill_value=0)
-            gp_c = jnp.take(gpair, idxr, axis=0, mode="fill", fill_value=0.0)
-            par_c = jnp.take(jnp.clip(par, 0, n_parents), idxr,
-                             mode="fill",
-                             fill_value=n_parents).astype(jnp.int32)
-            hist_b = build_hist(bins_c, gp_c, par_c, n_parents, max_nbins,
-                                method=hist_kernel, bins_t=bins_c.T)
-            left_h, right_h = subtract_siblings(prev_hist, hist_b,
-                                                built_is_left)
-            hist = jnp.stack([left_h, right_h], axis=1).reshape(
-                (n_level,) + left_h.shape[1:])
+            with stage("hist"):
+                with stage("permute"):
+                    idxr = jnp.nonzero(built_mask, size=cap,
+                                       fill_value=n)[0]
+                    bins_c = jnp.take(bins, idxr, axis=0, mode="fill",
+                                      fill_value=0)
+                    gp_c = jnp.take(gpair, idxr, axis=0, mode="fill",
+                                    fill_value=0.0)
+                    par_c = jnp.take(jnp.clip(par, 0, n_parents), idxr,
+                                     mode="fill",
+                                     fill_value=n_parents).astype(jnp.int32)
+                hist_b = build_hist(bins_c, gp_c, par_c, n_parents,
+                                    max_nbins, method=hist_kernel,
+                                    bins_t=bins_c.T)
+                left_h, right_h = subtract_siblings(prev_hist, hist_b,
+                                                    built_is_left)
+                hist = jnp.stack([left_h, right_h], axis=1).reshape(
+                    (n_level,) + left_h.shape[1:])
 
         prev_hist = hist
         level_key = jax.random.fold_in(key, depth)
@@ -837,7 +844,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
             fmask = fmask & allowed
 
         parent_sum = node_sum[lo:lo + n_level]
-        with jax.named_scope("xtpu.eval"):
+        with stage("eval"):
             res = evaluate_splits(
                 hist, parent_sum,
                 n_real_eval if use_coarse else n_real_bins, param,
@@ -858,7 +865,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
             local_feat, local_bin = res.feature, res.bin
             local_dl = res.default_left
             local_is_cat, local_words = res.is_cat, res.cat_words
-            with jax.named_scope("xtpu.exchange"):
+            with stage("exchange"):
                 res, mine = exchange_best_split(res, axis_name, F,
                                                 with_cat=cat is not None)
 
@@ -918,12 +925,13 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
 
         if dense_delta:
             # rows whose node just became a terminal leaf take its value now
-            leaf_now = active[idx] & ~can_split
-            w_level = jnp.where(leaf_now, level_weight(lo, n_level), 0.0)
-            rel_oh = (rel[:, None]
-                      == jnp.arange(n_level, dtype=jnp.int32)[None, :])
-            delta = delta + jnp.sum(
-                jnp.where(rel_oh, w_level[None, :], 0.0), axis=1)
+            with stage("delta"):
+                leaf_now = active[idx] & ~can_split
+                w_level = jnp.where(leaf_now, level_weight(lo, n_level), 0.0)
+                rel_oh = (rel[:, None]
+                          == jnp.arange(n_level, dtype=jnp.int32)[None, :])
+                delta = delta + jnp.sum(
+                    jnp.where(rel_oh, w_level[None, :], 0.0), axis=1)
 
         if use_fused or use_scan:
             # defer this level's advance to the NEXT boundary's fused/scan
@@ -954,41 +962,38 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
             # reference's partition-bitvector broadcast). Categorical
             # routing stays owner-local: the owner's bins hold the split
             # feature, so its local cat bitmask words decide
-            with jax.named_scope("xtpu.advance"):
-                positions = advance_positions_level(
-                    bins_f32, positions, rel,
-                    jnp.where(can_split & mine, local_feat, -1),
-                    jnp.where(can_split & mine, local_bin, 0),
-                    can_split & mine & local_dl, can_split, missing_bin,
-                    is_cat=(can_split & mine & local_is_cat)
-                    if cat is not None else None,
-                    cat_words=jnp.where(
-                        (mine & local_is_cat)[:, None], local_words,
-                        jnp.uint32(0)) if cat is not None else None,
-                    decision_axis=axis_name)
+            positions = advance_positions_level(
+                bins_f32, positions, rel,
+                jnp.where(can_split & mine, local_feat, -1),
+                jnp.where(can_split & mine, local_bin, 0),
+                can_split & mine & local_dl, can_split, missing_bin,
+                is_cat=(can_split & mine & local_is_cat)
+                if cat is not None else None,
+                cat_words=jnp.where(
+                    (mine & local_is_cat)[:, None], local_words,
+                    jnp.uint32(0)) if cat is not None else None,
+                decision_axis=axis_name)
         elif n_level <= DENSE_LEVEL_MAX:
-            with jax.named_scope("xtpu.advance"):
-                positions = advance_positions_level(
-                    bins_f32, positions, rel,
-                    jnp.where(can_split, res.feature, -1),
-                    jnp.where(can_split, res.bin, 0),
-                    can_split & res.default_left, can_split, missing_bin,
-                    is_cat=(can_split & res.is_cat)
-                    if cat is not None else None,
-                    cat_words=res.cat_words if cat is not None else None)
+            positions = advance_positions_level(
+                bins_f32, positions, rel,
+                jnp.where(can_split, res.feature, -1),
+                jnp.where(can_split, res.bin, 0),
+                can_split & res.default_left, can_split, missing_bin,
+                is_cat=(can_split & res.is_cat)
+                if cat is not None else None,
+                cat_words=res.cat_words if cat is not None else None)
         else:  # deep level: per-row gather walk bounds memory to O(n);
             # under col split the walk resolves only owned nodes and one
             # psum broadcasts the decisions (update_positions docstring)
             is_split_full = jnp.zeros((max_nodes,), bool).at[idx].set(
                 can_split)
-            with jax.named_scope("xtpu.advance"):
-                positions = update_positions(
-                    bins, positions, split_feature, split_bin, default_left,
-                    is_split_full, missing_bin,
-                    is_cat_split=is_cat_split if cat is not None else None,
-                    cat_words=cat_words if cat is not None else None,
-                    decision_axis=axis_name if col_split else None,
-                    feat_offset=feat_off)
+            positions = update_positions(
+                bins, positions, split_feature, split_bin, default_left,
+                is_split_full, missing_bin,
+                is_cat_split=is_cat_split if cat is not None else None,
+                cat_words=cat_words if cat is not None else None,
+                decision_axis=axis_name if col_split else None,
+                feat_offset=feat_off)
 
         if use_compaction and depth + 1 < max_depth:
             # next level's per-node row counts pick each parent's smaller
@@ -1006,7 +1011,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
     if (use_fused or use_scan) and pending_adv is not None:
         # epilogue: route rows below the deepest level's splits — advance
         # only, there is no next coarse pass left to fuse with
-        with jax.named_scope("xtpu.advance"):
+        with stage("advance"):
             if pending_adv["kind"] == "dense":
                 lo_p, nl_p = pending_adv["lo"], pending_adv["n_level"]
                 feat_v, bin_v, dl_v, cs_v = pending_adv["arrs"]
@@ -1023,26 +1028,28 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                     decision_axis=axis_name if col_split else None,
                     feat_offset=feat_off)
 
-    w = calc_weight(node_sum[:, 0], node_sum[:, 1], param)
-    if monotone is not None:
-        w = jnp.clip(w, node_lower, node_upper)
-    w = w * param.eta
-    leaf_value = jnp.where(active & is_leaf, w, 0.0).astype(jnp.float32)
-    base_weight = jnp.where(active, w, 0.0).astype(jnp.float32)
+    with stage("leaf"):
+        w = calc_weight(node_sum[:, 0], node_sum[:, 1], param)
+        if monotone is not None:
+            w = jnp.clip(w, node_lower, node_upper)
+        w = w * param.eta
+        leaf_value = jnp.where(active & is_leaf, w, 0.0).astype(jnp.float32)
+        base_weight = jnp.where(active, w, 0.0).astype(jnp.float32)
 
-    if dense_delta:
-        # deepest level: every surviving node is a leaf
-        lo = 2 ** max_depth - 1
-        n_level = 2 ** max_depth
-        w_last = jnp.where(active[lo:lo + n_level],
-                           level_weight(lo, n_level), 0.0)
-        rel = jnp.where(positions >= lo, positions - lo,
-                        n_level).astype(jnp.int32)
-        rel_oh = rel[:, None] == jnp.arange(n_level, dtype=jnp.int32)[None, :]
-        delta = delta + jnp.sum(jnp.where(rel_oh, w_last[None, :], 0.0),
-                                axis=1)
-    else:
-        delta = leaf_value[positions]
+        if dense_delta:
+            # deepest level: every surviving node is a leaf
+            lo = 2 ** max_depth - 1
+            n_level = 2 ** max_depth
+            w_last = jnp.where(active[lo:lo + n_level],
+                               level_weight(lo, n_level), 0.0)
+            rel = jnp.where(positions >= lo, positions - lo,
+                            n_level).astype(jnp.int32)
+            rel_oh = (rel[:, None]
+                      == jnp.arange(n_level, dtype=jnp.int32)[None, :])
+            delta = delta + jnp.sum(
+                jnp.where(rel_oh, w_last[None, :], 0.0), axis=1)
+        else:
+            delta = leaf_value[positions]
     return GrownTree(split_feature=split_feature, split_bin=split_bin,
                      default_left=default_left, is_leaf=is_leaf, active=active,
                      leaf_value=leaf_value, node_sum=node_sum, gain=gain,
@@ -1238,8 +1245,6 @@ class TreeGrower:
                           scan_acc=self.scan_acc)
             else:
                 g = self._sharded(bins, gpair, n_real_bins, tree_mask, key)
-            if mega_live and not isinstance(bins, jax.core.Tracer):
-                _trace.sync(g.positions)
         if self.param.max_leaves > 0:
             g = self._truncate_max_leaves(g)
         return g

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import trace as _trace
+from ..obs.trace import stage
 from ..ops.histogram import build_hist, scan_level_hists
 from ..ops.partition import cat_goes_right
 from ..ops.split import CatInfo, evaluate_splits
@@ -330,7 +331,7 @@ def _mega_greedy_loop(bins, gpair, positions, n_real_bins, bins_t,
     pinf2 = jnp.full((2,), jnp.inf, f32)
     words0 = jnp.zeros((1,), jnp.uint32)
 
-    with jax.named_scope("xtpu.root"):
+    with stage("root"):
         root = _root_sum(gpair, axis_name).astype(f32)
     sf = jnp.full((cap,), -1, i32)
     sb = jnp.zeros((cap,), i32)
@@ -348,7 +349,7 @@ def _mega_greedy_loop(bins, gpair, positions, n_real_bins, bins_t,
     cls_ = jnp.zeros((cap, 2), f32)
     crs = jnp.zeros((cap, 2), f32)
 
-    with jax.named_scope("xtpu.eval"):
+    with stage("eval"):
         res0 = _eval2(bins, gpair, positions, i32(0), i32(-1),
                       jnp.stack([root, jnp.zeros((2,), f32)]), fmask_root,
                       ninf2, pinf2, n_real_bins, bins_t, None, None, None,
@@ -366,7 +367,7 @@ def _mega_greedy_loop(bins, gpair, positions, n_real_bins, bins_t,
     def _body(_, c):
         (sf, sb, dl, lc, rc, pa, gn, gh, depth_of,
          cg, cf, cb, cd, cls_, crs, positions, n_nodes) = c
-        with jax.named_scope("xtpu.pop"):
+        with stage("pop"):
             best = jnp.argmax(cg).astype(i32)
             bg = cg[best]
             valid = bg > -jnp.inf
@@ -391,10 +392,10 @@ def _mega_greedy_loop(bins, gpair, positions, n_real_bins, bins_t,
             depth_of = depth_of.at[li_d].set(dchild, mode="drop")
             depth_of = depth_of.at[ri_d].set(dchild, mode="drop")
             n_nodes = n_nodes + 2 * valid.astype(i32)
-        with jax.named_scope("xtpu.apply"):
+        with stage("apply"):
             positions = _apply1(bins, positions, nid, feat, rbin, rdl,
                                 jnp.bool_(False), words0, li, ri, mb)
-        with jax.named_scope("xtpu.eval"):
+        with stage("eval"):
             # rows sit at ids < n_nodes, so on an invalid iteration
             # nothing matches li/ri and the eval is inert garbage —
             # the push gate below discards it
@@ -402,7 +403,7 @@ def _mega_greedy_loop(bins, gpair, positions, n_real_bins, bins_t,
                          jnp.stack([lsum, rsum]), fmask_pair, ninf2,
                          pinf2, n_real_bins, bins_t, None, None, None,
                          **kw)
-        with jax.named_scope("xtpu.push"):
+        with stage("push"):
             ok_d = (jnp.bool_(True) if max_depth <= 0
                     else dchild < max_depth)
             for slot, child in ((0, li), (1, ri)):
@@ -423,7 +424,7 @@ def _mega_greedy_loop(bins, gpair, positions, n_real_bins, bins_t,
     carry = jax.lax.fori_loop(0, max_leaves - 1, _body, carry)
     (sf, sb, dl, lc, rc, pa, gn, gh, depth_of,
      cg, cf, cb, cd, cls_, crs, positions, n_nodes) = carry
-    with jax.named_scope("xtpu.finalize"):
+    with stage("finalize"):
         w = calc_weight(gh[:, 0], gh[:, 1], param) * param.eta
         is_leaf = lc < 0
         leaf_value = jnp.where(is_leaf, w, 0.0).astype(f32)
@@ -770,7 +771,6 @@ class LossguideGrower:
         with _trace.span("lossguide/mega"):
             out = fn(bins, gpair, positions, n_real_bins, bins_t,
                      fmask_root, fmask_pair)
-            _trace.sync(out[-1])
         from ..utils.fetch import fetch_packed
 
         keys = ("sf", "sb", "dl", "lc", "rc", "pa", "gn", "gh",
@@ -941,7 +941,6 @@ class LossguideGrower:
                 if apply_args is not None:
                     with _trace.span("lossguide/apply"):
                         positions = apply1(bins, positions, *apply_args)
-                        _trace.sync(positions)
                 return
             i0 = ids[0]
             i1 = ids[1] if len(ids) > 1 else -1
@@ -965,18 +964,15 @@ class LossguideGrower:
                         bins, gpair, positions, *apply_args,
                         jnp.asarray(psums), jnp.asarray(fm), lowers,
                         uppers, n_real_bins, bins_t, cb_t)
-                    _trace.sync(res)
             else:
                 if apply_args is not None:
                     with _trace.span("lossguide/apply"):
                         positions = apply1(bins, positions, *apply_args)
-                        _trace.sync(positions)
                 with _trace.span("lossguide/eval"):
                     res = eval2(bins, gpair, positions, np.int32(i0),
                                 np.int32(i1), jnp.asarray(psums),
                                 jnp.asarray(fm), lowers, uppers,
                                 n_real_bins, bins_t, cb_t)
-                    _trace.sync(res)
             # ONE packed device->host pull for the whole SplitResult —
             # a per-field np.asarray costs 8 blocking device->host
             # transfers per split

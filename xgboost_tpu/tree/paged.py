@@ -1611,27 +1611,23 @@ class PagedGrower(TreeGrower):
             distributed = _coll.get_communicator().is_distributed()
             # Host spans per stage: this loop is the one place tree
             # growth has REAL host-visible stage boundaries (the resident
-            # path is one jitted dispatch, labeled with named_scope
-            # instead). Async dispatches mean a span times the dispatch
-            # unless _trace.sync() is armed (perf_report measurement
-            # mode) — then each span times its stage wall-clock.
+            # path is one jitted dispatch, labeled with obs.trace.stage
+            # scopes instead). The dispatches are async, so a span times
+            # the dispatch; the stage's device time is the profiler
+            # trace's to give.
             if single_dev and cached and not streamed and not distributed:
                 with _trace.span("paged/level_full",
-                                 args={"depth": depth}
-                                 if _trace.enabled() else None):
+                                 args={"depth": depth}):
                     positions, stash, state, prev = self._mk.level_full(
                         paged, gpair, positions, prev, lo, n_level,
                         n_static, self._ev, state, tree_mask, key, depth,
                         cached)
-                    _trace.sync(stash)
             elif self._coarse:
                 with _trace.span("paged/hist",
-                                 args={"depth": depth}
-                                 if _trace.enabled() else None):
+                                 args={"depth": depth}):
                     positions, hist_c, fine = self._mk.coarse_pass(
                         paged, gpair, positions, prev, lo, n_level,
                         n_static, cached, streamed)
-                    _trace.sync(hist_c)
                 with _trace.span("paged/exchange"):
                     hist_c = _host_allreduce(hist_c)
                 # node-level window choice from the GLOBAL coarse hist
@@ -1640,14 +1636,11 @@ class PagedGrower(TreeGrower):
                 # streamed pages' refine comes from their fine partials
                 with _trace.span("paged/window"):
                     span = self._ev.choose_window(hist_c, state)
-                    _trace.sync(span)
                 with _trace.span("paged/refine",
-                                 args={"depth": depth}
-                                 if _trace.enabled() else None):
+                                 args={"depth": depth}):
                     hist_r = self._mk.refine_pass(
                         paged, gpair, positions, span, lo, n_level,
                         n_static, cached, fine=fine)
-                    _trace.sync(hist_r)
                 with _trace.span("paged/exchange"):
                     hist_r = _host_allreduce(hist_r)
                 with _trace.span("paged/eval"):
@@ -1655,11 +1648,9 @@ class PagedGrower(TreeGrower):
                         (hist_c, hist_r, span), state, tree_mask, key,
                         jnp.int32(depth), jnp.int32(lo),
                         jnp.int32(n_level))
-                    _trace.sync(stash)
             else:
                 with _trace.span("paged/hist",
-                                 args={"depth": depth}
-                                 if _trace.enabled() else None):
+                                 args={"depth": depth}):
                     if prev is None:
                         hist = self._mk.level_hist(paged, gpair,
                                                    positions, lo, n_level,
@@ -1668,14 +1659,12 @@ class PagedGrower(TreeGrower):
                         positions, hist = self._mk.adv_hist(
                             paged, gpair, positions, prev, lo, n_level,
                             n_static)
-                    _trace.sync(hist)
                 with _trace.span("paged/exchange"):
                     hist = _host_allreduce(hist)
                 with _trace.span("paged/eval"):
                     stash, state, prev = self._ev(
                         hist, state, tree_mask, key, jnp.int32(depth),
                         jnp.int32(lo), jnp.int32(n_level))
-                    _trace.sync(stash)
             stashes.append(stash)
             # level boundary: HBM watermark sample (free when the
             # memory monitor is off — the page cache + ring buffers peak
@@ -1695,7 +1684,6 @@ class PagedGrower(TreeGrower):
             with _trace.span("paged/advance"):
                 positions = self._mk.final_advance(paged, positions, prev,
                                                    n_static)
-                _trace.sync(positions)
 
         # ---- host bookkeeping replay (one packed pull for the tree) ----
         with _trace.span("paged/fetch"):
@@ -1908,8 +1896,7 @@ class PagedMultiTargetGrower(MultiTargetGrower):
             n_level = 2 ** depth
 
             with _trace.span("paged/hist",
-                             args={"depth": depth}
-                             if _trace.enabled() else None):
+                             args={"depth": depth}):
                 if prev is None:
                     hist = self._mk.level_hist(paged, gpair, positions,
                                                lo, n_level, n_static,
@@ -1918,7 +1905,6 @@ class PagedMultiTargetGrower(MultiTargetGrower):
                     positions, hist = self._mk.adv_hist(
                         paged, gpair, positions, prev, lo, n_level,
                         n_static, multi=True)
-                _trace.sync(hist)
             with _trace.span("paged/exchange"):
                 hist = _host_allreduce(hist)
 
@@ -2000,7 +1986,6 @@ class PagedMultiTargetGrower(MultiTargetGrower):
             with _trace.span("paged/advance"):
                 positions = self._mk.final_advance(paged, positions, prev,
                                                    n_static)
-                _trace.sync(positions)
 
         w = np.asarray(calc_weight(jnp.asarray(node_sum[..., 0]),
                                    jnp.asarray(node_sum[..., 1]),
