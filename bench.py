@@ -315,14 +315,12 @@ def bench_higgs11m():
     twopass = (pinned_steady("coarse")
                if os.environ.get("BENCH_COARSE", "1") != "0" else None)
     # r12 segmented-scan formulation vs the r6 fused schedule, both
-    # PINNED so the speedup is schedule-vs-schedule, not auto-vs-auto
-    # (auto routes to scan where validate_scan.py promoted it)
+    # PINNED so the speedup is schedule-vs-schedule (auto is fused)
     scan = fused = None
     if os.environ.get("BENCH_SCAN", "1") != "0":
         fused = pinned_steady("fused")
         scan = pinned_steady("scan")
-    # r14 megakernel vs the r12 scan formulation, both PINNED (auto
-    # routes to mega where validate_mega.py promoted it)
+    # r14 megakernel vs the r12 scan formulation, both PINNED
     mega = (pinned_steady("mega")
             if os.environ.get("BENCH_MEGA", "1") != "0" else None)
     return 20.0 / t20, steady, exact, twopass, scan, fused, mega

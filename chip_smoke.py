@@ -301,7 +301,7 @@ def phase_train(ctx) -> dict:
     import xgboost_tpu as xgb
     from xgboost_tpu import core
     from xgboost_tpu.metric.auc import binary_roc_auc
-    from xgboost_tpu.obs.metrics import degrade_counts
+    from xgboost_tpu.obs.metrics import degrade_counts, grow_schedule_counts
     from xgboost_tpu.tree.grow import resolve_schedule
 
     sz = ctx["sizes"]
@@ -367,6 +367,11 @@ def phase_train(ctx) -> dict:
             f"{custom_calls}")
     if any(degrade_counts().values()):
         raise AssertionError(f"a degrade handler fired: {degrade_counts()}")
+    traced = grow_schedule_counts()
+    if set(traced) != {sched.name}:
+        raise AssertionError(
+            f"grow programs were traced under {traced}, not under "
+            f"{sched.name!r} alone")
 
     if pred.shape != (sz.holdout,) or not np.isfinite(pred).all():
         raise AssertionError("predictions: wrong shape or non-finite")
@@ -390,9 +395,12 @@ def phase_train(ctx) -> dict:
     say(f"train: {sz.rounds} batched rounds + predict {t4 - t3:.1f}s, "
         f"{sz.eval_rounds} evaluated rounds {t5 - t4:.1f}s, compile included "
         f"(smoke timings); held-out AUC {auc:.4f} (floor {sz.auc_floor}); "
-        f"tpu_custom_call per program {custom_calls}")
+        f"tpu_custom_call per program {custom_calls}; "
+        + ", ".join(f'xtpu_grow_schedule_total{{schedule="{k}"}} {v}'
+                    for k, v in traced.items()))
     ctx.update(bst=bst, Xh=Xh)
-    return {"rows": sz.rows, "schedule": sched.name, "auc": round(auc, 4),
+    return {"rows": sz.rows, "schedule": sched.name,
+            "grow_schedule_total": traced, "auc": round(auc, 4),
             "auc_floor": sz.auc_floor, "dispatches": got,
             "tpu_custom_call": custom_calls,
             "holdout_logloss": [round(float(v), 5) for v in ll],

@@ -1,0 +1,74 @@
+"""The row-split mesh grow program compiles for the chip, with no chip.
+
+The TPU's compiler is installed here and compiles for a described v5e
+2x2 (``/opt/skills/guides/on-chip-measurement`` section 2). CPU runs
+never promote ``auto`` and never hold a Mosaic kernel, so this is the one
+tier-1 check of what a four-chip ``xgb.train(mesh=...)`` traces since
+``auto`` is the fused sweep: ``shard_map`` keeps ``check_vma`` on there,
+and ``pallas_call`` refuses an ``out_shape`` that does not say over which
+mesh axes it varies (``ops/pallas/histogram.py _out_struct``). A compile
+that passes is not a chip run. All such tests stay in this one file: the
+process that describes the topology holds the TPU library's lock."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
+
+from xgboost_tpu.context import DATA_AXIS
+from xgboost_tpu.obs.metrics import grow_schedule_counts
+from xgboost_tpu.tree.grow import AUTO_COARSE_MIN_ROWS, TreeGrower
+from xgboost_tpu.tree.param import TrainParam
+from xgboost_tpu.tree.programs import _NumericCuts
+
+FEATURES, MAX_NBINS = 28, 256
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a TPU executable cannot be read back from the cache without a chip
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield Mesh(np.array(topo.devices).reshape(4), (DATA_AXIS,))
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("method,schedule,kernels", [
+    ("auto", "fused", 2 * 3),            # advance+coarse and refine, a level
+    ("mega", "mega", 1),                 # check_vma waived: the loop carry
+])
+def test_row_split_grow_program_compiles_for_v5e(monkeypatch, mesh, method,
+                                                 schedule, kernels):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows = 4 * AUTO_COARSE_MIN_ROWS      # each shard at auto's threshold
+    grower = TreeGrower(TrainParam(max_depth=3), MAX_NBINS,
+                        _NumericCuts(FEATURES), hist_method=method,
+                        mesh=mesh, has_missing=False)
+
+    def arg(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    before = grow_schedule_counts().get(schedule, 0)
+    compiled = grower.sharded_program().lower(
+        arg((rows, FEATURES), jnp.uint8, P(DATA_AXIS, None)),
+        arg((rows, 2), jnp.float32, P(DATA_AXIS, None)),
+        arg((FEATURES,), jnp.int32, P()), arg((FEATURES,), jnp.bool_, P()),
+        arg((2,), jnp.uint32, P())).compile()
+    assert grow_schedule_counts().get(schedule, 0) == before + 1
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= kernels
+    assert "all-reduce" in text          # the histogram psum

@@ -1,17 +1,19 @@
-"""Train two rounds under each pinned histogram schedule on the chip.
+"""Train a few rounds under each pinned histogram schedule on the chip.
 
 The quickest check that every value of ``hist_method`` the growers accept
 still compiles and runs on the attached TPU (``chip_smoke.py`` covers only
-what ``auto`` resolves to). Prints one line per schedule with a cold
-(compile included) and a warm wall time for the same two rounds. These are
-smoke timings, not benchmark numbers: no repeats, no spread.
+what ``auto`` resolves to: ``fused`` at its shape). Prints one line per
+schedule with a cold (compile included) and a warm wall time for the same
+rounds. These are smoke timings, not benchmark numbers: no repeats, no
+spread.
 
     python tools/chip_schedules.py                       # 1M x 28, all six
-    python tools/chip_schedules.py --rows 11000000 --methods mega,fused
-    XTPU_SCAN_PROMOTE=0 python tools/chip_schedules.py --methods auto
+    python tools/chip_schedules.py --rows 10500000 --depth 8 --rounds 4 \
+        --methods auto,fused,coarse,pallas,scan          # the cells' shape
+    python tools/chip_schedules.py --grow-policy lossguide --max-leaves 64 \
+        --rounds 4 --methods fused,scan,auto
 
-The env switches are read at import, so each needs a process of its own;
-chain them in one chip-tool command so they share the compile cache.
+Chain several in one chip-tool command so they share the compile cache.
 """
 
 from __future__ import annotations
@@ -25,13 +27,17 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 METHODS = ("auto", "mega", "scan", "fused", "coarse", "pallas")
-ROUNDS = 2
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=1_000_000)
     ap.add_argument("--methods", default=",".join(METHODS))
+    ap.add_argument("--depth", type=int, default=6)
+    ap.add_argument("--rounds", type=int, default=2)
+    ap.add_argument("--grow-policy", default="depthwise",
+                    choices=("depthwise", "lossguide"))
+    ap.add_argument("--max-leaves", type=int, default=0)
     args = ap.parse_args()
 
     import jax
@@ -45,6 +51,7 @@ def main() -> int:
     import xgboost_tpu as xgb
     from chip_smoke import MAX_BIN, SEED, make_data, train_params
     from xgboost_tpu.metric.auc import binary_roc_auc
+    from xgboost_tpu.obs.metrics import grow_schedule_counts
     from xgboost_tpu.tree.grow import resolve_schedule
 
     dev = jax.devices()[0]
@@ -53,30 +60,36 @@ def main() -> int:
     dtrain, dhold = xgb.DMatrix(X, label=y), xgb.DMatrix(Xh)
     binned = dtrain.binned(MAX_BIN)
     rows = []
+    lossguide = args.grow_policy == "lossguide"
     for method in args.methods.split(","):
-        params = {**train_params(6), "hist_method": method}
-        sched = resolve_schedule(
+        params = {**train_params(args.depth), "hist_method": method,
+                  "grow_policy": args.grow_policy,
+                  "max_leaves": args.max_leaves}
+        # the lossguide grower resolves "auto" itself (tree/lossguide.py)
+        runs_as = method if lossguide else resolve_schedule(
             method, args.rows, binned.max_nbins, binned.has_missing,
-            xgb.TrainParam(max_depth=6), numeric=True)
+            xgb.TrainParam(max_depth=args.depth), numeric=True).name
         walls = []
         for _ in ("cold", "warm"):
             t0 = time.perf_counter()
-            bst = xgb.train(params, dtrain, ROUNDS, verbose_eval=False)
+            bst = xgb.train(params, dtrain, args.rounds, verbose_eval=False)
             pred = bst.predict(dhold)     # host copy: the rounds finished
             walls.append(time.perf_counter() - t0)
         auc = binary_roc_auc(yh.astype(np.float64), pred.astype(np.float64),
                              np.ones(len(yh)))
         if not (np.isfinite(pred).all() and auc > 0.7):
-            raise AssertionError(f"{method}: AUC {auc} after {ROUNDS} rounds")
-        rows.append({"hist_method": method, "runs_as": sched.name,
-                     "rows": args.rows, "auc": round(auc, 4),
+            raise AssertionError(
+                f"{method}: AUC {auc} after {args.rounds} rounds")
+        rows.append({"hist_method": method, "runs_as": runs_as,
+                     "grow_policy": args.grow_policy, "rows": args.rows,
+                     "depth": args.depth, "rounds": args.rounds,
+                     "auc": round(auc, 4),
                      "cold_s": round(walls[0], 2),
                      "warm_s": round(walls[1], 2)})
         print(f"[chip_schedules] {rows[-1]}", flush=True)
     print(json.dumps({
         "ok": True, "smoke_timings": rows,
-        "env": {k: os.environ[k] for k in ("XTPU_SCAN_PROMOTE", "XTPU_MEGA")
-                if k in os.environ},
+        "grow_schedule_total": grow_schedule_counts(),
         "device": {"platform": dev.platform, "kind": dev.device_kind,
                    "count": len(jax.devices())}}))
     return 0

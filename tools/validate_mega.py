@@ -1,8 +1,9 @@
 """Promotion gate for hist_method='mega' vs the scan formulation.
 
-Round 14 mirrors the round-12 promotion protocol (tools/validate_scan.py):
-before 'auto' routes the whole per-tree level loop into the single
-compiled megakernel program, the SAME 3-task x 3-seed grid — widened by
+Round 14 mirrored the round-12 promotion protocol (tools/validate_scan.py)
+before 'auto' was routed into the single compiled megakernel program
+(since PR 28 'auto' stays on fused and mega is explicit; the gate
+stays): the SAME 3-task x 3-seed grid — widened by
 the tier axis (depthwise / lossguide / paged) and the max_bin axis
 (256 / 128), plus mesh row- and column-split cells — trains both
 schedules and checks quality. The megakernel reorders NOTHING: it runs
@@ -199,7 +200,7 @@ def main(argv=None):
               f"{r['scan_final']:.6f} | {r['mega_final']:.6f} | "
               f"{r['worst_round_gap']:g} | "
               f"{'identical' if r['raw_identical'] else 'DIFFERS'} |")
-    verdict = "PASS — bit-identical, auto promotion justified" \
+    verdict = "PASS — bit-identical" \
         if exact_parity else "FAIL — mega diverges from scan (bug)"
     print(f"\n{verdict}")
     print(json.dumps({"cells": rows, "exact_parity": exact_parity}))

@@ -1,7 +1,8 @@
 """Promotion gate for hist_method='scan' vs the fused one-dispatch path.
 
-Round 12 mirrors the round-6 promotion protocol (tools/validate_fused.py):
-before 'auto' routes to the segmented-scan build, the SAME 3-task x
+Round 12 mirrored the round-6 promotion protocol (tools/validate_fused.py)
+before 'auto' was routed to the segmented-scan build (since PR 28 'auto'
+stays on fused and scan is explicit; the gate stays): the SAME 3-task x
 3-seed grid — widened by a tier axis (depthwise / lossguide / paged) and
 a max_bin axis (256 / 128) — trains both schedules and checks quality.
 The scan scheme REORDERS the rows feeding the very same per-(node, bin)
@@ -161,7 +162,7 @@ def main(argv=None):
         print(f"| {r['cell']} | {r['metric']} | {r['seed']} | "
               f"{r['fused_final']:.6f} | {r['scan_final']:.6f} | "
               f"{r['worst_round_gap']:g} |")
-    verdict = "PASS — bit-identical, auto promotion justified" \
+    verdict = "PASS — bit-identical" \
         if exact_parity else "FAIL — scan diverges from fused (bug)"
     print(f"\n{verdict}")
     print(json.dumps({"cells": rows, "exact_parity": exact_parity}))

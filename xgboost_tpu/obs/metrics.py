@@ -30,6 +30,7 @@ import jax.monitoring
 __all__ = ["Sample", "Family", "HistogramData", "MetricsRegistry",
            "get_registry", "render_families", "count_degrade",
            "degrade_counts", "count_round_dispatch", "count_tree_flush",
+           "count_grow_schedule", "grow_schedule_counts",
            "program_compile_counts"]
 
 LabelSet = Tuple[Tuple[str, str], ...]
@@ -319,6 +320,30 @@ def count_tree_flush() -> None:
                   help="device-to-host pulls of pending trees")
 
 
+_GROW_SCHEDULE = "xtpu_grow_schedule_total"
+
+
+def count_grow_schedule(schedule: str) -> None:
+    """One trace of the depth-wise grow program under ``schedule``
+    (``tree/grow.py Schedule.name``): what ``hist_method`` resolved to at
+    the shape and on the backend it was traced for. Counted on the host
+    while jax traces, so a steady window pays nothing."""
+    _registry.inc(_GROW_SCHEDULE, labels=(("schedule", schedule),),
+                  help="grow programs traced, by histogram schedule")
+
+
+def _by_label(name: str, label: str) -> Dict[str, float]:
+    """``{value of label: count}`` over every series of counter ``name``."""
+    with _registry._lock:
+        counters = dict(_registry._counters)
+    return {dict(labels)[label]: value
+            for (n, labels), value in counters.items() if n == name}
+
+
+def grow_schedule_counts() -> Dict[str, int]:
+    return {k: int(v) for k, v in _by_label(_GROW_SCHEDULE, "schedule").items()}
+
+
 # ---- compile counters by program -------------------------------------------
 # jax.monitoring listeners, registered once at import. jax 0.9 passes
 # ``fun_name`` with its three compile-path duration events and announces
@@ -396,16 +421,13 @@ def program_compile_counts() -> Dict[str, Dict[str, float]]:
     """``{program: {"compiles", "compile_s", "cache_hits",
     "trace_lower_s"}}`` as counted so far in this process."""
     out: Dict[str, Dict[str, float]] = {}
-    with _registry._lock:
-        counters = dict(_registry._counters)
     for key, field in ((_COMPILES, "compiles"), (_COMPILE_S, "compile_s"),
                        (_CACHE_HITS, "cache_hits"),
                        (_TRACE_LOWER_S, "trace_lower_s")):
-        for (name, labels), value in counters.items():
-            if name == key:
-                out.setdefault(dict(labels)["program"], {
-                    "compiles": 0, "compile_s": 0.0, "cache_hits": 0,
-                    "trace_lower_s": 0.0})[field] = value
+        for program, value in _by_label(key, "program").items():
+            out.setdefault(program, {
+                "compiles": 0, "compile_s": 0.0, "cache_hits": 0,
+                "trace_lower_s": 0.0})[field] = value
     return out
 
 

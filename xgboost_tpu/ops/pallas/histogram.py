@@ -48,6 +48,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -59,6 +60,38 @@ def _round_up(x: int, m: int) -> int:
 
 
 _CONTRACT_LAST = (((1,), (1,)), ((), ()))  # oh [M, R] . P^T [K, R] -> [M, K]
+
+
+def _out_struct(shape, dtype, *inputs) -> jax.ShapeDtypeStruct:
+    """An ``out_shape`` that varies over the mesh axes its ``inputs`` vary
+    over: under ``shard_map(check_vma=True)`` ``pallas_call`` refuses an
+    ``out_shape`` that does not say (outside a mesh the set is empty)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in inputs))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+@jax.jit
+def _packed_hist(row, K4, PT4):
+    """One feature's histogram inside a fixed-point kernel: the packed-SWAR
+    one-hot of ``row`` ([1, R] u32 bin ids; ``_make_int8_kernel`` explains
+    the detect) against ``PT4`` ([4N, R] int8 byte planes) on the int8 MXU,
+    recombined to [B, 2N] f32. Jitted so that a kernel body traces it ONCE
+    and binds one equation per feature afterwards: the bodies are unrolled
+    in Python (28 features, up to 64 previous nodes), and binding their
+    primitives one by one cost the depth-8 batched round program 38.8 s of
+    tracing and lowering under ``fused`` at HIGGS's shape against 14.9 s
+    this way (one v5e host, PERF.md section 6, PR 28). Mosaic inlines the
+    call when it lowers: the kernel and its device time are the same."""
+    M7F = jnp.uint32(0x7F7F7F7F)
+    x = K4 ^ (row * jnp.uint32(0x01010101))                # [B/4, R]
+    y = (~(((x & M7F) + M7F) | x | M7F)) >> jnp.uint32(7)
+    oh = pltpu.bitcast(y, jnp.int8)                        # [B, R]
+    acc4 = jax.lax.dot_general(
+        oh, PT4, _CONTRACT_LAST,
+        preferred_element_type=jnp.int32)                  # [B, 4N]
+    n2 = PT4.shape[0] // 2
+    return (acc4[:, :n2].astype(jnp.float32) * 256.0
+            + acc4[:, n2:].astype(jnp.float32))
 
 
 def _u4_row(bins_ref, f):
@@ -186,26 +219,22 @@ def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
             w_iota = jax.lax.broadcasted_iota(jnp.uint32, (B // 4, R), 0)
             K4 = (w_iota * jnp.uint32(4) * jnp.uint32(0x01010101)
                   + jnp.uint32(0x03020100))
-            M7F = jnp.uint32(0x7F7F7F7F)
         else:
             bin_iota = jax.lax.broadcasted_iota(jnp.int32, (B, R), 0)
         for f in range(Fb):
             if packed:
                 row = (_u4_row(bins_ref, f).astype(jnp.uint32) if u4
                        else bins_ref[f:f + 1, :].astype(jnp.uint32))
-                x = K4 ^ (row * jnp.uint32(0x01010101))        # [B/4, R]
-                y = (~(((x & M7F) + M7F) | x | M7F)) >> jnp.uint32(7)
-                oh = pltpu.bitcast(y, jnp.int8)                # [B, R]
+                out_ref[f] += _packed_hist(row, K4, PT4)
             else:
                 row = (_u4_row(bins_ref, f) if u4
                        else bins_ref[f:f + 1, :].astype(jnp.int32))
                 oh = (bin_iota == row).astype(jnp.int8)        # [B, R]
-            acc4 = jax.lax.dot_general(
-                oh, PT4, _CONTRACT_LAST,
-                preferred_element_type=jnp.int32)          # [B, 4N]
-            acc = (acc4[:, : 2 * N].astype(jnp.float32) * 256.0
-                   + acc4[:, 2 * N:].astype(jnp.float32))
-            out_ref[f] += acc
+                acc4 = jax.lax.dot_general(
+                    oh, PT4, _CONTRACT_LAST,
+                    preferred_element_type=jnp.int32)      # [B, 4N]
+                out_ref[f] += (acc4[:, : 2 * N].astype(jnp.float32) * 256.0
+                               + acc4[:, 2 * N:].astype(jnp.float32))
 
     return kernel
 
@@ -238,6 +267,23 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
     bit-identical to the unfused one."""
     B, N, R, F = coarse_b, n_nodes, block_rows, n_feat
 
+    # the two unrolled loop bodies, jitted for the reason ``_packed_hist``
+    # gives: traced once a kernel, one equation an iteration afterwards
+    @jax.jit
+    def advance_below(bj, tj, dj, cj, j, pos_row, rel_prev, new_pos):
+        # the SMEM scalars enter as int32 operands only: a scalar bool
+        # broadcast against a vector does not lower
+        gr = jnp.where(bj == missing_bin, 1 - dj,
+                       (bj > tj).astype(jnp.int32))
+        child = 2 * pos_row + 1 + gr
+        take = jnp.where(rel_prev == j, cj, 0)
+        return jnp.where(take > 0, child, new_pos)
+
+    @jax.jit
+    def coarse_hist(row, K4, PT4):
+        cb = jnp.where(row == missing_bin, B - 1, row >> shift)
+        return _packed_hist(cb.astype(jnp.uint32), K4, PT4)
+
     def kernel(split_ref, bins_ref, q_ref, pos_ref, hist_ref, pos_out_ref,
                bins32):
         i = pl.program_id(0)
@@ -256,17 +302,10 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
         new_pos = pos_row
         for j in range(n_prev):
             fj = split_ref[0, j]
-            tj = split_ref[1, j]
-            dj = split_ref[2, j]
-            cj = split_ref[3, j]
             bj = bins32[pl.ds(fj, 1), :]                   # [1, R]
-            # the SMEM scalars enter as int32 operands only: a scalar
-            # bool broadcast against a vector does not lower
-            gr = jnp.where(bj == missing_bin, 1 - dj,
-                           (bj > tj).astype(jnp.int32))
-            child = 2 * pos_row + 1 + gr
-            take = jnp.where(rel_prev == j, cj, 0)
-            new_pos = jnp.where(take > 0, child, new_pos)
+            new_pos = advance_below(
+                bj, split_ref[1, j], split_ref[2, j], split_ref[3, j],
+                np.int32(j), pos_row, rel_prev, new_pos)
         pos_out_ref[:] = new_pos
         rel = jnp.where((new_pos >= lo) & (new_pos < lo + N),
                         new_pos - lo, N)                   # [1, R]
@@ -289,19 +328,8 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
         w_iota = jax.lax.broadcasted_iota(jnp.uint32, (B // 4, R), 0)
         K4 = (w_iota * jnp.uint32(4) * jnp.uint32(0x01010101)
               + jnp.uint32(0x03020100))
-        M7F = jnp.uint32(0x7F7F7F7F)
         for f in range(F):
-            row = bins32[f:f + 1, :]                       # [1, R]
-            cb = jnp.where(row == missing_bin, B - 1, row >> shift)
-            x = K4 ^ (cb.astype(jnp.uint32) * jnp.uint32(0x01010101))
-            y = (~(((x & M7F) + M7F) | x | M7F)) >> jnp.uint32(7)
-            oh = pltpu.bitcast(y, jnp.int8)                # [B, R]
-            acc4 = jax.lax.dot_general(
-                oh, PT4, _CONTRACT_LAST,
-                preferred_element_type=jnp.int32)          # [B, 4N]
-            acc = (acc4[:, : 2 * N].astype(jnp.float32) * 256.0
-                   + acc4[:, 2 * N:].astype(jnp.float32))
-            hist_ref[f] += acc
+            hist_ref[f] += coarse_hist(bins32[f:f + 1, :], K4, PT4)
 
     return kernel
 
@@ -359,8 +387,8 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         hist, pos_out = pl.pallas_call(
             _make_fused_kernel(F, n_prev, N, R, lo_prev, lo, missing_bin, B,
                                shift),
-            out_shape=[jax.ShapeDtypeStruct((F, B, 2 * N), jnp.float32),
-                       jax.ShapeDtypeStruct((1, n_pad), jnp.int32)],
+            out_shape=[_out_struct((F, B, 2 * N), jnp.float32, bins_t, q),
+                       _out_struct((1, n_pad), jnp.int32, bins_t, pos_t)],
             grid=grid,
             in_specs=[pl.BlockSpec((4, n_prev), lambda i: (0, 0),
                                    memory_space=pltpu.SMEM),
@@ -519,8 +547,8 @@ def scan_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     with stage("kernel.scan_hist"):
         acc = pl.pallas_call(
             _make_scan_kernel(F_blk, B, R),
-            out_shape=jax.ShapeDtypeStruct((n_nodes + 1, F_pad, B, 4),
-                                           jnp.int32),
+            out_shape=_out_struct((n_nodes + 1, F_pad, B, 4), jnp.int32,
+                                  bins_p, q_p),
             grid_spec=grid_spec,
             interpret=interpret,
             name="scan_hist",
@@ -658,7 +686,8 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                             memory_space=pltpu.VMEM)
     out_spec = pl.BlockSpec((F_blk, B, 2 * N), lambda j, i: (j, 0, 0),
                             memory_space=pltpu.VMEM)
-    out_shape = jax.ShapeDtypeStruct((F_pad, B, 2 * N), jnp.float32)
+    out_shape = _out_struct((F_pad, B, 2 * N), jnp.float32, bins_t,
+                            gpair_t, pos_t)
 
     if precision == "int8x2":
         # 15-bit fixed-point with a global per-component scale (reference

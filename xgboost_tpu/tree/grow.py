@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import trace as _trace
+from ..obs.metrics import count_grow_schedule
 from ..obs.trace import stage
 from ..ops.histogram import (build_hist, build_hist_prehot,
                              build_onehot_plane, fused_advance_coarse,
@@ -93,29 +94,14 @@ def _sample_features(key: jax.Array, base_mask: jnp.ndarray,
 AUTO_COARSE_MIN_ROWS = 1 << 16
 AUTO_COARSE_MIN_BINS = 128
 
-# Round 12: wherever "auto" promotes to the fused coarse schedule it now
-# promotes one step further, to the segmented-scan formulation
-# (hist_method="scan", ops/histogram.py scan_level_hists) — same two-level
-# search space, bit-identical models (tools/validate_scan.py grid gates
-# this), 7 data passes per level instead of fused's 13
-# (docs/performance.md round-12 table). XTPU_SCAN_PROMOTE=0 demotes auto
-# back to fused — the escape hatch if a validate_scan run ever fails on
-# new hardware. Read once at import (construction time), never traced.
-AUTO_SCAN_PROMOTE = os.environ.get("XTPU_SCAN_PROMOTE", "1").lower() \
-    not in ("0", "false", "off")
-
-# Round 14: wherever "auto" promotes to the scan formulation it now rolls
-# the whole per-tree level loop into ONE ``lax.fori_loop`` body
-# (hist_method="mega"): the same scan-formulation stage chain runs at a
-# static node capacity with sentinel-padded slots, so XLA compiles one
-# loop body instead of max_depth unrolled levels and the per-level launch
-# overhead collapses to ~1 (tools/roofline.py mega schedule). Models are
-# bit-identical to scan (tools/validate_mega.py pins the grid).
-# XTPU_MEGA=0 demotes auto back to the unrolled scan loop — the escape
-# hatch if a validate_mega run ever fails on new hardware. Read once at
-# import (construction time), never traced.
-AUTO_MEGA = os.environ.get("XTPU_MEGA", "1").lower() \
-    not in ("0", "false", "off")
+# Wherever "auto" promotes to the two-level search it runs the FUSED
+# schedule and goes no further. hist_method="scan" (ops/histogram.py
+# scan_level_hists) and "mega" (the scan stage chain under one
+# ``lax.fori_loop``) are reschedulings of the same search with
+# bit-identical models on the CPU grid (tools/validate_scan.py,
+# tools/validate_mega.py), explicit only: "auto" was moved to them on
+# roofline forecasts, and on the chip their row sort and the permute it
+# feeds were 92% of a round (PERF.md sections 5 and 6, PR 27 and PR 28).
 
 
 def auto_selects_coarse(n_rows: int, max_nbins: int, has_missing: bool, *,
@@ -207,8 +193,7 @@ def resolve_schedule(hist_method: str, n: int, max_nbins: int,
     """The histogram schedule ``_grow`` runs for ``hist_method`` at this
     shape: ``n`` local rows, ``numeric`` = no categorical feature,
     ``sharded`` = under a mesh axis. Reads the backend
-    (``auto_selects_coarse``) and the import-time ``AUTO_SCAN_PROMOTE`` /
-    ``AUTO_MEGA`` switches, nothing else."""
+    (``auto_selects_coarse``), nothing else."""
     # Smaller-child build + sibling subtraction (reference
     # src/tree/hist/histogram.h:192-207, updater_gpu_hist.cu:558): per split
     # parent only the child with FEWER rows is built — the built rows are
@@ -270,28 +255,25 @@ def resolve_schedule(hist_method: str, n: int, max_nbins: int,
     # stays measurable.
     use_fused = hist_kernel == "fused" or (hist_kernel == "auto"
                                            and use_coarse)
-    # Round 12: the segmented-scan formulation replaces the fused schedule's
-    # coarse+refine data passes with ONE sorted pass per level — rows are
-    # counting-sorted by node (ops/partition.py counting_sort_by_node), the
-    # fine histogram is a contiguous segment sum over the sorted runs, and
-    # the coarse + refine histograms are derived from it (integral
-    # slice-diffs on TPU, direct sorted builds on XLA) instead of being
-    # re-accumulated from the data. Search space and models are
-    # bit-identical to fused (tools/validate_scan.py pins the grid), so
-    # "auto" promotes scan wherever it promoted fused; explicit "fused"
-    # keeps the old schedule so the A/B stays measurable.
-    use_scan = (hist_kernel in ("scan", "mega")
-                or (hist_kernel == "auto"
-                    and use_coarse and AUTO_SCAN_PROMOTE))
+    # The segmented-scan formulation (explicit hist_method="scan") replaces
+    # the fused schedule's coarse+refine data passes with ONE sorted pass
+    # per level — rows are counting-sorted by node (ops/partition.py
+    # counting_sort_by_node), the fine histogram is a contiguous segment
+    # sum over the sorted runs, and the coarse + refine histograms are
+    # derived from it (integral slice-diffs on TPU, direct sorted builds on
+    # XLA) instead of being re-accumulated from the data. Search space and
+    # models are bit-identical to fused (tools/validate_scan.py pins the
+    # grid); "auto" never takes it: the sort and the permute cost more on
+    # the chip than the passes they save (PERF.md section 6, PR 28).
+    use_scan = hist_kernel in ("scan", "mega")
     use_coarse = use_coarse or use_scan
     use_fused = use_fused and not use_scan
-    # Round 14 megakernel (hist_method="mega"): the scan stage chain, but
+    # Megakernel (explicit hist_method="mega"): the scan stage chain, but
     # the Python depth loop becomes one ``lax.fori_loop`` with level
     # bounds as traced carries and node arrays padded to the static
-    # capacity N_cap = 2^(max_depth-1). Engages for explicit "mega" and
-    # for "auto" wherever scan promoted (XTPU_MEGA=0 opts out); outside
-    # its gates it falls back to the unrolled scan loop, which is
-    # bit-identical, so a fallback is never a correctness event:
+    # capacity N_cap = 2^(max_depth-1). Outside its gates it falls back
+    # to the unrolled scan loop, which is bit-identical, so a fallback is
+    # never a correctness event:
     # - numeric features only (scan's own restriction);
     # - every level dense (2^max_depth <= DENSE_LEVEL_MAX): the loop body
     #   is ONE program, so the dense/walk advance switch cannot vary by
@@ -303,9 +285,7 @@ def resolve_schedule(hist_method: str, n: int, max_nbins: int,
     #   (colsample_bylevel is safe: fold_in of the traced depth is
     #   value-identical to the unrolled fold_in);
     # - no smaller-child compaction (static per-level capacities).
-    use_mega = (use_scan
-                and (hist_kernel == "mega"
-                     or (hist_kernel == "auto" and AUTO_MEGA))
+    use_mega = (hist_kernel == "mega"
                 and numeric and not use_compaction
                 and param.max_depth >= 1
                 and 2 ** param.max_depth <= DENSE_LEVEL_MAX
@@ -415,6 +395,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
     sched = resolve_schedule(hist_method, n, max_nbins, has_missing, param,
                              numeric=cat is None, col_split=col_split,
                              sharded=axis_name is not None)
+    count_grow_schedule(sched.name)
     hist_kernel, use_compaction, use_prehot = (
         sched.kernel, sched.compaction, sched.prehot)
     use_coarse, use_fused, use_scan, use_mega = (
@@ -1157,9 +1138,8 @@ class TreeGrower:
         # scan-formulation partial-accumulator dtype (construction-time env
         # read; docs/env_knobs.md XTPU_SCAN_ACC): "bf16" accumulates the
         # segment sums in bf16 with an f32 residual fix-up pass — an
-        # opt-in A/B knob, NOT bit-compatible with fused, never selected
-        # by the hist-method "auto" promotion (tools/validate_scan.py
-        # gates promotion on f32 only). "auto" (Round 14) resolves to
+        # opt-in A/B knob, NOT bit-compatible with fused
+        # (tools/validate_scan.py holds f32 only). "auto" (Round 14) resolves to
         # bf16/f32 at first grow behind the measured RMS error-bound
         # gate (ops/histogram.py resolve_scan_acc)
         self.scan_acc = os.environ.get("XTPU_SCAN_ACC", "f32")
@@ -1229,11 +1209,9 @@ class TreeGrower:
         # host span for the megakernel tier — only when grow() IS the
         # dispatch (standalone/mesh); under the fused round this method
         # runs at trace time where a wall-clock span is meaningless
-        mega_live = (self.hist_method == "mega"
-                     or (self.hist_method == "auto" and AUTO_MEGA
-                         and jax.default_backend() == "tpu"))
         span = (_trace.span("round/mega")
-                if mega_live and not isinstance(bins, jax.core.Tracer)
+                if self.hist_method == "mega"
+                and not isinstance(bins, jax.core.Tracer)
                 else _contextlib.nullcontext())
         with span:
             if self.mesh is None:
@@ -1332,14 +1310,12 @@ class TreeGrower:
             # this jax), and the loop requires input/output reps to match
             # exactly — the values replicate fine (every hist passes the
             # in-loop psum), so the static check is waived like col mode
-            mega_possible = (self.hist_method == "mega"
-                             or (self.hist_method == "auto" and AUTO_MEGA
-                                 and jax.default_backend() == "tpu"))
             self._sharded_fn = jax.jit(jax.shard_map(
                 inner, mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
-                check_vma=self.split_mode != "col" and not mega_possible))
+                check_vma=(self.split_mode != "col"
+                           and self.hist_method != "mega")))
         return self._sharded_fn
 
     def _sharded(self, bins, gpair, n_real_bins, tree_mask, key) -> GrownTree:

@@ -545,8 +545,7 @@ class LossguideGrower:
         # segmented-scan histogram formulation (decided with _coarse at
         # first grow): one sorted pass per split instead of coarse+refine
         # data passes, same search space, bit-identical splits
-        # (tests/test_scan_hist.py; promotion gated by
-        # tools/validate_scan.py — see tree/grow.py AUTO_SCAN_PROMOTE)
+        # (tests/test_scan_hist.py, tools/validate_scan.py)
         self._scan = None
         # "auto" resolves to bf16/f32 at first grow via the measured RMS
         # error-bound gate (ops/histogram.py resolve_scan_acc) — bf16
@@ -802,6 +801,37 @@ class LossguideGrower:
         tree.heap_map = np.arange(nn, dtype=np.int32)  # already compact
         return LossguideGrown(positions=out[12], delta=out[13], tree=tree)
 
+    def _resolve_schedule(self, n: int) -> None:
+        """What ``hist_method`` runs at ``n`` rows (``_coarse``, ``_fused``,
+        ``_scan``): decided once (n is fixed per DMatrix), before the
+        jitted per-split programs are built; the threshold is LOCAL rows."""
+        from ..context import DATA_AXIS
+        from .grow import auto_selects_coarse
+
+        world = (1 if self.mesh is None
+                 else self.mesh.shape.get(DATA_AXIS, 1))
+        n_local = n if self.split_mode == "col" else n // max(world, 1)
+        self._coarse = self._base_hm in ("coarse", "fused", "scan",
+                                         "mega") or (
+            self._base_hm == "auto" and self.split_mode == "row"
+            and auto_selects_coarse(
+                n_local, self.max_nbins, self.has_missing,
+                numeric=self.cat is None, col_split=False))
+        # the fused (one-dispatch apply+eval) schedule rides with the
+        # coarse promotion — bit-exact, so "auto" takes it wherever
+        # it took coarse; explicit "coarse" keeps the two-dispatch
+        # schedule measurable on its own. The scan formulation keeps
+        # the one-dispatch schedule too (it changes the histogram
+        # build inside the program, not the dispatch shape).
+        self._fused = self._base_hm in ("fused", "scan", "mega") or (
+            self._base_hm == "auto" and self._coarse)
+        # "auto" stops at the fused schedule, as the depthwise grower's
+        # does (tree/grow.py resolve_schedule). One v5e chip, 1M x 28,
+        # max_leaves=64, 4 warm rounds, single samples (PERF.md section
+        # 6, PR 28): fused 9.3 s, mega (auto until then) 17.5 s, scan
+        # 21.7 s, same AUC.
+        self._scan = self._base_hm in ("scan", "mega")
+
     # ------------------------------------------------------------------ grow
     def grow(self, bins: jnp.ndarray, gpair: jnp.ndarray,
              n_real_bins: jnp.ndarray, key: jax.Array) -> LossguideGrown:
@@ -811,35 +841,7 @@ class LossguideGrower:
             2 ** max(param.max_depth, 1))
         cap = 2 * max_leaves - 1
         if self._coarse is None:
-            # decided once (n is fixed per DMatrix), before the jitted
-            # per-split programs are built; the threshold is LOCAL rows
-            from ..context import DATA_AXIS
-            from .grow import auto_selects_coarse
-
-            world = (1 if self.mesh is None
-                     else self.mesh.shape.get(DATA_AXIS, 1))
-            n_local = n if self.split_mode == "col" else n // max(world, 1)
-            self._coarse = self._base_hm in ("coarse", "fused", "scan",
-                                             "mega") or (
-                self._base_hm == "auto" and self.split_mode == "row"
-                and auto_selects_coarse(
-                    n_local, self.max_nbins, self.has_missing,
-                    numeric=self.cat is None, col_split=False))
-            # the fused (one-dispatch apply+eval) schedule rides with the
-            # coarse promotion — bit-exact, so "auto" takes it wherever
-            # it took coarse; explicit "coarse" keeps the two-dispatch
-            # schedule measurable on its own. The scan formulation keeps
-            # the one-dispatch schedule too (it changes the histogram
-            # build inside the program, not the dispatch shape).
-            self._fused = self._base_hm in ("fused", "scan", "mega") or (
-                self._base_hm == "auto" and self._coarse)
-            # Round 12: "auto" promotes the scan formulation wherever it
-            # promoted coarse (tree/grow.py AUTO_SCAN_PROMOTE gate)
-            from .grow import AUTO_SCAN_PROMOTE
-
-            self._scan = self._base_hm in ("scan", "mega") or (
-                self._base_hm == "auto" and bool(self._coarse)
-                and AUTO_SCAN_PROMOTE)
+            self._resolve_schedule(n)
         if self.scan_acc == "auto":
             # resolved ONCE per grower (shape class), on the first
             # round's gradients; paged bins can't feed the probe — they
@@ -888,17 +890,12 @@ class LossguideGrower:
         positions = self._init_positions(gpair.shape[0])
         bins_t = (None if getattr(bins, "is_paged", False)
                   else bins.T)  # loop-invariant relayout, once per tree
-        # megakernel tier (hist_method="mega", auto-promoted wherever
-        # scan promoted unless XTPU_MEGA=0): the whole greedy loop runs
-        # as ONE compiled program (_mega_greedy_loop). Restricted to the
-        # plain numeric resident/mesh-row tier — anything fancier keeps
-        # the host loop over the scan kernels, which is bit-identical
-        from .grow import AUTO_MEGA
-
+        # megakernel tier (explicit hist_method="mega"): the whole greedy
+        # loop runs as ONE compiled program (_mega_greedy_loop). Restricted
+        # to the plain numeric resident/mesh-row tier — anything fancier
+        # keeps the host loop over the scan kernels, which is bit-identical
         use_mega = (
-            bool(self._scan)
-            and (self._base_hm == "mega"
-                 or (self._base_hm == "auto" and AUTO_MEGA))
+            self._base_hm == "mega"
             and type(self) is LossguideGrower
             and self.split_mode != "col"
             and self.cat is None
