@@ -205,6 +205,13 @@ void xtpu_sketch_cuts(const float* X, int64_t n, int64_t nf,
       RadixSort(keys, nullptr);
     }
     std::vector<double> uniq, wsum;
+    // counted first and reserved exactly: grown by push_back, two vectors of
+    // up to n doubles leave a chain of freed blocks a column in each thread's
+    // arena, and 220 columns of 7.3M rows ran a 40 GiB host out of memory
+    size_t n_uniq = keys.empty() ? 0 : 1;
+    for (size_t i = 1; i < keys.size(); ++i) n_uniq += keys[i] != keys[i - 1];
+    uniq.reserve(n_uniq);
+    wsum.reserve(n_uniq);
     for (size_t i = 0; i < keys.size();) {
       size_t j = i;
       double acc = 0.0;

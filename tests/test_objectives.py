@@ -215,11 +215,15 @@ def test_weighted_training():
 
 
 @pytest.mark.parametrize("obj", ["rank:ndcg", "rank:pairwise", "rank:map"])
-@pytest.mark.parametrize("exp_gain", [True, False])
-def test_lambdarank_device_matches_host_loop(obj, exp_gain, monkeypatch):
+@pytest.mark.parametrize("exp_gain,cap", [(True, None), (False, None),
+                                          (True, 4)])
+def test_lambdarank_device_matches_host_loop(obj, exp_gain, cap, monkeypatch):
     # the padded [G, L, L] device gradient must reproduce the per-group
-    # host loop's math (topk default = deterministic all-anchor pairs),
-    # f32 vs f64 tolerance only; ragged groups + per-query weights
+    # host loop's math, f32 vs f64 tolerance only; ragged groups +
+    # per-query weights. ``cap`` None: the topk default (every pair of a
+    # group, deterministic); ``cap`` 4: only pairs whose better-ranked doc
+    # is currently in the top 4, exactly like the host ``_pairs`` (one case
+    # an objective: the cap used to be tested for rank:ndcg alone)
     from xgboost_tpu.objective import get_objective
 
     rng = np.random.RandomState(3)
@@ -233,6 +237,8 @@ def test_lambdarank_device_matches_host_loop(obj, exp_gain, monkeypatch):
     info = _Info(y, group_ptr=ptr, weights=w)
     params = {"ndcg_exp_gain": str(exp_gain).lower(),
               "lambdarank_pair_method": "topk"}
+    if cap is not None:
+        params["lambdarank_num_pair_per_sample"] = cap
 
     monkeypatch.delenv("XTPU_RANK_HOST", raising=False)
     o_dev = get_objective(obj, dict(params))
@@ -241,27 +247,12 @@ def test_lambdarank_device_matches_host_loop(obj, exp_gain, monkeypatch):
     o_host = get_objective(obj, dict(params))
     g_host = np.asarray(o_host.get_gradient(s, info))
     np.testing.assert_allclose(g_dev, g_host, rtol=2e-4, atol=1e-6)
-
-
-def test_lambdarank_device_respects_num_pair_cap(monkeypatch):
-    # kcap anchors only the currently top-ranked docs (pre-orientation),
-    # exactly like the host _pairs
-    from xgboost_tpu.objective import get_objective
-
-    rng = np.random.RandomState(5)
-    y = rng.randint(0, 3, 40).astype(np.float32)
-    s = rng.randn(40).astype(np.float32)
-    ptr = np.asarray([0, 18, 40], np.int64)
-    info = _Info(y, group_ptr=ptr)
-    params = {"lambdarank_num_pair_per_sample": 4,
-              "lambdarank_pair_method": "topk"}
-    monkeypatch.delenv("XTPU_RANK_HOST", raising=False)
-    g_dev = np.asarray(get_objective("rank:ndcg", dict(params))
-                       .get_gradient(s, info))
-    monkeypatch.setenv("XTPU_RANK_HOST", "1")
-    g_host = np.asarray(get_objective("rank:ndcg", dict(params))
-                        .get_gradient(s, info))
-    np.testing.assert_allclose(g_dev, g_host, rtol=2e-4, atol=1e-6)
+    if cap is not None:      # and the cap is not ignored
+        monkeypatch.delenv("XTPU_RANK_HOST", raising=False)
+        del params["lambdarank_num_pair_per_sample"]
+        g_all = np.asarray(get_objective(obj, dict(params))
+                           .get_gradient(s, info))
+        assert np.abs(g_all - g_dev).max() > 1e-3
 
 
 @pytest.mark.parametrize("obj", ["rank:ndcg", "rank:map"])

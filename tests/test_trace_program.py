@@ -183,6 +183,131 @@ def test_round_counters_count_at_the_spans_boundaries():
 
 # ------------------------------------------------------------ stage scopes
 
+def _rank_data(groups=12, f=6, seed=3):
+    rng = np.random.RandomState(seed)
+    sizes = rng.randint(3, 40, groups)
+    X = rng.randn(int(sizes.sum()), f).astype(np.float32)
+    y = rng.randint(0, 4, len(X)).astype(np.float32)
+    return X, y, sizes
+
+
+RANK = {"objective": "rank:ndcg", "lambdarank_pair_method": "topk",
+        "lambdarank_num_pair_per_sample": 8, "max_depth": 3, "eta": 0.3}
+
+
+def test_ranking_rounds_have_gradient_spans_and_counters(tmp_path):
+    """The general path's ``round/gradient`` beside ``round/general``, with
+    the objective, its groups and the layout key's cost; ``rank/layout``
+    once a dataset; the ranking counters from the group sizes alone."""
+    from xgboost_tpu.obs.metrics import rank_counts
+
+    X, y, sizes = _rank_data()
+    dm = xgb.DMatrix(X, label=y, group=sizes)
+    c0 = rank_counts()
+    with _Session(tmp_path) as session:
+        xgb.train(RANK, dm, 3, verbose_eval=False)
+    spans = session.spans()
+    names = [s[0] for s in spans]
+    assert names.count("round/gradient") == names.count("round/general") == 3
+    parents = {s[0]: _parent(spans, s) for s in spans}
+    assert parents["round/gradient"] == parents["round/general"] == "round"
+    grads = [s[3] for s in spans if s[0] == "round/gradient"]
+    assert all(g["objective"] == "rank:ndcg" and int(g["groups"]) == 12
+               for g in grads)
+    assert "layout_key_ms" not in grads[0]       # no call before the first
+    assert float(grads[1]["layout_key_ms"]) >= 0
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(session.dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    layouts = [e for pl in ProfileData.from_file(path).planes
+               for ln in pl.lines for e in ln.events
+               if e.name == "rank/layout"]
+    assert len(layouts) == 1
+    c1 = rank_counts()
+    L, G = int(sizes.max()), len(sizes)
+    m = np.minimum(8, sizes)
+    kept = int(np.sum(m * (sizes - 1) - m * (m - 1) // 2))
+    assert c1["dispatches"]["topk"] - c0["dispatches"].get("topk", 0) == 3
+    assert c1["pair_slots"] - c0["pair_slots"] == 3 * G * L * L
+    assert c1["pairs_kept"] - c0["pairs_kept"] == 3 * kept
+    assert c1["fill_ratio"] == pytest.approx(sizes.sum() / (G * L))
+
+
+RANK_SCOPE = re.compile(r"rank\.[a-z_]+")
+
+
+@pytest.mark.parametrize("method", ["topk", "mean"])
+def test_ranking_gradient_carries_its_root_and_parts(method):
+    """Every scoped op of the gradient program starts under
+    ``xtpu.gradient``, names a part of ``RANK_SCOPES`` below it, and no
+    ``rank.`` scope reads as a stage."""
+    from xgboost_tpu.objective import get_objective
+
+    X, y, sizes = _rank_data()
+    info = type("I", (), {})()
+    info.labels, info.weights = y, None
+    info.group_ptr = np.concatenate([[0], np.cumsum(sizes)])
+    obj = get_objective("rank:ndcg", {"lambdarank_pair_method": method,
+                                      "lambdarank_num_pair_per_sample": 2})
+    lay = obj._device_layout(info)
+    from xgboost_tpu.objective import ranking as rk
+    s = jnp.zeros(len(y), jnp.float32)
+    args = (s, lay["y"], lay["qidx"], lay["slot"], lay["starts"],
+            lay["sizes"], lay["w_row"])
+    kw = dict(L=lay["L"], exp_gain=True, objective="ndcg", chunk=4,
+              n_groups=lay["G"])
+    if method == "topk":
+        low = rk._lambda_grad_device.lower(*args, kcap=2, **kw)
+    else:
+        lay = obj._mean_stats(lay)
+        low = rk._lambda_grad_device_mean.lower(
+            *args, jax.random.key(0), lay["y_order"], lay["n_lefts"],
+            lay["n_geq"], k=2, **kw)
+    text = low.compile().as_text()
+    parts, heavy = set(), 0
+    for line in text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if not m:
+            continue
+        scopes = SCOPE.findall(m.group(1))
+        if m.group(1).startswith("jit("):
+            assert scopes[:1] == ["xtpu.gradient"], m.group(1)
+        assert set(scopes) <= {"xtpu.gradient"}, scopes
+        parts.update(RANK_SCOPE.findall(m.group(1)))
+        if HEAVY.search(line):
+            heavy += 1
+            assert RANK_SCOPE.search(m.group(1)), line.strip()[:200]
+    assert parts == {"rank." + p for p in tr.RANK_SCOPES}
+    assert heavy
+    with pytest.raises(ValueError, match="nonsense"):
+        tr.rank_scope("nonsense")
+
+
+def test_general_path_programs_open_the_round_roots():
+    """``_grow`` dispatched on its own (the general path) and the margin
+    update pass the check the fused programs pass."""
+    from xgboost_tpu.core import _add_margin_delta
+    from xgboost_tpu.tree.grow import TreeGrower, _grow
+    from xgboost_tpu.tree.param import TrainParam
+    from xgboost_tpu.tree.programs import _NumericCuts
+
+    n, F = 512, 5
+    grower = TreeGrower(TrainParam(max_depth=3), 32, _NumericCuts(F),
+                        hist_method="auto", has_missing=False)
+    rng = np.random.RandomState(0)
+    text = _grow.lower(
+        jnp.asarray(rng.randint(0, 31, (n, F)), jnp.uint8),
+        jnp.asarray(rng.randn(n, 2), jnp.float32),
+        jnp.full((F,), 31, jnp.int32), jnp.ones((F,), bool),
+        jax.random.key(0), None, None, None, param=grower.param,
+        max_nbins=32, hist_method=grower.hist_method, axis_name=None,
+        has_missing=False, scan_acc="f32").compile().as_text()
+    assert {"xtpu.grow", "xtpu.leaf"} <= _check_scopes(text)
+    text = _add_margin_delta.lower(jnp.zeros((n, 1)),
+                                   jnp.zeros((n, 1))).compile().as_text()
+    assert 'xtpu.margin' in text
+
+
 def test_unknown_stage_raises():
     with pytest.raises(ValueError, match="nonsense"):
         tr.stage("nonsense")

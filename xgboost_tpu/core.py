@@ -69,6 +69,14 @@ def _margin_bad_rows(margin, n_valid: int):
     return jnp.sum(~jnp.isfinite(margin[:n_valid]).all(axis=-1))
 
 
+@jax.jit
+@stage("margin")
+def _add_margin_delta(margin, delta):
+    """The general path's margin update as one named program, under the
+    root the fused round body gives the same add."""
+    return margin + delta
+
+
 def _check_margin_finite(margin, n_valid: int, objective: str,
                          first_round: int, n_rounds: int = 1,
                          bad=None) -> None:
@@ -958,7 +966,9 @@ class Booster:
                 self._mem_round(state)
             return
         margin = self.gbm.training_margin(state)
-        with self._monitor.section("GetGradient"):
+        with obs_trace.span("round/gradient", "train",
+                            self._gradient_span_args(state, iteration)), \
+                self._monitor.section("GetGradient"):
             if fobj is None:
                 if self._is_vertical_federated():
                     # margins replicate across parties, labels do not: the
@@ -1007,7 +1017,7 @@ class Booster:
         count_round_dispatch("general")
         with self._monitor.section("UpdateCache"):
             if self.gbm.supports_margin_cache:
-                state["margin"] = state["margin"] + delta
+                state["margin"] = _add_margin_delta(state["margin"], delta)
             else:
                 state["margin"] = self.gbm.compute_margin(state)
         if observer.enabled():
@@ -1016,6 +1026,20 @@ class Booster:
         self._note_host_round(iteration, _prior_trees)
         if obs_memory.enabled():
             self._mem_round(state)
+
+    def _gradient_span_args(self, state: Dict[str, Any],
+                            iteration: int) -> Dict[str, Any]:
+        """Args of the general path's ``round/gradient`` span: the objective,
+        the query groups it works over (0: none), and for a ranking
+        objective what its layout's content key cost at its LAST call
+        (``layout_key_ms``: the span opens before this call's is known)."""
+        ptr = getattr(state["info"], "group_ptr", None)
+        args = {"iteration": iteration, "objective": self.obj.name,
+                "groups": 0 if ptr is None else len(ptr) - 1}
+        key_ms = getattr(self.obj, "layout_key_ms", None)
+        if key_ms is not None:
+            args["layout_key_ms"] = round(key_ms, 3)
+        return args
 
     def _mem_round(self, state: Dict[str, Any]) -> None:
         """HBM-accounting round boundary (callers gate on

@@ -5,13 +5,14 @@ Reference: ``src/objective/lambdarank_obj.cc:44-160,620-628`` + caches in
 ``src/objective/lambdarank_obj.cu``. Per query group, pairs (i, j) with
 label_i > label_j get the RankNet lambda scaled by the metric delta
 (|ΔNDCG| / |ΔMAP| / 1). Pair generation follows the reference's two modes:
-``mean`` (k random pairs per doc) and ``topk`` (pairs anchored at the current
-top-k).
+``mean`` (k random pairs per doc) and ``topk`` (every pair whose
+better-ranked doc is inside the truncation, each pair once).
 
 All three objectives run ON DEVICE in both pair modes: groups pad into a
 ``[G, L]`` matrix (L = longest group), per-group ranks come from two
 stable argsorts, and the pair interaction is a ``[G, L, L]`` VPU tensor
-for ``topk`` (anchors × all docs, deterministic) or a sampled ``[G, L, k]``
+for ``topk`` (top-k docs × the docs ranked below them, deterministic) or a
+sampled ``[G, L, k]``
 tensor for ``mean`` (the default, matching the reference: k uniform
 out-of-label-bucket rivals per doc, ``lambdarank_obj.h:231-275``), chunked
 over groups by ``lax.map`` to bound memory — the TPU answer to the
@@ -26,8 +27,12 @@ the reference's extra empirical scalings — the per-pair
 ``delta /= (|s_i - s_j| + 0.01)`` division, the hessian x2, and the
 per-group ``log2(1+sum_lambda)/sum_lambda`` normalization borrowed from
 LightGBM (``lambdarank_obj.h:112-126``, ``lambdarank_obj.cc:178-231``).
-Measured quality at the MSLR shape matches (BASELINE.md #3); the paper
-recipe keeps the device kernels branch-free. ``lambdarank_unbiased``
+Quality against the reference implementation on a public LETOR set: not
+measured. Measured (PERF.md, cell ``istella-letor.train``, one v5e chip,
+7,325,625 x 220, 23,219 groups): gradients, trees and ``ndcg@10`` against a
+plain LambdaMART reference of this recipe (``benchmark/lib/
+reference_rank.py``). The paper recipe keeps the device kernels
+branch-free. ``lambdarank_unbiased``
 implements the same eq. 30/31 bias estimation the reference does, ON
 DEVICE for both pair methods (``_debias_dev``; the ti+/tj- vectors live
 on the host in f64 for the normalize/damp update and serialization, as
@@ -38,12 +43,16 @@ from __future__ import annotations
 
 import functools
 import os
+import time
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import trace as obs_trace
+from ..obs.metrics import count_rank_gradient
+from ..obs.trace import rank_scope, stage
 from ..registry import OBJECTIVES
 from .base import ObjInfo, Objective
 
@@ -86,6 +95,28 @@ def _map_prefix(yp, vp, order, L):
     T0 = jnp.concatenate([jnp.zeros((T.shape[0], 1), T.dtype), T], axis=1)
     R = jnp.maximum(Ck[:, -1], 1.0)
     return Ck, T0, R
+
+
+def _padded_layout(s, y, starts, sizes, *, n_groups, chunk, L):
+    """Scores and labels as ``[Gp, L]`` (Gp: the groups padded up to whole
+    chunks), the mask of real slots and the group sizes ``[Gp]``.
+    ``_device_layout`` lists the rows group by group, so group g's slots are
+    the L rows from ``starts[g]`` on, masked past its size: one gather of
+    whole slices, where a scatter of single rows costs the device a sort of
+    all rows and leaves ops that carry no scope."""
+    Gp = -(-n_groups // chunk) * chunk
+    first = jnp.zeros((Gp,), jnp.int32).at[:n_groups].set(starts)
+    sz = jnp.zeros((Gp,), jnp.int32).at[:n_groups].set(
+        sizes.astype(jnp.int32))
+    valid = jnp.arange(L, dtype=jnp.int32)[None, :] < sz[:, None]
+
+    def padded(rows, fill):
+        ext = jnp.concatenate([rows, jnp.zeros((L,), rows.dtype)])
+        block = jax.vmap(
+            lambda p: jax.lax.dynamic_slice(ext, (p,), (L,)))(first)
+        return jnp.where(valid, block, fill)
+
+    return Gp, padded(s, -jnp.inf), padded(y, 0.0), valid, sz
 
 
 def _ranknet_dev(s_i, s_j, a_is_i, delta, mask):
@@ -172,47 +203,55 @@ def _map_delta_dev(rank_i, rank_j, a_is_i, Ck, T0, R):
     jax.jit,
     static_argnames=("kcap", "L", "exp_gain", "objective", "chunk",
                      "n_groups", "kpos"))
-def _lambda_grad_device(s, y, qidx, slot, sizes, w_row, ti=None, tj=None, *,
+@stage("gradient")
+def _lambda_grad_device(s, y, qidx, slot, starts, sizes, w_row, ti=None,
+                        tj=None, *,
                         kcap, L, exp_gain, objective, chunk, n_groups,
                         kpos=0):
-    """All-pairs LambdaRank lambdas over padded [G, L] groups.
+    """Truncated all-pairs LambdaRank lambdas over padded [G, L] groups.
 
     Exactly the host loop's math (orientation, RankNet clip, 1e-16 hessian
-    floor) in f32. ``kcap`` = 0 means every doc anchors (the topk default);
-    otherwise only docs currently ranked < kcap anchor pairs — matching the
-    anchor-before-orientation semantics of ``_pairs``.
+    floor) in f32. Every unordered pair of rows with different labels
+    counts ONCE, from its better-ranked row, and only where that row is
+    currently ranked < ``kcap`` (the reference's ``MakePairs`` rule for
+    ``topk``: ``for i < k: for j > i``); ``kcap`` = 0 means no truncation.
+    The same pairs as ``_pairs``.
     """
-    Gp = -(-n_groups // chunk) * chunk
-    s_pad = jnp.full((Gp, L), -jnp.inf, jnp.float32).at[qidx, slot].set(s)
-    y_pad = jnp.zeros((Gp, L), jnp.float32).at[qidx, slot].set(y)
-    valid = jnp.zeros((Gp, L), bool).at[qidx, slot].set(True)
-    sz = jnp.zeros((Gp,), jnp.int32).at[:n_groups].set(
-        sizes.astype(jnp.int32))
-    kc = sz if kcap == 0 else jnp.minimum(kcap, sz)
-    disc = 1.0 / jnp.log2(jnp.arange(L, dtype=jnp.float32) + 2.0)
+    with rank_scope("layout"):
+        Gp, s_pad, y_pad, valid, sz = _padded_layout(
+            s, y, starts, sizes, n_groups=n_groups, chunk=chunk, L=L)
+        kc = sz if kcap == 0 else jnp.minimum(kcap, sz)
+        disc = 1.0 / jnp.log2(jnp.arange(L, dtype=jnp.float32) + 2.0)
 
     def gains_j(v):
         return (jnp.exp2(v) - 1.0) if exp_gain else v
 
+    def order_of(sp, yp):
+        with rank_scope("order"):
+            order = jnp.argsort(-sp, axis=1, stable=True)
+            rank_of = jnp.argsort(order, axis=1, stable=True)  # inverse perm
+            y_desc = -jnp.sort(-yp, axis=1)
+            idcg = jnp.sum(gains_j(y_desc) * disc[None, :], axis=1)
+            inv_idcg = jnp.where(idcg > 0, 1.0 / idcg, 0.0)
+            gv = gains_j(yp)                              # [C, L]
+            dv = disc[rank_of]                            # [C, L]
+        return order, rank_of, inv_idcg, gv, dv
+
     def one_chunk(args):
         sp, yp, vp, kcc = args                       # [C, L] / [C]
-        order = jnp.argsort(-sp, axis=1, stable=True)
-        rank_of = jnp.argsort(order, axis=1, stable=True)  # inverse perm
-        y_desc = -jnp.sort(-yp, axis=1)
-        idcg = jnp.sum(gains_j(y_desc) * disc[None, :], axis=1)
-        inv_idcg = jnp.where(idcg > 0, 1.0 / idcg, 0.0)
-        gv = gains_j(yp)                              # [C, L]
-        dv = disc[rank_of]                            # [C, L]
+        order, rank_of, inv_idcg, gv, dv = order_of(sp, yp)
         yi, yj = yp[:, :, None], yp[:, None, :]
+        ri, rj = rank_of[:, :, None], rank_of[:, None, :]
+        # each pair once: i is its better-ranked row, inside the truncation
         mask = (vp[:, :, None] & vp[:, None, :] & (yi != yj)
-                & (rank_of < kcc[:, None])[:, :, None])
+                & (ri < kcc[:, None, None]) & (ri < rj))
         a_is_i = yi > yj
         Cn = rank_of.shape[0]
         delta = _delta_dev(
             objective, yp=yp, vp=vp, order=order, L=L, gv=gv, dv=dv,
             inv_idcg=inv_idcg, gj=gv[:, None, :], dj=dv[:, None, :],
-            rank_i=jnp.broadcast_to(rank_of[:, :, None], (Cn, L, L)),
-            rank_j=jnp.broadcast_to(rank_of[:, None, :], (Cn, L, L)),
+            rank_i=jnp.broadcast_to(ri, (Cn, L, L)),
+            rank_j=jnp.broadcast_to(rj, (Cn, L, L)),
             a_is_i=a_is_i)
         lam, hes, p = _ranknet_dev(sp[:, :, None], sp[:, None, :], a_is_i,
                                    delta, mask)
@@ -237,26 +276,35 @@ def _lambda_grad_device(s, y, qidx, slot, sizes, w_row, ti=None, tj=None, *,
         return g, h, li_c, lj_c
 
     cs = lambda a: a.reshape(Gp // chunk, chunk, *a.shape[1:])
-    g_pad, h_pad, li_s, lj_s = jax.lax.map(
-        one_chunk, (cs(s_pad), cs(y_pad), cs(valid), cs(kc)))
-    g = g_pad.reshape(Gp, L)[qidx, slot] * w_row
-    h = h_pad.reshape(Gp, L)[qidx, slot] * w_row
-    gpair = jnp.stack([g, h], axis=-1)[:, None, :]   # [n, 1, 2] f32
-    if kpos > 0:
-        m = min(kpos, L)
-        li = jnp.zeros((kpos,), jnp.float32).at[:m].set(
-            li_s.sum(axis=0)[:m])
-        lj = jnp.zeros((kpos,), jnp.float32).at[:m].set(
-            lj_s.sum(axis=0)[:m])
-        return gpair, li, lj
-    return gpair, None, None
+    with rank_scope("pairs"):       # the chunk loop's own glue included
+        g_pad, h_pad, li_s, lj_s = jax.lax.map(
+            one_chunk, (cs(s_pad), cs(y_pad), cs(valid), cs(kc)))
+    return _rows_of(g_pad, h_pad, li_s, lj_s, qidx, slot, w_row, Gp, L, kpos)
+
+
+def _rows_of(g_pad, h_pad, li_s, lj_s, qidx, slot, w_row, Gp, L, kpos):
+    """The padded sums gathered back to rows: ([n, 1, 2] gradient pairs,
+    li, lj); the last two None unless the unbiased path is on."""
+    with rank_scope("reduce"):
+        g = g_pad.reshape(Gp, L)[qidx, slot] * w_row
+        h = h_pad.reshape(Gp, L)[qidx, slot] * w_row
+        gpair = jnp.stack([g, h], axis=-1)[:, None, :]   # [n, 1, 2] f32
+        if kpos > 0:
+            m = min(kpos, L)
+            li = jnp.zeros((kpos,), jnp.float32).at[:m].set(
+                li_s.sum(axis=0)[:m])
+            lj = jnp.zeros((kpos,), jnp.float32).at[:m].set(
+                lj_s.sum(axis=0)[:m])
+            return gpair, li, lj
+        return gpair, None, None
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("k", "L", "exp_gain", "objective", "chunk",
                      "n_groups", "kpos"))
-def _lambda_grad_device_mean(s, y, qidx, slot, sizes, w_row, key,
+@stage("gradient")
+def _lambda_grad_device_mean(s, y, qidx, slot, starts, sizes, w_row, key,
                              y_order_g, n_lefts_g, n_geq_g, ti=None,
                              tj=None, *, k, L, exp_gain, objective, chunk,
                              n_groups, kpos=0):
@@ -270,33 +318,31 @@ def _lambda_grad_device_mean(s, y, qidx, slot, sizes, w_row, key,
     ``lax.map`` step. RNG stream: jax.random.split(key, n_chunks)
     (chunk-size-dependent); the reference seeds per (iter, group), so
     distributional — not bitwise — parity."""
-    Gp = -(-n_groups // chunk) * chunk
-    s_pad = jnp.full((Gp, L), -jnp.inf, jnp.float32).at[qidx, slot].set(s)
-    y_pad = jnp.zeros((Gp, L), jnp.float32).at[qidx, slot].set(y)
-    valid = jnp.zeros((Gp, L), bool).at[qidx, slot].set(True)
-    sz = jnp.zeros((Gp,), jnp.int32).at[:n_groups].set(
-        sizes.astype(jnp.int32))
-    disc = 1.0 / jnp.log2(jnp.arange(L, dtype=jnp.float32) + 2.0)
+    with rank_scope("layout"):
+        Gp, s_pad, y_pad, valid, sz = _padded_layout(
+            s, y, starts, sizes, n_groups=n_groups, chunk=chunk, L=L)
+        disc = 1.0 / jnp.log2(jnp.arange(L, dtype=jnp.float32) + 2.0)
+        # pad the precomputed per-group bucket statistics to [Gp, L]
+        op = jnp.zeros((Gp, L), jnp.int32).at[:n_groups].set(y_order_g)
+        nl_p = jnp.zeros((Gp, L), jnp.int32).at[:n_groups].set(n_lefts_g)
+        ng_p = jnp.zeros((Gp, L), jnp.int32).at[:n_groups].set(n_geq_g)
 
     def gains_j(v):
         return (jnp.exp2(v) - 1.0) if exp_gain else v
 
-    # pad the precomputed per-group bucket statistics to [Gp, L]
-    op = jnp.zeros((Gp, L), jnp.int32).at[:n_groups].set(y_order_g)
-    nl_p = jnp.zeros((Gp, L), jnp.int32).at[:n_groups].set(n_lefts_g)
-    ng_p = jnp.zeros((Gp, L), jnp.int32).at[:n_groups].set(n_geq_g)
     C = chunk
     iota_c = jnp.arange(C, dtype=jnp.int32)
 
     def one_chunk(args):
         sp, yp, vp, szc, y_order, n_lefts, n_geq, ck = args
-        order = jnp.argsort(-sp, axis=1, stable=True)
-        rank_of = jnp.argsort(order, axis=1, stable=True)
-        y_desc = -jnp.sort(-yp, axis=1)
-        idcg = jnp.sum(gains_j(y_desc) * disc[None, :], axis=1)
-        inv_idcg = jnp.where(idcg > 0, 1.0 / idcg, 0.0)
-        gv = gains_j(yp)
-        dv = disc[rank_of]                          # [C, L]
+        with rank_scope("order"):
+            order = jnp.argsort(-sp, axis=1, stable=True)
+            rank_of = jnp.argsort(order, axis=1, stable=True)
+            y_desc = -jnp.sort(-yp, axis=1)
+            idcg = jnp.sum(gains_j(y_desc) * disc[None, :], axis=1)
+            inv_idcg = jnp.where(idcg > 0, 1.0 / idcg, 0.0)
+            gv = gains_j(yp)
+            dv = disc[rank_of]                          # [C, L]
         yi = yp[:, :, None]
         n_riv = n_lefts + (szc[:, None] - n_geq)
         u = (jax.random.uniform(ck, (C, L, k))
@@ -353,20 +399,11 @@ def _lambda_grad_device_mean(s, y, qidx, slot, sizes, w_row, key,
 
     cs = lambda a: a.reshape(Gp // chunk, chunk, *a.shape[1:])
     keys = jax.random.split(key, Gp // chunk)
-    g_pad, h_pad, li_s, lj_s = jax.lax.map(
-        one_chunk, (cs(s_pad), cs(y_pad), cs(valid), cs(sz), cs(op),
-                    cs(nl_p), cs(ng_p), keys))
-    g = g_pad.reshape(Gp, L)[qidx, slot] * w_row
-    h = h_pad.reshape(Gp, L)[qidx, slot] * w_row
-    gpair = jnp.stack([g, h], axis=-1)[:, None, :]   # [n, 1, 2] f32
-    if kpos > 0:
-        m = min(kpos, L)
-        li = jnp.zeros((kpos,), jnp.float32).at[:m].set(
-            li_s.sum(axis=0)[:m])
-        lj = jnp.zeros((kpos,), jnp.float32).at[:m].set(
-            lj_s.sum(axis=0)[:m])
-        return gpair, li, lj
-    return gpair, None, None
+    with rank_scope("pairs"):       # the chunk loop's own glue included
+        g_pad, h_pad, li_s, lj_s = jax.lax.map(
+            one_chunk, (cs(s_pad), cs(y_pad), cs(valid), cs(sz), cs(op),
+                        cs(nl_p), cs(ng_p), keys))
+    return _rows_of(g_pad, h_pad, li_s, lj_s, qidx, slot, w_row, Gp, L, kpos)
 
 
 class _LambdaRankBase(Objective):
@@ -393,11 +430,13 @@ class _LambdaRankBase(Objective):
             i = np.repeat(np.arange(n), k)[keep]
             j = order_y[np.clip(ridx, 0, n - 1)].ravel()[keep]
             return i, j
-        # topk: anchor docs currently ranked < k against everything
+        # topk: docs currently ranked < k against every doc ranked below
+        # them, so each pair counts once (reference MakePairs:
+        # ``for i < k: for j > i`` over the rank order)
         anchors = np.nonzero(rank_of < min(k, n))[0]
         i = np.repeat(anchors, n)
         j = np.tile(np.arange(n), len(anchors))
-        keep = y[i] != y[j]
+        keep = (y[i] != y[j]) & (rank_of[i] < rank_of[j])
         return i[keep], j[keep]
 
     def _delta(self, y, i, j, rank_of, inv_idcg, exp_gain) -> np.ndarray:
@@ -408,36 +447,60 @@ class _LambdaRankBase(Objective):
         hashes the CONTENT of labels/groups/weights, not object identity:
         a mutated-in-place MetaInfo or a recycled id() must rebuild, or the
         device gradient would silently use stale y/slots (the host path
-        re-reads them every call). Hashing ~1 MB of label bytes is ~0.1 ms
-        against a multi-hundred-ms gradient."""
+        re-reads them every call). What the key costs a call is kept in
+        ``layout_key_ms`` (``core.Booster.update`` puts the last reading on
+        its ``round/gradient`` span); the build itself is the ``rank/layout``
+        span, once a dataset."""
+        t0 = time.perf_counter()
         ptr = np.asarray(info.group_ptr, dtype=np.int64)
         y_np = np.asarray(info.labels, np.float32).reshape(-1)
         w_np = (None if info.weights is None
                 else np.asarray(info.weights, np.float32))
         key = (hash(ptr.tobytes()), hash(y_np.tobytes()),
                None if w_np is None else hash(w_np.tobytes()))
+        self.layout_key_ms = 1e3 * (time.perf_counter() - t0)
         cached = getattr(self, "_dev_layout", None)
         if cached is not None and cached[0] == key:
             return cached[1]
         sizes = np.diff(ptr)
         G, L = len(sizes), int(sizes.max(initial=1))
-        qidx = np.repeat(np.arange(G, dtype=np.int32), sizes)
-        slot = (np.arange(ptr[-1], dtype=np.int32)
-                - np.repeat(ptr[:-1], sizes).astype(np.int32))
-        if w_np is not None:
-            w_row = np.repeat(w_np, sizes) if len(w_np) == G else w_np
-        else:
-            w_row = np.ones(int(ptr[-1]), np.float32)
-        layout = dict(
-            G=G, L=L, _ptr=ptr, _y_np=y_np,
-            qidx=jnp.asarray(qidx), slot=jnp.asarray(slot),
-            sizes=jnp.asarray(sizes, jnp.int32),
-            w_row=jnp.asarray(w_row),
-            y=jnp.asarray(y_np),
-            # chunk groups so one [C, L, L] pair block stays ~64 MB
-            chunk=max(1, min(G, (1 << 24) // max(L * L, 1))))
+        with obs_trace.span("rank/layout", "train",
+                            {"groups": G, "longest": L,
+                             "rows": int(ptr[-1])}):
+            qidx = np.repeat(np.arange(G, dtype=np.int32), sizes)
+            slot = (np.arange(ptr[-1], dtype=np.int32)
+                    - np.repeat(ptr[:-1], sizes).astype(np.int32))
+            if w_np is not None:
+                w_row = np.repeat(w_np, sizes) if len(w_np) == G else w_np
+            else:
+                w_row = np.ones(int(ptr[-1]), np.float32)
+            layout = dict(
+                G=G, L=L, _ptr=ptr, _y_np=y_np,
+                qidx=jnp.asarray(qidx), slot=jnp.asarray(slot),
+                starts=jnp.asarray(ptr[:-1], jnp.int32),
+                sizes=jnp.asarray(sizes, jnp.int32),
+                w_row=jnp.asarray(w_row),
+                y=jnp.asarray(y_np),
+                fill=float(ptr[-1]) / max(G * L, 1),
+                # chunk groups so one [C, L, L] pair block stays ~64 MB
+                chunk=max(1, min(G, (1 << 24) // max(L * L, 1))))
         self._dev_layout = (key, layout)
         return layout
+
+    @staticmethod
+    def _pairs_kept(layout, kcap: int) -> int:
+        """Pairs the ``topk`` truncation admits inside the groups' real
+        rows, from the group sizes alone: a group of n rows with m = min(k,
+        n) truncated ranks holds m (n - 1) - m (m - 1) / 2 (rank r < m
+        against the n - 1 - r rows below it). Label ties are not counted
+        out, so it bounds the pairs that carry a lambda from above. Cached
+        on the layout by ``kcap``."""
+        kept = layout.setdefault("_kept", {})
+        if kcap not in kept:
+            n = np.diff(layout["_ptr"])
+            m = n if kcap == 0 else np.minimum(kcap, n)
+            kept[kcap] = int(np.sum(m * (n - 1) - m * (m - 1) // 2))
+        return kept[kcap]
 
     @staticmethod
     def _mean_stats(layout):
@@ -513,8 +576,8 @@ class _LambdaRankBase(Objective):
                 # _position_bias_state — it was left in flight so it
                 # overlapped that round's tree build instead of blocking
                 # twice per round (numerically identical, the update
-                # still precedes this iteration's gradient; the gain on
-                # the attached chip is not measured)
+                # still precedes this iteration's gradient; not measured
+                # on the chip: no cell runs the unbiased path, PERF.md 7)
                 kpos = self._position_bias_state(method, int(lay["L"]))
                 bias = jnp.asarray(
                     np.stack([self._ti_plus, self._tj_minus]), jnp.float32)
@@ -530,20 +593,26 @@ class _LambdaRankBase(Objective):
                 # own footprint, not the all-pairs [C, L, L] budget
                 chunk = max(1, min(lay["G"],
                                    (1 << 24) // max(lay["L"] * k, 1)))
+                slots, kept = lay["L"] * k, n * k
                 gpair, li, lj = _lambda_grad_device_mean(
-                    s, lay["y"], lay["qidx"], lay["slot"], lay["sizes"],
-                    lay["w_row"], key, lay["y_order"], lay["n_lefts"],
+                    s, lay["y"], lay["qidx"], lay["slot"], lay["starts"],
+                    lay["sizes"], lay["w_row"], key, lay["y_order"], lay["n_lefts"],
                     lay["n_geq"], ti_d, tj_d, k=k, L=lay["L"],
                     exp_gain=exp_gain, objective=self.name.split(":")[1],
                     chunk=chunk, n_groups=lay["G"], kpos=kpos)
             else:
                 kcap = int(self.params.get(
                     "lambdarank_num_pair_per_sample", 0))
+                chunk = lay["chunk"]
+                slots, kept = lay["L"] ** 2, self._pairs_kept(lay, kcap)
                 gpair, li, lj = _lambda_grad_device(
-                    s, lay["y"], lay["qidx"], lay["slot"], lay["sizes"],
-                    lay["w_row"], ti_d, tj_d, kcap=kcap, L=lay["L"],
+                    s, lay["y"], lay["qidx"], lay["slot"], lay["starts"],
+                    lay["sizes"], lay["w_row"], ti_d, tj_d, kcap=kcap, L=lay["L"],
                     exp_gain=exp_gain, objective=self.name.split(":")[1],
-                    chunk=lay["chunk"], n_groups=lay["G"], kpos=kpos)
+                    chunk=chunk, n_groups=lay["G"], kpos=kpos)
+            # groups padded up to whole chunks are swept too
+            count_rank_gradient(method, -(-lay["G"] // chunk) * chunk * slots,
+                                kept, lay["fill"])
             if unbiased:
                 # ONE packed device array, pulled lazily at the next
                 # gradient call / serialization (see _flush_bias_update)

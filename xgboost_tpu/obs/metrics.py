@@ -31,6 +31,7 @@ __all__ = ["Sample", "Family", "HistogramData", "MetricsRegistry",
            "get_registry", "render_families", "count_degrade",
            "degrade_counts", "count_round_dispatch", "count_tree_flush",
            "count_grow_schedule", "grow_schedule_counts",
+           "count_rank_gradient", "rank_counts",
            "program_compile_counts"]
 
 LabelSet = Tuple[Tuple[str, str], ...]
@@ -342,6 +343,44 @@ def _by_label(name: str, label: str) -> Dict[str, float]:
 
 def grow_schedule_counts() -> Dict[str, int]:
     return {k: int(v) for k, v in _by_label(_GROW_SCHEDULE, "schedule").items()}
+
+
+_RANK_SLOTS = "xtpu_rank_pair_slots_total"
+_RANK_KEPT = "xtpu_rank_pairs_kept_total"
+_RANK_DISPATCHES = "xtpu_rank_gradient_dispatches_total"
+_RANK_FILL = "xtpu_rank_layout_fill_ratio"
+
+
+def count_rank_gradient(method: str, pair_slots: int, pairs_kept: int,
+                        fill_ratio: float) -> None:
+    """One device dispatch of the ranking gradient (``objective/ranking.py``).
+    ``pair_slots``: slots of the pair blocks it sweeps (steps x C x L x L
+    under ``topk``, steps x C x L x k under ``mean``). ``pairs_kept``: pairs
+    the truncation admits inside the groups' real rows, from the group sizes
+    alone: an upper bound on the pairs that carry a lambda (label ties are
+    not counted out). Both are host arithmetic on the cached layout: no
+    device pull."""
+    _registry.inc(_RANK_DISPATCHES, labels=(("method", method),),
+                  help="device dispatches of the ranking gradient, by pair "
+                       "method")
+    _registry.inc(_RANK_SLOTS, by=float(pair_slots),
+                  help="pair-block slots swept by the ranking gradient")
+    _registry.inc(_RANK_KEPT, by=float(pairs_kept),
+                  help="pairs the truncation admits within real rows (upper "
+                       "bound on pairs with a lambda)")
+    _registry.set_gauge(_RANK_FILL, fill_ratio,
+                        help="rows / (groups x longest group) of the padded "
+                             "ranking layout")
+
+
+def rank_counts() -> Dict[str, Any]:
+    """The ranking counters as one dict: ``pair_slots``, ``pairs_kept``,
+    ``fill_ratio`` and ``dispatches`` by method."""
+    return {"pair_slots": _registry.get(_RANK_SLOTS),
+            "pairs_kept": _registry.get(_RANK_KEPT),
+            "fill_ratio": _registry.get(_RANK_FILL),
+            "dispatches": {k: int(v) for k, v in _by_label(
+                _RANK_DISPATCHES, "method").items()}}
 
 
 # ---- compile counters by program -------------------------------------------

@@ -47,7 +47,8 @@ from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 __all__ = ["Span", "Tracer", "enable", "disable", "enabled", "tracer",
            "span", "instant", "export", "reset", "set_identity",
-           "STAGES", "ROUND_ROOTS", "stage", "opened_stages"]
+           "STAGES", "ROUND_ROOTS", "stage", "opened_stages",
+           "RANK_SCOPES", "rank_scope"]
 
 # Every ``xtpu.<stage>`` scope a compiled program may carry. A device op
 # belongs to the INNERMOST one on its path. Nesting, outermost first:
@@ -102,6 +103,27 @@ def stage(name: str):
 def opened_stages() -> frozenset:
     """The stages this process has opened while tracing, so far."""
     return frozenset(_opened)
+
+
+# The ranking gradient's parts (``objective/ranking.py``), one level below
+# ``xtpu.gradient``: ``rank.<part>``. They are NOT stages: the name carries
+# no ``xtpu.`` prefix, so a reader that books an op to the innermost
+# ``xtpu.<stage>`` on its path still books the whole gradient to
+# ``gradient``, and a reader of the parts takes the innermost ``rank.``
+# scope. ``layout``: rows gathered into the padded ``[G, L]`` buffers;
+# ``order``: the per-group sorts, ranks, discounts and ideal DCG;
+# ``pairs``: the ``[C, L, L]`` pair block and the chunk loop around it;
+# ``reduce``: the padded sums gathered back to rows.
+RANK_SCOPES = ("layout", "order", "pairs", "reduce")
+
+
+def rank_scope(name: str):
+    """``jax.named_scope("rank.<name>")`` for a part in :data:`RANK_SCOPES`;
+    any other name raises at trace time, as :func:`stage` does."""
+    if name not in RANK_SCOPES:
+        raise ValueError(f"unknown ranking scope {name!r}: add it to "
+                         "xgboost_tpu.obs.trace.RANK_SCOPES")
+    return jax.named_scope("rank." + name)
 
 
 class Span:

@@ -299,6 +299,7 @@ def resolve_schedule(hist_method: str, n: int, max_nbins: int,
     jax.jit,
     static_argnames=("param", "max_nbins", "hist_method", "axis_name",
                      "has_missing", "split_mode", "scan_acc"))
+@stage("grow")      # its own root where it IS the program (the general path)
 def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
           tree_mask: jnp.ndarray, key: jax.Array,
           monotone: Optional[jnp.ndarray] = None,
