@@ -18,6 +18,12 @@ these tests pin it at three altitudes:
             depthwise, lossguide, paged external memory, and the mesh
             column-split composition — identical dumps/predictions.
 
+Below the LAST level of a deep tree there is no next coarse pass to fuse
+with: ``advance_leaf`` routes the rows and looks their leaf up in one
+kernel sweep where a TPU runs it (``advance_leaf_pallas``), bit for bit
+what ``update_positions`` + ``leaf_value[positions]`` give; the gate is
+held by ``xtpu_grow_epilogue_total{kind}``.
+
 Plus the ADVICE r5 #2 satellite: colsample draws seeded from real columns
 only, so padded mesh-col-split feature axes keep sampling parity.
 """
@@ -31,8 +37,12 @@ import jax
 import jax.numpy as jnp
 
 import xgboost_tpu as xgb
-from xgboost_tpu.ops.histogram import build_hist_segment, fused_advance_coarse
-from xgboost_tpu.ops.pallas.histogram import (build_hist_pallas,
+from xgboost_tpu.obs.metrics import grow_epilogue_counts
+from xgboost_tpu.ops.histogram import (advance_leaf, build_hist_segment,
+                                       fused_advance_coarse)
+from xgboost_tpu.ops.pallas.histogram import (ADVANCE_LEAF_MAX_NODES,
+                                              advance_leaf_pallas,
+                                              build_hist_pallas,
                                               fused_advance_coarse_pallas)
 from xgboost_tpu.ops.partition import advance_positions_level, update_positions
 from xgboost_tpu.ops.split import COARSE_B, coarse_bin_ids
@@ -169,6 +179,162 @@ def test_fused_op_walk_kind_matches_update_positions():
     hist_ref = build_hist_segment(cb, gpair, rel, n_level, COARSE_B)
     np.testing.assert_array_equal(np.asarray(pos_f), np.asarray(pos_ref))
     np.testing.assert_array_equal(np.asarray(hist_f), np.asarray(hist_ref))
+
+
+def _last_level(n, F, n_prev, has_missing, split_share, seed):
+    """A tree grown down to a last evaluated level of ``n_prev`` nodes, as
+    the walk's whole-heap arrays: rows parked on the level, a share that
+    stopped at a leaf on an earlier level, ``split_share`` of the level's
+    nodes splitting, a leaf table with signed zeros in it."""
+    rng = np.random.RandomState(seed)
+    lo, max_nodes = n_prev - 1, 4 * n_prev - 1
+    max_nbins = 257 if has_missing else 256
+    missing_bin = max_nbins - 1 if has_missing else max_nbins
+    bins = rng.randint(0, max_nbins, (n, F)).astype(
+        np.int16 if has_missing else np.uint8)
+    positions = rng.randint(lo, lo + n_prev, n).astype(np.int32)
+    stopped = rng.rand(n) < 0.15
+    positions[stopped] = rng.randint(0, lo, stopped.sum())
+    can_split = rng.rand(n_prev) < split_share
+    sf = np.full(max_nodes, -1, np.int32)
+    sb = np.zeros(max_nodes, np.int32)
+    dl = np.zeros(max_nodes, bool)
+    isf = np.zeros(max_nodes, bool)
+    sf[lo:lo + n_prev] = np.where(can_split, rng.randint(0, F, n_prev), -1)
+    sb[lo:lo + n_prev] = np.where(can_split,
+                                  rng.randint(0, max_nbins - 1, n_prev), 0)
+    dl[lo:lo + n_prev] = can_split & (rng.rand(n_prev) < 0.5)
+    isf[lo:lo + n_prev] = can_split
+    leaf = rng.randn(max_nodes).astype(np.float32)
+    leaf[rng.rand(max_nodes) < 0.1] = -0.0
+    arrs = tuple(jnp.asarray(a) for a in (sf, sb, dl, isf))
+    return (jnp.asarray(bins), jnp.asarray(positions), arrs,
+            jnp.asarray(leaf), missing_bin)
+
+
+def _bits(x):
+    return np.asarray(x).view(np.int32)
+
+
+# the block is 256 rows: 700 and 2,049 rows are no multiple of it
+@pytest.mark.parametrize("n_prev", [128, 256, ADVANCE_LEAF_MAX_NODES])
+@pytest.mark.parametrize("n,has_missing,split_share", [
+    (700, False, 0.8), (2049, True, 0.8),
+    (700, True, 0.0),            # a level where no node splits
+    (1024, False, 1.0),
+])
+def test_advance_leaf_pallas_interpret_matches_walk(n_prev, n, has_missing,
+                                                    split_share):
+    """The last level's kernel against ``update_positions`` +
+    ``leaf_value[positions]``: positions and delta bit for bit, signed
+    zeros too."""
+    F = 5
+    bins, positions, arrs, leaf, missing_bin = _last_level(
+        n, F, n_prev, has_missing, split_share, seed=n_prev + n)
+    lo = n_prev - 1
+    pos_k, delta_k = advance_leaf_pallas(
+        bins.T, positions, *(a[lo:lo + n_prev] for a in arrs), leaf,
+        n_prev=n_prev, missing_bin=missing_bin, block_rows=256,
+        interpret=True)
+    pos_ref = update_positions(bins, positions, *arrs, missing_bin)
+    np.testing.assert_array_equal(np.asarray(pos_k), np.asarray(pos_ref))
+    np.testing.assert_array_equal(_bits(delta_k), _bits(leaf[pos_ref]))
+    moved = np.asarray(pos_ref) != np.asarray(positions)
+    assert moved.any() == (split_share > 0)
+    assert (~moved).any()        # rows that stopped at a leaf earlier
+
+
+def test_advance_leaf_op_kinds(monkeypatch):
+    """What ``advance_leaf`` reports: the kernel for a walk-kind level on
+    a TPU, the XLA advance off it, for a dense level and wherever a
+    column split needs the decisions' psum."""
+    n, F, n_prev = 300, 4, 128
+    bins, positions, arrs, leaf, missing_bin = _last_level(
+        n, F, n_prev, False, 0.8, seed=5)
+    walk = {"kind": "walk", "lo": n_prev - 1, "n_level": n_prev,
+            "arrs": arrs, "feat_offset": jnp.int32(0)}
+    pos_ref = update_positions(bins, positions, *arrs, missing_bin)
+
+    pos, delta, kind = advance_leaf(bins, positions, walk, leaf, missing_bin)
+    assert kind == "walk" and delta is None      # the CPU keeps the walk
+    np.testing.assert_array_equal(np.asarray(pos), np.asarray(pos_ref))
+    pos, delta, kind = advance_leaf(bins, positions, walk, leaf, missing_bin,
+                                    interpret=True)
+    assert kind == "kernel"
+    np.testing.assert_array_equal(np.asarray(pos), np.asarray(pos_ref))
+    np.testing.assert_array_equal(_bits(delta), _bits(leaf[pos_ref]))
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def kind_of(prev, **kw):
+        out = {}
+
+        def fn(b, p):
+            pos, delta, out["kind"] = advance_leaf(b, p, prev, leaf,
+                                                   missing_bin, **kw)
+            return pos
+        out["jaxpr"] = str(jax.make_jaxpr(fn, axis_env=[("cols", 2)])(
+            bins, positions))
+        return out["kind"], "pallas_call" in out["jaxpr"]
+
+    assert kind_of(walk) == ("kernel", True)
+    # column split: the owner's decisions cross the shards by one psum
+    assert kind_of(walk, decision_axis="cols") == ("walk", False)
+    lo6 = 63
+    dense = {"kind": "dense", "lo": lo6, "n_level": 64,
+             "arrs": tuple(a[lo6:lo6 + 64] for a in arrs)}
+    assert kind_of(dense) == ("dense", False)
+    too_wide = dict(walk, lo=2 * ADVANCE_LEAF_MAX_NODES - 1,
+                    n_level=2 * ADVANCE_LEAF_MAX_NODES)
+    assert kind_of(too_wide) == ("walk", False)
+
+
+# a shape a case: jit's trace cache does not know the backend is patched
+@pytest.mark.parametrize("n,depth,backend,split_mode,kind", [
+    (641, 8, "tpu", "row", "kernel"), (642, 6, "tpu", "row", "dense"),
+    (643, 8, "cpu", "row", "walk"), (644, 8, "tpu", "col", "walk"),
+])
+def test_grow_epilogue_counter(monkeypatch, n, depth, backend, split_mode,
+                               kind):
+    """``xtpu_grow_epilogue_total{kind}``: ``_grow`` under the fused
+    schedule takes the kernel below a level past DENSE_LEVEL_MAX on a TPU,
+    and only there; one count a traced program."""
+    from xgboost_tpu.tree.grow import _grow
+    from xgboost_tpu.tree.param import TrainParam
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    F = 6
+    args = (jax.ShapeDtypeStruct((n, F), jnp.uint8),
+            jax.ShapeDtypeStruct((n, 2), jnp.float32),
+            jax.ShapeDtypeStruct((F,), jnp.int32),
+            jax.ShapeDtypeStruct((F,), jnp.bool_), jax.random.key(0))
+    kwargs = dict(param=TrainParam(max_depth=depth), max_nbins=256,
+                  hist_method="fused", has_missing=False)
+    if split_mode == "col":
+        kwargs.update(axis_name="cols", split_mode="col")
+
+    def trace():
+        # traced, never lowered: the CPU cannot compile a Mosaic kernel
+        return str(jax.make_jaxpr(
+            lambda *a: _grow(*a, **kwargs).delta,
+            axis_env=[("cols", 2)])(*args))
+
+    before = grow_epilogue_counts()
+    jaxpr = trace()
+    after = grow_epilogue_counts()
+    grew = {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
+    assert grew == {kind: 1}
+    assert ("name=advance_leaf" in jaxpr) == (kind == "kernel")
+
+
+def test_one_pass_schedule_has_no_epilogue():
+    X, y = _binary_data(n=1237, F=5)
+    before = grow_epilogue_counts().get("none", 0)
+    xgb.train({"objective": "binary:logistic", "max_depth": 3, "max_bin": 32,
+               "hist_method": "segment"}, xgb.DMatrix(X, label=y), 1,
+              verbose_eval=False)
+    assert grow_epilogue_counts().get("none", 0) == before + 1
 
 
 def _binary_data(n=4000, F=8, missing=False, seed=11):

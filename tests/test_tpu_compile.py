@@ -19,7 +19,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from xgboost_tpu.context import DATA_AXIS
-from xgboost_tpu.obs.metrics import grow_schedule_counts
+from xgboost_tpu.obs.metrics import grow_epilogue_counts, grow_schedule_counts
 from xgboost_tpu.tree.grow import AUTO_COARSE_MIN_ROWS, TreeGrower
 from xgboost_tpu.tree.param import TrainParam
 from xgboost_tpu.tree.programs import _NumericCuts
@@ -46,15 +46,20 @@ def mesh():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("method,schedule,kernels", [
-    ("auto", "fused", 2 * 3),            # advance+coarse and refine, a level
-    ("mega", "mega", 1),                 # check_vma waived: the loop carry
+@pytest.mark.parametrize("method,schedule,depth,kernels,epilogue", [
+    # advance+coarse and refine, a level
+    ("auto", "fused", 3, 2 * 3, "dense"),
+    ("mega", "mega", 3, 1, "dense"),     # check_vma waived: the loop carry
+    # the published depth: a last level of 128 nodes, past DENSE_LEVEL_MAX,
+    # takes the advance_leaf kernel below it
+    ("auto", "fused", 8, 2 * 8 + 1, "kernel"),
 ])
 def test_row_split_grow_program_compiles_for_v5e(monkeypatch, mesh, method,
-                                                 schedule, kernels):
+                                                 schedule, depth, kernels,
+                                                 epilogue):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     rows = 4 * AUTO_COARSE_MIN_ROWS      # each shard at auto's threshold
-    grower = TreeGrower(TrainParam(max_depth=3), MAX_NBINS,
+    grower = TreeGrower(TrainParam(max_depth=depth), MAX_NBINS,
                         _NumericCuts(FEATURES), hist_method=method,
                         mesh=mesh, has_missing=False)
 
@@ -63,12 +68,15 @@ def test_row_split_grow_program_compiles_for_v5e(monkeypatch, mesh, method,
                                     sharding=NamedSharding(mesh, spec))
 
     before = grow_schedule_counts().get(schedule, 0)
+    before_epilogue = grow_epilogue_counts().get(epilogue, 0)
     compiled = grower.sharded_program().lower(
         arg((rows, FEATURES), jnp.uint8, P(DATA_AXIS, None)),
         arg((rows, 2), jnp.float32, P(DATA_AXIS, None)),
         arg((FEATURES,), jnp.int32, P()), arg((FEATURES,), jnp.bool_, P()),
         arg((2,), jnp.uint32, P())).compile()
     assert grow_schedule_counts().get(schedule, 0) == before + 1
+    assert grow_epilogue_counts().get(epilogue, 0) == before_epilogue + 1
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= kernels
+    assert ("xtpu.kernel.advance_leaf" in text) == (epilogue == "kernel")
     assert "all-reduce" in text          # the histogram psum

@@ -314,6 +314,7 @@ def test_unknown_stage_raises():
     assert set(tr.ROUND_ROOTS) <= set(tr.STAGES)
     assert tr.opened_stages() <= set(tr.STAGES)
     assert "permute" in tr.STAGES and "kernel.scan_hist" in tr.STAGES
+    assert "advance_leaf" in tr.KERNELS and "kernel.advance_leaf" in tr.STAGES
     assert {"kernel." + k for k in tr.KERNELS} <= set(tr.STAGES)
 
 
@@ -396,7 +397,8 @@ def test_lossguide_programs_open_known_stages_only(hist_method):
 
 
 @pytest.mark.parametrize("kernel", ["build_hist_int8", "build_hist",
-                                    "fused_advance_coarse", "scan_hist"])
+                                    "fused_advance_coarse", "scan_hist",
+                                    "advance_leaf"])
 def test_pallas_kernels_are_named_and_scoped(kernel):
     """Interpret mode (no Mosaic on CPU): the kernel's name and its
     ``xtpu.kernel.<name>`` scope are on the traced program, with the
@@ -420,6 +422,18 @@ def test_pallas_kernels_are_named_and_scoped(kernel):
             b, g, p * 0, *split, lo_prev=0, n_prev=1, lo=1, n_level=2,
             missing_bin=B - 1, interpret=True))
         want = {"quantise", "fold"}
+    elif kernel == "advance_leaf":
+        # through the epilogue's dispatcher: the kernel sits under the
+        # scope the walk had, so what lies around it stays partition time
+        from xgboost_tpu.ops.histogram import advance_leaf
+
+        tree = (jnp.zeros(7, jnp.int32), jnp.full(7, 7, jnp.int32),
+                jnp.zeros(7, bool), jnp.zeros(7, bool).at[1:3].set(True))
+        prev = {"kind": "walk", "lo": 1, "n_level": 2, "arrs": tree}
+        fn = jax.jit(lambda b, g, p: advance_leaf(
+            b.T, p + 1, prev, jnp.arange(7, dtype=jnp.float32), B - 1,
+            bins_t=b, interpret=True)[:2])
+        want = {"advance"}
     else:
         precision = "int8x2" if kernel == "build_hist_int8" else "f32"
         fn = jax.jit(lambda b, g, p: ph.build_hist_pallas(

@@ -51,7 +51,8 @@ def main() -> int:
     import xgboost_tpu as xgb
     from chip_smoke import MAX_BIN, SEED, make_data, train_params
     from xgboost_tpu.metric.auc import binary_roc_auc
-    from xgboost_tpu.obs.metrics import grow_schedule_counts
+    from xgboost_tpu.obs.metrics import (grow_epilogue_counts,
+                                         grow_schedule_counts)
     from xgboost_tpu.tree.grow import resolve_schedule
 
     dev = jax.devices()[0]
@@ -90,6 +91,7 @@ def main() -> int:
     print(json.dumps({
         "ok": True, "smoke_timings": rows,
         "grow_schedule_total": grow_schedule_counts(),
+        "grow_epilogue_total": grow_epilogue_counts(),
         "device": {"platform": dev.platform, "kind": dev.device_kind,
                    "count": len(jax.devices())}}))
     return 0

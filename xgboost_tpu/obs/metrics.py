@@ -31,6 +31,7 @@ __all__ = ["Sample", "Family", "HistogramData", "MetricsRegistry",
            "get_registry", "render_families", "count_degrade",
            "degrade_counts", "count_round_dispatch", "count_tree_flush",
            "count_grow_schedule", "grow_schedule_counts",
+           "count_grow_epilogue", "grow_epilogue_counts",
            "count_rank_gradient", "rank_counts",
            "program_compile_counts"]
 
@@ -333,6 +334,20 @@ def count_grow_schedule(schedule: str) -> None:
                   help="grow programs traced, by histogram schedule")
 
 
+_GROW_EPILOGUE = "xtpu_grow_epilogue_total"
+
+
+def count_grow_epilogue(kind: str) -> None:
+    """One trace of the depth-wise grow program, by what advances the rows
+    below its LAST level (``ops/histogram.py advance_leaf``): ``kernel``
+    (one Mosaic sweep that also writes the leaf delta), ``walk`` (the
+    per-row gather walk), ``dense`` (the matmul advance) or ``none`` (no
+    deferred advance: the one-pass schedules). Counted while jax traces,
+    beside ``count_grow_schedule``."""
+    _registry.inc(_GROW_EPILOGUE, labels=(("kind", kind),),
+                  help="grow programs traced, by last-level advance")
+
+
 def _by_label(name: str, label: str) -> Dict[str, float]:
     """``{value of label: count}`` over every series of counter ``name``."""
     with _registry._lock:
@@ -343,6 +358,10 @@ def _by_label(name: str, label: str) -> Dict[str, float]:
 
 def grow_schedule_counts() -> Dict[str, int]:
     return {k: int(v) for k, v in _by_label(_GROW_SCHEDULE, "schedule").items()}
+
+
+def grow_epilogue_counts() -> Dict[str, int]:
+    return {k: int(v) for k, v in _by_label(_GROW_EPILOGUE, "kind").items()}
 
 
 _RANK_SLOTS = "xtpu_rank_pair_slots_total"

@@ -57,11 +57,12 @@ __all__ = ["Span", "Tracer", "enable", "disable", "enabled", "tracer",
 #     grow >  sort | advance_hist | hist     one data sweep of a level
 #               > advance | count_sort | permute | quantise
 #                 | kernel.<name> | fold
-#             exchange | window | refine | eval | delta | advance
+#             exchange | window | refine | eval | delta
+#             advance > kernel.advance_leaf     below the last level
 #     grow >  root | pop | apply | push | finalize     (lossguide), with
 #             hist / eval and the sweep's stages inside
 KERNELS = ("build_hist", "build_hist_int8", "fused_advance_coarse",
-           "scan_hist")         # the round programs' kernels, by ``name=``
+           "scan_hist", "advance_leaf")   # the round programs', by ``name=``
 STAGES = (
     "gradient", "grow", "leaf", "margin",
     "sort", "advance_hist", "hist",

@@ -494,6 +494,43 @@ def fused_advance_coarse(bins: jnp.ndarray, gpair: jnp.ndarray,
     return positions, hist
 
 
+def advance_leaf(bins: jnp.ndarray, positions: jnp.ndarray, prev: dict,
+                 leaf_value: jnp.ndarray, missing_bin: int, *,
+                 bins_t: jnp.ndarray = None, decision_axis=None,
+                 interpret: bool = False):
+    """The epilogue of a fused or scan grow program: advance rows below
+    the LAST evaluated level's splits (``prev``: ``fused_advance_coarse``
+    docstring), with no coarse pass left to fuse with. Returns
+    ``(new_positions, delta, kind)``.
+
+    ``kind`` says what ran. ``"kernel"``: the level is past the dense
+    matmul advance (``prev["kind"] == "walk"``) and one Mosaic sweep of
+    the bin tile routes the rows AND writes ``delta =
+    leaf_value[new_positions]`` (``ops/pallas/histogram.py
+    advance_leaf_pallas``), bit for bit what the walk and the leaf gather
+    give: on a TPU, with no cross-shard decision exchange (column split
+    keeps the walk and its psum), up to ``ADVANCE_LEAF_MAX_NODES`` nodes.
+    ``"dense"`` / ``"walk"``: the XLA advance of ``_advance_below``;
+    ``delta`` is None and the caller looks the leaves up."""
+    from .pallas.histogram import ADVANCE_LEAF_MAX_NODES, advance_leaf_pallas
+
+    lo, n_level = prev["lo"], prev["n_level"]
+    use_pallas = ((interpret or jax.default_backend() == "tpu")
+                  and decision_axis is None and prev["kind"] == "walk"
+                  and n_level <= ADVANCE_LEAF_MAX_NODES)
+    if not use_pallas:
+        return (_advance_below(bins, positions, prev, missing_bin,
+                               decision_axis), None, prev["kind"])
+    with stage("advance"):
+        # the level's rows of the walk's whole-heap arrays
+        feat, thr, dleft, cs = (a[lo:lo + n_level] for a in prev["arrs"])
+        new_positions, delta = advance_leaf_pallas(
+            bins.T if bins_t is None else bins_t, positions, feat, thr,
+            dleft, cs, leaf_value, n_prev=n_level, missing_bin=missing_bin,
+            interpret=interpret)
+    return new_positions, delta, "kernel"
+
+
 # ---- segmented-scan level scheme (hist_method="scan") ----------------------
 # Round 12: the scan formulation sorts the level's rows by node once, then
 # derives EVERY histogram the two-level scheme needs from that one ordering:

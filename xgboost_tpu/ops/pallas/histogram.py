@@ -413,6 +413,151 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         return pos_out[0, :n], gh.transpose(3, 0, 1, 2)  # [N, F, B, 2]
 
 
+def _make_advance_leaf_kernel(n_feat: int, n_prev: int, block_rows: int,
+                              missing_bin: int):
+    """The LAST level's advance with the leaf delta behind it: one sweep of
+    the ``[F, R]`` tile routes the rows below the deepest evaluated level
+    and writes each row's leaf value, where the XLA epilogue paid six
+    one-element gathers over all rows (``ops/partition.py
+    update_positions`` + ``leaf_value[positions]``).
+
+    The level is too wide for ``_make_fused_kernel``'s scalar loop (one
+    ``[1, R]`` pass a previous node: a sublane in eight at work), so the
+    row's payload comes by a one-hot of its node over the SUBLANES, on
+    full-width vectors and in integers. ``tab_ref`` is ``[3, 2N, 1]``
+    int32, row ``heap node id + 1`` (row 0 stays zero: pad rows carry
+    position -1), ``N = n_prev`` nodes on the level, which starts at heap
+    node ``N - 1``:
+
+    - plane 0, rows ``1..N-1`` (the levels above): the node's leaf value,
+      as its bit pattern: a row that stopped there takes it;
+    - plane 0, rows ``N..2N-1`` (the level): the split, packed
+      ``feature | bin << 16 | default_left << 28 | can_split << 29``;
+    - planes 1 and 2, rows ``N..2N-1``: the left and the right child's
+      leaf bits where the node splits, the node's own where it does not.
+
+    A select-and-sum over the one-hot has one non-zero term a row, so it
+    IS the table entry, bit for bit (a signed zero too: the leaf values
+    never pass through float arithmetic). The row's bin of its node's
+    split feature is the same select over the tile's feature sublanes."""
+    F, N, R = n_feat, n_prev, block_rows
+    C = min(N, _LEAF_CHUNK)
+
+    def take(acc, hit, tab_ref, plane, c):
+        return acc + jnp.where(hit, tab_ref[plane, c:c + C, :], 0)
+
+    def kernel(tab_ref, bins_ref, pos_ref, pos_out_ref, delta_ref):
+        pos = pos_ref[:]                                   # [1, R] i32
+        idx = pos + 1
+        iota = jax.lax.broadcasted_iota(jnp.int32, (C, R), 0)
+        zero = jnp.zeros((C, R), jnp.int32)
+        own = zero
+        for c in range(0, N, C):
+            own = take(own, iota == idx - c, tab_ref, 0, c)
+        split, left, right = zero, zero, zero
+        for c in range(N, 2 * N, C):
+            hit = iota == idx - c
+            split = take(split, hit, tab_ref, 0, c)
+            left = take(left, hit, tab_ref, 1, c)
+            right = take(right, hit, tab_ref, 2, c)
+        own, split, left, right = (
+            jnp.sum(a, axis=0, keepdims=True)
+            for a in (own, split, left, right))            # [1, R] each
+        feat = split & 0xFFFF
+        thr = (split >> 16) & 0xFFF
+        dleft = (split >> 28) & 1
+        can_split = split >> 29
+
+        fiota = jax.lax.broadcasted_iota(jnp.int32, (F, R), 0)
+        b = jnp.sum(jnp.where(fiota == feat,
+                              bins_ref[:].astype(jnp.int32), 0),
+                    axis=0, keepdims=True)                 # [1, R]
+
+        go_right = jnp.where(b == missing_bin, 1 - dleft,
+                             (b > thr).astype(jnp.int32))
+        pos_out_ref[:] = jnp.where(can_split > 0, 2 * pos + 1 + go_right,
+                                   pos)
+        bits = own + jnp.where(go_right > 0, right, left)
+        delta_ref[:] = jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+    return kernel
+
+
+# the widest last level ``advance_leaf_pallas`` takes: max_depth 10
+ADVANCE_LEAF_MAX_NODES = 512
+# table sublanes a step of the kernel's select: ``[C, R]`` accumulators
+_LEAF_CHUNK = 64
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_prev", "missing_bin", "block_rows", "interpret"))
+def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
+                        feat: jnp.ndarray, thr: jnp.ndarray,
+                        dleft: jnp.ndarray, can_split: jnp.ndarray,
+                        leaf_value: jnp.ndarray, *, n_prev: int,
+                        missing_bin: int, block_rows: int = 2048,
+                        interpret: bool = False):
+    """Advance below the deepest evaluated level and look the leaf up, in
+    one sweep (see ``_make_advance_leaf_kernel``).
+
+    bins_t: [F, n] bin ids; positions: [n] heap node ids, none below the
+    level; feat/thr/dleft/can_split: [n_prev] the level's splits, the
+    level starting at heap node ``n_prev - 1``; leaf_value: [max_nodes]
+    f32 over the whole heap, the level's children included.
+    -> (new_positions [n] int32, delta [n] f32 = leaf_value[new_positions])
+    """
+    F, n = bins_t.shape
+    N, lo = n_prev, n_prev - 1
+    if F > 0xFFFF or missing_bin > 0xFFF:
+        raise NotImplementedError("advance_leaf_pallas packs the feature "
+                                  "in 16 bits and the bin in 12")
+    R = min(block_rows, max(_round_up(n, 128), 128))
+    n_pad = _round_up(max(n, R), R)
+    if n_pad != n:
+        bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
+        positions = jnp.pad(positions, (0, n_pad - n), constant_values=-1)
+    pos_t = positions.astype(jnp.int32)[None, :]           # [1, n]
+
+    bits = jax.lax.bitcast_convert_type(
+        leaf_value.astype(jnp.float32), jnp.int32)
+    node = lo + jnp.arange(N, dtype=jnp.int32)
+    cs = can_split.astype(bool)
+    packed = (jnp.maximum(feat, 0).astype(jnp.int32)
+              | thr.astype(jnp.int32) << 16
+              | dleft.astype(jnp.int32) << 28
+              | cs.astype(jnp.int32) << 29)
+    zeros = jnp.zeros((N,), jnp.int32)
+    tab = jnp.stack([
+        jnp.concatenate([zeros[:1], bits[:lo], packed]),
+        jnp.concatenate([zeros, jnp.where(cs, bits[2 * node + 1],
+                                          bits[node])]),
+        jnp.concatenate([zeros, jnp.where(cs, bits[2 * node + 2],
+                                          bits[node])]),
+    ])[:, :, None]                                         # [3, 2N, 1]
+
+    with stage("kernel.advance_leaf"):
+        pos_out, delta = pl.pallas_call(
+            _make_advance_leaf_kernel(F, N, R, missing_bin),
+            out_shape=[_out_struct((1, n_pad), jnp.int32, bins_t, pos_t),
+                       _out_struct((1, n_pad), jnp.float32, bins_t, pos_t)],
+            grid=(n_pad // R,),
+            in_specs=[pl.BlockSpec((3, 2 * N, 1), lambda i: (0, 0, 0),
+                                   memory_space=pltpu.VMEM),
+                      pl.BlockSpec((F, R), lambda i: (0, i),
+                                   memory_space=pltpu.VMEM),
+                      pl.BlockSpec((1, R), lambda i: (0, i),
+                                   memory_space=pltpu.VMEM)],
+            out_specs=[pl.BlockSpec((1, R), lambda i: (0, i),
+                                    memory_space=pltpu.VMEM),
+                       pl.BlockSpec((1, R), lambda i: (0, i),
+                                    memory_space=pltpu.VMEM)],
+            interpret=interpret,
+            name="advance_leaf",
+        )(tab, bins_t, pos_t)
+    return pos_out[0, :n], delta[0, :n]
+
+
 def _make_scan_kernel(n_feat: int, n_bins: int, block_rows: int):
     """Segmented-scan histogram kernel (hist_method="scan"): rows arrive
     pre-sorted by node into R-row blocks that each hold rows of exactly

@@ -28,9 +28,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import trace as _trace
-from ..obs.metrics import count_grow_schedule
+from ..obs.metrics import count_grow_epilogue, count_grow_schedule
 from ..obs.trace import stage
-from ..ops.histogram import (build_hist, build_hist_prehot,
+from ..ops.histogram import (advance_leaf, build_hist, build_hist_prehot,
                              build_onehot_plane, fused_advance_coarse,
                              scan_advance_level, scan_level_hists,
                              subtract_siblings)
@@ -160,7 +160,21 @@ def exchange_best_split(res, axis_name, F: int, *, with_cat: bool = False):
 
 # The gather-free level ops materialise [n, n_level] intermediates; past
 # this level width the memory cost outweighs the gather cost, so deeper
-# levels fall back to the per-row gather walk.
+# levels fall back to the per-row gather walk (``update_positions``: five
+# one-element gathers over all rows, 545 ms a round at 10.5M rows on a
+# v5e, PERF.md section 6, PR 30). What still walks, and where:
+# - the LAST level of a fused or scan program (128 nodes at max_depth 8)
+#   does not on a TPU: one kernel sweep routes the rows and writes their
+#   leaf delta (``ops/histogram.py advance_leaf``, up to 512 nodes). It
+#   walks on the CPU, under column split (the decisions' psum), and past
+#   max_depth 10;
+# - the IN-LOOP boundaries behind levels of 128 and 256 nodes (max_depth
+#   9 and 10: ``fused_advance_coarse`` falls to its XLA body there), and
+#   every deep level of the one-pass schedules and of explicit ``coarse``
+#   (which advance inside the level loop), walk on every backend;
+# - without ``dense_delta`` (2^max_depth past this constant) the leaf
+#   values come by ``leaf_value[positions]``, one more gather, wherever
+#   the kernel did not write them.
 DENSE_LEVEL_MAX = 64
 
 
@@ -990,26 +1004,6 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                 num_segments=n_next + 1)[:n_next]
             built_is_left = counts[0::2] <= counts[1::2]
 
-    if (use_fused or use_scan) and pending_adv is not None:
-        # epilogue: route rows below the deepest level's splits — advance
-        # only, there is no next coarse pass left to fuse with
-        with stage("advance"):
-            if pending_adv["kind"] == "dense":
-                lo_p, nl_p = pending_adv["lo"], pending_adv["n_level"]
-                feat_v, bin_v, dl_v, cs_v = pending_adv["arrs"]
-                rel_p = jnp.where(
-                    (positions >= lo_p) & (positions < lo_p + nl_p),
-                    positions - lo_p, nl_p).astype(jnp.int32)
-                positions = advance_positions_level(
-                    bins.astype(jnp.float32), positions, rel_p, feat_v,
-                    bin_v, dl_v, cs_v, missing_bin,
-                    decision_axis=axis_name if col_split else None)
-            else:
-                positions = update_positions(
-                    bins, positions, *pending_adv["arrs"], missing_bin,
-                    decision_axis=axis_name if col_split else None,
-                    feat_offset=feat_off)
-
     with stage("leaf"):
         w = calc_weight(node_sum[:, 0], node_sum[:, 1], param)
         if monotone is not None:
@@ -1018,6 +1012,19 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
         leaf_value = jnp.where(active & is_leaf, w, 0.0).astype(jnp.float32)
         base_weight = jnp.where(active, w, 0.0).astype(jnp.float32)
 
+    # what advances the rows below the last level (xtpu_grow_epilogue_total):
+    # mega's own dense advance, or nothing where every level advanced itself
+    epilogue, leaf_delta = "dense" if use_mega else "none", None
+    if pending_adv is not None:
+        # epilogue: route rows below the deepest level's splits — there is
+        # no next coarse pass left to fuse with. Past DENSE_LEVEL_MAX, on
+        # a TPU, one kernel sweep also looks each row's leaf up
+        positions, leaf_delta, epilogue = advance_leaf(
+            bins, positions, pending_adv, leaf_value, missing_bin,
+            bins_t=bins_t, decision_axis=axis_name if col_split else None)
+    count_grow_epilogue(epilogue)
+
+    with stage("leaf"):
         if dense_delta:
             # deepest level: every surviving node is a leaf
             lo = 2 ** max_depth - 1
@@ -1030,6 +1037,8 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                       == jnp.arange(n_level, dtype=jnp.int32)[None, :])
             delta = delta + jnp.sum(
                 jnp.where(rel_oh, w_last[None, :], 0.0), axis=1)
+        elif leaf_delta is not None:
+            delta = leaf_delta
         else:
             delta = leaf_value[positions]
     return GrownTree(split_feature=split_feature, split_bin=split_bin,
