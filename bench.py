@@ -314,16 +314,7 @@ def bench_higgs11m():
              if os.environ.get("BENCH_EXACT", "1") != "0" else None)
     twopass = (pinned_steady("coarse")
                if os.environ.get("BENCH_COARSE", "1") != "0" else None)
-    # r12 segmented-scan formulation vs the r6 fused schedule, both
-    # PINNED so the speedup is schedule-vs-schedule (auto is fused)
-    scan = fused = None
-    if os.environ.get("BENCH_SCAN", "1") != "0":
-        fused = pinned_steady("fused")
-        scan = pinned_steady("scan")
-    # r14 megakernel vs the r12 scan formulation, both PINNED
-    mega = (pinned_steady("mega")
-            if os.environ.get("BENCH_MEGA", "1") != "0" else None)
-    return 20.0 / t20, steady, exact, twopass, scan, fused, mega
+    return 20.0 / t20, steady, exact, twopass
 
 
 def bench_shard1375k():
@@ -600,8 +591,7 @@ def main():
         # every_n_rounds=10 on the 1Mx28 shape; acceptance bar < 2%
         result["checkpoint_overhead_pct"] = ckpt_pct
     if os.environ.get("BENCH_11M", "1") != "0":
-        (cold20, steady, exact, twopass, scan, fused,
-         mega) = bench_higgs11m()
+        cold20, steady, exact, twopass = bench_higgs11m()
         # gpu_hist-class derived target: BASELINE.md "North star" section
         result["higgs11m_cold20_rounds_per_sec"] = round(cold20, 4)
         result["higgs11m_steady_rounds_per_sec"] = (
@@ -618,20 +608,6 @@ def main():
             None if steady is None else round(steady, 4))
         result["higgs11m_twopass_steady_rounds_per_sec"] = twopass
         result["higgs11m_exact_steady_rounds_per_sec"] = exact
-        # r12 headline pair: the scan formulation's steady ms/round and
-        # its speedup over the pinned fused schedule (roofline predicts
-        # 1.21x at this shape — tools/roofline.py)
-        result["higgs11m_scan_ms_per_round"] = (
-            None if not scan else round(1000.0 / scan, 2))
-        result["scan_vs_fused_speedup"] = (
-            None if not (scan and fused) else round(scan / fused, 4))
-        # r14 headline pair: the whole-tree megakernel's steady ms/round
-        # and its speedup over the pinned scan schedule (roofline
-        # predicts 1.40x at this shape — tools/roofline.py mega)
-        result["higgs11m_mega_ms_per_round"] = (
-            None if not mega else round(1000.0 / mega, 2))
-        result["mega_vs_scan_speedup"] = (
-            None if not (mega and scan) else round(mega / scan, 4))
     if os.environ.get("BENCH_SHARD", "1") != "0":
         # v5e-8 projection input (1.375M rows/chip; VERDICT r5 item 8)
         result["shard1375k_ms_per_round"] = bench_shard1375k()

@@ -48,7 +48,6 @@ class Sizes:
     kernel_rows: int
     kernel_feats: int
     kernel_nodes: tuple  # n_nodes per kernel check; node 1 is left empty
-    kernel_wide_feats: int   # past the scan kernel's whole-F tile budget
     serve_sizes: tuple
     contrib_sizes: tuple
     mesh_shard_rows: int
@@ -61,14 +60,12 @@ class Sizes:
 # under it.
 CHIP = Sizes(rows=11_000_000, holdout=200_000, depth=6, rounds=20,
              eval_rounds=3, auc_floor=0.87, kernel_rows=8192, kernel_feats=28,
-             kernel_nodes=(1, 4, 32), kernel_wide_feats=136,
-             serve_sizes=(1, 7, 64, 300, 1000),
+             kernel_nodes=(1, 4, 32), serve_sizes=(1, 7, 64, 300, 1000),
              contrib_sizes=(1, 7, 50), mesh_shard_rows=1_000_000,
              col_rows=50_000)
 DRY = Sizes(rows=3000, holdout=500, depth=4, rounds=20, eval_rounds=2,
             auc_floor=0.6, kernel_rows=256, kernel_feats=4,
-            kernel_nodes=(1, 4), kernel_wide_feats=40,
-            serve_sizes=(1, 5, 33), contrib_sizes=(1, 5),
+            kernel_nodes=(1, 4), serve_sizes=(1, 5, 33), contrib_sizes=(1, 5),
             mesh_shard_rows=512, col_rows=1024)
 
 
@@ -194,7 +191,7 @@ def phase_device(ctx) -> dict:
 
 
 def phase_kernels(ctx) -> dict:
-    """The three Pallas histogram kernels against ``build_hist_segment`` on
+    """The two Pallas histogram kernels against ``build_hist_segment`` on
     identical inputs, compiled (interpret mode only in the dry run). Bound:
     each row's (g, h) is rounded to 15-bit fixed point with a global
     per-component scale (kernel docstrings: relative error 2^-15 of max|g|
@@ -206,7 +203,7 @@ def phase_kernels(ctx) -> dict:
     from xgboost_tpu.ops.histogram import (build_hist_segment,
                                            fused_advance_coarse)
     from xgboost_tpu.ops.pallas.histogram import (
-        build_hist_pallas, fused_advance_coarse_pallas, scan_hist_pallas)
+        build_hist_pallas, fused_advance_coarse_pallas)
     from xgboost_tpu.ops.split import COARSE_B, coarse_bin_ids
 
     sz, interp = ctx["sizes"], ctx["dry_run"]
@@ -254,13 +251,6 @@ def phase_kernels(ctx) -> dict:
             close(f"build_hist_pallas.{tag}",
                   build_hist_pallas(bins_t, gpair, rel, N, B,
                                     interpret=interp), ref, cnt)
-            fine, coarse = scan_hist_pallas(
-                bins_t, gpair, rel, N, B, missing_bin=miss,
-                with_coarse=True, interpret=interp)
-            close(f"scan_hist_pallas.fine.{tag}", fine, ref, cnt)
-            close(f"scan_hist_pallas.coarse.{tag}", coarse,
-                  build_hist_segment(cb, gpair, rel, N, COARSE_B),
-                  build_hist_segment(cb, ones, rel, N, COARSE_B))
 
         # fused sweep: advance below a 2-node level's splits, then the
         # 4-node level's coarse histogram; reference = its own XLA body
@@ -281,15 +271,6 @@ def phase_kernels(ctx) -> dict:
         close(f"fused_advance_coarse_pallas.B{B}", got_h, ref_h,
               build_hist_segment(cb, ones, rel4, 4, COARSE_B))
 
-    # a wide matrix: the scan kernel splits F into feature blocks (its
-    # whole-F accumulator tile at F=136 was refused by scoped VMEM)
-    Fw = sz.kernel_wide_feats
-    bins = jnp.asarray(rng.integers(0, 256, (n, Fw)).astype(np.uint8))
-    rel = jnp.asarray(rng.integers(0, 5, n).astype(np.int32))
-    fine, _ = scan_hist_pallas(bins.T, gpair, rel, 4, 256, interpret=interp)
-    close(f"scan_hist_pallas.fine.F{Fw}", fine,
-          build_hist_segment(bins, gpair, rel, 4, 256),
-          build_hist_segment(bins, ones, rel, 4, 256))
     say(f"kernels: {len(checked)} checks inside the int8x2 bound "
         f"({'INTERPRET mode' if interp else 'compiled'})")
     return {"checks": len(checked), "compiled": not interp}
@@ -319,8 +300,7 @@ def phase_train(ctx) -> dict:
 
     params = train_params(sz.depth)
     sched = resolve_schedule(
-        "auto", sz.rows, binned.max_nbins, binned.has_missing,
-        xgb.TrainParam(max_depth=sz.depth), numeric=True)
+        "auto", sz.rows, binned.max_nbins, binned.has_missing, numeric=True)
     say(f"train: hist_method=auto resolves to {sched.name!r} "
         f"(max_nbins={binned.max_nbins}, has_missing={binned.has_missing})")
 

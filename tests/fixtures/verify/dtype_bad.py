@@ -1,6 +1,5 @@
 """Bad twin: dtype-discipline — bf16 values reach an accumulate
-primitive (scatter-add, the histogram-build shape) in a tier whose
-contract does not allow bf16 accumulation. Note ``jnp.sum`` would NOT
+primitive (scatter-add, the histogram-build shape). Note ``jnp.sum`` would NOT
 trip this: jax upcasts reductions to an f32 accumulator itself — the
 hazard is manual accumulation."""
 
@@ -10,8 +9,7 @@ import jax.numpy as jnp
 from tools.xtpuverify.contracts import ProgramContract
 from xgboost_tpu.programs import ProgramSpec, RoundPlan, _abstract
 
-CONTRACT = ProgramContract("fx.dtype", dispatch_budget=1,
-                           allow_bf16_accumulate=False)
+CONTRACT = ProgramContract("fx.dtype", dispatch_budget=1)
 
 
 @jax.jit  # VERIFY[dtype-discipline]

@@ -7,8 +7,7 @@ import jax.numpy as jnp
 from tools.xtpuverify.contracts import ProgramContract
 from xgboost_tpu.programs import ProgramSpec, RoundPlan, _abstract
 
-CONTRACT = ProgramContract("fx.dtype", dispatch_budget=1,
-                           allow_bf16_accumulate=False)
+CONTRACT = ProgramContract("fx.dtype", dispatch_budget=1)
 
 
 @jax.jit

@@ -1,4 +1,4 @@
-"""Cross-level fused histogram sweep parity (hist_method="fused", round 6).
+"""Cross-level fused histogram sweep parity (hist_method="fused").
 
 The fused scheme reschedules the two-level coarse->refine histogram: at
 each level boundary the row advance below level L's decoded splits and
@@ -15,8 +15,15 @@ these tests pin it at three altitudes:
 - op:       the XLA ``fused_advance_coarse`` body against the sequential
             composition, dense and walk kinds (bit-identical);
 - model:    trains with hist_method 'fused' vs 'coarse' — resident
-            depthwise, lossguide, paged external memory, and the mesh
-            column-split composition — identical dumps/predictions.
+            depthwise, lossguide, paged external memory, the row-split
+            mesh under both growers and the mesh column-split
+            composition, to a level past DENSE_LEVEL_MAX and under the
+            growers' options — identical dumps; and 'fused' against the
+            one-pass search where max_bin <= 32 makes the two the same
+            search.
+
+The round driver's budget of two dispatches a round is pinned here too
+(``test_dispatch_count_resident``).
 
 Below the LAST level of a deep tree there is no next coarse pass to fuse
 with: ``advance_leaf`` routes the rows and looks their leaf up in one
@@ -346,73 +353,6 @@ def _binary_data(n=4000, F=8, missing=False, seed=11):
     return X, y
 
 
-@pytest.mark.parametrize("missing", [False, True])
-def test_fused_train_depthwise_matches_coarse(missing):
-    """Resident depthwise: 'fused' is the coarse scheme rescheduled —
-    identical trees, stats included."""
-    X, y = _binary_data(missing=missing)
-    params = {"objective": "binary:logistic", "eta": 0.3, "max_bin": 256,
-              "max_depth": 5}
-    b_c = xgb.train({**params, "hist_method": "coarse"},
-                    xgb.DMatrix(X, label=y), 4, verbose_eval=False)
-    b_f = xgb.train({**params, "hist_method": "fused"},
-                    xgb.DMatrix(X, label=y), 4, verbose_eval=False)
-    assert b_f.get_dump(with_stats=True) == b_c.get_dump(with_stats=True)
-
-
-def test_fused_train_lossguide_matches_coarse():
-    """Lossguide: the fused one-dispatch apply+eval schedule is the
-    sequential apply1 -> eval2 composition, op for op."""
-    X, y = _binary_data(n=3000, F=6, seed=12)
-    params = {"objective": "binary:logistic", "eta": 0.3, "max_bin": 64,
-              "grow_policy": "lossguide", "max_leaves": 10, "max_depth": 0}
-    b_c = xgb.train({**params, "hist_method": "coarse"},
-                    xgb.DMatrix(X, label=y), 3, verbose_eval=False)
-    b_f = xgb.train({**params, "hist_method": "fused"},
-                    xgb.DMatrix(X, label=y), 3, verbose_eval=False)
-    assert b_f.get_dump(with_stats=True) == b_c.get_dump(with_stats=True)
-
-
-def test_fused_train_paged_matches_coarse(tmp_path, monkeypatch):
-    """Paged external memory: 'fused' selects the same two-level scheme
-    whose advance + coarse page pass has been one fused body since r5."""
-    from xgboost_tpu.data.dmatrix import DataIter
-
-    X, y = _binary_data(n=3000, F=5, seed=13)
-
-    def make_dm():
-        class It(DataIter):
-            def __init__(self):
-                super().__init__()
-                self.parts = np.array_split(np.arange(len(X)), 3)
-                self.i = 0
-
-            def next(self, input_data):
-                if self.i >= len(self.parts):
-                    return 0
-                idx = self.parts[self.i]
-                input_data(data=X[idx], label=y[idx])
-                self.i += 1
-                return 1
-
-            def reset(self):
-                self.i = 0
-
-        it = It()
-        it.cache_prefix = str(tmp_path / "pc")
-        return xgb.QuantileDMatrix(it, max_bin=64)
-
-    monkeypatch.setenv("XTPU_PAGE_ROWS", "1024")
-    monkeypatch.setenv("XTPU_PAGED_COLLAPSE", "0")  # stay on page kernels
-    params = {"objective": "binary:logistic", "eta": 0.3, "max_bin": 64,
-              "max_depth": 4}
-    b_c = xgb.train({**params, "hist_method": "coarse"}, make_dm(), 3,
-                    verbose_eval=False)
-    b_f = xgb.train({**params, "hist_method": "fused"}, make_dm(), 3,
-                    verbose_eval=False)
-    assert b_f.get_dump(with_stats=True) == b_c.get_dump(with_stats=True)
-
-
 @pytest.fixture(scope="module")
 def mesh():
     if len(jax.devices()) < 2:
@@ -420,32 +360,186 @@ def mesh():
     return xgb.make_data_mesh()
 
 
-def test_fused_mesh_row_split_matches_coarse(mesh):
-    """Row-split mesh depthwise: the fused boundary sweep psums the same
-    coarse histogram the two-pass schedule does."""
-    X, y = _binary_data(n=4096, F=6, seed=14)
-    params = {"objective": "binary:logistic", "eta": 0.3, "max_bin": 256,
-              "max_depth": 4, "mesh": mesh}
-    b_c = xgb.train({**params, "hist_method": "coarse"},
-                    xgb.DMatrix(X, label=y), 3, verbose_eval=False)
-    b_f = xgb.train({**params, "hist_method": "fused"},
-                    xgb.DMatrix(X, label=y), 3, verbose_eval=False)
-    assert b_f.get_dump(with_stats=True) == b_c.get_dump(with_stats=True)
+LOSSGUIDE = {"grow_policy": "lossguide", "max_leaves": 10, "max_depth": 0}
+# tier -> (extra params, rows); depth 8 is a level past DENSE_LEVEL_MAX:
+# the "walk" boundary inside the loop and the walk epilogue below it
+TIERS = {
+    "resident": ({}, 4000),
+    "lossguide": (LOSSGUIDE, 3000),
+    "paged": ({}, 3000),
+    "mesh-row": ({}, 4096),
+    "mesh-row-lossguide": (LOSSGUIDE, 4096),
+    "mesh-col-lossguide": ({**LOSSGUIDE, "data_split_mode": "col"}, 3000),
+}
+CASES = [(tier, missing, 4) for tier in TIERS for missing in (False, True)]
+CASES += [(tier, missing, 8) for tier in ("resident", "paged", "mesh-row")
+          for missing in (False, True)]
 
 
-def test_fused_mesh_col_split_lossguide_matches_coarse(mesh):
-    """Mesh column split x lossguide: owner-decision advance + feature-
-    local eval fused into one program must match the two-dispatch coarse
-    schedule."""
-    X, y = _binary_data(n=3000, F=6, seed=15)
+def _paged_dmatrix(X, y, tmp_path, monkeypatch):
+    """Three streamed pages, held on the page kernels."""
+    from xgboost_tpu.data.dmatrix import DataIter
+
+    monkeypatch.setenv("XTPU_PAGE_ROWS", "1024")
+    monkeypatch.setenv("XTPU_PAGED_COLLAPSE", "0")
+
+    class It(DataIter):
+        def __init__(self):
+            super().__init__()
+            self.parts = np.array_split(np.arange(len(X)), 3)
+            self.i = 0
+
+        def next(self, input_data):
+            if self.i >= len(self.parts):
+                return 0
+            idx = self.parts[self.i]
+            input_data(data=X[idx], label=y[idx])
+            self.i += 1
+            return 1
+
+        def reset(self):
+            self.i = 0
+
+    it = It()
+    it.cache_prefix = str(tmp_path / "pc")
+    return xgb.QuantileDMatrix(it, max_bin=64)
+
+
+@pytest.mark.parametrize(
+    "tier,missing,depth", CASES,
+    ids=[f"{t}-{'missing' if m else 'dense'}-d{d}" for t, m, d in CASES])
+def test_fused_matches_coarse(tier, missing, depth, tmp_path, monkeypatch,
+                              request):
+    """'fused' is the coarse scheme rescheduled: identical trees, stats
+    included, in every tier that runs the two-level search — resident
+    depthwise, lossguide (one-dispatch apply + eval), paged external
+    memory (one advance + coarse page body), the row-split mesh (the
+    boundary sweep psums the same coarse histogram) under both growers,
+    and column split x lossguide (owner-decision advance + feature-local
+    eval in one program)."""
+    extra, n = TIERS[tier]
+    X, y = _binary_data(n=n, F=6, missing=missing, seed=11 + depth)
     params = {"objective": "binary:logistic", "eta": 0.3, "max_bin": 64,
-              "grow_policy": "lossguide", "max_leaves": 8, "max_depth": 0,
-              "mesh": mesh, "data_split_mode": "col"}
-    b_c = xgb.train({**params, "hist_method": "coarse"},
-                    xgb.DMatrix(X, label=y), 3, verbose_eval=False)
-    b_f = xgb.train({**params, "hist_method": "fused"},
-                    xgb.DMatrix(X, label=y), 3, verbose_eval=False)
+              "max_depth": depth, **extra}
+    if tier.startswith("mesh"):
+        params["mesh"] = request.getfixturevalue("mesh")
+
+    def train(method):
+        dm = (_paged_dmatrix(X, y, tmp_path, monkeypatch)
+              if tier == "paged" else xgb.DMatrix(X, label=y))
+        return xgb.train({**params, "hist_method": method}, dm,
+                         2 if depth == 8 else 3, verbose_eval=False)
+
+    b_c, b_f = train("coarse"), train("fused")
     assert b_f.get_dump(with_stats=True) == b_c.get_dump(with_stats=True)
+    if depth == 8 and "grow_policy" not in extra:
+        assert max(t.max_depth() for t in b_f.gbm.trees) == 8
+
+
+@pytest.mark.parametrize("extra", [
+    {"gamma": 0.5, "min_child_weight": 5.0},
+    {"colsample_bytree": 0.6, "subsample": 0.8, "reg_alpha": 0.5,
+     "max_delta_step": 0.7},
+    {"objective": "multi:softprob", "num_class": 4},
+    {**LOSSGUIDE, "colsample_bylevel": 0.7},
+    {**LOSSGUIDE, "monotone_constraints": "(1,-1,0,0,0,0,0,0)"},
+], ids=["gamma-mcw", "sampling-alpha-mds", "multiclass",
+        "lossguide-bylevel", "lossguide-monotone"])
+def test_fused_matches_coarse_under_options(extra):
+    rng = np.random.RandomState(12)
+    X = rng.randn(1500, 8).astype(np.float32)
+    w = rng.randn(8)
+    y = ((np.abs(X @ w) * 2).astype(np.int32) % 4 if "num_class" in extra
+         else X @ w > 0).astype(np.float32)
+    params = {"objective": "binary:logistic", "eta": 0.3, "max_bin": 64,
+              "max_depth": 3, **extra}
+    b_c, b_f = (xgb.train({**params, "hist_method": m},
+                          xgb.DMatrix(X, label=y), 3, verbose_eval=False)
+                for m in ("coarse", "fused"))
+    assert b_f.get_dump(with_stats=True) == b_c.get_dump(with_stats=True)
+
+
+SMALL_BIN_CASES = [(b, m, d) for b in (16, 32) for m in (False, True)
+                   for d in (3, 8)]
+
+
+@pytest.mark.parametrize(
+    "max_bin,missing,depth", SMALL_BIN_CASES,
+    ids=[f"b{b}-{'missing' if m else 'dense'}-d{d}"
+         for b, m, d in SMALL_BIN_CASES])
+def test_fused_matches_exact_search_at_small_max_bin(max_bin, missing,
+                                                     depth):
+    """With max_bin <= 32 every fine bin lives inside the refine window,
+    so the two-level search space IS the one-pass search's: the fused
+    schedule must pick the same splits, walk boundary included."""
+    X, y = _binary_data(n=5000, F=6, missing=missing, seed=max_bin + depth)
+    params = {"objective": "binary:logistic", "max_depth": depth,
+              "max_bin": max_bin}
+    b_e, b_f = (xgb.train({**params, "hist_method": m},
+                          xgb.DMatrix(X, label=y), 2, verbose_eval=False)
+                for m in ("segment", "fused"))
+    for te, tf in zip(b_e.gbm.trees, b_f.gbm.trees):
+        np.testing.assert_array_equal(te.split_feature, tf.split_feature)
+        np.testing.assert_array_equal(te.split_bin, tf.split_bin)
+        np.testing.assert_allclose(te.leaf_value, tf.leaf_value,
+                                   rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("hist_method", ["auto", "fused"])
+def test_dispatch_count_resident(monkeypatch, hist_method):
+    """A steady resident boosting round is <=2 compiled-program launches
+    (the round driver's budget: ``core.steady_round_dispatches``).
+
+    jax runs cache-hit jit calls AND cache-hit eager ops entirely on the
+    C++ fast path, invisible to any Python hook. Only a program's FIRST
+    execution after compilation routes through Python
+    ``ExecuteReplicated``. So the launch count is pinned from two
+    directions:
+
+    - steady rounds: the two known entry points (``_fused_round_fn``,
+      ``_margin_bad_rows``) are each called exactly once per round and
+      ZERO fresh executions happen — no recompiles, no stray eager ops
+      with novel shapes;
+    - after ``jax.clear_caches()``: ONE round re-executes exactly 2
+      distinct compiled programs — every launch is a first launch, so
+      the Python path sees them all.
+    """
+    import jax._src.interpreters.pxla as pxla
+
+    from xgboost_tpu import core
+
+    X, y = _binary_data(n=2000, seed=20)
+    dtr = xgb.DMatrix(X, label=y)
+    params = {"objective": "binary:logistic", "eta": 0.3, "max_bin": 64,
+              "max_depth": 3, "hist_method": hist_method, "seed": 0}
+    bst = xgb.train(params, dtr, 3, verbose_eval=False)
+    assert bst._fused_round is not None  # the round-program fast path
+
+    calls = {"fused": 0, "margin": 0, "exec": 0}
+    orig_fused, orig_margin = core._fused_round_fn, core._margin_bad_rows
+    monkeypatch.setattr(core, "_fused_round_fn", lambda *a, **k: (
+        calls.__setitem__("fused", calls["fused"] + 1),
+        orig_fused(*a, **k))[1])
+    monkeypatch.setattr(core, "_margin_bad_rows", lambda *a, **k: (
+        calls.__setitem__("margin", calls["margin"] + 1),
+        orig_margin(*a, **k))[1])
+    orig_exec = pxla.ExecuteReplicated.__call__
+
+    def spy(self, *a, **k):
+        calls["exec"] += 1
+        return orig_exec(self, *a, **k)
+
+    monkeypatch.setattr(pxla.ExecuteReplicated, "__call__", spy)
+    for it in (3, 4, 5):
+        bst.update(dtr, it)
+    assert calls["fused"] == 3      # one round-program launch per round
+    assert calls["margin"] == 3     # one NaN-guard launch per round
+    assert calls["exec"] == 0       # zero fresh compiles in steady state
+
+    jax.clear_caches()
+    calls["exec"] = 0
+    bst.update(dtr, 6)
+    assert calls["exec"] <= 2       # the whole round is <=2 programs
 
 
 def test_fused_rejected_outside_hist_scalar():

@@ -49,10 +49,13 @@ def mesh():
 @pytest.mark.parametrize("method,schedule,depth,kernels,epilogue", [
     # advance+coarse and refine, a level
     ("auto", "fused", 3, 2 * 3, "dense"),
-    ("mega", "mega", 3, 1, "dense"),     # check_vma waived: the loop carry
     # the published depth: a last level of 128 nodes, past DENSE_LEVEL_MAX,
     # takes the advance_leaf kernel below it
     ("auto", "fused", 8, 2 * 8 + 1, "kernel"),
+    # 256 and 512 nodes, the widest levels advance_leaf's gate admits; the
+    # levels past 128 nodes build their histograms in XLA
+    ("auto", "fused", 9, 2 * 8 + 1, "kernel"),
+    ("auto", "fused", 10, 2 * 8 + 1, "kernel"),
 ])
 def test_row_split_grow_program_compiles_for_v5e(monkeypatch, mesh, method,
                                                  schedule, depth, kernels,

@@ -64,15 +64,16 @@ def test_no_stale_baseline_entries():
                     for e in result.stale))
 
 
-def test_mega_dispatch_contract_is_pinned():
-    """PR 11's bet in contract form: the resident tiers stay at budget 2
-    (fused_round + margin_bad_rows) and the paged tier at zero steady
-    page uploads. Loosening these is an explicit, reviewable diff."""
+def test_round_dispatch_contract_is_pinned():
+    """The round driver's bet in contract form: the resident tiers stay
+    at budget 2 (fused_round + margin_bad_rows) and the paged tier at zero
+    steady page uploads. Loosening these is an explicit, reviewable
+    diff."""
     from tools.xtpuverify.contracts import CONTRACTS
 
     by_handle = {c.handle: c for c in CONTRACTS}
-    for tier in ("resident.fused", "resident.scan", "resident.mega"):
+    for tier in ("resident.fused", "resident.fused.insight"):
         assert by_handle[tier].dispatch_budget == 2
         assert by_handle[tier].donated
     assert by_handle["paged.level_full"].uploads_per_level == 0
-    assert by_handle["lossguide.mega"].dispatch_budget == 1
+    assert by_handle["mesh.row"].dispatch_budget == 1

@@ -432,10 +432,9 @@ def test_vertical_dart_matches_pooled():
 def test_vertical_coarse_hist_method_warns_and_falls_back():
     """hist_method='coarse'/'fused' is a row-split resident/paged scheme;
     the vertical federated growers now degrade to the exact one-pass
-    kernels with a warning instead of raising (docs/performance.md
-    "Round 7"). Asserted single-threaded on the grower constructors —
-    warning capture is process-global and must stay out of the
-    multi-rank thread harness."""
+    kernels with a warning instead of raising. Asserted single-threaded
+    on the grower constructors — warning capture is process-global and
+    must stay out of the multi-rank thread harness."""
     from xgboost_tpu.tree.param import TrainParam
     from xgboost_tpu.tree.vertical import (VerticalFederatedGrower,
                                            VerticalLossguideGrower)
@@ -446,7 +445,7 @@ def test_vertical_coarse_hist_method_warns_and_falls_back():
                        (VerticalLossguideGrower, {"max_leaves": 6})):
         param = TrainParam()
         param.update_allow_unknown({"max_depth": 3, **extra})
-        for hm, resolved in (("coarse", "auto"), ("fused+sub", "auto+sub")):
+        for hm, resolved in (("coarse", "auto"), ("fused", "auto")):
             with pytest.warns(UserWarning, match="requires row split"):
                 g = cls(param, binned.max_nbins, binned.cuts,
                         hist_method=hm)

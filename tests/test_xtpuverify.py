@@ -98,16 +98,16 @@ def _contract(handle):
     return next(c for c in CONTRACTS if c.handle == handle)
 
 
-def test_resident_mega_plan_is_contract_clean():
+def test_resident_fused_plan_is_contract_clean():
     from xgboost_tpu.programs import build_plan
     findings, skipped = verify_pairs(
-        [(_contract("resident.mega"), build_plan("resident.mega"))],
+        [(_contract("resident.fused"), build_plan("resident.fused"))],
         root=REPO)
     assert not skipped
     assert findings == [], "\n".join(f.render() for f in findings)
 
 
-def test_mega_budget_catches_a_third_dispatch():
+def test_round_budget_catches_a_third_dispatch():
     """A refactor that adds a stray third per-round program must fail the
     dispatch-budget contract statically, even where the runtime
     dispatch-count test is skipped."""
@@ -115,12 +115,12 @@ def test_mega_budget_catches_a_third_dispatch():
 
     from xgboost_tpu.programs import ProgramSpec, _abstract, build_plan
 
-    plan = build_plan("resident.mega")
+    plan = build_plan("resident.fused")
     stray = jax.jit(lambda m: m * 0.5)
     plan.dispatches.append(ProgramSpec(
         name="stray_update", fn=stray,
         args=(_abstract((512, 1), "float32"),)))
-    findings, _ = verify_pairs([(_contract("resident.mega"), plan)],
+    findings, _ = verify_pairs([(_contract("resident.fused"), plan)],
                                root=REPO)
     budget = [f for f in findings if f.checker == "dispatch-budget"]
     assert budget and "3 dispatches" in budget[0].message
@@ -277,7 +277,7 @@ def test_cli_list_checkers_and_contracts():
         "donation-ineffective", "collective-symmetry", "constant-bloat"}
     proc = _run_cli("--list-contracts")
     assert proc.returncode == 0
-    assert "resident.mega: dispatch_budget=2 donated" in proc.stdout
+    assert "resident.fused: dispatch_budget=2 donated" in proc.stdout
 
 
 def test_cli_single_handle_json():

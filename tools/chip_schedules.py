@@ -7,11 +7,11 @@ schedule with a cold (compile included) and a warm wall time for the same
 rounds. These are smoke timings, not benchmark numbers: no repeats, no
 spread.
 
-    python tools/chip_schedules.py                       # 1M x 28, all six
-    python tools/chip_schedules.py --rows 10500000 --depth 8 --rounds 4 \
-        --methods auto,fused,coarse,pallas,scan          # the cells' shape
+    python tools/chip_schedules.py                       # 1M x 28, all four
+    python tools/chip_schedules.py --rows 10500000 --depth 8 \
+        --rounds 4                                       # the cells' shape
     python tools/chip_schedules.py --grow-policy lossguide --max-leaves 64 \
-        --rounds 4 --methods fused,scan,auto
+        --rounds 4 --methods fused,coarse,auto
 
 Chain several in one chip-tool command so they share the compile cache.
 """
@@ -26,7 +26,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-METHODS = ("auto", "mega", "scan", "fused", "coarse", "pallas")
+METHODS = ("auto", "fused", "coarse", "pallas")
 
 
 def main() -> int:
@@ -69,7 +69,7 @@ def main() -> int:
         # the lossguide grower resolves "auto" itself (tree/lossguide.py)
         runs_as = method if lossguide else resolve_schedule(
             method, args.rows, binned.max_nbins, binned.has_missing,
-            xgb.TrainParam(max_depth=args.depth), numeric=True).name
+            numeric=True).name
         walls = []
         for _ in ("cold", "warm"):
             t0 = time.perf_counter()

@@ -24,15 +24,6 @@ python -m tools.xtpuverify || exit $?
 
 [ "$1" = "--lint" ] && exit 0
 
-echo "== validate_scan (scan vs fused bit-parity grid, smoke scale) =="
-JAX_PLATFORMS=cpu python tools/validate_scan.py --scale 0.25 --seeds 1 || exit $?
-
-echo "== validate_mega (mega vs scan bit-parity grid, smoke scale) =="
-# scale 0.1, not 0.25: the mega smoke keeps the mesh cells (the tier most
-# likely to break parity) and those recompile per device count, so the
-# grid is compile-dominated — 0.25 buys nothing but wall clock.
-JAX_PLATFORMS=cpu python tools/validate_mega.py --smoke --scale 0.1 --seeds 1 || exit $?
-
 echo "== validate_obs (traced-vs-untraced byte equality + exposition lint) =="
 JAX_PLATFORMS=cpu python tools/validate_obs.py || exit $?
 

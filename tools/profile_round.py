@@ -51,8 +51,7 @@ def main():
     y = (X @ w + rng.randn(ROWS).astype(np.float32) > 0).astype(np.float32)
 
     import xgboost_tpu as xgb
-    from xgboost_tpu.ops.histogram import (build_hist, build_hist_prehot,
-                                           build_onehot_plane)
+    from xgboost_tpu.ops.histogram import build_hist
     from xgboost_tpu.ops.partition import advance_positions_level
     from xgboost_tpu.ops.split import evaluate_splits
     from xgboost_tpu.tree.param import TrainParam
@@ -72,16 +71,12 @@ def main():
     gpair = jnp.stack([jnp.asarray(y) - 0.5,
                        jnp.full((ROWS,), 0.25, jnp.float32)], axis=1)
     bins_t = bins.T
-    # the prehot plane costs n*F*B bytes (79 GB at 11M x 28 x 256) — only
-    # materialise it for the one phase that reads it
-    oh_pre = (jax.jit(lambda bt: build_onehot_plane(bt, max_nbins))(bins_t)
-              if "prehot" in PHASES else None)
     row_iota = jnp.arange(ROWS, dtype=jnp.int32)
 
     # ---- phase: histogram, all 6 levels per rep (arrays passed as args —
-    # a closed-over plane would be captured as a 7GB program constant).
+    # a closed-over matrix would be captured as a program constant).
     # "hist" measures the production auto path (Pallas int8x2 via
-    # build_hist); "prehot" measures the opt-in plane kernel.
+    # build_hist).
     def hist_body(i, acc, bt, gpr, iota):
         gp = gpr * (1.0 + i.astype(jnp.float32) * 1e-7 + acc * 1e-30)
         g = jnp.float32(0.0)
@@ -91,20 +86,9 @@ def main():
             g = g + jnp.sum(h).astype(jnp.float32)
         return g
 
-    def prehot_body(i, acc, oh, gpr, iota):
-        gp = gpr * (1.0 + i.astype(jnp.float32) * 1e-7 + acc * 1e-30)
-        g = jnp.float32(0.0)
-        for d in range(DEPTH):
-            h = build_hist_prehot(oh, gp, iota % (2 ** d),
-                                  2 ** d, max_nbins)
-            g = g + jnp.sum(h).astype(jnp.float32)
-        return g
-
     ms_hist = (bench(hist_body, "hist auto/pallas (6 levels)",
                      bins_t, gpair, row_iota)
                if "hist" in PHASES else 0.0)
-    if "prehot" in PHASES:
-        bench(prehot_body, "hist prehot (6 levels)", oh_pre, gpair, row_iota)
 
     # ---- phase: two-level coarse->refine histogram, all 6 levels per rep
     # (the DEFAULT production path at scale since round 5: coarse pass +
@@ -153,9 +137,8 @@ def main():
               bins_t, gpair, row_iota)
 
     # ---- phase: split evaluation, all 6 levels per rep (args, not
-    # closures: a closed-over plane becomes a 7GB program constant).
-    # hist32 comes from the production Pallas path, NOT the prehot plane,
-    # so 'eval' stays runnable at 11M-row shapes.
+    # closures: a closed-over histogram becomes a program constant).
+    # hist32 comes from the production Pallas path.
     hist32 = (jax.jit(lambda bt, gp, it: build_hist(
         bt.T, gp, it % 32, 32, max_nbins, method="auto", bins_t=bt))(
             bins_t, gpair, row_iota)

@@ -16,7 +16,7 @@ empty ring would make byte-equality vacuous). Two extra cells re-run
 resident and paged with the FULL xtpuflight stack armed (memory
 monitor, rank identity, black box) and additionally require a round of
 memory samples plus a CRC-valid postmortem bundle. Four more cells
-re-run resident (with an eval set), mega, paged and mesh with
+re-run resident (with an eval set), pinned fused, paged and mesh with
 xtpuinsight armed (``XTPU_INSIGHT=1`` + in-carry eval): per-round
 telemetry and the eval fold must leave the model bytes untouched while
 actually recording a :class:`~xgboost_tpu.obs.insight.TrainingLog`.
@@ -78,12 +78,6 @@ def _cell_resident(X, y, rounds):
 def _cell_lossguide(X, y, rounds):
     p = {**BASE, "max_depth": 6, "grow_policy": "lossguide",
          "max_leaves": 16}
-    return xgb.train(p, xgb.DMatrix(X, label=y), rounds,
-                     verbose_eval=False).save_raw()
-
-
-def _cell_mega(X, y, rounds):
-    p = {**BASE, "max_depth": 4, "hist_method": "mega"}
     return xgb.train(p, xgb.DMatrix(X, label=y), rounds,
                      verbose_eval=False).save_raw()
 
@@ -234,7 +228,7 @@ def run_flight_cells(rows: int, rounds: int):
 def run_insight_cells(rows: int, rounds: int):
     """Byte-equality with xtpuinsight armed: per-round telemetry (and,
     on the resident tier, the in-carry eval fold) must not move a single
-    model byte — resident fused, mega, paged streamed and virtual-mesh
+    model byte — resident, pinned fused, paged streamed and virtual-mesh
     tiers. Coverage makes the equality non-vacuous: every armed run must
     actually record per-round telemetry, and the resident cell must land
     in-carry eval history for its eval set."""
@@ -251,8 +245,8 @@ def run_insight_cells(rows: int, rounds: int):
 
     cells = [
         ("resident+insight", _resident_eval),
-        ("mega+insight", lambda: xgb.train(
-            {**BASE, "max_depth": 4, "hist_method": "mega"},
+        ("fused+insight", lambda: xgb.train(
+            {**BASE, "max_depth": 4, "hist_method": "fused"},
             xgb.DMatrix(X, label=y), rounds, verbose_eval=False)),
         ("paged+insight", lambda: _train_paged(X, y, rounds)),
         ("mesh+insight", lambda: _train_mesh(X, y, rounds)),

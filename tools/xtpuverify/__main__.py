@@ -78,9 +78,7 @@ def main(argv: List[str] = None) -> int:
                      if c.uploads_per_level is not None else "")
                   + (f" mesh_axes={list(c.mesh_axes)}" if c.mesh_axes
                      else "")
-                  + (" donated" if c.donated else "")
-                  + (" allow_bf16_accumulate"
-                     if c.allow_bf16_accumulate else ""))
+                  + (" donated" if c.donated else ""))
         return 0
 
     select = tuple(s.strip() for s in args.select.split(",")) \

@@ -1,17 +1,9 @@
 """dtype-discipline: no f64/c128 anywhere in a traced program, and bf16
-never reaches an accumulate primitive outside the RMS-gated policy.
+never reaches an accumulate primitive.
 
-The bf16 rule is the static half of the ``XTPU_SCAN_ACC`` policy
-(``ops/histogram.py resolve_scan_acc``): the bf16 head + f32 residual
-split accumulator is a *measured* opt-in, so any OTHER path where a bf16
-value arrives at add/scatter-add/reduce_sum is an unreviewed precision
-loss — exactly the class of bug that shows up as a 1e-2 AUC wobble three
-PRs later. Contracts with ``allow_bf16_accumulate=True`` (only
-``ops.hist_scan_bf16``) opt out of the bf16 rule, not the x64 rule.
-
-Calibration (PR 12): the gated bf16 kernel's jaxpr shows bf16 on
-``add``/``scatter-add`` (plus reshape/broadcast/convert plumbing); the
-f32 variant contains zero bf16 values anywhere.
+A bf16 value arriving at add/scatter-add/reduce_sum is an unreviewed
+precision loss — exactly the class of bug that shows up as a 1e-2 AUC
+wobble three PRs later. No contract is exempt.
 """
 
 from __future__ import annotations
@@ -57,17 +49,12 @@ def check_dtypes(ctx: CheckContext) -> Iterator[Finding]:
                              "boundary; jax x64 mode must not reach "
                              "compiled tiers")
                 if (name == "bfloat16"
-                        and not ctx.contract.allow_bf16_accumulate
                         and prim in ACCUM_PRIMS
                         and ("bf16", prim) not in seen):
                     seen.add(("bf16", prim))
                     yield ctx.finding(
                         "dtype-discipline",
-                        f"bf16 reaches accumulate primitive `{prim}` in a "
-                        "tier whose contract does not allow bf16 "
-                        "accumulation",
+                        f"bf16 reaches accumulate primitive `{prim}`",
                         detail=f"bf16 at {prim}",
                         spec=tp.spec,
-                        hint="accumulate in f32 (upcast before the sum) or "
-                             "route through the RMS-gated XTPU_SCAN_ACC "
-                             "split-accumulator policy")
+                        hint="accumulate in f32 (upcast before the sum)")

@@ -11,17 +11,13 @@ Fields:
 
 - ``dispatch_budget``: max distinct compiled programs per steady
   scheduling unit (the plan's ``unit``: round / tree / level / batch).
-  PR 11's megakernel bet is the canonical entry: resident rounds are
-  exactly [fused_round, margin_bad_rows] — budget 2.
+  The canonical entry: resident rounds are exactly
+  [fused_round, margin_bad_rows] — budget 2.
 - ``uploads_per_level``: paged tiers only — host->device page transfers
   per steady level (0: the all-cached page-major path re-reads HBM).
 - ``max_carry_kb``: byte bound on any single loop carry AT THE HANDLE'S
   TRACE SHAPES (a structural-blowup tripwire, e.g. a whole histogram
   stack riding in a fori_loop carry — not a production HBM estimate).
-- ``allow_bf16_accumulate``: only the RMS-gated ``XTPU_SCAN_ACC=bf16``
-  split-accumulator kernel may accumulate in bf16
-  (``ops/histogram.py resolve_scan_acc``); everywhere else bf16 reaching
-  an accumulate primitive is a silent-precision-loss bug.
 - ``mesh_axes``: axis names collectives may reference; empty means the
   tier's programs must contain NO collectives.
 - ``donated``: the tier declares buffer donation and the verifier must
@@ -41,7 +37,6 @@ class ProgramContract:
     handle: str
     dispatch_budget: int
     max_carry_kb: float = 1024.0
-    allow_bf16_accumulate: bool = False
     mesh_axes: Tuple[str, ...] = ()
     donated: bool = False
     uploads_per_level: Optional[int] = None
@@ -62,22 +57,14 @@ def contract_from_dict(d: dict) -> ProgramContract:
 
 
 CONTRACTS: Tuple[ProgramContract, ...] = (
-    # resident boosting rounds: the PR-11 <=2-dispatch megakernel budget,
-    # margin donated into the round program
+    # resident boosting rounds: the round programs' budget of 2
+    # dispatches, margin donated into the round program
     ProgramContract("resident.fused", dispatch_budget=2, donated=True),
-    ProgramContract("resident.scan", dispatch_budget=2, donated=True),
-    ProgramContract("resident.mega", dispatch_budget=2, donated=True),
     # xtpuinsight-armed rounds: telemetry + in-carry eval must ride the
     # round program as extra OUTPUTS — the budget stays the unarmed 2,
     # so an extra telemetry dispatch is a gate failure, not a regression
     ProgramContract("resident.fused.insight", dispatch_budget=2,
                     donated=True),
-    ProgramContract("resident.scan.insight", dispatch_budget=2,
-                    donated=True),
-    ProgramContract("resident.mega.insight", dispatch_budget=2,
-                    donated=True),
-    # lossguide megakernel: the whole greedy tree is ONE program
-    ProgramContract("lossguide.mega", dispatch_budget=1),
     # paged page-major fast path: one program per level boundary, zero
     # steady-state page re-uploads, positions+state donated through it
     ProgramContract("paged.level_full", dispatch_budget=1, donated=True,
@@ -92,9 +79,4 @@ CONTRACTS: Tuple[ProgramContract, ...] = (
     # program, and the device TreeSHAP scan behind /contribs
     ProgramContract("serve.walk_packed", dispatch_budget=1),
     ProgramContract("serve.shap", dispatch_budget=1),
-    # scan-histogram accumulator policy (XTPU_SCAN_ACC): bf16 may reach
-    # accumulate primitives ONLY in the RMS-gated bf16 kernel
-    ProgramContract("ops.hist_scan", dispatch_budget=1),
-    ProgramContract("ops.hist_scan_bf16", dispatch_budget=1,
-                    allow_bf16_accumulate=True),
 )

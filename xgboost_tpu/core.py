@@ -64,8 +64,9 @@ import functools as _functools
 def _margin_bad_rows(margin, n_valid: int):
     """The NaN-guard reduction as ONE compiled program (op-by-op eager
     jnp here would cost several extra launches per fused round, breaking
-    the megakernel tier's <=2-dispatch-per-round budget —
-    tests/test_mega.py pins the count)."""
+    the round programs' <=2-dispatch-per-round budget —
+    tests/test_fused_hist.py test_dispatch_count_resident pins the
+    count)."""
     return jnp.sum(~jnp.isfinite(margin[:n_valid]).all(axis=-1))
 
 
@@ -198,9 +199,10 @@ def steady_round_dispatches():
     """The jitted programs ONE steady resident boosting round dispatches,
     in call order: the fused round itself and the NaN-guard reduction
     (``_fused_step`` below is the driver that calls exactly these two).
-    This list is the source of truth for the megakernel tier's
-    dispatches-per-round budget — ``tests/test_mega.py`` pins it at
-    runtime, and ``tools/xtpuverify``'s dispatch-budget contract checks
+    This list is the source of truth for the round programs'
+    dispatches-per-round budget — ``tests/test_fused_hist.py
+    test_dispatch_count_resident`` pins it at runtime, and
+    ``tools/xtpuverify``'s dispatch-budget contract checks
     it statically (xgboost_tpu/programs.py), so the budget survives even
     where cache-hit calls run on the C++ fast path invisible to Python
     hooks. Adding a per-round dispatch means growing this list AND
@@ -597,12 +599,26 @@ class Booster:
                 "multi_output_tree does not support monotone constraints "
                 "or the dart booster (the reference rejects both for "
                 "vector-leaf trees)")
-        if self.learner_params.get("hist_method") in ("coarse", "fused",
-                                                      "scan", "mega") \
+        from .tree.grow import HIST_METHODS, TWO_LEVEL_METHODS
+
+        # XTPU_HIST_METHOD overrides the default kernel selection for
+        # harness A/Bs without touching params (construction-time env
+        # read, docs/env_knobs.md); an explicit param always wins
+        hist_method = self.learner_params.get("hist_method")
+        hist_from = "hist_method"
+        if hist_method is None:
+            hist_method = os.environ.get("XTPU_HIST_METHOD", "auto")
+            hist_from = "XTPU_HIST_METHOD"
+        if hist_method not in HIST_METHODS:
+            raise ValueError(
+                f"unknown {hist_from} {hist_method!r}: the accepted names "
+                f"are {', '.join(HIST_METHODS)} ('scan', 'mega', 'prehot' "
+                "and the '+sub'/'+nosub' suffixes were removed in PR 31)")
+        if hist_method in TWO_LEVEL_METHODS \
                 and (tm in ("approx", "exact")
                      or ms == "multi_output_tree"):
             raise NotImplementedError(
-                "hist_method='coarse'/'fused'/'scan'/'mega' supports the "
+                "hist_method='coarse'/'fused' supports the "
                 "hist updaters (depthwise or lossguide, resident or "
                 "external-memory depthwise) with scalar trees only")
         dsm = self.learner_params.get("data_split_mode", "row")
@@ -641,11 +657,7 @@ class Booster:
         kwargs = dict(
             num_parallel_tree=int(self.learner_params.get(
                 "num_parallel_tree", 1)),
-            # XTPU_HIST_METHOD overrides the default kernel selection for
-            # harness A/Bs without touching params (construction-time env
-            # read, docs/env_knobs.md); an explicit param always wins
-            hist_method=self.learner_params.get(
-                "hist_method", os.environ.get("XTPU_HIST_METHOD", "auto")),
+            hist_method=hist_method,
             mesh=self.ctx.mesh, monotone=mono, constraint_sets=ics,
             tree_method=tm if tm in ("approx", "exact") else "hist",
             multi_strategy=ms, split_mode=dsm)

@@ -21,7 +21,6 @@ f32 accumulation already gives run-to-run reproducible histograms.
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -66,120 +65,6 @@ def build_hist_segment(bins: jnp.ndarray, gpair: jnp.ndarray, rel_pos: jnp.ndarr
     return hist[: n_nodes * stride].reshape(n_nodes, F, max_nbins, 2)
 
 
-def _segment_hist_acc(bins: jnp.ndarray, gpair: jnp.ndarray,
-                      rel_pos: jnp.ndarray, n_nodes: int, max_nbins: int,
-                      acc: str) -> jnp.ndarray:
-    """``build_hist_segment`` with a selectable accumulator dtype.
-
-    ``acc="f32"`` is the exact default. ``acc="bf16"`` is the
-    reduced-precision split accumulator (ISSUE 9 tentpole c): the gpair is
-    split into a bf16 head and an f32 residual, the head accumulates in
-    bf16 (the cheap partial-accumulation stream the TPU scan kernel would
-    keep in VMEM at half the footprint) and the residual's f32 segment
-    sum is the fix-up pass — the recombined result carries f32-class
-    error, not bf16-class (tests/test_scan_hist.py pins the bound).
-    Opt-in via ``XTPU_SCAN_ACC=bf16`` and NOT bit-compatible with the
-    fused path, which is why the hist-method ``auto`` promotion never
-    selects it and the tools/validate_scan.py promotion grid runs the
-    default. ``XTPU_SCAN_ACC=auto`` (Round 14) engages it only behind
-    the measured per-shape-class error bound (``resolve_scan_acc``)."""
-    if acc == "f32":
-        return build_hist_segment(bins, gpair, rel_pos, n_nodes, max_nbins)
-    if acc != "bf16":
-        raise ValueError(f"unknown scan accumulator {acc!r}")
-    head16 = gpair.astype(jnp.bfloat16)
-    resid = gpair - head16.astype(jnp.float32)
-    n, F = bins.shape
-    stride = F * max_nbins
-    seg = (rel_pos.astype(jnp.int32)[:, None] * stride
-           + jnp.arange(F, dtype=jnp.int32)[None, :] * max_nbins
-           + bins.astype(jnp.int32)).reshape(-1)
-    nseg = (n_nodes + 1) * stride
-    h_head = jax.ops.segment_sum(
-        jnp.broadcast_to(head16[:, None, :], (n, F, 2)).reshape(-1, 2),
-        seg, num_segments=nseg)                        # bf16 accumulation
-    h_fix = jax.ops.segment_sum(
-        jnp.broadcast_to(resid[:, None, :], (n, F, 2)).reshape(-1, 2),
-        seg, num_segments=nseg)                        # f32 fix-up
-    hist = h_head.astype(jnp.float32) + h_fix
-    return hist[: n_nodes * stride].reshape(n_nodes, F, max_nbins, 2)
-
-
-SCAN_ACC_RMS_BOUND = float(os.environ.get("XTPU_SCAN_ACC_RMS", "1e-6"))
-
-
-@partial(jax.jit, static_argnames=("max_nbins",))
-def _scan_acc_rms(bins: jnp.ndarray, gpair: jnp.ndarray,
-                  max_nbins: int) -> jnp.ndarray:
-    """Relative RMS gap of the bf16-split root histogram vs the exact
-    f32 build — the probe behind ``XTPU_SCAN_ACC=auto``."""
-    rel = jnp.zeros((bins.shape[0],), jnp.int32)
-    h32 = _segment_hist_acc(bins, gpair, rel, 1, max_nbins, "f32")
-    h16 = _segment_hist_acc(bins, gpair, rel, 1, max_nbins, "bf16")
-    num = jnp.sqrt(jnp.mean(jnp.square(h16 - h32)))
-    den = jnp.sqrt(jnp.mean(jnp.square(h32)))
-    return num / jnp.maximum(den, jnp.float32(1e-30))
-
-
-def resolve_scan_acc(bins: jnp.ndarray, gpair: jnp.ndarray,
-                     max_nbins: int, has_missing: bool = True) -> str:
-    """``XTPU_SCAN_ACC=auto`` -> ``"bf16"`` or ``"f32"`` for one shape
-    class (ROADMAP item 1c): the bf16 head + f32 residual split
-    accumulator halves the hot accumulate bytes, but it is only taken
-    when its MEASURED relative RMS error on the root histogram of the
-    first round's gradients stays within ``XTPU_SCAN_ACC_RMS``
-    (default 1e-6); otherwise auto falls back to the exact f32
-    accumulator. Growers call this once per shape class and cache the
-    resolved string, so the probe costs one extra histogram build per
-    training run."""
-    rms = float(_scan_acc_rms(bins, gpair, max_nbins))
-    return "bf16" if rms <= SCAN_ACC_RMS_BOUND else "f32"
-
-
-def build_hist_scan(bins: jnp.ndarray, gpair: jnp.ndarray,
-                    rel_pos: jnp.ndarray, n_nodes: int, max_nbins: int,
-                    *, bins_t: jnp.ndarray = None, order: jnp.ndarray = None,
-                    axis_name=None, acc: str = "f32") -> jnp.ndarray:
-    """Sort-based segmented-scan histogram (``hist_method="scan"``).
-
-    Rows are stably counting-sorted by node id
-    (``ops/partition.py counting_sort_by_node``) so every (node, feature,
-    bin) segment becomes a contiguous run, and the per-segment gpair sums
-    stream sequentially instead of scatter-adding at random offsets — on
-    TPU the block-padded layout feeds the per-node-block Pallas kernel
-    (``ops/pallas/histogram.py scan_hist_pallas``), whose one-hot
-    contraction loses the ``[4N, R]`` node-scatter plane entirely (the
-    block's node is static, so the PT operand is ``[4, R]`` — N-free).
-
-    BITWISE equal to ``build_hist_segment`` on the XLA path: the stable
-    sort preserves within-segment row order and ``segment_sum``
-    accumulates in operand order, so only the segment numbering moves
-    (tests/test_scan_hist.py).
-
-    ``order``: precomputed sort permutation (callers building several
-    histograms per level — fine + coarse — sort once).
-    ``acc``: accumulator dtype, see ``_segment_hist_acc``.
-    """
-    from .partition import counting_sort_by_node
-
-    if (jax.default_backend() == "tpu" and acc == "f32"
-            and n_nodes <= 128 and order is None):
-        from .pallas.histogram import scan_hist_pallas
-
-        if bins_t is None:
-            bins_t = bins.T
-        fine, _ = scan_hist_pallas(bins_t, gpair, rel_pos, n_nodes,
-                                   max_nbins, axis_name=axis_name)
-        return fine
-    if order is None:
-        order = counting_sort_by_node(rel_pos, n_nodes)
-    with stage("permute"):
-        bins_s = jnp.take(bins, order, axis=0)
-        gp_s = jnp.take(gpair, order, axis=0)
-        rel_s = jnp.take(rel_pos, order)
-    return _segment_hist_acc(bins_s, gp_s, rel_s, n_nodes, max_nbins, acc)
-
-
 def build_hist_onehot(bins: jnp.ndarray, gpair: jnp.ndarray, rel_pos: jnp.ndarray,
                       n_nodes: int, max_nbins: int,
                       block_rows: int = 1 << 16) -> jnp.ndarray:
@@ -219,75 +104,14 @@ def build_hist_onehot(bins: jnp.ndarray, gpair: jnp.ndarray, rel_pos: jnp.ndarra
         return carry + per_feat, None
 
     init = jnp.zeros((F, max_nbins, n_nodes * 2), dtype=jnp.float32)
+    # under a row-split shard_map the rows vary over the mesh axis, and a
+    # scan's carry must come in as it goes out
+    vma = tuple(jax.typeof(gpair_b).vma)
+    if vma:
+        init = jax.lax.pcast(init, vma, to="varying")
     acc, _ = jax.lax.scan(block_body, init, (bins_b, gpair_b, pos_b))
     # [F, B, n_nodes, 2] -> [n_nodes, F, B, 2]
     return acc.reshape(F, max_nbins, n_nodes, 2).transpose(2, 0, 1, 3)
-
-
-def build_onehot_plane(bins_t: jnp.ndarray, max_nbins: int) -> jnp.ndarray:
-    """Materialise the full one-hot plane [F * max_nbins, n] int8 in HBM.
-
-    Bins are loop-invariant across a round's levels (and across rounds), so
-    the plane is built once and every level's histogram becomes ONE int8
-    MXU contraction against it (``build_hist_prehot``) — trading HBM
-    capacity (n x F x B bytes) for the per-level VMEM one-hot builds that
-    otherwise dominate. Built feature-by-feature so the peak temporary is
-    one [B, n] block, not a second full plane."""
-    F, n = bins_t.shape
-    iota = jnp.arange(max_nbins, dtype=jnp.int32)[:, None]
-    blocks = [(bins_t[f][None, :].astype(jnp.int32) == iota).astype(jnp.int8)
-              for f in range(F)]
-    return jnp.concatenate(blocks, axis=0)
-
-
-def build_hist_prehot(oh_pre: jnp.ndarray, gpair: jnp.ndarray,
-                      rel_pos: jnp.ndarray, n_nodes: int, max_nbins: int,
-                      axis_name=None) -> jnp.ndarray:
-    """Histogram from the pre-materialised one-hot plane: the same 15-bit
-    fixed-point quantisation as the Pallas ``int8x2`` kernel (reference
-    ``GradientQuantiser``, src/tree/gpu_hist/histogram.cu:55-100), but the
-    whole contraction runs as ONE plain XLA int8 matmul with int32
-    accumulation — exact, deterministic, and entirely MXU/HBM-bound.
-
-    oh_pre: [F * max_nbins, n] int8 (from ``build_onehot_plane``)
-    -> [n_nodes, F, max_nbins, 2] f32
-
-    The hi/lo byte planes ride as extra COLUMNS of a single [n, 4N] RHS so
-    the 7-GB-class plane is streamed from HBM once per level, not twice —
-    the level cost is plane-read-bound, and two separate dot_generals were
-    measured at ~2x the single-pass time (23 ms vs ~12 ms per level at
-    1M x 28 x 256 on v5e).
-
-    int32 accumulation is exact while n * 128 < 2^31 (n <= ~16.7M rows per
-    shard); callers gate on that.
-    """
-    FB, n = oh_pre.shape
-    F = FB // max_nbins
-    N = n_nodes
-    gpair_t = gpair.T                                   # [2, n]
-    max_abs = jnp.max(jnp.abs(gpair_t), axis=1)         # [2]
-    if axis_name is not None:
-        max_abs = jax.lax.pmax(max_abs, axis_name)      # global scale
-    scale = 32512.0 / jnp.maximum(max_abs, 1e-30)
-    q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
-    node_oh = (rel_pos.astype(jnp.int32)[None, :]
-               == jnp.arange(N, dtype=jnp.int32)[:, None])  # [N, n]
-    g_scat = jnp.where(node_oh, q[0][None, :], 0)
-    h_scat = jnp.where(node_oh, q[1][None, :], 0)
-    PT = jnp.concatenate([g_scat, h_scat], axis=0)      # [2N, n] i32
-    hi = (PT + 128) >> 8                                # round-to-nearest
-    lo = (PT - hi * 256).astype(jnp.int8)
-    hi = hi.astype(jnp.int8)
-    PT4 = jnp.concatenate([hi, lo], axis=0)             # [4N, n] i8
-    contract = (((1,), (1,)), ((), ()))                 # oh . PT^T over rows
-    acc = jax.lax.dot_general(oh_pre, PT4, contract,
-                              preferred_element_type=jnp.int32)  # [FB, 4N]
-    out = (acc[:, : 2 * N].astype(jnp.float32) * 256.0
-           + acc[:, 2 * N:].astype(jnp.float32))
-    inv = jnp.repeat(1.0 / scale, N)[None, :]           # [1, 2N]
-    out = out * inv                                     # dequantise
-    gh = out.reshape(F, max_nbins, 2, N)
-    return gh.transpose(3, 0, 1, 2)                     # [N, F, B, 2]
 
 
 @partial(jax.jit, static_argnames=("n_nodes", "max_nbins", "method",
@@ -320,12 +144,6 @@ def build_hist(bins: jnp.ndarray, gpair: jnp.ndarray, rel_pos: jnp.ndarray,
             "growers only (tree/grow.py resident, tree/paged.py external "
             "memory); this code path (lossguide / vector-leaf / vertical) "
             "does not support it")
-    if method == "scan":
-        # the sort-based segmented-scan build is a drop-in histogram
-        # formulation (unlike coarse/fused, which are SCHEDULES) — any
-        # caller may request it; bitwise equal to the default build
-        return build_hist_scan(bins, gpair, rel_pos, n_nodes, max_nbins,
-                               bins_t=bins_t, axis_name=axis_name)
     if method == "auto":
         backend = jax.default_backend()
         # The fused Pallas kernel accumulates [F_blk, max_nbins, 2*n_nodes]
@@ -351,21 +169,6 @@ def build_hist(bins: jnp.ndarray, gpair: jnp.ndarray, rel_pos: jnp.ndarray,
             bins_t = bins.T
         return build_hist_pallas(bins_t, gpair, rel_pos, n_nodes, max_nbins,
                                  precision=precision, axis_name=axis_name)
-    if method == "prehot":
-        # int32 accumulation is exact only while n * 128 < 2^31 (~16.7M rows
-        # per shard) — enforce here, not just on the auto path, so an
-        # explicit hist_method="prehot" can't silently overflow (row count
-        # is a static shape, so this resolves at trace time)
-        if bins.shape[0] * 128 >= 2 ** 31:
-            return build_hist_onehot(
-                bins, gpair, rel_pos, n_nodes, max_nbins,
-                block_rows=min(block_rows, max(bins.shape[0], 8)))
-        oh = build_onehot_plane(bins_t if bins_t is not None else bins.T,
-                                max_nbins)
-        # the onehot fallback above needs no axis sync (exact f32, no
-        # quantisation scale); prehot's int8x2 scale must be global
-        return build_hist_prehot(oh, gpair, rel_pos, n_nodes, max_nbins,
-                                 axis_name=axis_name)
     if method == "segment":
         return build_hist_segment(bins, gpair, rel_pos, n_nodes, max_nbins)
     if method == "onehot":
@@ -409,8 +212,7 @@ def _advance_below(bins: jnp.ndarray, positions: jnp.ndarray, prev: dict,
                    missing_bin: int, decision_axis) -> jnp.ndarray:
     """The row decision of a boundary sweep: advance rows below the
     PREVIOUS level's decoded splits (``prev``: ``fused_advance_coarse``
-    docstring). Pure integer routing, shared by the fused and scan
-    sweeps, so their positions are bit-identical."""
+    docstring). Pure integer routing."""
     from .partition import advance_positions_level, update_positions
 
     lo_prev, nl_prev = prev["lo"], prev["n_level"]
@@ -498,7 +300,7 @@ def advance_leaf(bins: jnp.ndarray, positions: jnp.ndarray, prev: dict,
                  leaf_value: jnp.ndarray, missing_bin: int, *,
                  bins_t: jnp.ndarray = None, decision_axis=None,
                  interpret: bool = False):
-    """The epilogue of a fused or scan grow program: advance rows below
+    """The epilogue of a fused grow program: advance rows below
     the LAST evaluated level's splits (``prev``: ``fused_advance_coarse``
     docstring), with no coarse pass left to fuse with. Returns
     ``(new_positions, delta, kind)``.
@@ -529,102 +331,3 @@ def advance_leaf(bins: jnp.ndarray, positions: jnp.ndarray, prev: dict,
             dleft, cs, leaf_value, n_prev=n_level, missing_bin=missing_bin,
             interpret=interpret)
     return new_positions, delta, "kernel"
-
-
-# ---- segmented-scan level scheme (hist_method="scan") ----------------------
-# Round 12: the scan formulation sorts the level's rows by node once, then
-# derives EVERY histogram the two-level scheme needs from that one ordering:
-# the full fine histogram streams as contiguous segment sums (no per-node
-# scatter), the coarse histogram is the same sorted pass over coarse keys
-# (bitwise equal to the fused path's direct coarse build), and the refine
-# window is an O(1) slice of the fine build (ops/split.py refine_from_fine's
-# bit-equality argument) — the refine DATA pass disappears. On TPU the
-# Pallas kernel additionally derives coarse from the fine INTEGER
-# accumulators by integral slice-diffs (exact: integer addition is
-# associative), so one block-streamed pass yields both.
-
-def scan_level_hists(bins: jnp.ndarray, gpair: jnp.ndarray,
-                     rel: jnp.ndarray, n_level: int, max_nbins: int,
-                     missing_bin: int, *, bins_t: jnp.ndarray = None,
-                     method: str = "auto", axis_name=None,
-                     acc: str = "f32"):
-    """One sorted ordering -> ``(fine [N,F,max_nbins,2],
-    coarse [N,F,COARSE_B,2])`` for a level.
-
-    CPU/XLA: both builds are sorted segment sums — each bitwise equal to
-    its unsorted ``build_hist_segment`` counterpart, which is exactly what
-    the fused schedule builds, so models are bit-identical
-    (tools/validate_scan.py). The coarse histogram is built DIRECTLY from
-    coarse keys rather than folded from the f32 fine build: f32 addition
-    is not associative, so only the direct build preserves bit-parity —
-    the integral fold is reserved for the TPU kernel's integer domain.
-    """
-    from .partition import counting_sort_by_node
-    from .split import coarse_bin_ids
-
-    if (jax.default_backend() == "tpu" and acc == "f32"
-            and method in ("auto", "pallas") and n_level <= 128):
-        from .pallas.histogram import scan_hist_pallas
-
-        if bins_t is None:
-            bins_t = bins.T
-        return scan_hist_pallas(bins_t, gpair, rel, n_level, max_nbins,
-                                missing_bin=missing_bin,
-                                with_coarse=True, axis_name=axis_name)
-    order = counting_sort_by_node(rel, n_level)
-    with stage("permute"):
-        bins_s = jnp.take(bins, order, axis=0)
-        gp_s = jnp.take(gpair, order, axis=0)
-        rel_s = jnp.take(rel, order)
-    fine = _segment_hist_acc(bins_s, gp_s, rel_s, n_level, max_nbins, acc)
-    cb_s = coarse_bin_ids(bins_s.astype(jnp.int32), missing_bin)
-    from .split import COARSE_B
-
-    coarse = _segment_hist_acc(cb_s, gp_s, rel_s, n_level, COARSE_B, acc)
-    return fine, coarse
-
-
-def scan_advance_level(bins: jnp.ndarray, gpair: jnp.ndarray,
-                       positions: jnp.ndarray, prev: dict, lo: int,
-                       n_level: int, missing_bin: int, *, max_nbins: int,
-                       bins_t: jnp.ndarray = None, method: str = "auto",
-                       axis_name=None, decision_axis=None,
-                       acc: str = "f32", n_cap: int = None):
-    """Scan-formulation boundary sweep: advance rows below the previous
-    level's decoded splits, then ONE sorted ordering of the new level
-    yields its fine + coarse histograms
-    (the scan counterpart of ``fused_advance_coarse`` — same advance ops,
-    so positions are bit-identical; the builds are sorted segment sums,
-    bit-equal to the fused schedule's. Returns
-    ``(positions, fine, coarse)``).
-
-    ``n_cap``: static node capacity for the megakernel (hist_method="mega",
-    tree/grow.py). Inside the per-tree ``lax.fori_loop`` the level bounds
-    ``lo`` / ``n_level`` (and ``prev``'s) are TRACED carry values, so the
-    histogram shape must come from a loop-invariant bound instead: rows
-    outside the level take the sentinel ``n_cap`` and the builds run at
-    capacity ``n_cap``. Rows [0:n_level] of the result are bitwise equal
-    to the uncapped build — the stable counting sort produces the same
-    permutation either way (the sentinel is the unique maximum key in
-    both), and ``segment_sum`` only gains trailing empty segments."""
-    positions = _advance_below(bins, positions, prev, missing_bin,
-                               decision_axis)
-    cap = n_level if n_cap is None else n_cap
-    rel = jnp.where((positions >= lo) & (positions < lo + n_level),
-                    positions - lo, cap).astype(jnp.int32)
-    fine, coarse = scan_level_hists(
-        bins, gpair, rel, cap, max_nbins, missing_bin, bins_t=bins_t,
-        method=method, axis_name=axis_name, acc=acc)
-    return positions, fine, coarse
-
-
-@stage("fold")
-def subtract_siblings(parent_hist: jnp.ndarray, child_hist: jnp.ndarray,
-                      built_is_left: jnp.ndarray) -> jnp.ndarray:
-    """Sibling subtraction trick (reference ``src/tree/hist/histogram.h:192-207``):
-    given the parent's histogram and ONE built child, the sibling is the
-    difference. Returns [n, ...] histograms for (left, right) stacked."""
-    sibling = parent_hist - child_hist
-    left = jnp.where(built_is_left[:, None, None, None], child_hist, sibling)
-    right = jnp.where(built_is_left[:, None, None, None], sibling, child_hist)
-    return left, right

@@ -207,8 +207,8 @@ def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
         g_hi, g_lo = planes(q_ref[0:1, :])
         h_hi, h_lo = planes(q_ref[1:2, :])
         # hi/lo byte planes as extra COLUMNS of one [4N, R] RHS: a single
-        # MXU pass over the one-hot instead of two (same trick as
-        # build_hist_prehot — the one-hot operand feed dominates)
+        # MXU pass over the one-hot instead of two (the one-hot operand
+        # feed dominates)
         PT4 = jnp.concatenate([g_hi, h_hi, g_lo, h_lo], axis=0)  # [4N, R] i8
 
         # Per-FEATURE one-hot + dot (not one big [Fb*B, R] staged matmul):
@@ -556,188 +556,6 @@ def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
             name="advance_leaf",
         )(tab, bins_t, pos_t)
     return pos_out[0, :n], delta[0, :n]
-
-
-def _make_scan_kernel(n_feat: int, n_bins: int, block_rows: int):
-    """Segmented-scan histogram kernel (hist_method="scan"): rows arrive
-    pre-sorted by node into R-row blocks that each hold rows of exactly
-    ONE node (``ops/partition.py counting_sort_by_node(block=R)``), and
-    the grid walks the blocks in node order while the scalar-prefetched
-    ``block_node`` vector drives the OUTPUT index map — consecutive
-    same-node blocks revisit one VMEM-resident accumulator tile and the
-    carry between them never touches HBM (the decoupled look-back of the
-    segmented scan, expressed through Pallas' revisit semantics).
-
-    ``n_feat`` is the FEATURE BLOCK the kernel owns: the grid is
-    (feature blocks, row blocks) with the row sweep innermost, so each
-    (node, feature block) tile's visits stay contiguous.
-
-    What the sorted layout buys over ``_make_int8_kernel``: the block's
-    node is fixed, so the ``[4N, R]`` node-scatter plane and the N-wide
-    MXU columns vanish — the gradient operand is a node-free ``[4, R]``
-    plane and the per-feature dot is ``[B, R] x [R, 4]``, making the
-    sweep's VPU+MXU cost independent of the level width N.
-
-    Accumulation is pure int32 on the quantised planes: integer addition
-    is associative, so the per-(node, bin) sums are EXACT in the
-    quantised domain regardless of block order — which is also what makes
-    the integral coarse fold in the wrapper exact."""
-    B, R, F = n_bins, block_rows, n_feat
-
-    def kernel(bn_ref, bins_ref, q_ref, out_ref):
-        i = pl.program_id(1)
-        # first block of a node: zero its accumulator tile (block_node is
-        # nondecreasing, so each output row's visits are contiguous)
-        first = jnp.logical_or(
-            i == 0, bn_ref[i] != bn_ref[jnp.maximum(i - 1, 0)])
-
-        @pl.when(first)
-        def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
-
-        def planes(row):                                   # [1, R] i32
-            hi = (row + 128) >> 8                          # round-to-nearest
-            lo = row - hi * 256                            # in [-128, 127]
-            return hi.astype(jnp.int8), lo.astype(jnp.int8)
-
-        g_hi, g_lo = planes(q_ref[0:1, :])
-        h_hi, h_lo = planes(q_ref[1:2, :])
-        PT4 = jnp.concatenate([g_hi, h_hi, g_lo, h_lo], axis=0)  # [4, R]
-
-        bin_iota = jax.lax.broadcasted_iota(jnp.int32, (B, R), 0)
-        for f in range(F):
-            row = bins_ref[f:f + 1, :].astype(jnp.int32)   # [1, R]
-            oh = (bin_iota == row).astype(jnp.int8)        # [B, R]
-            acc4 = jax.lax.dot_general(
-                oh, PT4, _CONTRACT_LAST,
-                preferred_element_type=jnp.int32)          # [B, 4]
-            out_ref[0, f] += acc4
-
-    return kernel
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_nodes", "max_nbins", "missing_bin", "with_coarse",
-                     "block_rows", "interpret", "axis_name"))
-def scan_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
-                     rel_pos: jnp.ndarray, n_nodes: int, max_nbins: int,
-                     missing_bin: Optional[int] = None,
-                     with_coarse: bool = False, block_rows: int = 2048,
-                     axis_name=None, interpret: bool = False):
-    """Sort-based segmented-scan histogram build (see ``_make_scan_kernel``).
-
-    bins_t: [F, n] fine bin ids; gpair: [n, 2] f32; rel_pos: [n] int32 in
-    [0, n_nodes] (n_nodes = inactive). The wrapper counting-sorts rows by
-    node into R-aligned blocks, quantises gpair with the SAME 15-bit
-    fixed-point scheme as ``build_hist_pallas(precision="int8x2")``
-    (global per-component scale, pmax'd over ``axis_name``), streams the
-    blocks through the kernel, and recombines/dequantises the integer
-    accumulators.
-
-    ``with_coarse=True``: also derives the COARSE_B-slot coarse histogram
-    from the fine INTEGER accumulators by an integral (prefix-sum)
-    slice-diff — int32 addition is associative, so the fold is exactly
-    the direct coarse build's integer sums; the refine pass of the
-    two-level scheme then comes from ``ops/split.py refine_from_fine``
-    and the level needs ONE data sweep where fused needs two.
-    -> (fine [n_nodes, F, max_nbins, 2] f32, coarse or None)
-    """
-    from ..partition import counting_sort_by_node
-
-    F, n = bins_t.shape
-    B = max_nbins
-    R = min(block_rows, max(_round_up(n, 128), 128))
-    perm, block_node = counting_sort_by_node(rel_pos, n_nodes, block=R)
-    nb = perm.shape[0] // R
-    # Whole-F accumulator tile when it fits: the [F_blk, B, 4] int32 tile
-    # pads its 4-wide minor axis to 128 lanes and Pallas double-buffers
-    # it, so 4 MiB per buffer is what the 16 MiB scoped-VMEM limit leaves
-    # beside the input blocks (F=28 x 256 bins: 3.5 MiB; F=136 whole was
-    # refused at 34.5 MiB). Past it, split F into the fewest fitting
-    # blocks of a multiple of 8 features, as build_hist_pallas does.
-    budget = 4 * 2 ** 20
-    per_feat = _round_up(B, 8) * 128 * 4
-    if F * per_feat <= budget:
-        F_blk = F
-    else:
-        cap = max(8, (budget // per_feat) // 8 * 8)
-        n_blocks = -(-F // cap)
-        F_blk = min(cap, _round_up(-(-F // n_blocks), 8))
-    F_pad = _round_up(F, F_blk)
-    # pad slots carry the sentinel row id n -> bins 0 / q 0: zero payload
-    with stage("permute"):
-        bins_p = jnp.take(bins_t, perm, axis=1, mode="fill", fill_value=0)
-        if F_pad != F:
-            bins_p = jnp.pad(bins_p, ((0, F_pad - F), (0, 0)))
-    with stage("quantise"):
-        gpair_t = gpair.T                                # [2, n]
-        max_abs = jnp.max(jnp.abs(gpair_t), axis=1)
-        if axis_name is not None:
-            max_abs = jax.lax.pmax(max_abs, axis_name)   # global scale
-        scale = 32512.0 / jnp.maximum(max_abs, 1e-30)
-        q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
-    with stage("permute"):
-        q_p = jnp.take(q, perm, axis=1, mode="fill", fill_value=0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(F_pad // F_blk, nb),
-        in_specs=[pl.BlockSpec((F_blk, R), lambda j, i, bn: (j, i)),
-                  pl.BlockSpec((2, R), lambda j, i, bn: (0, i))],
-        # the scalar-prefetched block_node drives the output row: pad /
-        # stray blocks land on the trash row n_nodes, dropped below
-        out_specs=pl.BlockSpec((1, F_blk, B, 4),
-                               lambda j, i, bn: (bn[i], j, 0, 0)))
-    with stage("kernel.scan_hist"):
-        acc = pl.pallas_call(
-            _make_scan_kernel(F_blk, B, R),
-            out_shape=_out_struct((n_nodes + 1, F_pad, B, 4), jnp.int32,
-                                  bins_p, q_p),
-            grid_spec=grid_spec,
-            interpret=interpret,
-            name="scan_hist",
-        )(block_node, bins_p, q_p)
-    with stage("fold"):
-        return _scan_fold(acc[:n_nodes, :F], scale, B, missing_bin,
-                          with_coarse)
-
-
-def _scan_fold(acc, scale, B: int, missing_bin, with_coarse: bool):
-    """Dequantise the scan kernel's byte-plane sums ``[N, F, B, 4]`` and,
-    ``with_coarse``, fold the coarse histogram from them in the integer
-    domain (``scan_hist_pallas`` docstring)."""
-    from ..split import COARSE_B, COARSE_SPAN
-
-    inv = (1.0 / scale)[None, None, None, :]             # [1, 1, 1, 2]
-
-    def dequant(a4):
-        # columns: [g_hi, h_hi, g_lo, h_lo] per-row byte-plane sums
-        return (a4[..., :2].astype(jnp.float32) * 256.0
-                + a4[..., 2:].astype(jnp.float32)) * inv
-
-    fine = dequant(acc)
-    if not with_coarse:
-        return fine, None
-    # integral coarse fold, integer domain: zero the missing slot, prefix
-    # sum over bins, COARSE_SPAN-wide slice diffs for the real coarse
-    # slots, missing mass on slot COARSE_B - 1 — exactly coarse_bin_ids'
-    # grouping, with sums identical to the direct build's integers
-    if missing_bin is not None and missing_bin < B:
-        macc = acc[:, :, missing_bin, :]                 # [N, F, 4]
-        accz = acc.at[:, :, missing_bin, :].set(0)
-    else:
-        macc = jnp.zeros(acc.shape[:2] + (4,), acc.dtype)
-        accz = acc
-    cum = jnp.cumsum(accz, axis=2)
-    cz = jnp.concatenate(
-        [jnp.zeros(acc.shape[:2] + (1, 4), acc.dtype), cum], axis=2)
-    edges = [min(c * COARSE_SPAN, B) for c in range(17)]
-    real = jnp.stack([cz[:, :, edges[c + 1], :] - cz[:, :, edges[c], :]
-                      for c in range(16)], axis=2)       # [N, F, 16, 4]
-    pad = jnp.zeros(acc.shape[:2] + (COARSE_B - 17, 4), acc.dtype)
-    coarse_q = jnp.concatenate([real, pad, macc[:, :, None, :]], axis=2)
-    return fine, dequant(coarse_q)
 
 
 @functools.partial(

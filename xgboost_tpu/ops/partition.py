@@ -86,82 +86,6 @@ def advance_positions_level(bins_f32: jnp.ndarray, positions: jnp.ndarray,
                      positions)
 
 
-@stage("count_sort")
-def counting_sort_by_node(rel_pos: jnp.ndarray, n_nodes: int,
-                          block: Optional[int] = None):
-    """Stable counting-sort permutation grouping rows by level node id —
-    the ordering pass of the segmented-scan histogram formulation
-    (``hist_method="scan"``, ops/histogram.py build_hist_scan).
-
-    rel_pos: [n] int32 in [0, n_nodes]; n_nodes marks inactive rows.
-
-    ``block=None`` -> ``order [n]``: a stable permutation placing node 0's
-    rows first, then node 1's, ..., with inactive rows last. Stability is
-    the load-bearing property: within every (node, feature, bin) segment
-    the sorted gather preserves the original row order, and XLA's
-    ``segment_sum`` accumulates in operand order — so a histogram built
-    over the sorted rows is BITWISE equal to the unsorted scatter-add
-    build (tests/test_scan_hist.py pins this).
-
-    ``block=R`` -> ``(perm [cap], block_node [cap // R])``: the
-    block-padded layout the Pallas kernel streams — each node's run
-    starts R-aligned so every R-row block holds rows of exactly one node,
-    ``block_node[b]`` names it (``n_nodes`` for pad/stray blocks), and
-    pad slots carry the sentinel row id ``n`` (callers gather with
-    ``mode="fill"`` so pad rows contribute zero). ``cap`` is the static
-    worst case ``n + n_nodes * (R - 1)`` rounded up to R.
-    """
-    n = rel_pos.shape[0]
-    if n_nodes == 1:
-        # two buckets (node 0 / inactive): the stable grouping permutation
-        # is a cumsum counting rank — no sort primitive, so the root level
-        # works under shard_map even when ``rel_pos`` traces as a constant
-        # (jax's replication rule for the multi-result sort primitive
-        # returns None and check_rep/check_vma crashes; cumsum + scatter
-        # both have rules), and it stays traceable inside the megakernel's
-        # ``lax.fori_loop`` body (hist_method="mega"). Bitwise equal to
-        # ``argsort(stable=True)``: node-0 rows first in original order,
-        # then inactive rows in original order.
-        in0 = (rel_pos.astype(jnp.int32) < 1).astype(jnp.int32)
-        c0 = jnp.cumsum(in0)
-        rank0 = c0 - in0
-        in1 = 1 - in0
-        rank1 = jnp.cumsum(in1) - in1
-        dest = jnp.where(in0 == 1, rank0, c0[-1] + rank1)
-        order = jnp.zeros((n,), jnp.int32).at[dest].set(
-            jnp.arange(n, dtype=jnp.int32))
-    else:
-        order = jnp.argsort(rel_pos.astype(jnp.int32), stable=True)
-    if block is None:
-        return order
-    R = block
-    counts = jnp.bincount(jnp.clip(rel_pos, 0, n_nodes),
-                          length=n_nodes + 1)[:n_nodes]       # [N]
-    # every node owns >= 1 block even when empty: its output row must be
-    # zero-initialised by a block visit, never left as uninitialised HBM
-    padded = jnp.maximum(((counts + R - 1) // R) * R, R)
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), padded.dtype), jnp.cumsum(padded)])  # [N + 1]
-    cap = (-(-n // R) + n_nodes) * R
-    rel_s = jnp.take(rel_pos, order).astype(jnp.int32)        # sorted keys
-    run_start = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)])  # [N + 1]
-    rank = jnp.arange(n) - run_start[jnp.clip(rel_s, 0, n_nodes)]
-    dest = starts[jnp.clip(rel_s, 0, n_nodes)] + rank
-    dest = jnp.where(rel_s < n_nodes, dest, cap)              # drop strays
-    perm = jnp.full((cap,), n, order.dtype).at[dest].set(order, mode="drop")
-    edges = starts[1:]                                        # [N], R-mult
-    # block b's node = #runs ending at or before b*R (a searchsorted over
-    # N <= 128 edges, written as a dense comparison count so every
-    # primitive has a shard_map replication rule)
-    bstart = jnp.arange(cap // R, dtype=starts.dtype) * R     # [cap//R]
-    block_node = jnp.sum(
-        (edges[None, :] <= bstart[:, None]).astype(jnp.int32), axis=1)
-    # blocks past the last real run are pure padding -> sentinel node
-    block_node = jnp.where(bstart < edges[-1], block_node, n_nodes)
-    return perm, block_node
-
-
 @stage("advance")
 def update_positions(bins: jnp.ndarray, positions: jnp.ndarray,
                      split_feature: jnp.ndarray, split_bin: jnp.ndarray,

@@ -1,7 +1,7 @@
 """Traceable program handles — the library's declared hot-path schedule.
 
-Each *handle* names one execution tier (resident fused/scan/mega,
-lossguide mega, paged level_full, mesh row/col, serve walk) and builds a
+Each *handle* names one execution tier (resident fused, paged
+level_full, mesh row/col, serve walk) and builds a
 :class:`RoundPlan`: the ordered list of jitted programs that tier
 dispatches per steady scheduling unit (round / tree / level / batch),
 each paired with abstract avals so the program can be traced with
@@ -109,7 +109,6 @@ def load_all() -> None:
     global _LOADED
     if _LOADED:
         return
-    from .ops import programs as _ops_programs        # noqa: F401
     from .serve import programs as _serve_programs    # noqa: F401
     from .tree import programs as _tree_programs      # noqa: F401
     _LOADED = True
@@ -141,7 +140,8 @@ def _abstract(shape, dtype):
     return jax.ShapeDtypeStruct(shape, getattr(jnp, dtype))
 
 
-def _resident_plan(hist_method: str) -> RoundPlan:
+@register_program("resident.fused")
+def _resident_fused() -> RoundPlan:
     from . import core
     from .registry import OBJECTIVES
     from .tree.param import TrainParam
@@ -161,7 +161,7 @@ def _resident_plan(hist_method: str) -> RoundPlan:
               None, None, None),                  # monotone/constraints/cat
         kwargs=dict(obj_cls=obj_cls, obj_params=(),
                     param=TrainParam(max_depth=3), max_nbins=_B,
-                    hist_method=hist_method, has_missing=True,
+                    hist_method="fused", has_missing=True,
                     nan_policy="raise"),
         donate_argnums=(1,))
     guard_spec = ProgramSpec(
@@ -169,29 +169,15 @@ def _resident_plan(hist_method: str) -> RoundPlan:
         fn=guard_fn,
         args=(_abstract((_R, 1), "float32"),),
         kwargs=dict(n_valid=_R))
-    return RoundPlan(handle=f"resident.{hist_method}", unit="round",
+    return RoundPlan(handle="resident.fused", unit="round",
                      dispatches=[round_spec, guard_spec])
-
-
-@register_program("resident.fused")
-def _resident_fused() -> RoundPlan:
-    return _resident_plan("fused")
-
-
-@register_program("resident.scan")
-def _resident_scan() -> RoundPlan:
-    return _resident_plan("scan")
-
-
-@register_program("resident.mega")
-def _resident_mega() -> RoundPlan:
-    return _resident_plan("mega")
 
 
 _RE = 64  # eval rows in the insight-armed abstract trace
 
 
-def _resident_insight_plan(hist_method: str) -> RoundPlan:
+@register_program("resident.fused.insight")
+def _resident_fused_insight() -> RoundPlan:
     """The xtpuinsight-armed resident round (obs/insight.py): telemetry
     scalars and ONE armed eval set (margin walk + metric partials) ride
     the round program as extra outputs. Same dispatch list length as the
@@ -220,7 +206,7 @@ def _resident_insight_plan(hist_method: str) -> RoundPlan:
               (None,)),                           # eval weights
         kwargs=dict(obj_cls=obj_cls, obj_params=(),
                     param=TrainParam(max_depth=3), max_nbins=_B,
-                    hist_method=hist_method, has_missing=True,
+                    hist_method="fused", has_missing=True,
                     nan_policy="raise",
                     eval_specs=(("logloss", 0.0),),
                     eval_missing=(_B - 1,)),
@@ -230,20 +216,5 @@ def _resident_insight_plan(hist_method: str) -> RoundPlan:
         fn=guard_fn,
         args=(_abstract((_R, 1), "float32"),),
         kwargs=dict(n_valid=_R))
-    return RoundPlan(handle=f"resident.{hist_method}.insight", unit="round",
+    return RoundPlan(handle="resident.fused.insight", unit="round",
                      dispatches=[round_spec, guard_spec])
-
-
-@register_program("resident.fused.insight")
-def _resident_fused_insight() -> RoundPlan:
-    return _resident_insight_plan("fused")
-
-
-@register_program("resident.scan.insight")
-def _resident_scan_insight() -> RoundPlan:
-    return _resident_insight_plan("scan")
-
-
-@register_program("resident.mega.insight")
-def _resident_mega_insight() -> RoundPlan:
-    return _resident_insight_plan("mega")

@@ -18,22 +18,16 @@ like the broadcast seed at ``src/tree/updater_gpu_hist.cu:786-789``).
 
 from __future__ import annotations
 
-import contextlib as _contextlib
 import functools
-import os
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs import trace as _trace
 from ..obs.metrics import count_grow_epilogue, count_grow_schedule
 from ..obs.trace import stage
-from ..ops.histogram import (advance_leaf, build_hist, build_hist_prehot,
-                             build_onehot_plane, fused_advance_coarse,
-                             scan_advance_level, scan_level_hists,
-                             subtract_siblings)
+from ..ops.histogram import advance_leaf, build_hist, fused_advance_coarse
 from ..ops.partition import advance_positions_level, update_positions
 from ..ops.split import CatInfo, evaluate_splits
 from ..registry import TREE_UPDATERS
@@ -79,30 +73,30 @@ def _sample_features(key: jax.Array, base_mask: jnp.ndarray,
     return base_mask & (u <= thr)
 
 
+# The accepted ``hist_method`` names, each list written once. The
+# two-level names are SCHEDULES of the depthwise and lossguide growers
+# (``Schedule`` below); every other name but "auto" is a one-pass kernel
+# of ``ops/histogram.py build_hist`` ("pallas:<precision>": the Pallas
+# kernel's precision ladder, ops/pallas/histogram.py).
+TWO_LEVEL_METHODS = ("coarse", "fused")
+HIST_METHODS = ("auto",) + TWO_LEVEL_METHODS + (
+    "segment", "onehot", "pallas", "pallas:int8x2", "pallas:bf16x2",
+    "pallas:bf16", "pallas:f32")
+
 # hist_method="auto" -> two-level coarse histogram promotion rule.
-# Engages only where coarse is BOTH supported and measured faster than the
-# one-pass exact kernel: TPU backend (on CPU the segment-sum kernel's cost
-# is bin-width-independent, so two passes are a strict loss), numeric
-# features, row split, wide bins (the win scales with bin count; below
-# ~128 slots the one-pass kernel is already cheap), and enough local rows
-# that the second pass + window choice amortise (crossover measured on
-# v5e — tools/bench_hist_coarse.py + docs/performance.md round-5 table).
-# Quality: eval-set parity validated across binary/multiclass/ranking x 3
-# seeds (docs/performance.md); coarse is bit-exact for max_bin <= 32 and
-# scores every coarse boundary exactly, so the promotion changes argmax
-# choices only among near-tie fine splits inside unrefined windows.
+# Engages only where the two-level search is BOTH supported and cheaper
+# than the one-pass exact kernel: TPU backend (on CPU the segment-sum
+# kernel's cost is bin-width-independent, so two passes are a strict
+# loss), numeric features, row split (with F/world features per shard the
+# second pass amortises worse), wide bins (the win scales with bin count;
+# below ~128 slots the one-pass kernel is already cheap), and enough local
+# rows that the second pass + window choice amortise
+# (tools/bench_hist_coarse.py measures the crossover).
+# Quality: the two-level search is bit-exact for max_bin <= 32 and scores
+# every coarse boundary exactly, so the promotion changes argmax choices
+# only among near-tie fine splits inside unrefined windows.
 AUTO_COARSE_MIN_ROWS = 1 << 16
 AUTO_COARSE_MIN_BINS = 128
-
-# Wherever "auto" promotes to the two-level search it runs the FUSED
-# schedule and goes no further. hist_method="scan" (ops/histogram.py
-# scan_level_hists) and "mega" (the scan stage chain under one
-# ``lax.fori_loop``) are reschedulings of the same search with
-# bit-identical models on the CPU grid (tools/validate_scan.py,
-# tools/validate_mega.py), explicit only: "auto" was moved to them on
-# roofline forecasts, and on the chip their row sort and the permute it
-# feeds were 92% of a round (PERF.md sections 5 and 6, PR 27 and PR 28).
-
 
 def auto_selects_coarse(n_rows: int, max_nbins: int, has_missing: bool, *,
                         numeric: bool, col_split: bool,
@@ -163,8 +157,8 @@ def exchange_best_split(res, axis_name, F: int, *, with_cat: bool = False):
 # levels fall back to the per-row gather walk (``update_positions``: five
 # one-element gathers over all rows, 545 ms a round at 10.5M rows on a
 # v5e, PERF.md section 6, PR 30). What still walks, and where:
-# - the LAST level of a fused or scan program (128 nodes at max_depth 8)
-#   does not on a TPU: one kernel sweep routes the rows and writes their
+# - the LAST level of a fused program (128 nodes at max_depth 8) does
+#   not on a TPU: one kernel sweep routes the rows and writes their
 #   leaf delta (``ops/histogram.py advance_leaf``, up to 512 nodes). It
 #   walks on the CPU, under column split (the decisions' psum), and past
 #   max_depth 10;
@@ -182,137 +176,56 @@ class Schedule(NamedTuple):
     """What ``hist_method`` resolves to for one grow program — every
     field is a trace-time constant of ``_grow``."""
 
-    kernel: str        # hist_method with the "+sub"/"+nosub" suffix cut
-    compaction: bool   # smaller-child build + sibling subtraction
-    prehot: bool       # pre-materialised one-hot plane
+    kernel: str        # the one-pass build_hist method where not coarse
     coarse: bool       # two-level coarse->refine search space
     fused: bool        # ... scheduled as the cross-level fused sweep
-    scan: bool         # ... scheduled as the segmented scan
-    mega: bool         # ... with the level loop rolled into one fori_loop
 
     @property
     def name(self) -> str:
-        """The schedule that runs: ``mega``/``scan``/``fused``/``coarse``,
-        or the one-pass build ``kernel`` names."""
-        for flag in ("mega", "scan", "fused", "coarse"):
-            if getattr(self, flag):
-                return flag
-        return self.kernel
+        """The schedule that runs: ``fused``/``coarse``, or the one-pass
+        build ``kernel`` names."""
+        if self.fused:
+            return "fused"
+        return "coarse" if self.coarse else self.kernel
 
 
 def resolve_schedule(hist_method: str, n: int, max_nbins: int,
-                     has_missing: bool, param: TrainParam, *, numeric: bool,
-                     col_split: bool = False,
-                     sharded: bool = False) -> Schedule:
-    """The histogram schedule ``_grow`` runs for ``hist_method`` at this
-    shape: ``n`` local rows, ``numeric`` = no categorical feature,
-    ``sharded`` = under a mesh axis. Reads the backend
-    (``auto_selects_coarse``), nothing else."""
-    # Smaller-child build + sibling subtraction (reference
-    # src/tree/hist/histogram.h:192-207, updater_gpu_hist.cu:558): per split
-    # parent only the child with FEWER rows is built — the built rows are
-    # compacted into a fixed n//2-capacity buffer (sum over parents of
-    # min(left, right) can never exceed n/2) — and the sibling is the
-    # parent-minus-child difference. OPT-IN via "<kernel>+sub": measured
-    # SLOWER on TPU v5e (the nonzero-compaction + row gathers cost more
-    # than the halved one-hot build they save; interleaved A/B 2.7-2.9 vs
-    # 3.3-4.3 rounds/s at 1M x 28 depth 6), so the default is a full build
-    # per level — kept for revisiting with a gather-fused kernel.
-    # "+nosub" is accepted as the explicit spelling of the default. Never
-    # used under a mesh: the count-based choice bounds GLOBAL rows, but one
-    # shard's share of the built children can exceed its local half, so a
-    # static per-shard compaction capacity cannot be guaranteed.
-    hist_kernel = hist_method
-    use_compaction = False
-    for _suffix, _enable in (("+sub", True), ("+nosub", False)):
-        if hist_kernel.endswith(_suffix):
-            hist_kernel = hist_kernel[: -len(_suffix)]
-            use_compaction = _enable
-    use_compaction &= not sharded and not col_split and n >= 8
-    # Pre-materialised one-hot plane (ops/histogram.py build_onehot_plane):
-    # one [F*B, n] int8 plane in HBM turns every level's histogram into a
-    # single int8 MXU contraction. EXPLICIT opt-in only since round 2: with
-    # the hi/lo byte planes fused into one [4N]-column matmul the Pallas
-    # kernel (VMEM one-hot, ~28 MB/level HBM traffic) measures faster at
-    # every level width (8.3 ms flat vs 9.7-37 ms at 1M x 28 x 256 on v5e)
-    # and costs no plane memory, so "auto" routes to it via build_hist.
-    use_prehot = (not use_compaction and n * 128 < 2 ** 31
-                  and hist_kernel == "prehot")
+                     has_missing: bool, *, numeric: bool,
+                     col_split: bool = False) -> Schedule:
+    """The histogram schedule every grower runs for ``hist_method`` at
+    this shape: ``n`` local rows, ``numeric`` = no categorical feature.
+    Reads the backend (``auto_selects_coarse``), nothing else.
 
-    # Two-level coarse->refine histogram (hist_method="coarse"): a 20-slot
-    # pass over bins >> 4, a span choice per (node, feature) from the
-    # coarse boundary gains, a 16-bin refine pass over the chosen span,
-    # and an exact evaluate_splits over the order-preserving synthetic
-    # layout — 2.8x cheaper per level than the 256-wide one-pass kernel
-    # (docs/performance.md round-4 section). Exactness: every coarse
-    # boundary is scored exactly; in-span fine boundaries exactly; fine
-    # splits OUTSIDE the chosen span are not searched.
-    #
-    # Round 5: "auto" promotes to coarse where its preconditions hold and
-    # it measured faster (TPU, numeric, wide bins, enough rows) — the
-    # eval-set validation table in docs/performance.md is the quality
-    # justification. All sizes below the thresholds keep the exact kernel.
-    use_coarse = hist_kernel in ("coarse", "fused")
-    if hist_kernel == "auto":
+    Two-level coarse->refine search ("coarse"): a 20-slot pass over
+    bins >> 4, a span choice per (node, feature) from the coarse boundary
+    gains, a 16-bin refine pass over the chosen span, and an exact
+    ``evaluate_splits`` over the order-preserving synthetic layout. Every
+    coarse boundary and every in-span fine boundary is scored exactly;
+    fine splits OUTSIDE the chosen span are not searched. "auto" takes it
+    where ``auto_selects_coarse`` holds and keeps the exact one-pass
+    kernel everywhere else.
+
+    "fused" is a rescheduling of that search, not another search space:
+    per level boundary the row advance below level L's decoded splits and
+    level L+1's coarse accumulation share one read of the bin tile
+    (``ops/histogram.py fused_advance_coarse``). Bit-exact with "coarse"
+    (tests/test_fused_hist.py), so "auto" runs the fused schedule wherever
+    it promotes; explicit "coarse" keeps the two-pass schedule as the
+    reference that test holds it to."""
+    use_coarse = hist_method in TWO_LEVEL_METHODS
+    if hist_method == "auto":
         use_coarse = auto_selects_coarse(
             n, max_nbins, has_missing, numeric=numeric,
             col_split=col_split)
-    # Round 6: the cross-level FUSED sweep is a rescheduling of the coarse
-    # scheme, not a new search space — per level boundary the row advance
-    # below level L's decoded splits and level L+1's coarse accumulation
-    # share one read of the bin tile (ops/histogram.py
-    # fused_advance_coarse), where the unfused path streams a persistent
-    # [n, F] f32 copy for the advance matmul plus the coarse-id copy.
-    # Bit-exact with "coarse" (tests/test_fused_hist.py), so "auto"
-    # promotes straight to the fused scheduling wherever it promoted to
-    # coarse; explicit "coarse" keeps the two-pass scheduling so the A/B
-    # stays measurable.
-    use_fused = hist_kernel == "fused" or (hist_kernel == "auto"
+    use_fused = hist_method == "fused" or (hist_method == "auto"
                                            and use_coarse)
-    # The segmented-scan formulation (explicit hist_method="scan") replaces
-    # the fused schedule's coarse+refine data passes with ONE sorted pass
-    # per level — rows are counting-sorted by node (ops/partition.py
-    # counting_sort_by_node), the fine histogram is a contiguous segment
-    # sum over the sorted runs, and the coarse + refine histograms are
-    # derived from it (integral slice-diffs on TPU, direct sorted builds on
-    # XLA) instead of being re-accumulated from the data. Search space and
-    # models are bit-identical to fused (tools/validate_scan.py pins the
-    # grid); "auto" never takes it: the sort and the permute cost more on
-    # the chip than the passes they save (PERF.md section 6, PR 28).
-    use_scan = hist_kernel in ("scan", "mega")
-    use_coarse = use_coarse or use_scan
-    use_fused = use_fused and not use_scan
-    # Megakernel (explicit hist_method="mega"): the scan stage chain, but
-    # the Python depth loop becomes one ``lax.fori_loop`` with level
-    # bounds as traced carries and node arrays padded to the static
-    # capacity N_cap = 2^(max_depth-1). Outside its gates it falls back
-    # to the unrolled scan loop, which is bit-identical, so a fallback is
-    # never a correctness event:
-    # - numeric features only (scan's own restriction);
-    # - every level dense (2^max_depth <= DENSE_LEVEL_MAX): the loop body
-    #   is ONE program, so the dense/walk advance switch cannot vary by
-    #   depth;
-    # - colsample_bynode == 1: per-node subsampling draws
-    #   ``jax.random.split(key, n_level)`` whose RESULTS depend on the
-    #   level width, which is traced here — jax's split is not
-    #   prefix-stable, so the padded draw would change sampled features
-    #   (colsample_bylevel is safe: fold_in of the traced depth is
-    #   value-identical to the unrolled fold_in);
-    # - no smaller-child compaction (static per-level capacities).
-    use_mega = (hist_kernel == "mega"
-                and numeric and not use_compaction
-                and param.max_depth >= 1
-                and 2 ** param.max_depth <= DENSE_LEVEL_MAX
-                and param.colsample_bynode >= 1.0)
-    return Schedule(kernel=hist_kernel, compaction=use_compaction,
-                    prehot=use_prehot, coarse=use_coarse, fused=use_fused,
-                    scan=use_scan, mega=use_mega)
+    return Schedule(kernel=hist_method, coarse=use_coarse, fused=use_fused)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("param", "max_nbins", "hist_method", "axis_name",
-                     "has_missing", "split_mode", "scan_acc"))
+                     "has_missing", "split_mode"))
 @stage("grow")      # its own root where it IS the program (the general path)
 def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
           tree_mask: jnp.ndarray, key: jax.Array,
@@ -322,7 +235,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
           param: TrainParam, max_nbins: int, hist_method: str = "auto",
           axis_name: Optional[str] = None,
           has_missing: bool = True,
-          split_mode: str = "row", scan_acc: str = "f32") -> GrownTree:
+          split_mode: str = "row") -> GrownTree:
     """``split_mode="row"``: rows sharded over ``axis_name``, histograms
     psum'd (reference ``DataSplitMode::kRow``). ``split_mode="col"``:
     FEATURES sharded, rows replicated — split finding is local per feature
@@ -407,17 +320,10 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                          node_upper[lo:lo + n_level])
         return w * param.eta
 
-    sched = resolve_schedule(hist_method, n, max_nbins, has_missing, param,
-                             numeric=cat is None, col_split=col_split,
-                             sharded=axis_name is not None)
+    sched = resolve_schedule(hist_method, n, max_nbins, has_missing,
+                             numeric=cat is None, col_split=col_split)
     count_grow_schedule(sched.name)
-    hist_kernel, use_compaction, use_prehot = (
-        sched.kernel, sched.compaction, sched.prehot)
-    use_coarse, use_fused, use_scan, use_mega = (
-        sched.coarse, sched.fused, sched.scan, sched.mega)
-    prev_hist = None
-    built_is_left = None
-    oh_pre = (build_onehot_plane(bins_t, max_nbins) if use_prehot else None)
+    hist_kernel, use_coarse, use_fused = sched
     if use_coarse:
         if cat is not None or max_nbins > 256 + int(has_missing):
             raise NotImplementedError(
@@ -432,270 +338,18 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
         # is explicit opt-in.
         from ..ops.split import (assemble_two_level, choose_refine_window,
                                  coarse_bin_ids, decode_two_level_bin,
-                                 refine_bin_ids, refine_from_fine)
+                                 refine_bin_ids)
         cb_t = coarse_bin_ids(bins_t.astype(jnp.int32), missing_bin)
         cb = cb_t.T
 
     pending_adv = None  # fused: splits awaiting the next boundary sweep
-    if use_mega:
-        # ---- megakernel: one fori_loop body for every level ------------
-        # Same stage chain as the unrolled scan loop below — boundary
-        # sweep (advance + one sorted ordering -> fine+coarse), window,
-        # integral refine, eval, heap bookkeeping — with the level bounds
-        # ``lo`` / ``n_level`` as TRACED values and every per-level array
-        # padded to the static capacity N_cap = 2^(max_depth-1).
-        # Bit-parity with scan:
-        # - the boundary sweep runs EVERY iteration; at d=0 the pending
-        #   decision arrays are all-inert (can_split False), so the
-        #   advance is `where(False, ..., positions)` — bitwise identity —
-        #   and the sweep's hist build IS the root build;
-        # - histogram rows [0:n_level] are bitwise equal to the uncapped
-        #   build (scan_advance_level n_cap docstring);
-        # - padded node slots (j >= n_level) never write: every scatter
-        #   routes through a sentinel index with mode="drop", and
-        #   ``can_split`` is masked on ``valid``, so padded lanes cannot
-        #   influence real rows or the heap;
-        # - per-node stages (window/refine/eval/assemble/decode) are
-        #   row-independent, so padded lanes just compute dead values.
-        N_cap = 2 ** (max_depth - 1)
-        mega_row_axis = axis_name if not col_split else None
-        mega_dec_axis = axis_name if col_split else None
-        lane = jnp.arange(N_cap, dtype=jnp.int32)
-
-        def _mega_body(d, carry):
-            n_level = (jnp.int32(1) << d).astype(jnp.int32)
-            lo = n_level - 1
-            nl_prev = n_level >> 1
-            lo_prev = nl_prev - 1
-            valid = lane < n_level
-            idx = lo + lane
-            drop_idx = jnp.where(valid, idx, max_nodes)
-            positions = carry["positions"]
-            prev = {"kind": "dense", "lo": lo_prev, "n_level": nl_prev,
-                    "arrs": (carry["feat_p"], carry["bin_p"],
-                             carry["dl_p"], carry["cs_p"])}
-            with stage("sort"):
-                positions, hist_f, hist_c = scan_advance_level(
-                    bins, gpair, positions, prev, lo, n_level,
-                    missing_bin, max_nbins=max_nbins, bins_t=bins_t,
-                    method="auto", axis_name=mega_row_axis,
-                    decision_axis=mega_dec_axis, acc=scan_acc,
-                    n_cap=N_cap)
-            with stage("exchange"):
-                hist_f = allreduce(hist_f)
-                hist_c = allreduce(hist_c)
-            node_sum_l = jax.lax.dynamic_slice(
-                carry["node_sum"], (lo, jnp.int32(0)), (N_cap, 2))
-            active_l = jax.lax.dynamic_slice(carry["active"], (lo,),
-                                             (N_cap,))
-            if monotone is not None:
-                nlow_l = jax.lax.dynamic_slice(carry["node_lower"], (lo,),
-                                               (N_cap,))
-                nupp_l = jax.lax.dynamic_slice(carry["node_upper"], (lo,),
-                                               (N_cap,))
-            with stage("window"):
-                span = choose_refine_window(hist_c, node_sum_l,
-                                            n_real_bins, param,
-                                            has_missing)          # [N, F]
-            with stage("refine"):
-                hist_r = refine_from_fine(hist_f, span, missing_bin)
-            hist, n_real_eval = assemble_two_level(
-                hist_c, hist_r, span, n_real_bins, has_missing)
-
-            # fold_in of the traced depth is value-identical to the
-            # unrolled loop's fold_in of the Python int
-            level_key = jax.random.fold_in(key, d)
-            fmask = _sample_features(level_key, tree_mask,
-                                     param.colsample_bylevel)[None, :]
-            if constraint_sets is not None:
-                path = jax.lax.dynamic_slice(
-                    carry["node_path"], (lo, jnp.int32(0)),
-                    (N_cap, F_cons))
-                allowed = interaction_allowed_dev(path, constraint_sets)
-                if col_split:
-                    allowed = jax.lax.dynamic_slice(
-                        allowed, (jnp.int32(0), feat_off), (N_cap, F))
-                fmask = fmask & allowed
-
-            with stage("eval"):
-                res = evaluate_splits(
-                    hist, node_sum_l, n_real_eval, param,
-                    feature_mask=fmask, monotone=mono_loc,
-                    node_lower=nlow_l if monotone is not None else None,
-                    node_upper=nupp_l if monotone is not None else None,
-                    cat=None, has_missing=has_missing)
-            span_sel = jnp.take_along_axis(
-                span, jnp.maximum(res.feature, 0)[:, None], axis=1)[:, 0]
-            res = res._replace(bin=decode_two_level_bin(res.bin, span_sel))
-            if col_split:
-                local_feat, local_bin = res.feature, res.bin
-                local_dl = res.default_left
-                with stage("exchange"):
-                    res, mine = exchange_best_split(res, axis_name, F)
-
-            can_split = (valid & active_l
-                         & (res.gain > max(param.gamma, _EPS))
-                         & jnp.isfinite(res.gain))
-
-            out = dict(carry)
-            out["split_feature"] = carry["split_feature"].at[drop_idx].set(
-                jnp.where(can_split, res.feature, -1), mode="drop")
-            out["split_bin"] = carry["split_bin"].at[drop_idx].set(
-                jnp.where(can_split, res.bin, 0), mode="drop")
-            out["default_left"] = carry["default_left"].at[drop_idx].set(
-                can_split & res.default_left, mode="drop")
-            out["is_leaf"] = carry["is_leaf"].at[drop_idx].set(
-                ~can_split, mode="drop")
-            out["gain"] = carry["gain"].at[drop_idx].set(
-                jnp.where(can_split, res.gain, 0.0), mode="drop")
-
-            li_d = jnp.where(valid, 2 * idx + 1, max_nodes)
-            ri_d = jnp.where(valid, 2 * idx + 2, max_nodes)
-            out["active"] = (carry["active"]
-                             .at[li_d].set(can_split, mode="drop")
-                             .at[ri_d].set(can_split, mode="drop"))
-            zero2 = jnp.zeros_like(res.left_sum)
-            out["node_sum"] = (carry["node_sum"]
-                               .at[li_d].set(jnp.where(can_split[:, None],
-                                                       res.left_sum, zero2),
-                                             mode="drop")
-                               .at[ri_d].set(jnp.where(can_split[:, None],
-                                                       res.right_sum, zero2),
-                                             mode="drop"))
-            if monotone is not None:
-                wl = jnp.clip(calc_weight(res.left_sum[:, 0],
-                                          res.left_sum[:, 1], param),
-                              nlow_l, nupp_l)
-                wr = jnp.clip(calc_weight(res.right_sum[:, 0],
-                                          res.right_sum[:, 1], param),
-                              nlow_l, nupp_l)
-                mid = (wl + wr) * 0.5
-                mc = monotone[jnp.maximum(res.feature, 0)]
-                l_hi = jnp.where(mc > 0, mid, nupp_l)
-                r_lo = jnp.where(mc > 0, mid, nlow_l)
-                l_lo = jnp.where(mc < 0, mid, nlow_l)
-                r_hi = jnp.where(mc < 0, mid, nupp_l)
-                out["node_lower"] = (
-                    carry["node_lower"]
-                    .at[li_d].set(jnp.where(can_split, l_lo, 0),
-                                  mode="drop")
-                    .at[ri_d].set(jnp.where(can_split, r_lo, 0),
-                                  mode="drop"))
-                out["node_upper"] = (
-                    carry["node_upper"]
-                    .at[li_d].set(jnp.where(can_split, l_hi, 0),
-                                  mode="drop")
-                    .at[ri_d].set(jnp.where(can_split, r_hi, 0),
-                                  mode="drop"))
-            if constraint_sets is not None:
-                fsel = (jnp.arange(F_cons, dtype=jnp.int32)[None, :]
-                        == jnp.maximum(res.feature, 0)[:, None]) \
-                    & can_split[:, None]
-                child_path = path | fsel
-                out["node_path"] = (
-                    carry["node_path"]
-                    .at[li_d].set(child_path, mode="drop")
-                    .at[ri_d].set(child_path, mode="drop"))
-
-            with stage("delta"):
-                # rows whose node just became a terminal leaf take its
-                # value now (the unrolled loop's dense_delta block)
-                leaf_now = active_l & ~can_split
-                w_level = calc_weight(node_sum_l[:, 0], node_sum_l[:, 1],
-                                      param)
-                if monotone is not None:
-                    w_level = jnp.clip(w_level, nlow_l, nupp_l)
-                w_level = jnp.where(leaf_now, w_level * param.eta, 0.0)
-                rel = jnp.where(
-                    (positions >= lo) & (positions < lo + n_level),
-                    positions - lo, N_cap).astype(jnp.int32)
-                rel_oh = rel[:, None] == lane[None, :]
-                out["delta"] = carry["delta"] + jnp.sum(
-                    jnp.where(rel_oh, w_level[None, :], 0.0), axis=1)
-
-            if col_split:
-                out["feat_p"] = jnp.where(can_split & mine, local_feat, -1)
-                out["bin_p"] = jnp.where(can_split & mine, local_bin, 0)
-                out["dl_p"] = can_split & mine & local_dl
-            else:
-                out["feat_p"] = jnp.where(can_split, res.feature, -1)
-                out["bin_p"] = jnp.where(can_split, res.bin, 0)
-                out["dl_p"] = can_split & res.default_left
-            out["cs_p"] = can_split
-            out["positions"] = positions
-            return out
-
-        carry0 = {
-            "split_feature": split_feature, "split_bin": split_bin,
-            "default_left": default_left, "is_leaf": is_leaf,
-            "active": active, "gain": gain, "node_sum": node_sum,
-            "positions": positions, "delta": delta,
-            # pending boundary decisions, all-inert before the root level
-            "feat_p": jnp.full((N_cap,), -1, jnp.int32),
-            "bin_p": jnp.zeros((N_cap,), jnp.int32),
-            "dl_p": jnp.zeros((N_cap,), bool),
-            "cs_p": jnp.zeros((N_cap,), bool),
-        }
-        if monotone is not None:
-            carry0["node_lower"] = node_lower
-            carry0["node_upper"] = node_upper
-        if constraint_sets is not None:
-            carry0["node_path"] = node_path
-        carry = jax.lax.fori_loop(0, max_depth, _mega_body, carry0)
-        split_feature = carry["split_feature"]
-        split_bin = carry["split_bin"]
-        default_left = carry["default_left"]
-        is_leaf = carry["is_leaf"]
-        active = carry["active"]
-        gain = carry["gain"]
-        node_sum = carry["node_sum"]
-        positions = carry["positions"]
-        delta = carry["delta"]
-        if monotone is not None:
-            node_lower = carry["node_lower"]
-            node_upper = carry["node_upper"]
-        # epilogue advance below the deepest level's splits — the deepest
-        # level is exactly N_cap wide, so the pending arrays are unpadded
-        # and the static-bound advance matches the unrolled epilogue
-        lo_p = 2 ** (max_depth - 1) - 1
-        with stage("advance"):
-            rel_p = jnp.where(
-                (positions >= lo_p) & (positions < lo_p + N_cap),
-                positions - lo_p, N_cap).astype(jnp.int32)
-            positions = advance_positions_level(
-                bins_f32, positions, rel_p, carry["feat_p"],
-                carry["bin_p"], carry["dl_p"], carry["cs_p"], missing_bin,
-                decision_axis=mega_dec_axis)
-
-    # mega replaces the unrolled loop wholesale (fori_loop above); the
-    # generic fused/scan epilogue is skipped via pending_adv=None
-    for depth in range(0 if use_mega else max_depth):
+    for depth in range(max_depth):
         lo = 2 ** depth - 1
         n_level = 2 ** depth
         idx = lo + jnp.arange(n_level)
 
         hist_c = None
-        hist_f = None  # scan: this level's full fine histogram
-        if use_scan and pending_adv is not None:
-            # scan boundary sweep: advance rows below the previous level's
-            # decoded splits, then one sorted ordering of the new level
-            # yields BOTH its fine and coarse histograms
-            row_axis = axis_name if not col_split else None
-            # named_scope: stage labels on the device timeline — _grow is
-            # ONE jitted dispatch, so in-trace scopes (not host spans) are
-            # what aligns its stages with jax.profiler captures
-            with stage("sort"):
-                positions, hist_f, hist_c = scan_advance_level(
-                    bins, gpair, positions, pending_adv, lo, n_level,
-                    missing_bin, max_nbins=max_nbins, bins_t=bins_t,
-                    method="auto", axis_name=row_axis,
-                    decision_axis=axis_name if col_split else None,
-                    acc=scan_acc)
-            with stage("exchange"):
-                hist_f = allreduce(hist_f)
-                hist_c = allreduce(hist_c)
-            pending_adv = None
-        elif use_fused and pending_adv is not None:
+        if use_fused and pending_adv is not None:
             # cross-level fused sweep: advance rows below the previous
             # level's decoded splits AND build this level's coarse
             # histogram from the same bin-tile read
@@ -715,17 +369,6 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
         span = None
         if use_coarse:
             row_axis = axis_name if not col_split else None
-            if use_scan and hist_f is None:
-                # root level (and any level not fed by a boundary sweep):
-                # one sorted pass builds fine + coarse together
-                with stage("sort"):
-                    hist_f, hist_c = scan_level_hists(
-                        bins, gpair, rel, n_level, max_nbins, missing_bin,
-                        bins_t=bins_t, method="auto", axis_name=row_axis,
-                        acc=scan_acc)
-                with stage("exchange"):
-                    hist_f = allreduce(hist_f)
-                    hist_c = allreduce(hist_c)
             if hist_c is None:
                 with stage("hist"):
                     hist_c = allreduce(build_hist(
@@ -736,89 +379,47 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                                             node_sum[lo:lo + n_level],
                                             n_real_bins, param,
                                             has_missing)          # [N, F]
-            if use_scan:
-                # integral-histogram refine: the refine pass is an O(1)
-                # WINDOW-slice of the fine histogram already in hand —
-                # bit-equal to the direct refine build of the same rows
-                # (ops/split.py refine_from_fine docstring) — so the
-                # level needs NO second data sweep
-                with stage("refine"):
-                    hist_r = refine_from_fine(hist_f, span, missing_bin)
-            else:
-                # per-row window of the row's node, via one [F,N+1]@[N+1,n]
-                # MXU matmul (rows outside the level hit the zero pad row;
-                # their kernel contribution is dropped by rel == n_level)
-                with stage("refine"):
-                    span_pad = jnp.concatenate(
-                        [span.astype(jnp.float32),
-                         jnp.zeros((1, F), jnp.float32)]).T  # [F, N+1]
-                    oh_rel = (rel[None, :] == jnp.arange(
-                        n_level + 1,
-                        dtype=jnp.int32)[:, None]).astype(jnp.float32)
-                    c_row_t = jax.lax.dot_general(
-                        span_pad, oh_rel, (((1,), (0,)), ((), ())),
-                        precision=jax.lax.Precision.HIGHEST)    # [F, n]
-                    # out-of-window sentinel (refine_bin_ids) must be a
-                    # VALID slot of the kernel — the flat-index segment
-                    # path would bleed an out-of-range id into the next
-                    # feature's bins; the pad slots of the WINDOW+4-wide
-                    # pass are discarded
-                    from ..ops.split import WINDOW
-                    rb_t = refine_bin_ids(bins_t.astype(jnp.int32),
-                                          c_row_t.astype(jnp.int32),
-                                          missing_bin)
-                    hist_r = allreduce(build_hist(
-                        rb_t.T, gpair, rel, n_level, WINDOW + 4,
-                        method="auto", bins_t=rb_t,
-                        axis_name=row_axis))[:, :, :WINDOW, :]
+            # per-row window of the row's node, via one [F,N+1]@[N+1,n]
+            # MXU matmul (rows outside the level hit the zero pad row;
+            # their kernel contribution is dropped by rel == n_level)
+            with stage("refine"):
+                span_pad = jnp.concatenate(
+                    [span.astype(jnp.float32),
+                     jnp.zeros((1, F), jnp.float32)]).T  # [F, N+1]
+                oh_rel = (rel[None, :] == jnp.arange(
+                    n_level + 1,
+                    dtype=jnp.int32)[:, None]).astype(jnp.float32)
+                c_row_t = jax.lax.dot_general(
+                    span_pad, oh_rel, (((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST)    # [F, n]
+                # out-of-window sentinel (refine_bin_ids) must be a
+                # VALID slot of the kernel — the flat-index segment
+                # path would bleed an out-of-range id into the next
+                # feature's bins; the pad slots of the WINDOW+4-wide
+                # pass are discarded
+                from ..ops.split import WINDOW
+                rb_t = refine_bin_ids(bins_t.astype(jnp.int32),
+                                      c_row_t.astype(jnp.int32),
+                                      missing_bin)
+                hist_r = allreduce(build_hist(
+                    rb_t.T, gpair, rel, n_level, WINDOW + 4,
+                    method="auto", bins_t=rb_t,
+                    axis_name=row_axis))[:, :, :WINDOW, :]
             hist, n_real_eval = assemble_two_level(
                 hist_c, hist_r, span, n_real_bins, has_missing)
-        elif depth == 0 or not use_compaction:
+        else:
             with stage("hist"):
-                if use_prehot:
-                    hist = build_hist_prehot(
-                        oh_pre, gpair, rel, n_level, max_nbins,
-                        axis_name=axis_name if not col_split else None)
-                else:
-                    hist = build_hist(
-                        bins, gpair, rel, n_level, max_nbins,
-                        method=hist_kernel, bins_t=bins_t,
-                        # int8x2 quantisation scale must be pmax'd across
-                        # row shards so every shard quantises identically
-                        # (col split replicates rows — local scale is
-                        # already global)
-                        axis_name=axis_name if not col_split else None)
+                hist = build_hist(
+                    bins, gpair, rel, n_level, max_nbins,
+                    method=hist_kernel, bins_t=bins_t,
+                    # int8x2 quantisation scale must be pmax'd across
+                    # row shards so every shard quantises identically
+                    # (col split replicates rows — local scale is
+                    # already global)
+                    axis_name=axis_name if not col_split else None)
             with stage("exchange"):
                 hist = allreduce(hist)
-        else:
-            n_parents = n_level // 2
-            child = positions - lo
-            par = child >> 1
-            is_left_child = (child & 1) == 0
-            built_mask = in_level & (
-                is_left_child == built_is_left[
-                    jnp.clip(par, 0, n_parents - 1)])
-            cap = max(n // 2, 1)
-            with stage("hist"):
-                with stage("permute"):
-                    idxr = jnp.nonzero(built_mask, size=cap,
-                                       fill_value=n)[0]
-                    bins_c = jnp.take(bins, idxr, axis=0, mode="fill",
-                                      fill_value=0)
-                    gp_c = jnp.take(gpair, idxr, axis=0, mode="fill",
-                                    fill_value=0.0)
-                    par_c = jnp.take(jnp.clip(par, 0, n_parents), idxr,
-                                     mode="fill",
-                                     fill_value=n_parents).astype(jnp.int32)
-                hist_b = build_hist(bins_c, gp_c, par_c, n_parents,
-                                    max_nbins, method=hist_kernel,
-                                    bins_t=bins_c.T)
-                left_h, right_h = subtract_siblings(prev_hist, hist_b,
-                                                    built_is_left)
-                hist = jnp.stack([left_h, right_h], axis=1).reshape(
-                    (n_level,) + left_h.shape[1:])
 
-        prev_hist = hist
         level_key = jax.random.fold_in(key, depth)
         level_mask = _sample_features(level_key, tree_mask,
                                       param.colsample_bylevel)
@@ -929,8 +530,8 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                 delta = delta + jnp.sum(
                     jnp.where(rel_oh, w_level[None, :], 0.0), axis=1)
 
-        if use_fused or use_scan:
-            # defer this level's advance to the NEXT boundary's fused/scan
+        if use_fused:
+            # defer this level's advance to the NEXT boundary's fused
             # sweep; categorical args never arise (coarse is numeric-only)
             if col_split and n_level <= DENSE_LEVEL_MAX:
                 pending_adv = {
@@ -991,19 +592,6 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                 decision_axis=axis_name if col_split else None,
                 feat_offset=feat_off)
 
-        if use_compaction and depth + 1 < max_depth:
-            # next level's per-node row counts pick each parent's smaller
-            # child (count-based, which is what bounds the compaction
-            # capacity at n//2)
-            lo_next = 2 * lo + 1
-            n_next = 2 * n_level
-            cn = positions - lo_next
-            valid = (cn >= 0) & (cn < n_next)
-            counts = jax.ops.segment_sum(
-                valid.astype(jnp.int32), jnp.where(valid, cn, n_next),
-                num_segments=n_next + 1)[:n_next]
-            built_is_left = counts[0::2] <= counts[1::2]
-
     with stage("leaf"):
         w = calc_weight(node_sum[:, 0], node_sum[:, 1], param)
         if monotone is not None:
@@ -1013,8 +601,8 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
         base_weight = jnp.where(active, w, 0.0).astype(jnp.float32)
 
     # what advances the rows below the last level (xtpu_grow_epilogue_total):
-    # mega's own dense advance, or nothing where every level advanced itself
-    epilogue, leaf_delta = "dense" if use_mega else "none", None
+    # nothing where every level advanced itself
+    epilogue, leaf_delta = "none", None
     if pending_adv is not None:
         # epilogue: route rows below the deepest level's splits — there is
         # no next coarse pass left to fuse with. Past DENSE_LEVEL_MAX, on
@@ -1145,18 +733,6 @@ class TreeGrower:
         self.split_mode = split_mode
         self.cuts = cuts
         self.hist_method = hist_method
-        # scan-formulation partial-accumulator dtype (construction-time env
-        # read; docs/env_knobs.md XTPU_SCAN_ACC): "bf16" accumulates the
-        # segment sums in bf16 with an f32 residual fix-up pass — an
-        # opt-in A/B knob, NOT bit-compatible with fused
-        # (tools/validate_scan.py holds f32 only). "auto" (Round 14) resolves to
-        # bf16/f32 at first grow behind the measured RMS error-bound
-        # gate (ops/histogram.py resolve_scan_acc)
-        self.scan_acc = os.environ.get("XTPU_SCAN_ACC", "f32")
-        if self.scan_acc not in ("f32", "bf16", "auto"):
-            raise ValueError(
-                f"XTPU_SCAN_ACC must be 'f32', 'bf16' or 'auto', got "
-                f"{self.scan_acc!r}")
         self.mesh = mesh
         self.monotone = (None if monotone is None
                          else jnp.asarray(monotone, jnp.int32))
@@ -1204,35 +780,14 @@ class TreeGrower:
                                      base_mask,
                                      self.param.colsample_bytree)
         key = jax.random.fold_in(key, 0x5EED)
-        if self.scan_acc == "auto":
-            # resolved ONCE per grower (shape class) on the first
-            # round's gradients, before the jitted tree program (where
-            # scan_acc is static) is built
-            if not getattr(bins, "is_paged", False):
-                from ..ops.histogram import resolve_scan_acc
-
-                self.scan_acc = resolve_scan_acc(bins, gpair,
-                                                 self.max_nbins,
-                                                 self.has_missing)
-            else:
-                self.scan_acc = "f32"
-        # host span for the megakernel tier — only when grow() IS the
-        # dispatch (standalone/mesh); under the fused round this method
-        # runs at trace time where a wall-clock span is meaningless
-        span = (_trace.span("round/mega")
-                if self.hist_method == "mega"
-                and not isinstance(bins, jax.core.Tracer)
-                else _contextlib.nullcontext())
-        with span:
-            if self.mesh is None:
-                g = _grow(bins, gpair, n_real_bins, tree_mask, key,
-                          self.monotone, self.constraint_sets, self.cat,
-                          param=self.param, max_nbins=self.max_nbins,
-                          hist_method=self.hist_method, axis_name=None,
-                          has_missing=self.has_missing,
-                          scan_acc=self.scan_acc)
-            else:
-                g = self._sharded(bins, gpair, n_real_bins, tree_mask, key)
+        if self.mesh is None:
+            g = _grow(bins, gpair, n_real_bins, tree_mask, key,
+                      self.monotone, self.constraint_sets, self.cat,
+                      param=self.param, max_nbins=self.max_nbins,
+                      hist_method=self.hist_method, axis_name=None,
+                      has_missing=self.has_missing)
+        else:
+            g = self._sharded(bins, gpair, n_real_bins, tree_mask, key)
         if self.param.max_leaves > 0:
             g = self._truncate_max_leaves(g)
         return g
@@ -1291,8 +846,7 @@ class TreeGrower:
                              hist_method=self.hist_method,
                              axis_name=DATA_AXIS,
                              has_missing=self.has_missing,
-                             split_mode=self.split_mode,
-                             scan_acc=self.scan_acc)
+                             split_mode=self.split_mode)
 
             if self.split_mode == "col":
                 # features sharded over the axis, rows replicated; every
@@ -1314,18 +868,12 @@ class TreeGrower:
                     is_cat_split=P(), cat_words=P(), base_weight=P())
             # col mode: outputs ARE replicated (every split field passes
             # through a psum / all_gather), but the static replication
-            # checker cannot prove it through the owner-shard select chain.
-            # mega: the fori_loop carry mixes proven-replicated outputs
-            # with unknown-rep inits (scatter has no replication rule on
-            # this jax), and the loop requires input/output reps to match
-            # exactly — the values replicate fine (every hist passes the
-            # in-loop psum), so the static check is waived like col mode
+            # checker cannot prove it through the owner-shard select chain
             self._sharded_fn = jax.jit(jax.shard_map(
                 inner, mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
-                check_vma=(self.split_mode != "col"
-                           and self.hist_method != "mega")))
+                check_vma=self.split_mode != "col"))
         return self._sharded_fn
 
     def _sharded(self, bins, gpair, n_real_bins, tree_mask, key) -> GrownTree:

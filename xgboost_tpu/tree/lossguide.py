@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import heapq
 import math
-import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -34,10 +33,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import trace as _trace
-from ..obs.trace import stage
-from ..ops.histogram import build_hist, scan_level_hists
+from ..ops.histogram import build_hist
 from ..ops.partition import cat_goes_right
 from ..ops.split import CatInfo, evaluate_splits
+from .grow import TWO_LEVEL_METHODS, exchange_best_split, resolve_schedule
 from .param import TrainParam, calc_weight
 from .tree import TreeModel
 
@@ -56,8 +55,7 @@ def _eval2(bins, gpair, positions, id0, id1, parent_sums, fmask,
            node_lower, node_upper, n_real_bins, bins_t, cb_t, monotone,
            cat, *, param: TrainParam, max_nbins: int, hist_method: str,
            axis_name: Optional[str], has_missing: bool = True,
-           coarse: bool = False, scan: bool = False,
-           scan_acc: str = "f32"):
+           coarse: bool = False):
     """Histogram + split enumeration for (up to) two sibling nodes.
     ``bins_t`` is the loop-invariant [F, n] transpose, computed once per
     tree so every per-split program skips the relayout.
@@ -67,16 +65,10 @@ def _eval2(bins, gpair, positions, id0, id1, parent_sums, fmask,
     build pays the full 256-wide one-hot cost exactly like a depthwise
     level did, so the same ~2.8x kernel win applies). Both passes psum
     under a mesh; the final enumeration is exact over the assembled
-    synthetic layout and the winning slot decodes to a fine bin.
-
-    ``scan`` (implies the coarse search space): one sorted segment-sum
-    pass yields the pair's fine + coarse histograms and the refine pass
-    is an O(1) window slice of the fine build
-    (``ops/split.py refine_from_fine``) — bit-identical splits to the
-    coarse/fused builds (tests/test_scan_hist.py)."""
+    synthetic layout and the winning slot decodes to a fine bin."""
     rel = jnp.where(positions == id0, 0,
                     jnp.where(positions == id1, 1, 2)).astype(jnp.int32)
-    if not (coarse or scan):
+    if not coarse:
         hist = build_hist(bins, gpair, rel, 2, max_nbins,
                           method=hist_method, bins_t=bins_t)
         if axis_name is not None:
@@ -88,40 +80,29 @@ def _eval2(bins, gpair, positions, id0, id1, parent_sums, fmask,
                                cat=cat, has_missing=has_missing)
     from ..ops.split import (COARSE_B, WINDOW, assemble_two_level,
                              choose_refine_window, decode_two_level_bin,
-                             refine_bin_ids, refine_from_fine)
+                             refine_bin_ids)
 
     missing_bin = max_nbins - 1 if has_missing else max_nbins
-    if scan:
-        hist_f, hist_c = scan_level_hists(
-            bins, gpair, rel, 2, max_nbins, missing_bin, bins_t=bins_t,
-            method="auto", axis_name=axis_name, acc=scan_acc)
-        if axis_name is not None:
-            hist_f = jax.lax.psum(hist_f, axis_name)
-            hist_c = jax.lax.psum(hist_c, axis_name)
-        span = choose_refine_window(hist_c, parent_sums, n_real_bins,
-                                    param, has_missing)       # [2, F]
-        hist_r = refine_from_fine(hist_f, span, missing_bin)
-    else:
-        # cb_t is hoisted per TREE by the grower (loop-invariant, like
-        # bins_t); the int32 view feeding refine_bin_ids stays in-jit so
-        # XLA fuses the upcast into the consumer instead of materialising
-        # [F,n]i32
-        bt_i32 = bins_t.astype(jnp.int32)
-        hist_c = build_hist(cb_t.T, gpair, rel, 2, COARSE_B, method="auto",
-                            bins_t=cb_t)
-        if axis_name is not None:
-            hist_c = jax.lax.psum(hist_c, axis_name)
-        span = choose_refine_window(hist_c, parent_sums, n_real_bins,
-                                    param, has_missing)       # [2, F]
-        # per-row window of the row's node (N=2: two selects, no matmul)
-        c_row_t = jnp.where(rel[None, :] == 0, span[0][:, None],
-                            jnp.where(rel[None, :] == 1, span[1][:, None],
-                                      0)).astype(jnp.int32)   # [F, n]
-        rb_t = refine_bin_ids(bt_i32, c_row_t, missing_bin)
-        hist_r = build_hist(rb_t.T, gpair, rel, 2, WINDOW + 4,
-                            method="auto", bins_t=rb_t)[:, :, :WINDOW, :]
-        if axis_name is not None:
-            hist_r = jax.lax.psum(hist_r, axis_name)
+    # cb_t is hoisted per TREE by the grower (loop-invariant, like
+    # bins_t); the int32 view feeding refine_bin_ids stays in-jit so
+    # XLA fuses the upcast into the consumer instead of materialising
+    # [F,n]i32
+    bt_i32 = bins_t.astype(jnp.int32)
+    hist_c = build_hist(cb_t.T, gpair, rel, 2, COARSE_B, method="auto",
+                        bins_t=cb_t)
+    if axis_name is not None:
+        hist_c = jax.lax.psum(hist_c, axis_name)
+    span = choose_refine_window(hist_c, parent_sums, n_real_bins,
+                                param, has_missing)       # [2, F]
+    # per-row window of the row's node (N=2: two selects, no matmul)
+    c_row_t = jnp.where(rel[None, :] == 0, span[0][:, None],
+                        jnp.where(rel[None, :] == 1, span[1][:, None],
+                                  0)).astype(jnp.int32)   # [F, n]
+    rb_t = refine_bin_ids(bt_i32, c_row_t, missing_bin)
+    hist_r = build_hist(rb_t.T, gpair, rel, 2, WINDOW + 4,
+                        method="auto", bins_t=rb_t)[:, :, :WINDOW, :]
+    if axis_name is not None:
+        hist_r = jax.lax.psum(hist_r, axis_name)
     hist, n_real_eval = assemble_two_level(hist_c, hist_r, span,
                                            n_real_bins, has_missing)
     res = evaluate_splits(hist, parent_sums, n_real_eval, param,
@@ -138,8 +119,7 @@ def _eval2_col(bins, gpair, positions, id0, id1, parent_sums, fmask,
                monotone, cat, *,
                param: TrainParam, max_nbins: int, hist_method: str,
                axis_name: str, has_missing: bool = True,
-               coarse: bool = False, scan: bool = False,
-               scan_acc: str = "f32"):
+               coarse: bool = False):
     """Column-split ``_eval2``: this shard's bins hold global features
     [off, off + F); rows replicate so the two-node histogram needs no
     psum, each shard evaluates ITS features (local slices of the
@@ -170,10 +150,7 @@ def _eval2_col(bins, gpair, positions, id0, id1, parent_sums, fmask,
                  node_lower, node_upper, n_real_bins, bins_t, cb_t,
                  mono_loc, cat_loc, param=param, max_nbins=max_nbins,
                  hist_method=hist_method, axis_name=None,
-                 has_missing=has_missing, coarse=coarse, scan=scan,
-                 scan_acc=scan_acc)
-    from .grow import exchange_best_split
-
+                 has_missing=has_missing, coarse=coarse)
     res, _ = exchange_best_split(res, axis_name, F,
                                  with_cat=cat is not None)
     return res
@@ -184,8 +161,7 @@ def _apply_eval2(bins, gpair, positions, nid, feat_a, sbin_a, dleft_a,
                  fmask, node_lower, node_upper, n_real_bins, bins_t, cb_t,
                  monotone, cat, *, param: TrainParam, max_nbins: int,
                  hist_method: str, axis_name: Optional[str],
-                 has_missing: bool = True, coarse: bool = False,
-                 scan: bool = False, scan_acc: str = "f32"):
+                 has_missing: bool = True, coarse: bool = False):
     """Cross-level fusion, lossguide form (hist_method="fused"): the popped
     node's one-column row advance and its fresh children's histogram +
     enumeration run as ONE jitted program — the greedy loop's two
@@ -200,8 +176,7 @@ def _apply_eval2(bins, gpair, positions, nid, feat_a, sbin_a, dleft_a,
                  fmask, node_lower, node_upper, n_real_bins, bins_t, cb_t,
                  monotone, cat, param=param, max_nbins=max_nbins,
                  hist_method=hist_method, axis_name=axis_name,
-                 has_missing=has_missing, coarse=coarse, scan=scan,
-                 scan_acc=scan_acc)
+                 has_missing=has_missing, coarse=coarse)
     return positions, res
 
 
@@ -210,8 +185,7 @@ def _apply_eval2_col(bins, gpair, positions, nid, feat_a, sbin_a, dleft_a,
                      fmask, node_lower, node_upper, n_real_bins, bins_t,
                      cb_t, monotone, cat, *, param: TrainParam,
                      max_nbins: int, hist_method: str, axis_name: str,
-                     has_missing: bool = True, coarse: bool = False,
-                     scan: bool = False, scan_acc: str = "f32"):
+                     has_missing: bool = True, coarse: bool = False):
     """Column-split ``_apply_eval2``: the owner-decision advance
     (``_apply1_col``) and the feature-local eval + winner exchange
     (``_eval2_col``) composed into one program."""
@@ -223,7 +197,7 @@ def _apply_eval2_col(bins, gpair, positions, nid, feat_a, sbin_a, dleft_a,
                      n_real_bins, bins_t, cb_t, monotone, cat, param=param,
                      max_nbins=max_nbins, hist_method=hist_method,
                      axis_name=axis_name, has_missing=has_missing,
-                     coarse=coarse, scan=scan, scan_acc=scan_acc)
+                     coarse=coarse)
     return positions, res
 
 
@@ -278,159 +252,6 @@ def _apply1(bins, positions, nid, feat, sbin, dleft, is_cat, words,
 def _root_sum(gpair, axis_name: Optional[str]):
     s = jnp.sum(gpair, axis=0)
     return jax.lax.psum(s, axis_name) if axis_name is not None else s
-
-
-def _mega_greedy_loop(bins, gpair, positions, n_real_bins, bins_t,
-                      fmask_root, fmask_pair, *, param: TrainParam,
-                      max_nbins: int, has_missing: bool, max_leaves: int,
-                      cap: int, gain_thresh: float, scan_acc: str,
-                      axis_name: Optional[str]):
-    """The whole lossguide greedy loop as ONE jitted program
-    (``hist_method="mega"``): root sum + root eval, then a
-    ``lax.fori_loop`` of ``max_leaves - 1`` pop→apply→eval→push
-    iterations over compact node-array carries, then the leaf-value
-    finalize — zero host round-trips between splits.
-
-    Bit-exactness with the host heapq loop rests on three invariants:
-
-    * ``argmax(cand_gain)`` with first-max tie-break IS the host heap's
-      ``(-gain, push_counter)`` order: candidates are pushed in node-id
-      order (children allocate ids in creation order, left slot first),
-      so among equal f32 gains the smallest node id is also the earliest
-      push, and f32 values order identically under the host's f64 view.
-    * the host threshold ``gain > max(gamma, 1e-6)`` runs in f64 on an
-      exact f32 gain; with ``c = largest f32 <= max(gamma, 1e-6)``
-      (``gain_thresh``, host-precomputed via ``np.nextafter``) the f32
-      comparison ``gain > c`` decides identically.
-    * NO-OP iterations (queue empty before ``max_leaves`` is reached):
-      ``argmax`` of an all ``-inf`` queue returns 0, so every scatter
-      routes through a ``where(valid, id, cap)`` sentinel index with
-      ``mode="drop"`` — an invalid iteration writes nothing, advances
-      nothing (``positions == cap`` never holds) and pushes nothing.
-
-    The f32 ``gh`` carry matches the host's f64 bookkeeping because the
-    host only ever stores exact f32 values into it (SplitResult sums),
-    and casts back to f32 for every device consumer. Under a mesh the
-    whole loop runs inside ``shard_map`` with the per-split histogram
-    ``psum`` inside the body (rows sharded, tree arrays replicated).
-
-    Gated by the caller to the plain numeric resident/mesh-row tier:
-    no categoricals, no monotone/interaction constraints, and
-    ``colsample_bylevel == colsample_bynode == 1`` (per-node masks all
-    equal the bytree mask, so no RNG draws happen mid-loop); everything
-    else falls back to the host loop over the scan kernels, which is
-    bit-identical by construction.
-    """
-    i32, f32 = jnp.int32, jnp.float32
-    mb = max_nbins - 1 if has_missing else max_nbins
-    max_depth = param.max_depth
-    kw = dict(param=param, max_nbins=max_nbins, hist_method="scan",
-              axis_name=axis_name, has_missing=has_missing, coarse=True,
-              scan=True, scan_acc=scan_acc)
-    ninf2 = jnp.full((2,), -jnp.inf, f32)
-    pinf2 = jnp.full((2,), jnp.inf, f32)
-    words0 = jnp.zeros((1,), jnp.uint32)
-
-    with stage("root"):
-        root = _root_sum(gpair, axis_name).astype(f32)
-    sf = jnp.full((cap,), -1, i32)
-    sb = jnp.zeros((cap,), i32)
-    dl = jnp.zeros((cap,), jnp.bool_)
-    lc = jnp.full((cap,), -1, i32)
-    rc = jnp.full((cap,), -1, i32)
-    pa = jnp.full((cap,), -1, i32)
-    gn = jnp.zeros((cap,), f32)
-    gh = jnp.zeros((cap, 2), f32).at[0].set(root)
-    depth_of = jnp.zeros((cap,), i32)
-    cg = jnp.full((cap,), -jnp.inf, f32)      # candidate queue: gain or -inf
-    cf = jnp.zeros((cap,), i32)
-    cb = jnp.zeros((cap,), i32)
-    cd = jnp.zeros((cap,), jnp.bool_)
-    cls_ = jnp.zeros((cap, 2), f32)
-    crs = jnp.zeros((cap, 2), f32)
-
-    with stage("eval"):
-        res0 = _eval2(bins, gpair, positions, i32(0), i32(-1),
-                      jnp.stack([root, jnp.zeros((2,), f32)]), fmask_root,
-                      ninf2, pinf2, n_real_bins, bins_t, None, None, None,
-                      **kw)
-    g0 = res0.gain[0]
-    ok0 = jnp.isfinite(g0) & (g0 > gain_thresh)
-    idx0 = jnp.where(ok0, i32(0), i32(cap))
-    cg = cg.at[idx0].set(g0, mode="drop")
-    cf = cf.at[idx0].set(res0.feature[0], mode="drop")
-    cb = cb.at[idx0].set(res0.bin[0], mode="drop")
-    cd = cd.at[idx0].set(res0.default_left[0], mode="drop")
-    cls_ = cls_.at[idx0].set(res0.left_sum[0], mode="drop")
-    crs = crs.at[idx0].set(res0.right_sum[0], mode="drop")
-
-    def _body(_, c):
-        (sf, sb, dl, lc, rc, pa, gn, gh, depth_of,
-         cg, cf, cb, cd, cls_, crs, positions, n_nodes) = c
-        with stage("pop"):
-            best = jnp.argmax(cg).astype(i32)
-            bg = cg[best]
-            valid = bg > -jnp.inf
-            nid = jnp.where(valid, best, i32(cap))
-            feat, rbin, rdl = cf[best], cb[best], cd[best]
-            lsum, rsum = cls_[best], crs[best]
-            li, ri = n_nodes, n_nodes + 1
-            li_d = jnp.where(valid, li, i32(cap))
-            ri_d = jnp.where(valid, ri, i32(cap))
-            cg = cg.at[nid].set(-jnp.inf, mode="drop")
-            sf = sf.at[nid].set(feat, mode="drop")
-            sb = sb.at[nid].set(rbin, mode="drop")
-            dl = dl.at[nid].set(rdl, mode="drop")
-            gn = gn.at[nid].set(bg, mode="drop")
-            lc = lc.at[nid].set(li, mode="drop")
-            rc = rc.at[nid].set(ri, mode="drop")
-            pa = pa.at[li_d].set(nid, mode="drop")
-            pa = pa.at[ri_d].set(nid, mode="drop")
-            gh = gh.at[li_d].set(lsum, mode="drop")
-            gh = gh.at[ri_d].set(rsum, mode="drop")
-            dchild = depth_of[best] + 1
-            depth_of = depth_of.at[li_d].set(dchild, mode="drop")
-            depth_of = depth_of.at[ri_d].set(dchild, mode="drop")
-            n_nodes = n_nodes + 2 * valid.astype(i32)
-        with stage("apply"):
-            positions = _apply1(bins, positions, nid, feat, rbin, rdl,
-                                jnp.bool_(False), words0, li, ri, mb)
-        with stage("eval"):
-            # rows sit at ids < n_nodes, so on an invalid iteration
-            # nothing matches li/ri and the eval is inert garbage —
-            # the push gate below discards it
-            res = _eval2(bins, gpair, positions, li, ri,
-                         jnp.stack([lsum, rsum]), fmask_pair, ninf2,
-                         pinf2, n_real_bins, bins_t, None, None, None,
-                         **kw)
-        with stage("push"):
-            ok_d = (jnp.bool_(True) if max_depth <= 0
-                    else dchild < max_depth)
-            for slot, child in ((0, li), (1, ri)):
-                g = res.gain[slot]
-                ok = valid & ok_d & jnp.isfinite(g) & (g > gain_thresh)
-                idx = jnp.where(ok, child, i32(cap))
-                cg = cg.at[idx].set(g, mode="drop")
-                cf = cf.at[idx].set(res.feature[slot], mode="drop")
-                cb = cb.at[idx].set(res.bin[slot], mode="drop")
-                cd = cd.at[idx].set(res.default_left[slot], mode="drop")
-                cls_ = cls_.at[idx].set(res.left_sum[slot], mode="drop")
-                crs = crs.at[idx].set(res.right_sum[slot], mode="drop")
-        return (sf, sb, dl, lc, rc, pa, gn, gh, depth_of,
-                cg, cf, cb, cd, cls_, crs, positions, n_nodes)
-
-    carry = (sf, sb, dl, lc, rc, pa, gn, gh, depth_of,
-             cg, cf, cb, cd, cls_, crs, positions, i32(1))
-    carry = jax.lax.fori_loop(0, max_leaves - 1, _body, carry)
-    (sf, sb, dl, lc, rc, pa, gn, gh, depth_of,
-     cg, cf, cb, cd, cls_, crs, positions, n_nodes) = carry
-    with stage("finalize"):
-        w = calc_weight(gh[:, 0], gh[:, 1], param) * param.eta
-        is_leaf = lc < 0
-        leaf_value = jnp.where(is_leaf, w, 0.0).astype(f32)
-        delta = jnp.take(leaf_value, positions)
-    return (sf, sb, dl, lc, rc, pa, gn, gh, depth_of, n_nodes, w,
-            leaf_value, positions, delta)
 
 
 def col_masks(param: TrainParam, seed: int, F: int,
@@ -507,16 +328,7 @@ class LossguideGrower:
         else:
             self.cat = None
             self.n_words = 1
-        # two-level coarse->refine per-split histogram: explicit
-        # "coarse", or the "auto" promotion at scale (decided at first
-        # grow, when n is known — see grow()); numeric row split only
-        base_hm = hist_method
-        sfx = ""
-        for _sfx in ("+sub", "+nosub"):
-            if base_hm.endswith(_sfx):
-                base_hm = base_hm[: -len(_sfx)]
-                sfx = _sfx
-        if base_hm in ("coarse", "fused", "scan", "mega") and (
+        if hist_method in TWO_LEVEL_METHODS and (
                 self.cat is not None
                 or max_nbins > 256 + int(has_missing)):
             # warn-and-fall-back, matching the depthwise "auto" promotion
@@ -529,32 +341,16 @@ class LossguideGrower:
             why = ("categorical features" if self.cat is not None
                    else f"max_bin > 256 (max_nbins={max_nbins})")
             warnings.warn(
-                f"hist_method='{base_hm}' with grow_policy=lossguide "
+                f"hist_method='{hist_method}' with grow_policy=lossguide "
                 f"supports numeric features and max_bin <= 256; got {why} "
                 "— falling back to the exact one-pass histogram "
                 "(hist_method='auto')", UserWarning, stacklevel=3)
-            base_hm = "auto"
-            self.hist_method = "auto" + sfx
-        self._base_hm = base_hm
+            self.hist_method = "auto"
+        # what hist_method runs (the two-level search, and its one-dispatch
+        # apply + child eval schedule): decided at first grow, when n is
+        # known (_resolve_schedule)
         self._coarse = None
-        # cross-level fused dispatch (apply + child eval as ONE program):
-        # decided with _coarse at first grow — "fused" forces it, "auto"
-        # promotes it alongside the coarse promotion (bit-exact with the
-        # two-dispatch schedule; tests/test_fused_hist.py)
         self._fused = None
-        # segmented-scan histogram formulation (decided with _coarse at
-        # first grow): one sorted pass per split instead of coarse+refine
-        # data passes, same search space, bit-identical splits
-        # (tests/test_scan_hist.py, tools/validate_scan.py)
-        self._scan = None
-        # "auto" resolves to bf16/f32 at first grow via the measured RMS
-        # error-bound gate (ops/histogram.py resolve_scan_acc) — bf16
-        # split accumulators engage only where the bound holds
-        self.scan_acc = os.environ.get("XTPU_SCAN_ACC", "f32")
-        if self.scan_acc not in ("f32", "bf16", "auto"):
-            raise ValueError(
-                f"XTPU_SCAN_ACC must be 'f32', 'bf16' or 'auto', got "
-                f"{self.scan_acc!r}")
         if split_mode == "col":
             # bins pad the feature axis to a multiple of the mesh width;
             # the replicated GLOBAL constraint/cat arrays must match so
@@ -578,7 +374,6 @@ class LossguideGrower:
                         is_cat=jnp.pad(self.cat.is_cat, (0, pad)),
                         is_onehot=jnp.pad(self.cat.is_onehot, (0, pad)))
         self._fns = None
-        self._mega_fns = None
 
     # ------------------------------------------------------------- jit setup
     def _functions(self):
@@ -588,8 +383,7 @@ class LossguideGrower:
 
         kw = dict(param=self.param, max_nbins=self.max_nbins,
                   hist_method=self.hist_method,
-                  has_missing=self.has_missing,
-                  scan=bool(self._scan), scan_acc=self.scan_acc)
+                  has_missing=self.has_missing)
         if self.mesh is None:
             ev = functools.partial(_eval2, monotone=self.monotone,
                                    cat=self.cat, axis_name=None,
@@ -721,116 +515,23 @@ class LossguideGrower:
             return np.ones(len(path), bool)
         return np.any(cs[compat], axis=0)
 
-    # ------------------------------------------------------------- megakernel
-    def _mega_functions(self, max_leaves: int, cap: int):
-        if self._mega_fns is not None:
-            return self._mega_fns
-        import functools
-
-        # largest f32 <= max(gamma, eps): makes the in-trace f32 gain
-        # comparison decide exactly like the host loop's f64 one
-        # (_mega_greedy_loop docstring)
-        t64 = max(self.param.gamma, _EPS)
-        c = np.float32(t64)
-        if float(c) > t64:
-            c = np.nextafter(c, np.float32(-np.inf))
-        kw = dict(param=self.param, max_nbins=self.max_nbins,
-                  has_missing=self.has_missing, max_leaves=max_leaves,
-                  cap=cap, gain_thresh=float(c), scan_acc=self.scan_acc)
-        if self.mesh is None:
-            self._mega_fns = jax.jit(functools.partial(
-                _mega_greedy_loop, axis_name=None, **kw))
-        else:
-            from ..context import DATA_AXIS
-            P = jax.sharding.PartitionSpec
-
-            fn = functools.partial(_mega_greedy_loop,
-                                   axis_name=DATA_AXIS, **kw)
-            # the fori_loop carry defeats the static replication checker
-            # (scatter-built carries enter with unknown replication but
-            # come out proven-replicated after the in-body psum) — same
-            # waiver as the depthwise mega program (grow.py _sharded)
-            self._mega_fns = jax.jit(jax.shard_map(
-                fn, mesh=self.mesh,
-                in_specs=(P(DATA_AXIS, None), P(DATA_AXIS, None),
-                          P(DATA_AXIS), P(), P(None, DATA_AXIS), P(),
-                          P()),
-                out_specs=(P(),) * 12 + (P(DATA_AXIS), P(DATA_AXIS)),
-                check_vma=False))
-        return self._mega_fns
-
-    def _grow_mega(self, bins, gpair, n_real_bins, bins_t, positions,
-                   node_mask, max_leaves: int, cap: int) -> LossguideGrown:
-        # bylevel == bynode == 1 (gate), so every node's mask IS the
-        # bytree mask and the depth-0 call consumes no RNG draws
-        mask = node_mask(0)
-        fmask_root = jnp.asarray(np.stack([mask, np.zeros_like(mask)]))
-        fmask_pair = jnp.asarray(np.stack([mask, mask]))
-        fn = self._mega_functions(max_leaves, cap)
-        with _trace.span("lossguide/mega"):
-            out = fn(bins, gpair, positions, n_real_bins, bins_t,
-                     fmask_root, fmask_pair)
-        from ..utils.fetch import fetch_packed
-
-        keys = ("sf", "sb", "dl", "lc", "rc", "pa", "gn", "gh",
-                "depth_of", "n_nodes", "w", "leaf_value")
-        with _trace.span("lossguide/fetch"):
-            host = fetch_packed([dict(zip(keys, out[:12]))])[0]
-        (sf, sb, dl, lc, rc, pa, gn, gh, n_nodes, w, leaf_value) = (
-            host["sf"], host["sb"], host["dl"], host["lc"], host["rc"],
-            host["pa"], host["gn"], host["gh"], host["n_nodes"],
-            host["w"], host["leaf_value"])
-        nn = int(n_nodes)
-        lc = np.asarray(lc[:nn], np.int32)
-        is_leaf = lc < 0
-        sf = np.asarray(sf[:nn], np.int32)
-        sb = np.asarray(sb[:nn], np.int32)
-        tree = TreeModel(
-            left_child=lc, right_child=np.asarray(rc[:nn], np.int32),
-            parent=np.asarray(pa[:nn], np.int32),
-            split_feature=sf, split_bin=sb,
-            split_value=self._split_values(sf, sb),
-            default_left=np.asarray(dl[:nn], bool), is_leaf=is_leaf,
-            leaf_value=np.asarray(leaf_value[:nn], np.float32),
-            sum_hess=np.asarray(gh[:nn, 1], np.float32),
-            gain=np.where(is_leaf, 0.0,
-                          np.asarray(gn[:nn])).astype(np.float32),
-            is_cat_split=np.zeros(nn, bool),
-            cat_words=np.zeros((nn, self.n_words), np.uint32),
-            base_weight=np.asarray(w[:nn], np.float32))
-        tree.heap_map = np.arange(nn, dtype=np.int32)  # already compact
-        return LossguideGrown(positions=out[12], delta=out[13], tree=tree)
-
     def _resolve_schedule(self, n: int) -> None:
-        """What ``hist_method`` runs at ``n`` rows (``_coarse``, ``_fused``,
-        ``_scan``): decided once (n is fixed per DMatrix), before the
-        jitted per-split programs are built; the threshold is LOCAL rows."""
+        """What ``hist_method`` runs at ``n`` rows (``_coarse``,
+        ``_fused``): decided once (n is fixed per DMatrix), before the
+        jitted per-split programs are built; the threshold is LOCAL rows.
+        Explicit "coarse" keeps the two-dispatch schedule, "fused" and a
+        promoting "auto" run apply + child eval as one program
+        (bit-exact: tests/test_fused_hist.py)."""
         from ..context import DATA_AXIS
-        from .grow import auto_selects_coarse
 
         world = (1 if self.mesh is None
                  else self.mesh.shape.get(DATA_AXIS, 1))
-        n_local = n if self.split_mode == "col" else n // max(world, 1)
-        self._coarse = self._base_hm in ("coarse", "fused", "scan",
-                                         "mega") or (
-            self._base_hm == "auto" and self.split_mode == "row"
-            and auto_selects_coarse(
-                n_local, self.max_nbins, self.has_missing,
-                numeric=self.cat is None, col_split=False))
-        # the fused (one-dispatch apply+eval) schedule rides with the
-        # coarse promotion — bit-exact, so "auto" takes it wherever
-        # it took coarse; explicit "coarse" keeps the two-dispatch
-        # schedule measurable on its own. The scan formulation keeps
-        # the one-dispatch schedule too (it changes the histogram
-        # build inside the program, not the dispatch shape).
-        self._fused = self._base_hm in ("fused", "scan", "mega") or (
-            self._base_hm == "auto" and self._coarse)
-        # "auto" stops at the fused schedule, as the depthwise grower's
-        # does (tree/grow.py resolve_schedule). One v5e chip, 1M x 28,
-        # max_leaves=64, 4 warm rounds, single samples (PERF.md section
-        # 6, PR 28): fused 9.3 s, mega (auto until then) 17.5 s, scan
-        # 21.7 s, same AUC.
-        self._scan = self._base_hm in ("scan", "mega")
+        col = self.split_mode == "col"
+        sched = resolve_schedule(
+            self.hist_method, n if col else n // max(world, 1),
+            self.max_nbins, self.has_missing, numeric=self.cat is None,
+            col_split=col)
+        self._coarse, self._fused = sched.coarse, sched.fused
 
     # ------------------------------------------------------------------ grow
     def grow(self, bins: jnp.ndarray, gpair: jnp.ndarray,
@@ -842,18 +543,6 @@ class LossguideGrower:
         cap = 2 * max_leaves - 1
         if self._coarse is None:
             self._resolve_schedule(n)
-        if self.scan_acc == "auto":
-            # resolved ONCE per grower (shape class), on the first
-            # round's gradients; paged bins can't feed the probe — they
-            # keep the exact accumulator
-            if self._scan and not getattr(bins, "is_paged", False):
-                from ..ops.histogram import resolve_scan_acc
-
-                self.scan_acc = resolve_scan_acc(bins, gpair,
-                                                 self.max_nbins,
-                                                 self.has_missing)
-            else:
-                self.scan_acc = "f32"
         fns = self._functions()
         eval2, apply1, root_sum_fn, gather = fns[:4]
         apply_eval = fns[4] if len(fns) > 4 else None
@@ -890,23 +579,6 @@ class LossguideGrower:
         positions = self._init_positions(gpair.shape[0])
         bins_t = (None if getattr(bins, "is_paged", False)
                   else bins.T)  # loop-invariant relayout, once per tree
-        # megakernel tier (explicit hist_method="mega"): the whole greedy
-        # loop runs as ONE compiled program (_mega_greedy_loop). Restricted
-        # to the plain numeric resident/mesh-row tier — anything fancier
-        # keeps the host loop over the scan kernels, which is bit-identical
-        use_mega = (
-            self._base_hm == "mega"
-            and type(self) is LossguideGrower
-            and self.split_mode != "col"
-            and self.cat is None
-            and self.monotone is None
-            and self.constraint_sets is None
-            and param.colsample_bylevel >= 1.0
-            and param.colsample_bynode >= 1.0
-            and bins_t is not None)
-        if use_mega:
-            return self._grow_mega(bins, gpair, n_real_bins, bins_t,
-                                   positions, node_mask, max_leaves, cap)
         cb_t = None
         if self._coarse and bins_t is not None:
             # coarse-pass bin ids are loop-invariant too — one pass per

@@ -43,20 +43,14 @@ from ..ops.histogram import build_hist
 from ..ops.partition import advance_positions_level, update_positions
 from ..ops.split import evaluate_splits
 from ..utils.fetch import fetch_packed, fetch_struct
-from .grow import (GrownTree, TreeGrower, _sample_features,
-                   interaction_allowed_host, monotone_child_bounds_host)
+from .grow import (TWO_LEVEL_METHODS, GrownTree, TreeGrower,
+                   _sample_features, interaction_allowed_host,
+                   monotone_child_bounds_host, resolve_schedule)
 from .lossguide import LossguideGrower
 from .multi import MultiLossguideGrower, MultiTargetGrower
 from .param import calc_weight
 
 _EPS = 1e-6
-
-
-def _strip_hist_suffix(method: str) -> str:
-    for suffix in ("+sub", "+nosub"):
-        if method.endswith(suffix):
-            return method[: -len(suffix)]
-    return method
 
 
 def _make_kernels(grower):
@@ -67,25 +61,10 @@ def _make_kernels(grower):
     """
     missing_bin = (grower.max_nbins - 1 if grower.has_missing
                    else grower.max_nbins)
-    method = _strip_hist_suffix(grower.hist_method)
-    if (method in ("coarse", "fused", "scan", "mega")
-            or getattr(grower, "_coarse", False)):
-        # two-level scheme: the coarse/refine page passes are plain
-        # narrow-width builds — let the per-backend auto selection pick
-        # their kernel. "fused" names the cross-level fused sweep, which
-        # the paged tier's adv_hist body has been structurally since r5
-        # (advance + next coarse in one page read) — same machinery.
-        # "scan" maps here too: the page-major schedule already builds
-        # the full fine partial per page visit and slices the refine
-        # window from it (refine_from_fine) — structurally the integral-
-        # histogram half of the scan formulation, so the paged two-level
-        # schedule IS the scan schedule for out-of-core data and the two
-        # methods are trivially bit-identical (tests/test_scan_hist.py);
-        # the sorted in-VMEM segment build targets the resident tiers.
-        # "mega" lowers here identically: the single-program level loop
-        # needs resident bins (tree/grow.py gate), so on the paged tier
-        # it IS the scan/page-major schedule — bit-identical by
-        # construction (tests/test_mega.py paged cell).
+    method = grower.hist_method
+    if method in TWO_LEVEL_METHODS or getattr(grower, "_coarse", False):
+        # the coarse/refine page passes are plain narrow-width builds:
+        # the per-backend "auto" selection picks their kernel
         method = "auto"
     if grower.mesh is not None:
         return _MeshPageKernels(grower.mesh, grower.max_nbins, missing_bin,
@@ -1520,15 +1499,12 @@ class PagedGrower(TreeGrower):
             # choice is node-level after the coarse pass — decided once
             # (n is fixed per DMatrix), before the kernels are built so
             # their underlying builds run the plain kernel selection
-            from .grow import auto_selects_coarse
-
-            base = _strip_hist_suffix(self.hist_method)
-            if base in ("coarse", "fused", "scan", "mega") and (
+            if self.hist_method in TWO_LEVEL_METHODS and (
                     self.cat is not None
                     or self.max_nbins > 256 + int(self.has_missing)):
                 raise NotImplementedError(
-                    f"hist_method='{base}' supports numeric features and "
-                    "max_bin <= 256")
+                    f"hist_method='{self.hist_method}' supports numeric "
+                    "features and max_bin <= 256")
             # the promotion threshold is LOCAL rows per shard (the
             # measured crossover is per-device work); on the mesh tier
             # gpair is the padded GLOBAL row count
@@ -1538,15 +1514,11 @@ class PagedGrower(TreeGrower):
                 n_local = n // self.mesh.shape.get(DATA_AXIS, 1)
             else:
                 n_local = n
-            # "fused" selects the same two-level scheme: the advance +
-            # coarse page pass has been one fused body here since r5.
-            # "scan" does too — the page-major schedule's fine-partial +
-            # refine_from_fine slicing already IS the integral-histogram
-            # half of the scan formulation (_make_kernels comment)
-            self._coarse = base in ("coarse", "fused", "scan", "mega") or (
-                base == "auto" and auto_selects_coarse(
-                    n_local, self.max_nbins, self.has_missing,
-                    numeric=self.cat is None, col_split=False))
+            # "fused" and "coarse" are one schedule here: the advance +
+            # coarse page pass is one body (adv_hist)
+            self._coarse = resolve_schedule(
+                self.hist_method, n_local, self.max_nbins,
+                self.has_missing, numeric=self.cat is None).coarse
             self._mk = _make_kernels(self)
         max_depth = param.max_depth
         max_nodes = 2 ** (max_depth + 1) - 1
@@ -1785,14 +1757,13 @@ class PagedLossguideGrower(LossguideGrower):
                          mesh=None, monotone=monotone,
                          constraint_sets=constraint_sets,
                          has_missing=has_missing)
-        if self._base_hm in ("coarse", "fused", "scan", "mega"):
+        if self.hist_method in TWO_LEVEL_METHODS:
             raise NotImplementedError(
-                f"hist_method='{self._base_hm}' with grow_policy="
+                f"hist_method='{self.hist_method}' with grow_policy="
                 "lossguide runs on resident matrices only (the paged "
                 "per-split kernels use the one-pass build)")
         self._coarse = False  # page kernels ignore the resident auto rule
         self._fused = False   # per-split page loops stay two-dispatch
-        self._scan = False    # sorted in-VMEM build is resident-only too
         self.mesh = mesh
         self._mk: Optional[_MeshPageKernels] = None
 
@@ -2025,12 +1996,11 @@ class PagedMultiLossguideGrower(MultiLossguideGrower):
         super().__init__(param, max_nbins, cuts, hist_method=hist_method,
                          mesh=None, has_missing=has_missing,
                          constraint_sets=constraint_sets)
-        if _strip_hist_suffix(hist_method) in ("coarse", "fused", "scan",
-                                               "mega"):
+        if hist_method in TWO_LEVEL_METHODS:
             # same contract as the scalar PagedLossguideGrower (and the
             # core guard already rejects coarse/fused for vector leaves)
             raise NotImplementedError(
-                "hist_method='coarse'/'fused'/'scan'/'mega' with "
+                "hist_method='coarse'/'fused' with "
                 "grow_policy=lossguide runs on resident matrices only")
         self.mesh = mesh
         self._mk = None

@@ -1,11 +1,11 @@
-"""Tree-tier program handles: lossguide mega, paged level_full, mesh twins.
+"""Tree-tier program handles: paged level_full, mesh twins.
 
 Registered into :mod:`xgboost_tpu.programs` (see that module's docstring
 for the plan format). Every builder returns the SAME jitted callables the
 drivers dispatch — pulled from the grower/kernel caches via the
 non-dispatching accessors (``TreeGrower.sharded_program``,
-``LossguideGrower._mega_functions``, ``_PageKernels.level_full_fn``) —
-paired with abstract avals, so tracing a handle traces the real program.
+``_PageKernels.level_full_fn``) — paired with abstract avals, so tracing
+a handle traces the real program.
 """
 
 from __future__ import annotations
@@ -41,30 +41,6 @@ def _grow_args():
             _abstract((_F,), "int32"),         # n_real_bins
             _abstract((_F,), "bool_"),         # tree_mask
             _abstract((2,), "uint32"))         # key
-
-
-@register_program("lossguide.mega")
-def _lossguide_mega() -> RoundPlan:
-    from .lossguide import LossguideGrower
-    from .param import TrainParam
-
-    max_leaves, cap = 8, 15
-    grower = LossguideGrower(TrainParam(max_leaves=max_leaves),
-                             _B, _NumericCuts(_F), hist_method="mega")
-    fn = grower._mega_functions(max_leaves, cap)
-    spec = ProgramSpec(
-        name="mega_greedy_loop",
-        fn=fn,
-        args=(_abstract((_R, _F), "uint8"),      # bins
-              _abstract((_R, 2), "float32"),     # gpair
-              _abstract((_R,), "int32"),         # positions
-              _abstract((_F,), "int32"),         # n_real_bins
-              _abstract((_F, _R), "uint8"),      # bins_t
-              _abstract((2, _F), "bool_"),       # fmask_root
-              _abstract((2, _F), "bool_")),      # fmask_pair
-        src=fn)
-    return RoundPlan(handle="lossguide.mega", unit="tree",
-                     dispatches=[spec])
 
 
 @register_program("paged.level_full")
@@ -116,7 +92,7 @@ def _paged_level_full() -> RoundPlan:
                      meta={"uploads_per_level": 0})
 
 
-def _mesh_plan(split_mode: str, hist_method: str) -> RoundPlan:
+def _mesh_plan(split_mode: str) -> RoundPlan:
     import jax
 
     from ..context import DATA_AXIS, make_data_mesh
@@ -130,7 +106,7 @@ def _mesh_plan(split_mode: str, hist_method: str) -> RoundPlan:
             "--xla_force_host_platform_device_count=8)")
     mesh = make_data_mesh()
     grower = TreeGrower(TrainParam(max_depth=3), _B, _NumericCuts(_F),
-                        hist_method=hist_method, mesh=mesh,
+                        hist_method="fused", mesh=mesh,
                         split_mode=split_mode)
     spec = ProgramSpec(
         name=f"sharded_grow_{split_mode}",
@@ -144,12 +120,12 @@ def _mesh_plan(split_mode: str, hist_method: str) -> RoundPlan:
 
 @register_program("mesh.row")
 def _mesh_row() -> RoundPlan:
-    # mega: the PR-11 steady tier — the fori_loop level loop, in-body
-    # histogram psum, and scatter-built carries all inside the shard_map
-    return _mesh_plan("row", "mega")
+    # what "auto" runs on a mesh: in-body histogram psum inside the
+    # shard_map
+    return _mesh_plan("row")
 
 
 @register_program("mesh.col")
 def _mesh_col() -> RoundPlan:
     # col split: local split finding + best-split allgather + decision psum
-    return _mesh_plan("col", "fused")
+    return _mesh_plan("col")

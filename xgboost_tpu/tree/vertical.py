@@ -44,7 +44,7 @@ import numpy as np
 from ..ops.histogram import build_hist
 from ..ops.split import evaluate_splits
 from ..parallel import collective
-from .grow import (_EPS, GrownTree, _sample_features,
+from .grow import (_EPS, TWO_LEVEL_METHODS, GrownTree, _sample_features,
                    interaction_allowed_host, monotone_child_bounds_host)
 from .lossguide import LossguideGrower
 from .param import TrainParam, calc_weight
@@ -55,30 +55,17 @@ def row_split_hist_method(hist_method: str) -> str:
     """Normalise ``hist_method`` for the vertical federated growers: the
     two-level coarse/fused schedules are ROW-split resident/paged
     schemes (their win is device histogram bandwidth; the federated
-    level loop is host-collective-latency-bound — see
-    docs/performance.md "Round 7: coarse x vertical federated"). An
-    explicit request degrades to the exact one-pass kernels with a
-    warning instead of killing the job, mirroring the lossguide
-    fallback policy."""
-    base, sfx = hist_method, ""
-    for s in ("+sub", "+nosub"):
-        if base.endswith(s):
-            base, sfx = base[: -len(s)], s
-    if base in ("coarse", "fused"):
+    level loop is host-collective-latency-bound). An explicit request
+    degrades to the exact one-pass kernels with a warning instead of
+    killing the job, mirroring the lossguide fallback policy."""
+    if hist_method in TWO_LEVEL_METHODS:
         import warnings
 
         warnings.warn(
-            f"hist_method='{base}' requires row split; vertical federated "
-            "(column split) trains with the exact one-pass histogram "
-            "kernels instead (docs/performance.md round 7)", UserWarning,
-            stacklevel=3)
-        return "auto" + sfx
-    if base == "mega":
-        # the single-program level loop needs row-split resident bins;
-        # the scan formulation is its bit-identical per-level schedule,
-        # so degrade silently to that (the lossguide/paged growers apply
-        # their own scan-tier policy downstream)
-        return "scan" + sfx
+            f"hist_method='{hist_method}' requires row split; vertical "
+            "federated (column split) trains with the exact one-pass "
+            "histogram kernels instead", UserWarning, stacklevel=3)
+        return "auto"
     return hist_method
 
 
