@@ -282,7 +282,8 @@ def phase_train(ctx) -> dict:
     import xgboost_tpu as xgb
     from xgboost_tpu import core
     from xgboost_tpu.metric.auc import binary_roc_auc
-    from xgboost_tpu.obs.metrics import degrade_counts, grow_schedule_counts
+    from xgboost_tpu.obs.metrics import (degrade_counts, eval_walk_counts,
+                                         grow_schedule_counts)
     from xgboost_tpu.tree.grow import resolve_schedule
 
     sz = ctx["sizes"]
@@ -352,6 +353,13 @@ def phase_train(ctx) -> dict:
         raise AssertionError(
             f"grow programs were traced under {traced}, not under "
             f"{sched.name!r} alone")
+    # every evaluated round's new tree walked over the held-out rows from
+    # its device heap on a TPU; the CPU keeps the forest walk
+    walks = eval_walk_counts()
+    want_walks = {"forest" if ctx["dry_run"] else "heap": sz.eval_rounds}
+    if walks != want_walks:
+        raise AssertionError(
+            f"xtpu_eval_walk_total {walks}, expected {want_walks}")
 
     if pred.shape != (sz.holdout,) or not np.isfinite(pred).all():
         raise AssertionError("predictions: wrong shape or non-finite")
@@ -376,11 +384,15 @@ def phase_train(ctx) -> dict:
         f"{sz.eval_rounds} evaluated rounds {t5 - t4:.1f}s, compile included "
         f"(smoke timings); held-out AUC {auc:.4f} (floor {sz.auc_floor}); "
         f"tpu_custom_call per program {custom_calls}; "
-        + ", ".join(f'xtpu_grow_schedule_total{{schedule="{k}"}} {v}'
-                    for k, v in traced.items()))
+        + ", ".join(
+            [f'xtpu_grow_schedule_total{{schedule="{k}"}} {v}'
+             for k, v in traced.items()]
+            + [f'xtpu_eval_walk_total{{kind="{k}"}} {v}'
+               for k, v in walks.items()]))
     ctx.update(bst=bst, Xh=Xh)
     return {"rows": sz.rows, "schedule": sched.name,
-            "grow_schedule_total": traced, "auc": round(auc, 4),
+            "grow_schedule_total": traced, "eval_walk_total": walks,
+            "auc": round(auc, 4),
             "auc_floor": sz.auc_floor, "dispatches": got,
             "tpu_custom_call": custom_calls,
             "holdout_logloss": [round(float(v), 5) for v in ll],

@@ -83,3 +83,41 @@ def test_row_split_grow_program_compiles_for_v5e(monkeypatch, mesh, method,
     assert text.count("tpu_custom_call") >= kernels
     assert ("xtpu.kernel.advance_leaf" in text) == (epilogue == "kernel")
     assert "all-reduce" in text          # the histogram psum
+
+
+# the held-out rows of the two configurations, and the deepest tree the
+# walk's gate admits
+@pytest.mark.parametrize("rows,features,bins_dtype,depth,kernels", [
+    (500_000, 28, jnp.uint8, 8, 1),      # HIGGS: a last level of 128 nodes
+    (3_129_004, 220, jnp.uint8, 6, 1),   # the Istella test split: last level
+    (500_000, 28, jnp.uint16, 10, 3),    # 128, 256 and 512 nodes
+])
+def test_heap_walk_program_compiles_for_v5e(mesh, rows, features, bins_dtype,
+                                            depth, kernels):
+    """The eval walk from the device heap (``boosting/gbtree.py
+    _heap_margin_delta``) for one described chip: Mosaic takes the
+    ``advance_leaf`` kernel at the last level's width, and no op of the
+    program gathers over the rows."""
+    from jax.sharding import SingleDeviceSharding
+
+    from xgboost_tpu.boosting.gbtree import _heap_margin_delta
+
+    one = SingleDeviceSharding(mesh.devices.flat[0])
+    nodes = 2 ** (depth + 1) - 1
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    heap = {"split_feature": arg((nodes,), jnp.int32),
+            "split_bin": arg((nodes,), jnp.int32),
+            "default_left": arg((nodes,), jnp.bool_),
+            "is_leaf": arg((nodes,), jnp.bool_),
+            "leaf_value": arg((nodes,), jnp.float32)}
+    text = _heap_margin_delta.lower(
+        (heap,), arg((rows, features), bins_dtype), missing_bin=256,
+        max_depth=depth).compile().as_text()
+    assert text.count("tpu_custom_call") >= kernels
+    assert "xtpu.margin" in text and "xtpu.kernel.advance_leaf" in text
+    row_gathers = [line for line in text.splitlines()
+                   if " gather(" in line and str(rows) in line]
+    assert not row_gathers

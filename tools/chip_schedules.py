@@ -12,6 +12,8 @@ spread.
         --rounds 4                                       # the cells' shape
     python tools/chip_schedules.py --grow-policy lossguide --max-leaves 64 \
         --rounds 4 --methods fused,coarse,auto
+    python tools/chip_schedules.py --rows 10500000 --depth 8 --rounds 4 \
+        --methods auto --eval-rows 500000     # an eval set every round
 
 Chain several in one chip-tool command so they share the compile cache.
 """
@@ -38,6 +40,9 @@ def main() -> int:
     ap.add_argument("--grow-policy", default="depthwise",
                     choices=("depthwise", "lossguide"))
     ap.add_argument("--max-leaves", type=int, default=0)
+    ap.add_argument("--eval-rows", type=int, default=0,
+                    help="evaluate this many held-out rows every round "
+                         "(the per-round driver and the eval walk)")
     args = ap.parse_args()
 
     import jax
@@ -51,7 +56,8 @@ def main() -> int:
     import xgboost_tpu as xgb
     from chip_smoke import MAX_BIN, SEED, make_data, train_params
     from xgboost_tpu.metric.auc import binary_roc_auc
-    from xgboost_tpu.obs.metrics import (grow_epilogue_counts,
+    from xgboost_tpu.obs.metrics import (eval_walk_counts, get_registry,
+                                         grow_epilogue_counts,
                                          grow_schedule_counts)
     from xgboost_tpu.tree.grow import resolve_schedule
 
@@ -60,6 +66,10 @@ def main() -> int:
     Xh, yh = make_data(100_000, SEED + 100)
     dtrain, dhold = xgb.DMatrix(X, label=y), xgb.DMatrix(Xh)
     binned = dtrain.binned(MAX_BIN)
+    evals = []
+    if args.eval_rows:
+        Xe, ye = make_data(args.eval_rows, SEED + 200)
+        evals = [(xgb.DMatrix(Xe, label=ye), "eval")]
     rows = []
     lossguide = args.grow_policy == "lossguide"
     for method in args.methods.split(","):
@@ -73,7 +83,8 @@ def main() -> int:
         walls = []
         for _ in ("cold", "warm"):
             t0 = time.perf_counter()
-            bst = xgb.train(params, dtrain, args.rounds, verbose_eval=False)
+            bst = xgb.train(params, dtrain, args.rounds, evals=evals,
+                            verbose_eval=False)
             pred = bst.predict(dhold)     # host copy: the rounds finished
             walls.append(time.perf_counter() - t0)
         auc = binary_roc_auc(yh.astype(np.float64), pred.astype(np.float64),
@@ -92,6 +103,9 @@ def main() -> int:
         "ok": True, "smoke_timings": rows,
         "grow_schedule_total": grow_schedule_counts(),
         "grow_epilogue_total": grow_epilogue_counts(),
+        "eval_walk_total": eval_walk_counts(),
+        "tree_flushes_total": int(get_registry().get(
+            "xtpu_tree_flushes_total", ())),
         "device": {"platform": dev.platform, "kind": dev.device_kind,
                    "count": len(jax.devices())}}))
     return 0

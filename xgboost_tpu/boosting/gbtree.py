@@ -9,6 +9,7 @@ ids in ``tree_info`` and per-iteration offsets in ``iteration_indptr``.
 from __future__ import annotations
 
 import bisect
+import functools
 from typing import List, Optional
 
 import os
@@ -19,14 +20,16 @@ import numpy as np
 
 from ..data.binned import BinnedMatrix
 from ..obs import trace as _trace
-from ..obs.metrics import count_tree_flush
+from ..obs.metrics import count_eval_walk, count_tree_flush
+from ..ops.histogram import (HEAP_WALK_FIELDS, heap_walk_delta,
+                             heap_walk_takes)
 from ..registry import BOOSTERS
 from ..tree.grow import GrownTree, TreeGrower
 from ..tree.param import TrainParam
 from ..tree.tree import TreeModel
-# One packed transfer per flush regardless of tree count — a 7-tree dart
-# round used to flush 77 arrays, one blocking transfer each. Shared with
-# the paged level loop.
+# One packed transfer per shared array dict — a 7-tree dart round used to
+# flush 77 arrays, one blocking transfer each. Shared with the paged level
+# loop.
 from ..utils.fetch import fetch_packed as _fetch_packed
 
 
@@ -133,6 +136,22 @@ class _PendingTree:
         self.index = index
 
 
+@functools.partial(
+    jax.jit, static_argnames=("missing_bin", "max_depth", "interpret"))
+def _heap_margin_delta(heaps, bins, *, missing_bin: int, max_depth: int,
+                       interpret: bool = False) -> jnp.ndarray:
+    """Margin increment ``[n, 1]`` of pending trees over binned rows, from
+    their device heaps (``ops/histogram.py heap_walk_delta``), summed in
+    tree order. One program a (trees, rows, F, depth) shape; no round
+    program: its device time is the eval's."""
+    delta = None
+    for heap in heaps:
+        d = heap_walk_delta(heap, bins, missing_bin, max_depth,
+                            interpret=interpret)
+        delta = d if delta is None else delta + d
+    return delta[:, None]
+
+
 class _HostGrown:
     """Host-side view of fetched grown-tree arrays (duck-types GrownTree for
     ``TreeGrower.to_tree_model``)."""
@@ -179,8 +198,8 @@ class GBTree:
     # -- deferred tree materialisation ---------------------------------------
     # Pulling a grown tree to the host costs one blocking transfer per
     # array, so plain-hist training keeps the
-    # per-node arrays on device and converts them to TreeModels lazily, in ONE
-    # batched ``jax.device_get`` for however many trees have accumulated.
+    # per-node arrays on device and converts them to TreeModels lazily, in
+    # one packed pull a round's (or a round batch's) trees.
     @property
     def trees(self) -> List[TreeModel]:
         self._flush()
@@ -200,12 +219,17 @@ class GBTree:
         with _trace.span("round/flush", "train",
                          {"iteration": first, "trees": len(pending)}):
             # round-batched trees share one stacked-array dict — fetch each
-            # distinct dict once, then slice host-side
+            # distinct dict once, then slice host-side. One packed pull a
+            # dict: the pack program then has one shape a dict layout,
+            # however many rounds' trees have accumulated (an eval job
+            # keeps them pending to its end). Packed into ONE program they
+            # compile for 0.2 s a dict for a v5e: 19 s at 92 trees, 119 s
+            # at 500 (PERF.md section 6, PR 32).
             unique: dict = {}
             for _, t in pending:
                 unique.setdefault(id(t.arrays), t.arrays)
-            fetched = dict(zip(unique.keys(),
-                               _fetch_packed(list(unique.values()))))
+            fetched = {key: _fetch_packed([arrays])[0]
+                       for key, arrays in unique.items()}
             for i, t in pending:
                 arrs = fetched[id(t.arrays)]
                 if t.index is not None:
@@ -631,12 +655,59 @@ class GBTree:
             outs.append(m)
         return jnp.concatenate(outs)
 
+    # tests set it: the CPU then runs the heap walk's kernel through the
+    # Pallas interpreter
+    _heap_walk_interpret = False
+
+    def _heap_walk_heaps(self, binned, tree_lo: int, tree_hi: int):
+        """``(device heaps, max_depth)`` of trees [tree_lo, tree_hi) where
+        ``heap_walk_delta`` states their margin increment: every one still
+        pending and grown by one depthwise ``TreeGrower`` (a heap the host
+        will state node for node: no ``max_leaves`` truncation, no
+        categorical split, one output), over a resident matrix on one
+        device, at a shape the walk takes. None otherwise."""
+        trees = self._trees[tree_lo:tree_hi]
+        bins = getattr(binned, "bins", None)
+        g = getattr(trees[0], "grower", None) if trees else None
+        if (type(g) is not TreeGrower or g.mesh is not None
+                or g.cat is not None or g.param.max_leaves > 0
+                or not all(isinstance(t, _PendingTree) and t.grower is g
+                           for t in trees)
+                or self.n_groups != 1 or self.mesh is not None
+                or getattr(binned, "is_paged", False)
+                or not isinstance(bins, jax.Array)
+                or len(bins.sharding.device_set) != 1):
+            return None
+        # the depth the heaps were grown to: ``set_param`` edits the
+        # grower's ``param`` in place, the arrays keep their size
+        nodes = {t.arrays["leaf_value"].shape[-1] for t in trees}
+        max_depth = (min(nodes) + 1).bit_length() - 2
+        if len(nodes) != 1 or not heap_walk_takes(
+                bins.shape[1], binned.missing_bin, max_depth,
+                self._heap_walk_interpret):
+            return None
+        # a batch-grown tree's arrays carry the batch axis: sliced on the
+        # device
+        return tuple(
+            {f: t.arrays[f] if t.index is None else t.arrays[f][t.index]
+             for f in HEAP_WALK_FIELDS} for t in trees), max_depth
+
     def margin_delta_binned(self, binned, tree_lo: int, tree_hi: int):
         """Margin contribution of trees [tree_lo, tree_hi) on quantized data
-        (the prediction-cache increment)."""
+        (the prediction-cache increment). Trees still on the device are
+        walked there from their heaps and stay pending; anything else is
+        flushed and walked by the ``ForestPredictor``
+        (``xtpu_eval_walk_total{kind}`` says which)."""
+        walk = self._heap_walk_heaps(binned, tree_lo, tree_hi)
+        if walk is not None:
+            count_eval_walk("heap")
+            return _heap_margin_delta(
+                walk[0], binned.bins, missing_bin=binned.missing_bin,
+                max_depth=walk[1], interpret=self._heap_walk_interpret)
         pred = self._predictor(tree_lo, tree_hi)
         if pred is None:
             return 0.0
+        count_eval_walk("forest")
         zero = np.zeros(self.n_groups, np.float32)
         if getattr(binned, "is_paged", False):
             return self._margin_binned_paged(pred, binned, zero)

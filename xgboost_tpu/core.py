@@ -260,7 +260,8 @@ def _fused_round_insight_fn(bins, margin, labels, weights, n_real, seed,
     for i, (ebins, emargin, elabels, eweights) in enumerate(
             zip(eval_bins, eval_margins, eval_labels, eval_weights)):
         delta = _insight.walk_leaf_delta(grown, ebins, eval_missing[i],
-                                         max(param.max_depth, 1))
+                                         max(param.max_depth, 1),
+                                         numeric=cat is None)
         nem = emargin + delta[:, None]
         new_eval_margins.append(nem)
         preds = obj.pred_transform(nem)[:, 0]

@@ -215,18 +215,28 @@ def grown_telemetry(grown, gpair, levels: int) -> Dict[str, Any]:
 
 # ------------------------------------------------------- in-carry eval walk
 
-def walk_leaf_delta(grown, ebins, missing_bin: int, max_depth: int):
+def walk_leaf_delta(grown, ebins, missing_bin: int, max_depth: int,
+                    numeric: bool = False):
     """Per-row leaf value of ``grown`` over a BINNED eval matrix — the
     eval-set margin update folded into the round program. Valid because
     eval DMatrices are binned against the training cuts
     (``core._state_of`` passes ``ref_cuts``), so the tree's ``split_bin``
     thresholds index the same bin space. Routing replicates
     ``ops.partition.advance_positions_level``: strict ``bin > thr`` goes
-    right, category-bit-set goes left, missing follows ``default_left``."""
+    right, category-bit-set goes left, missing follows ``default_left``.
+
+    ``numeric`` (no categorical feature in the grower): where the
+    gather-free heap walk takes the shape (a TPU: ``ops/histogram.py
+    heap_walk_takes``) it states the same leaf values; the per-row gather
+    walk below stays for every other case."""
     import jax.numpy as jnp
 
+    from ..ops.histogram import heap_walk_delta, heap_walk_takes
     from ..ops.partition import cat_goes_right
 
+    if numeric and heap_walk_takes(ebins.shape[1], missing_bin, max_depth):
+        return heap_walk_delta(grown._asdict(), ebins, missing_bin,
+                               max_depth)
     b32 = ebins.astype(jnp.int32)                       # [n, F]
     n = b32.shape[0]
     rows = jnp.arange(n)

@@ -32,6 +32,7 @@ __all__ = ["Sample", "Family", "HistogramData", "MetricsRegistry",
            "degrade_counts", "count_round_dispatch", "count_tree_flush",
            "count_grow_schedule", "grow_schedule_counts",
            "count_grow_epilogue", "grow_epilogue_counts",
+           "count_eval_walk", "eval_walk_counts",
            "count_rank_gradient", "rank_counts",
            "program_compile_counts"]
 
@@ -362,6 +363,24 @@ def grow_schedule_counts() -> Dict[str, int]:
 
 def grow_epilogue_counts() -> Dict[str, int]:
     return {k: int(v) for k, v in _by_label(_GROW_EPILOGUE, "kind").items()}
+
+
+_EVAL_WALK = "xtpu_eval_walk_total"
+
+
+def count_eval_walk(kind: str) -> None:
+    """One margin increment of new trees over a binned non-training matrix
+    (``boosting/gbtree.py margin_delta_binned``: an eval set's rows every
+    round), by the walk that computed it: ``heap`` (on the device from the
+    pending trees' heap arrays, ``ops/histogram.py heap_walk_delta``) or
+    ``forest`` (the trees flushed to the host and walked by
+    ``ForestPredictor``'s per-row gathers)."""
+    _registry.inc(_EVAL_WALK, labels=(("kind", kind),),
+                  help="binned margin increments, by walk")
+
+
+def eval_walk_counts() -> Dict[str, int]:
+    return {k: int(v) for k, v in _by_label(_EVAL_WALK, "kind").items()}
 
 
 _RANK_SLOTS = "xtpu_rank_pair_slots_total"

@@ -331,3 +331,74 @@ def advance_leaf(bins: jnp.ndarray, positions: jnp.ndarray, prev: dict,
             dleft, cs, leaf_value, n_prev=n_level, missing_bin=missing_bin,
             interpret=interpret)
     return new_positions, delta, "kernel"
+
+
+# ---- the eval walk, from the device heap -----------------------------------
+# A round's new tree is still on the device as its heap arrays when the
+# held-out rows want its margin increment. The rows are routed down the
+# heap by the two gather-free advances the training rows take in ``_grow``:
+# the dense matmul advance at the narrow levels, one kernel sweep at the
+# wide ones and at the last, which also writes each row's leaf value. No
+# per-row gather (12-16M elements a second on a v5e: 211 ms a round for
+# 500k rows at depth 8, PERF.md section 6, PR 32), and no pull of the tree
+# to the host first.
+
+# the heap arrays the walk reads, of ``tree/grow.py GrownTree``
+HEAP_WALK_FIELDS = ("split_feature", "split_bin", "default_left", "is_leaf",
+                    "leaf_value")
+
+
+def heap_walk_takes(n_features: int, missing_bin: int, max_depth: int,
+                    interpret: bool = False) -> bool:
+    """Whether ``heap_walk_delta`` runs at this shape on this backend: a
+    TPU (or the Pallas interpreter), a last level ``advance_leaf_pallas``
+    takes, and a feature count and bin range its packed split word holds."""
+    from .pallas.histogram import ADVANCE_LEAF_MAX_NODES
+
+    return ((interpret or jax.default_backend() == "tpu")
+            and 1 <= max_depth
+            and 2 ** (max_depth - 1) <= ADVANCE_LEAF_MAX_NODES
+            and n_features <= 0xFFFF and missing_bin <= 0xFFF)
+
+
+def heap_walk_delta(grown, bins: jnp.ndarray, missing_bin: int,
+                    max_depth: int, *, interpret: bool = False
+                    ) -> jnp.ndarray:
+    """Margin increment of one depthwise, numeric tree over binned rows,
+    from its heap arrays: ``leaf_value[final heap position]`` per row,
+    f32 ``[n]``, bit for bit the table entry (``advance_leaf_pallas``).
+
+    ``grown``: a mapping with the ``HEAP_WALK_FIELDS`` of a ``GrownTree``
+    over a heap of ``2^(max_depth+1) - 1`` nodes (the dict a pending tree
+    holds, or the tuple's ``_asdict()``); ``bins``: ``[n, F]`` ids binned against the cuts the tree
+    was grown with. Level-synchronous from the root: every level of at
+    most ``DENSE_LEVEL_MAX`` nodes but the last takes
+    ``advance_positions_level``, the others ``advance_leaf_pallas``, whose
+    last call leaves the delta, the rows that stopped at a shallower leaf
+    included. Callers hold the shape to ``heap_walk_takes``."""
+    from ..tree.grow import DENSE_LEVEL_MAX
+    from .pallas.histogram import advance_leaf_pallas
+    from .partition import advance_positions_level
+
+    splits = (grown["split_feature"], grown["split_bin"],
+              grown["default_left"], ~grown["is_leaf"])
+    with stage("margin"):
+        positions = jnp.zeros((bins.shape[0],), jnp.int32)
+        bins_t = bins.T
+        # f32 operand computed in the trace, as ``_advance_below``
+        bins_f32 = bins.astype(jnp.float32)
+        for depth in range(max_depth):
+            lo, n_level = 2 ** depth - 1, 2 ** depth
+            level = tuple(a[lo:lo + n_level] for a in splits)
+            if depth < max_depth - 1 and n_level <= DENSE_LEVEL_MAX:
+                rel = jnp.where(positions >= lo, positions - lo, n_level)
+                positions = advance_positions_level(
+                    bins_f32, positions, rel, *level, missing_bin)
+            else:
+                with stage("advance"):
+                    positions, delta = advance_leaf_pallas(
+                        bins_t, positions, *level, grown["leaf_value"],
+                        n_prev=n_level, missing_bin=missing_bin,
+                        interpret=interpret)
+    return delta
+
