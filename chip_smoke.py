@@ -464,9 +464,39 @@ def phase_mesh(ctx):
     logloss = get_metric("logloss")
     problems = []
 
-    def both(name, rows, extra, rounds, sharded_axis):
+    def batches(X, y, n_batches=4):
+        """The rows through a ``DataIter``: ingest then lands on the shards
+        (``DMatrix.place_binned``), where it used to put the whole matrix
+        on device 0 first."""
+        step = -(-len(y) // n_batches)
+
+        class It(xgb.DataIter):
+            def __init__(self):
+                super().__init__()
+                self.at = 0
+
+            def reset(self):
+                self.at = 0
+
+            def next(self, input_data):
+                if self.at >= len(y):
+                    return 0
+                input_data(data=X[self.at:self.at + step],
+                           label=y[self.at:self.at + step])
+                self.at += step
+                return 1
+        return xgb.QuantileDMatrix(It(), max_bin=256)
+
+    def whole_copies(shape):
+        """Live uint8 arrays of the bin matrix's shape on ONE device."""
+        return sum(1 for a in jax.live_arrays()
+                   if a.shape == shape and a.dtype == np.uint8
+                   and len(a.sharding.device_set) == 1)
+
+    def both(name, rows, extra, rounds, sharded_axis, by_iterator=False):
         X, y = make_data(rows, SEED + 200)
-        dm = xgb.DMatrix(X, label=y)
+        whole_before = whole_copies(X.shape)
+        dm = batches(X, y) if by_iterator else xgb.DMatrix(X, label=y)
         b_mesh = xgb.train({**params, "mesh": mesh, **extra}, dm, rounds,
                            verbose_eval=False)
         bins = b_mesh._caches[id(dm)]["binned"].bins
@@ -478,6 +508,18 @@ def phase_mesh(ctx):
             problems.append(
                 f"{name}: bins {bins.shape} are not split over {n_dev} "
                 f"devices: {[(str(s.device), s.data.shape) for s in shards]}")
+        if by_iterator:
+            # no device holds more of the bin matrix than its share: the
+            # whole matrix alive on one device is the fault ingest had (an
+            # in-memory DMatrix keeps its own whole copy, by design)
+            whole = whole_copies(bins.shape) - whole_before
+            if whole > 0 or any(s.data.shape[0] * n_dev > rows
+                                for s in shards):
+                problems.append(
+                    f"{name}: a device holds more than rows / chips of the "
+                    f"bin matrix {bins.shape}: {whole} new whole copies on "
+                    f"one device, shards {[s.data.shape for s in shards]}")
+            dm = xgb.DMatrix(X, label=y)      # the one-chip twin's matrix
         b_one = xgb.train(params, dm, rounds, verbose_eval=False)
 
         def same(a, b):
@@ -499,13 +541,23 @@ def phase_mesh(ctx):
         return out
 
     row = both("row split", n_dev * sz.mesh_shard_rows, {}, 3, 0)
+    fed = both("row split by iterator", n_dev * sz.mesh_shard_rows, {}, 3, 0,
+               by_iterator=True)
     # col split keeps the one-pass kernel, so its one-chip twin must too:
     # col_rows stays under the 65,536-row promotion threshold of "auto"
     col = both("col split", sz.col_rows, {"data_split_mode": "col"}, 2, 1)
-    say(f"mesh: {n_dev} devices; row split {row}; col split {col}")
+    from xgboost_tpu.obs.metrics import mesh_counts
+
+    counts = mesh_counts()
+    say(f"mesh: {n_dev} devices; row split {row}; by iterator {fed}; col "
+        f"split {col}; xtpu_mesh_allreduce_total {counts['allreduce']}, "
+        f"bytes {counts['bytes']}")
+    if not counts["allreduce"].get("hist_psum"):
+        problems.append(f"no histogram exchange was counted: {counts}")
     if problems:
         raise AssertionError("; ".join(problems))
-    return {"devices": n_dev, "row_split": row, "col_split": col}
+    return {"devices": n_dev, "row_split": row, "row_split_by_iterator": fed,
+            "col_split": col, "mesh_allreduce_total": counts["allreduce"]}
 
 
 PHASES = (("device", phase_device, ()), ("kernels", phase_kernels, ()),

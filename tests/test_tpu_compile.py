@@ -24,7 +24,7 @@ from xgboost_tpu.tree.grow import AUTO_COARSE_MIN_ROWS, TreeGrower
 from xgboost_tpu.tree.param import TrainParam
 from xgboost_tpu.tree.programs import _NumericCuts
 
-FEATURES, MAX_NBINS = 28, 256
+MAX_NBINS = 256
 
 
 @pytest.fixture(scope="module")
@@ -46,20 +46,23 @@ def mesh():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("method,schedule,depth,kernels,epilogue", [
+@pytest.mark.parametrize("method,schedule,depth,kernels,epilogue,FEATURES", [
     # advance+coarse and refine, a level
-    ("auto", "fused", 3, 2 * 3, "dense"),
+    ("auto", "fused", 3, 2 * 3, "dense", 28),
     # the published depth: a last level of 128 nodes, past DENSE_LEVEL_MAX,
     # takes the advance_leaf kernel below it
-    ("auto", "fused", 8, 2 * 8 + 1, "kernel"),
+    ("auto", "fused", 8, 2 * 8 + 1, "kernel", 28),
     # 256 and 512 nodes, the widest levels advance_leaf's gate admits; the
     # levels past 128 nodes build their histograms in XLA
-    ("auto", "fused", 9, 2 * 8 + 1, "kernel"),
-    ("auto", "fused", 10, 2 * 8 + 1, "kernel"),
+    ("auto", "fused", 9, 2 * 8 + 1, "kernel", 28),
+    ("auto", "fused", 10, 2 * 8 + 1, "kernel", 28),
+    # the click-log job's width (benchmark cell criteo-ctr.mesh-train): 67
+    # features, no multiple of the sublane's 8, at the published depth
+    ("auto", "fused", 8, 2 * 8 + 1, "kernel", 67),
 ])
 def test_row_split_grow_program_compiles_for_v5e(monkeypatch, mesh, method,
                                                  schedule, depth, kernels,
-                                                 epilogue):
+                                                 epilogue, FEATURES):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     rows = 4 * AUTO_COARSE_MIN_ROWS      # each shard at auto's threshold
     grower = TreeGrower(TrainParam(max_depth=depth), MAX_NBINS,
@@ -76,13 +79,17 @@ def test_row_split_grow_program_compiles_for_v5e(monkeypatch, mesh, method,
         arg((rows, FEATURES), jnp.uint8, P(DATA_AXIS, None)),
         arg((rows, 2), jnp.float32, P(DATA_AXIS, None)),
         arg((FEATURES,), jnp.int32, P()), arg((FEATURES,), jnp.bool_, P()),
-        arg((2,), jnp.uint32, P())).compile()
+        arg((2,), jnp.uint32, P()),
+        None, None, None).compile()     # monotone, constraint sets, cat
     assert grow_schedule_counts().get(schedule, 0) == before + 1
     assert grow_epilogue_counts().get(epilogue, 0) == before_epilogue + 1
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= kernels
     assert ("xtpu.kernel.advance_leaf" in text) == (epilogue == "kernel")
     assert "all-reduce" in text          # the histogram psum
+    # the collectives carry their mesh.* scopes into the compiled program
+    for scope in ("mesh.hist_psum", "mesh.root_psum", "mesh.scale_pmax"):
+        assert scope in text
 
 
 # the held-out rows of the two configurations, and the deepest tree the

@@ -78,6 +78,14 @@ def _add_margin_delta(margin, delta):
     return margin + delta
 
 
+@_functools.partial(jax.jit, static_argnames=("rows", "sharding"))
+def _broadcast_rows(base, *, rows: int, sharding):
+    """``base`` ``[K]`` as a ``[rows, K]`` margin laid out under
+    ``sharding``: every device fills its own rows."""
+    return jax.lax.with_sharding_constraint(
+        jnp.broadcast_to(base[None, :], (rows, base.shape[0])), sharding)
+
+
 def _check_margin_finite(margin, n_valid: int, objective: str,
                          first_round: int, n_rounds: int = 1,
                          bad=None) -> None:
@@ -747,9 +755,9 @@ class Booster:
                     tm == "approx" and getattr(
                         getattr(dm, "_binned", None), "is_paged", False)))
             elif is_train:
-                binned = dm.binned(self.tree_param.max_bin)
                 if self.ctx.mesh is not None:
-                    return self._make_sharded_train_state(key, dm, binned)
+                    return self._make_sharded_train_state(key, dm)
+                binned = dm.binned(self.tree_param.max_bin)
                 binned = self._collapse_paged_if_fits(binned)
                 self._check_row_comm_sync(
                     paged=getattr(binned, "is_paged", False))
@@ -860,12 +868,17 @@ class Booster:
         base = jnp.asarray(self.base_margin_, jnp.float32).reshape(-1)
         return jnp.broadcast_to(base[None, :], (n, self.n_groups))
 
-    def _make_sharded_train_state(self, key: int, dm: DMatrix,
-                                  binned) -> Dict[str, Any]:
+    def _make_sharded_train_state(self, key: int,
+                                  dm: DMatrix) -> Dict[str, Any]:
         """Shard the quantized matrix / margin over the mesh ``data`` axis,
         padding rows to a multiple of the axis size. Padded rows carry weight 0
         so gradients vanish (the reference's row shards are simply unequal;
-        static XLA shapes want equal shards instead).
+        static XLA shapes want equal shards instead). Every per-row array
+        (bins, labels, weights, margin) goes from the host to its shards
+        block by block (``data/binned.py put_row_shards``): no device holds
+        a whole one. An iterator's bin matrix that is still on the host
+        (``DMatrix.place_binned``) is placed that way too, and never pulled
+        back.
 
         With ``data_split_mode=col`` the FEATURE axis is sharded instead
         (reference ``DataSplitMode::kCol``): rows replicate, features pad to
@@ -874,14 +887,21 @@ class Booster:
         import jax.sharding as jsh
 
         from .context import DATA_AXIS
-        from .data.binned import BinnedMatrix
+        from .data.binned import BinnedMatrix, put_row_shards
         from .data.dmatrix import MetaInfo
+        from .obs.metrics import set_mesh_layout
 
         mesh = self.ctx.mesh
         world = mesh.shape.get(DATA_AXIS, 1)
         n = dm.num_row()
+        sharding = jsh.NamedSharding(mesh, jsh.PartitionSpec(DATA_AXIS, None))
+        col = self.learner_params.get("data_split_mode", "row") == "col"
+        placed = None if col or not hasattr(dm, "place_binned") \
+            else dm.place_binned(sharding)
+        binned = placed if placed is not None \
+            else dm.binned(self.tree_param.max_bin)
         paged = getattr(binned, "is_paged", False)
-        if self.learner_params.get("data_split_mode", "row") == "col":
+        if col:
             if paged:
                 raise NotImplementedError(
                     "external-memory (paged) training supports "
@@ -892,56 +912,69 @@ class Booster:
             margin = jnp.asarray(self._broadcast_base_margin(dm, n))
             return self._store_cache(key, binned_p, margin, True, dm,
                                      dm.info, n)
-        sharding = jsh.NamedSharding(mesh, jsh.PartitionSpec(DATA_AXIS, None))
         if paged:
             # mesh x external memory: bins STAY host-resident and stream
             # per-shard (PagedBinnedMatrix.pages_sharded); only the per-row
             # vectors pad to the page-aligned mesh layout and shard
             n_pad = binned.mesh_layout(world)[0]
-            pad = n_pad - n
             binned_p = binned
+        elif placed is not None:
+            n_pad = placed.n_rows
+            binned_p = placed
         else:
             n_pad = ((n + world - 1) // world) * world
-            pad = n_pad - n
-            bins_np = np.asarray(binned.bins)
-            if pad:
-                # any in-range bin works: padded rows carry zero gradient,
-                # so they never contribute to histograms or leaf sums
-                fill = np.full((pad, bins_np.shape[1]),
-                               min(binned.missing_bin, binned.max_nbins - 1),
-                               dtype=bins_np.dtype)
-                bins_np = np.concatenate([bins_np, fill], axis=0)
-            bins_dev = jax.device_put(bins_np, sharding)
-            binned_p = BinnedMatrix(bins=bins_dev, cuts=binned.cuts,
-                                    max_nbins=binned.max_nbins,
-                                    has_missing=binned.has_missing)
+            # any in-range bin works: padded rows carry zero gradient,
+            # so they never contribute to histograms or leaf sums
+            binned_p = BinnedMatrix(
+                bins=put_row_shards(
+                    np.asarray(binned.bins), sharding, n_pad,
+                    min(binned.missing_bin, binned.max_nbins - 1)),
+                cuts=binned.cuts, max_nbins=binned.max_nbins,
+                has_missing=binned.has_missing)
+        pad = n_pad - n
+        set_mesh_layout(world, n_pad // world)
 
         info = dm.info
         labels = info.labels if info.labels is not None else np.zeros(n)
         labels = np.asarray(labels, dtype=np.float32)
-        lab2 = labels.reshape(n, -1)
+        # unweighted rows that fill their shards need no weight vector
         weights = (np.asarray(info.weights, np.float32)
-                   if info.weights is not None else np.ones(n, np.float32))
+                   if info.weights is not None
+                   else np.ones(n, np.float32) if pad else None)
         lb, ub = info.label_lower_bound, info.label_upper_bound
-        if pad:
-            lab2 = np.concatenate([lab2, np.zeros((pad, lab2.shape[1]),
-                                                  np.float32)])
-            weights = np.concatenate([weights, np.zeros(pad, np.float32)])
-            if lb is not None:
-                lb = np.concatenate([lb, np.ones(pad, np.float32)])
-            if ub is not None:
-                ub = np.concatenate([ub, np.ones(pad, np.float32)])
-        info_p = MetaInfo(
-            labels=lab2 if labels.ndim == 2 else lab2[:, 0],
-            weights=weights, group_ptr=info.group_ptr,
-            label_lower_bound=lb, label_upper_bound=ub,
-            feature_names=info.feature_names, feature_types=info.feature_types)
 
-        bm = jnp.asarray(self._broadcast_base_margin(dm, n))
-        if pad:
-            bm = jnp.concatenate([bm, jnp.zeros((pad, self.n_groups),
-                                                jnp.float32)])
-        margin = jax.device_put(bm, sharding)
+        def padded(a, fill):
+            return a if a is None or not pad else np.concatenate(
+                [a, np.full((pad,) + a.shape[1:], fill, np.float32)])
+        info_p = MetaInfo(
+            labels=padded(labels, 0.0), weights=padded(weights, 0.0),
+            group_ptr=info.group_ptr,
+            label_lower_bound=padded(lb, 1.0),
+            label_upper_bound=padded(ub, 1.0),
+            feature_names=info.feature_names, feature_types=info.feature_types)
+        # the device copies the objective reads every round, sharded like
+        # the rows (``MetaInfo.labels_device`` keys them by array identity)
+        row_sharding = jsh.NamedSharding(mesh, jsh.PartitionSpec(DATA_AXIS))
+        for host, slot in ((info_p.labels, "_labels_dev"),
+                           (info_p.weights, "_weights_dev")):
+            if host is not None:
+                setattr(info_p, slot, (host, put_row_shards(
+                    host, sharding if host.ndim == 2 else row_sharding,
+                    n_pad)))
+
+        # the whole-array device copies the stump fit left on one device
+        # (``_configure`` reads ``dm.info`` before any state exists) go: the
+        # rounds read the sharded ones
+        for slot in ("_labels_dev", "_weights_dev"):
+            dm.info.__dict__.pop(slot, None)
+
+        if dm.info.base_margin is not None:
+            margin = put_row_shards(self._broadcast_base_margin(dm, n),
+                                    sharding, n_pad)
+        else:       # a constant: broadcast on the devices, shard by shard
+            margin = _broadcast_rows(
+                jnp.asarray(self.base_margin_, jnp.float32).reshape(-1),
+                rows=n_pad, sharding=sharding)
         return self._store_cache(key, binned_p, margin, True, dm, info_p, n)
 
     def update(self, dtrain: DMatrix, iteration: int,

@@ -133,6 +133,24 @@ def search_bin_into(X: np.ndarray, cuts: HistogramCuts, missing_bin: int,
     out[:] = np.where(b < 0, missing_bin, b)
 
 
+def put_row_shards(arr: np.ndarray, sharding, n_pad: int, fill=0):
+    """A host array's rows, padded with ``fill`` to ``n_pad``, as one global
+    array under ``sharding`` (rows over its first axis): every device is
+    handed its own block of the host array and nothing else, so no device
+    ever holds the whole and nothing is concatenated on the host."""
+    n = arr.shape[0]
+    shape = (n_pad,) + tuple(arr.shape[1:])
+    parts = []
+    for dev, idx in sharding.addressable_devices_indices_map(shape).items():
+        lo, hi, _ = idx[0].indices(n_pad)
+        block = arr[lo:min(hi, n)]
+        if hi > n:
+            block = np.concatenate([block, np.full(
+                (hi - max(lo, n),) + shape[1:], fill, arr.dtype)])
+        parts.append(jax.device_put(block, dev))
+    return jax.make_array_from_single_device_arrays(shape, sharding, parts)
+
+
 @functools.partial(jax.jit, donate_argnums=0)
 def _collapse_page(buf: jnp.ndarray, page: jnp.ndarray,
                    start) -> jnp.ndarray:

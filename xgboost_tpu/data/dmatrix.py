@@ -18,9 +18,11 @@ from typing import Any, Iterator, List, Optional
 
 import numpy as np
 
+from ..obs import trace as obs_trace
 from .adapters import to_dense
 from .binned import BinnedMatrix
-from .quantile import FeatureSummary, HistogramCuts, cuts_from_summaries, sketch_matrix
+from .quantile import (FeatureSummary, HistogramCuts, cover_maxima,
+                       cuts_from_summaries, sketch_matrix)
 
 
 @dataclass
@@ -170,6 +172,58 @@ class DMatrix:
         self.info.validate(self.num_row())
         self._binned: Optional[BinnedMatrix] = None
         self._binned_max_bin: Optional[int] = None
+
+    # --- where an iterator's bins live -----------------------------------------
+    # ``_init_from_iter`` leaves its bin matrix on the HOST (``_host_bins``):
+    # where it goes is the first asker's to say. A booster with a row-split
+    # mesh takes it shard by shard (``place_binned``); anyone else who reads
+    # ``_binned`` gets it whole on the default device, as it always was.
+    @property
+    def _binned(self):
+        pending = self.__dict__.get("_host_bins")
+        if pending is not None:
+            self.__dict__["_host_bins"] = None
+            local, cuts, max_nbins, has_missing = pending
+            with obs_trace.span("ingest/upload", "ingest",
+                                {"rows": int(local.shape[0]), "shards": 1}):
+                self.__dict__["_binned_v"] = BinnedMatrix.from_local_bins(
+                    local, cuts, max_nbins=max_nbins,
+                    has_missing=has_missing)
+        return self.__dict__.get("_binned_v")
+
+    @_binned.setter
+    def _binned(self, value) -> None:
+        self.__dict__["_binned_v"] = value
+
+    def place_binned(self, sharding) -> Optional[BinnedMatrix]:
+        """An iterator's bin matrix that nobody has asked for yet, placed
+        under ``sharding`` (rows over its first axis, padded to a whole
+        number a shard with an in-range bin: pad rows carry weight 0). Each
+        device is handed its block of the host matrix; no device holds the
+        whole and nothing comes back to the host. None when there is
+        nothing pending (built in memory, paged, or already placed). With
+        no pad rows the placed matrix becomes this DMatrix's own and the
+        host copy is dropped."""
+        pending = self.__dict__.get("_host_bins")
+        if pending is None:
+            return None
+        from .binned import put_row_shards
+
+        local, cuts, max_nbins, has_missing = pending
+        world = sharding.mesh.shape[sharding.spec[0]]
+        n = local.shape[0]
+        n_pad = -(-n // world) * world
+        missing_bin = max_nbins - 1 if has_missing else max_nbins
+        with obs_trace.span("ingest/upload", "ingest",
+                            {"rows": int(n), "shards": world}):
+            placed = BinnedMatrix(
+                bins=put_row_shards(local, sharding, n_pad,
+                                    min(missing_bin, max_nbins - 1)),
+                cuts=cuts, max_nbins=max_nbins, has_missing=has_missing)
+        if n_pad == n:
+            self.__dict__["_host_bins"] = None
+            self.__dict__["_binned_v"] = placed
+        return placed
 
     # --- shape --------------------------------------------------------------
     def num_row(self) -> int:
@@ -388,70 +442,85 @@ class DMatrix:
         summaries = None
         n_rows = 0
         n_feat = 0
+        n_batches = 0
         has_missing = False
         need_sketch = ref is None
         feature_names: Optional[List[str]] = None
         feature_types: Optional[List[str]] = None
-        cat_max: Optional[np.ndarray] = None  # exact per-feature max code
-        for batch in it.collect():
-            X, bn, bt = to_dense(batch["data"], missing,
-                                 batch.get("feature_names"),
-                                 batch.get("feature_types"))
-            n_rows += X.shape[0]
-            n_feat = X.shape[1]
-            has_missing = has_missing or bool(np.isnan(X).any())
-            if bn is not None:
-                feature_names = list(bn)
-            if bt is not None:
-                feature_types = list(bt)
-            # category codes must cover every batch EXACTLY — the sketch's
-            # strided subsample may skip the max code, and a missing top
-            # category would fold rows into the wrong bin (reference:
-            # categories bypass the sketch entirely, src/common/
-            # hist_util.cc CutsBuilder for categorical). Tracked for ALL
-            # columns unconditionally: feature_types may be announced on
-            # any batch, and codes seen before the announcement count too.
-            if need_sketch:  # ref= copies cuts; cat_max would be unused
-                batch_max = np.fmax.reduce(
-                    X, axis=0, initial=-np.inf)  # NaN-ignoring, no copy
-                cat_max = (batch_max if cat_max is None
-                           else np.fmax(cat_max, batch_max))
-            for key, dest in (("label", labels), ("weight", weights),
-                              ("base_margin", margins),
-                              ("label_lower_bound", lbound),
-                              ("label_upper_bound", ubound)):
-                if batch.get(key) is not None:
-                    dest.append(np.asarray(batch[key], dtype=np.float32))
-            if batch.get("qid") is not None:
-                qids.append(np.asarray(batch["qid"]))
-            if need_sketch:
-                # strided subsample PER BATCH (cap = SKETCH_SAMPLE_ROWS/4):
-                # the sketch is approximate by design and per-feature numpy
-                # sorts dominate iterator construction at scale (41 s for
-                # 11M x 28 unsampled). A per-batch cap — rather than a
-                # global budget consumed in stream order — keeps every
-                # batch contributing equally, so time-ordered streams with
-                # distribution drift keep bin resolution over their whole
-                # range; the cost is that long streams sample more total
-                # rows than the resident path would (each batch's sort is
-                # still capped, which is what the limit is for). Weighted
-                # batches are never subsampled: dropping a heavily
-                # weighted row would starve its bin resolution.
-                from .quantile import SKETCH_SAMPLE_ROWS
+        col_max: Optional[np.ndarray] = None  # exact per-feature maximum
+        from concurrent.futures import ThreadPoolExecutor
 
-                bw = batch.get("weight")
-                Xs = X
-                ws = None if bw is None else np.asarray(bw, np.float64)
-                cap = SKETCH_SAMPLE_ROWS // 4 if SKETCH_SAMPLE_ROWS else 0
-                if bw is None and cap and X.shape[0] > cap:
-                    Xs = X[:: -(-X.shape[0] // cap)]
-                batch_s = [FeatureSummary.from_data(Xs[:, f], ws)
-                           for f in range(Xs.shape[1])]
-                if summaries is None:
-                    summaries = batch_s
-                else:
-                    summaries = [a.merge(b).prune(max_bin * 8)
-                                 for a, b in zip(summaries, batch_s)]
+        from .quantile import SKETCH_SAMPLE_ROWS
+
+        # numpy's sorts and reductions release the interpreter lock: a few
+        # threads take a batch's columns side by side
+        with ThreadPoolExecutor(max(1, min(16, (os.cpu_count() or 2) - 1))) \
+                as pool, obs_trace.span(
+                    "ingest/sketch", "ingest",
+                    {"max_bin": max_bin, "sample_rows": SKETCH_SAMPLE_ROWS}):
+            for batch in it.collect():
+                X, bn, bt = to_dense(batch["data"], missing,
+                                     batch.get("feature_names"),
+                                     batch.get("feature_types"))
+                n_rows += X.shape[0]
+                n_feat = X.shape[1]
+                n_batches += 1
+                if bn is not None:
+                    feature_names = list(bn)
+                if bt is not None:
+                    feature_types = list(bt)
+                # every column's TRUE maximum over every batch, NaN ignored (a
+                # column minimum of NaN says the batch has a missing value).
+                # The sketch's strided subsample may skip it: a category's top
+                # code would fold rows into the wrong bin (reference:
+                # categories bypass the sketch entirely, src/common/
+                # hist_util.cc CutsBuilder for categorical), and a numeric
+                # column's last cut has to lie above it (``cover_maxima``).
+                # Tracked for ALL columns: feature_types may be announced on
+                # any batch, and codes seen before the announcement count too.
+                step = max(1, -(-X.shape[0] // 16))
+                parts = list(pool.map(
+                    lambda lo: (np.fmax.reduce(X[lo:lo + step], axis=0,
+                                               initial=-np.inf),
+                                bool(np.isnan(X[lo:lo + step].min(initial=0.0)))),
+                    range(0, X.shape[0], step)))
+                has_missing = has_missing or any(nan for _, nan in parts)
+                for part_max, _ in parts:
+                    col_max = (part_max if col_max is None
+                               else np.fmax(col_max, part_max))
+                for key, dest in (("label", labels), ("weight", weights),
+                                  ("base_margin", margins),
+                                  ("label_lower_bound", lbound),
+                                  ("label_upper_bound", ubound)):
+                    if batch.get(key) is not None:
+                        dest.append(np.asarray(batch[key], dtype=np.float32))
+                if batch.get("qid") is not None:
+                    qids.append(np.asarray(batch["qid"]))
+                if need_sketch:
+                    # strided subsample PER BATCH (cap = SKETCH_SAMPLE_ROWS/4):
+                    # the sketch is approximate by design and per-feature numpy
+                    # sorts dominate iterator construction at scale (41 s for
+                    # 11M x 28 unsampled). A per-batch cap — rather than a
+                    # global budget consumed in stream order — keeps every
+                    # batch contributing equally, so time-ordered streams with
+                    # distribution drift keep bin resolution over their whole
+                    # range; the cost is that long streams sample more total
+                    # rows than the resident path would (each batch's sort is
+                    # still capped, which is what the limit is for). Weighted
+                    # batches are never subsampled: dropping a heavily
+                    # weighted row would starve its bin resolution.
+                    bw = batch.get("weight")
+                    Xs = X
+                    ws = None if bw is None else np.asarray(bw, np.float64)
+                    cap = SKETCH_SAMPLE_ROWS // 4 if SKETCH_SAMPLE_ROWS else 0
+                    if bw is None and cap and X.shape[0] > cap:
+                        Xs = X[:: -(-X.shape[0] // cap)]
+
+                    def summarise(f, Xs=Xs, ws=ws, prev=summaries):
+                        s = FeatureSummary.from_data(Xs[:, f], ws)
+                        return s if prev is None \
+                            else prev[f].merge(s).prune(max_bin * 8)
+                    summaries = list(pool.map(summarise, range(Xs.shape[1])))
         self.X = None  # external-memory: no whole raw matrix
         self.info = MetaInfo(feature_names=feature_names,
                              feature_types=feature_types,
@@ -488,22 +557,24 @@ class DMatrix:
         if ref is not None:
             cuts = ref.binned(max_bin).cuts
         else:
+            if (col_max is not None and _collective.is_distributed()
+                    and self._data_split_mode == "row"):
+                col_max = _collective.allreduce(
+                    np.asarray(col_max, np.float32), op="max")
             if (feature_types is not None and "c" in feature_types
-                    and cat_max is not None and summaries is not None):
+                    and col_max is not None and summaries is not None):
                 # override the (possibly subsampled) summary for categorical
                 # features with the exact observed code range: the cat
                 # branch of cuts_from_summaries only reads values.max()
-                if (_collective.is_distributed()
-                        and self._data_split_mode == "row"):
-                    cat_max = _collective.allreduce(
-                        np.asarray(cat_max, np.float32), op="max")
                 for f, t in enumerate(feature_types or []):
                     if t == "c" and f < len(summaries):
-                        m = max(float(cat_max[f]), 0.0)
+                        m = max(float(col_max[f]), 0.0)
                         summaries[f] = FeatureSummary.from_data(
                             np.asarray([0.0, m], np.float32))
             cuts = cuts_from_summaries(summaries or [], max_bin,
                                        feature_types)
+            if col_max is not None:
+                cuts = cover_maxima(cuts, col_max)
 
         # pass 2: quantize batch-by-batch into one preallocated matrix
         max_nbins = int(cuts.n_real_bins().max(initial=0)) + int(has_missing)
@@ -516,11 +587,13 @@ class DMatrix:
         from .binned import search_bin_into
 
         row = 0
-        for batch in it.collect():
-            X, _, _ = to_dense(batch["data"], missing)
-            search_bin_into(X, cuts, max_nbins - 1,
-                            local[row:row + X.shape[0]])
-            row += X.shape[0]
+        with obs_trace.span("ingest/bin", "ingest",
+                            {"rows": n_rows, "batches": n_batches}):
+            for batch in it.collect():
+                X, _, _ = to_dense(batch["data"], missing)
+                search_bin_into(X, cuts, max_nbins - 1,
+                                local[row:row + X.shape[0]])
+                row += X.shape[0]
         if cache_prefix:
             # external-memory tier: the quantized matrix stays host-resident
             # (disk-backed memmap) and STREAMS to the device in row pages
@@ -533,9 +606,8 @@ class DMatrix:
                 has_missing=has_missing,
                 page_rows=max(page_rows, 1))
         else:
-            self._binned = BinnedMatrix.from_local_bins(
-                np.asarray(local), cuts, max_nbins=max_nbins,
-                has_missing=has_missing)
+            self._binned = None
+            self._host_bins = (local, cuts, max_nbins, has_missing)
         self._binned_max_bin = max_bin
         self._n_rows = n_rows
         self._n_cols = n_feat

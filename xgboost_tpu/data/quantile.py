@@ -233,6 +233,32 @@ def cuts_from_summaries(summaries: Sequence[FeatureSummary], max_bin: int,
                          max_bin=max_bin, feature_types=feature_types)
 
 
+def cover_maxima(cuts: HistogramCuts, col_max: np.ndarray) -> HistogramCuts:
+    """Raise each numeric feature's LAST cut above the column's true
+    maximum, where a sketch over a row sample left it below. A cut is its
+    bin's inclusive upper bound, and ``search_bin`` clamps a value past the
+    last cut into the last real bin: such a row then trains on one side of
+    a split at that bin and is predicted, by its raw value against the
+    threshold, on the other. With the last cut over the true maximum no row
+    is clamped, so the model the cuts state is the model that was trained.
+    ``col_max``: NaN-ignoring maxima of ALL rows (``-inf`` for an empty
+    column). The one helper of ``sketch_matrix`` and the iterator's
+    sketch."""
+    ptrs = np.asarray(cuts.ptrs, np.int64)
+    last = ptrs[1:] - 1
+    m = np.asarray(col_max, np.float64)[:len(last)]
+    fix = (ptrs[1:] > ptrs[:-1]) & ~cuts.is_cat() & np.isfinite(m)
+    fix[fix] = m[fix] > cuts.values[last[fix]]
+    if not fix.any():
+        return cuts
+    values = np.array(cuts.values, np.float32)
+    values[last[fix]] = (m[fix] + (np.abs(m[fix]) * 1e-5 + 1e-5)
+                         ).astype(np.float32)
+    return HistogramCuts(values=values, ptrs=cuts.ptrs,
+                         min_vals=cuts.min_vals, max_bin=cuts.max_bin,
+                         feature_types=cuts.feature_types)
+
+
 def _sketch_matrix_native(X: np.ndarray, max_bin: int,
                           weights: Optional[np.ndarray],
                           feature_types: Optional[List[str]]
@@ -307,8 +333,8 @@ def _sketch_matrix_native(X: np.ndarray, max_bin: int,
 # sketch is itself approximate (GK summaries with eps ~ 1/max_bin); at 2M
 # sampled rows the order-statistic error is ~0.07% of rank = ~0.2 of one
 # 256-bin width, far inside that budget, while an 11M x 28 exact sketch
-# costs 21 s of single-core sort time. Values above the sampled maximum
-# clamp into the last real bin (search_bin already clamps). 0 disables.
+# costs 21 s of single-core sort time. The last cut is raised over the true
+# maximum of all rows (``cover_maxima``), so no value is clamped. 0 disables.
 SKETCH_SAMPLE_ROWS = int(__import__("os").environ.get(
     "XTPU_SKETCH_SAMPLE_ROWS", 2_000_000))
 
@@ -320,11 +346,14 @@ def sketch_matrix(X: np.ndarray, max_bin: int,
     """``SketchOnDMatrix`` analogue (reference ``src/common/hist_util.cc:32-69``)
     for an in-memory dense matrix with NaN as missing."""
     limit = SKETCH_SAMPLE_ROWS if sample_rows is None else sample_rows
+    col_max = None
     if weights is None and limit and X.shape[0] > limit:
         stride = -(-X.shape[0] // limit)
+        col_max = np.fmax.reduce(X, axis=0, initial=-np.inf)
         X = np.ascontiguousarray(X[::stride])
     out = _sketch_matrix_native(X, max_bin, weights, feature_types)
-    if out is not None:
-        return out
-    summaries = [FeatureSummary.from_data(X[:, f], weights) for f in range(X.shape[1])]
-    return cuts_from_summaries(summaries, max_bin, feature_types)
+    if out is None:
+        summaries = [FeatureSummary.from_data(X[:, f], weights)
+                     for f in range(X.shape[1])]
+        out = cuts_from_summaries(summaries, max_bin, feature_types)
+    return out if col_max is None else cover_maxima(out, col_max)

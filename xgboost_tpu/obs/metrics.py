@@ -34,6 +34,7 @@ __all__ = ["Sample", "Family", "HistogramData", "MetricsRegistry",
            "count_grow_epilogue", "grow_epilogue_counts",
            "count_eval_walk", "eval_walk_counts",
            "count_rank_gradient", "rank_counts",
+           "count_mesh_dispatch", "set_mesh_layout", "mesh_counts",
            "program_compile_counts"]
 
 LabelSet = Tuple[Tuple[str, str], ...]
@@ -419,6 +420,49 @@ def rank_counts() -> Dict[str, Any]:
             "fill_ratio": _registry.get(_RANK_FILL),
             "dispatches": {k: int(v) for k, v in _by_label(
                 _RANK_DISPATCHES, "method").items()}}
+
+
+_MESH_ALLREDUCE = "xtpu_mesh_allreduce_total"
+_MESH_BYTES = "xtpu_mesh_allreduce_bytes_total"
+_MESH_SHARDS = "xtpu_mesh_shards"
+_MESH_ROWS = "xtpu_mesh_rows_per_shard"
+
+
+def count_mesh_dispatch(collectives: Dict[str, Tuple[int, int]]) -> None:
+    """One dispatch of the row-split mesh grow program (``tree/grow.py
+    TreeGrower._sharded``). ``collectives``: ``{what: (count, bytes)}`` of
+    the program's collectives by their ``mesh.<what>`` scope, read once off
+    the traced program (shapes only): bytes are one shard's operand, what
+    each chip contributes to the exchange. Host arithmetic, no device pull."""
+    for what, (count, nbytes) in collectives.items():
+        labels = (("what", what),)
+        _registry.inc(_MESH_ALLREDUCE, by=count, labels=labels,
+                      help="row-split collectives dispatched, by mesh.* "
+                           "scope")
+        _registry.inc(_MESH_BYTES, by=float(nbytes), labels=labels,
+                      help="operand bytes a shard handed to those "
+                           "collectives")
+
+
+def set_mesh_layout(shards: int, rows_per_shard: int) -> None:
+    """The row-sharded training state as it was placed (``core.Booster.
+    _make_sharded_train_state``)."""
+    _registry.set_gauge(_MESH_SHARDS, shards,
+                        help="devices the training rows are sharded over")
+    _registry.set_gauge(_MESH_ROWS, rows_per_shard,
+                        help="rows of the binned matrix a device holds, "
+                             "pad rows included")
+
+
+def mesh_counts() -> Dict[str, Any]:
+    """The mesh counters as one dict: ``allreduce`` and ``bytes`` by what,
+    ``shards`` and ``rows_per_shard``."""
+    return {"allreduce": {k: int(v) for k, v in _by_label(
+                _MESH_ALLREDUCE, "what").items()},
+            "bytes": {k: int(v) for k, v in _by_label(
+                _MESH_BYTES, "what").items()},
+            "shards": int(_registry.get(_MESH_SHARDS)),
+            "rows_per_shard": int(_registry.get(_MESH_ROWS))}
 
 
 # ---- compile counters by program -------------------------------------------

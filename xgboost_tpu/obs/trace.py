@@ -48,7 +48,7 @@ from jax.profiler import StepTraceAnnotation, TraceAnnotation
 __all__ = ["Span", "Tracer", "enable", "disable", "enabled", "tracer",
            "span", "instant", "export", "reset", "set_identity",
            "STAGES", "ROUND_ROOTS", "stage", "opened_stages",
-           "RANK_SCOPES", "rank_scope"]
+           "RANK_SCOPES", "rank_scope", "MESH_SCOPES", "mesh_scope"]
 
 # Every ``xtpu.<stage>`` scope a compiled program may carry. A device op
 # belongs to the INNERMOST one on its path. Nesting, outermost first:
@@ -125,6 +125,25 @@ def rank_scope(name: str):
         raise ValueError(f"unknown ranking scope {name!r}: add it to "
                          "xgboost_tpu.obs.trace.RANK_SCOPES")
     return jax.named_scope("rank." + name)
+
+
+# The row-split collectives of the mesh grow program (``tree/grow.py _grow``
+# under ``shard_map``), one level below the ``xtpu.`` stage they sit in:
+# ``mesh.<what>``. Like ``rank.``, no ``xtpu.`` prefix, so the stage readers
+# keep booking the ops to their stage and a reader of the collectives takes
+# the innermost ``mesh.`` scope. ``hist_psum``: a level's histogram sums
+# across the row shards; ``root_psum``: the root's gradient sums;
+# ``scale_pmax``: the int8x2 quantisation scale every shard must share.
+MESH_SCOPES = ("hist_psum", "root_psum", "scale_pmax")
+
+
+def mesh_scope(name: str):
+    """``jax.named_scope("mesh.<name>")`` for a collective in
+    :data:`MESH_SCOPES`; any other name raises at trace time."""
+    if name not in MESH_SCOPES:
+        raise ValueError(f"unknown mesh scope {name!r}: add it to "
+                         "xgboost_tpu.obs.trace.MESH_SCOPES")
+    return jax.named_scope("mesh." + name)
 
 
 class Span:

@@ -52,7 +52,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...obs.trace import stage
+from ...obs.trace import mesh_scope, stage
 
 
 def _round_up(x: int, m: int) -> int:
@@ -373,7 +373,8 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         gpair_t = gpair.T                                # [2, n]
         max_abs = jnp.max(jnp.abs(gpair_t), axis=1)
         if axis_name is not None:
-            max_abs = jax.lax.pmax(max_abs, axis_name)
+            with mesh_scope("scale_pmax"):
+                max_abs = jax.lax.pmax(max_abs, axis_name)
         scale = 32512.0 / jnp.maximum(max_abs, 1e-30)
         q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
     pos_t = positions.astype(jnp.int32)[None, :]         # [1, n]
@@ -658,7 +659,8 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         with stage("quantise"):
             max_abs = jnp.max(jnp.abs(gpair_t), axis=1)      # [2]
             if axis_name is not None:
-                max_abs = jax.lax.pmax(max_abs, axis_name)   # global scale
+                with mesh_scope("scale_pmax"):               # global scale
+                    max_abs = jax.lax.pmax(max_abs, axis_name)
             scale = 32512.0 / jnp.maximum(max_abs, 1e-30)    # vs 32767
             q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
         # SWAR one-hot needs every bin id to fit a byte and whole words:

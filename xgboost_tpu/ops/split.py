@@ -131,7 +131,20 @@ def evaluate_splits(hist: jnp.ndarray, parent_sum: jnp.ndarray,
                           < param.max_cat_threshold))
         base_valid = jnp.where(ic4, cat_valid, base_valid)
 
-    right = parent5 - left
+    # right sums from THIS histogram's own total (the last prefix), not from
+    # the inherited ``parent_sum``: float32 sums of millions of rows drift
+    # by 1e-4 of their size between a level's histogram and the one its
+    # parent's sums came from, so ``parent - left`` gave a right child of a
+    # few rows beside a sibling of millions sums off by whole rows' worth
+    # (a leaf stated at 2.26 where its 215 rows' sums give 0.04, and a
+    # spurious gain for the split that isolates it: PERF.md section 6,
+    # PR 33). Prefix and total of one histogram round together.
+    rest = cum[..., -1:] - cum                            # real bins right
+    right = jnp.stack([rest + miss[:, :, :, None], rest][:n_dirs],
+                      axis=2)                             # [N,F,dirs,2,nb]
+    if cat is not None:
+        # one-hot and sorted-partition left sets are not bin prefixes
+        right = jnp.where(ic5, parent5 - left, right)
 
     lg, lh = left[:, :, :, 0, :], left[:, :, :, 1, :]     # [N,F,dirs,nb]
     rg, rh = right[:, :, :, 0, :], right[:, :, :, 1, :]
@@ -179,7 +192,9 @@ def evaluate_splits(hist: jnp.ndarray, parent_sum: jnp.ndarray,
     best_left = jnp.stack(
         [left[nn, f_idx, d_idx, 0, b_idx],
          left[nn, f_idx, d_idx, 1, b_idx]], axis=1)       # [N,2]
-    best_right = parent_sum - best_left
+    best_right = jnp.stack(
+        [right[nn, f_idx, d_idx, 0, b_idx],
+         right[nn, f_idx, d_idx, 1, b_idx]], axis=1)      # [N,2]
 
     if cat is None:
         w = 1
@@ -244,8 +259,10 @@ def evaluate_splits_multi(hist: jnp.ndarray, parent_sum: jnp.ndarray,
     n_dirs = 2 if has_missing else 1
     left = jnp.stack([cum, cum + miss[..., None]][:n_dirs],
                      axis=2)                               # [N,F,dirs,K,2,nb]
-    parent6 = parent_sum[:, None, None, :, :, None]        # [N,1,1,K,2,1]
-    right = parent6 - left
+    # right sums from this histogram's own total, as ``evaluate_splits``
+    # takes them (and for its reason): not ``parent_sum - left``
+    rest = cum[..., -1:] - cum
+    right = jnp.stack([rest + miss[..., None], rest][:n_dirs], axis=2)
 
     lg, lh = left[..., 0, :], left[..., 1, :]              # [N,F,dirs,K,nb]
     rg, rh = right[..., 0, :], right[..., 1, :]
@@ -276,7 +293,7 @@ def evaluate_splits_multi(hist: jnp.ndarray, parent_sum: jnp.ndarray,
     # [N,F,dirs,K,2,nb] -> advanced indices (nn, f, d, b) with slices at
     # (K, 2): separated advanced indices put the broadcast dim first
     best_left = jnp.moveaxis(left, 5, 3)[nn, f_idx, d_idx, b_idx]  # [N,K,2]
-    best_right = parent_sum - best_left
+    best_right = jnp.moveaxis(right, 5, 3)[nn, f_idx, d_idx, b_idx]
     return MultiSplitResult(
         gain=best_gain, feature=f_idx, bin=b_idx,
         default_left=d_idx.astype(bool), left_sum=best_left,

@@ -25,8 +25,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs.metrics import count_grow_epilogue, count_grow_schedule
-from ..obs.trace import stage
+from ..obs.metrics import (count_grow_epilogue, count_grow_schedule,
+                           count_mesh_dispatch)
+from ..obs.trace import mesh_scope, stage
 from ..ops.histogram import advance_leaf, build_hist, fused_advance_coarse
 from ..ops.partition import advance_positions_level, update_positions
 from ..ops.split import CatInfo, evaluate_splits
@@ -250,11 +251,12 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
     # out-of-range sentinel when the matrix carries no missing slot
     missing_bin = max_nbins - 1 if has_missing else max_nbins
 
-    def allreduce(x):
+    def allreduce(x, what="hist_psum"):
         # column split: every shard already sees all rows -> no hist psum
         if axis_name is None or col_split:
             return x
-        return jax.lax.psum(x, axis_name)
+        with mesh_scope(what):
+            return jax.lax.psum(x, axis_name)
 
     split_feature = jnp.full((max_nodes,), -1, jnp.int32)
     split_bin = jnp.zeros((max_nodes,), jnp.int32)
@@ -263,7 +265,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
     active = jnp.zeros((max_nodes,), bool).at[0].set(True)
     gain = jnp.zeros((max_nodes,), jnp.float32)
     node_sum = jnp.zeros((max_nodes, 2), jnp.float32)
-    root_sum = allreduce(jnp.sum(gpair, axis=0))
+    root_sum = allreduce(jnp.sum(gpair, axis=0), "root_psum")
     node_sum = node_sum.at[0].set(root_sum)
     positions = jnp.zeros((n,), jnp.int32)
     if monotone is not None:
@@ -709,6 +711,86 @@ def monotone_child_bounds_host(ls: np.ndarray, rs: np.ndarray,
     return (l_lo, l_hi), (r_lo, r_hi)
 
 
+@functools.lru_cache(maxsize=None)
+def _mesh_program(mesh, param: TrainParam, max_nbins: int, hist_method: str,
+                  has_missing: bool, split_mode: str):
+    """``_grow`` under ``shard_map`` over the mesh's ``data`` axis, jitted:
+    ``(bins, gpair, n_real_bins, tree_mask, key, monotone, constraint_sets,
+    cat) -> GrownTree``. Cached by what the trace closes over, so growers
+    that come and go (``Booster.set_param`` rebinds one a ``train`` call)
+    share the traced and compiled program."""
+    from ..context import DATA_AXIS
+
+    P = jax.sharding.PartitionSpec
+
+    def _grow_mesh(b, g, nr, tm, k, monotone, constraint_sets, cat):
+        return _grow(b, g, nr, tm, k, monotone, constraint_sets, cat,
+                     param=param, max_nbins=max_nbins,
+                     hist_method=hist_method, axis_name=DATA_AXIS,
+                     has_missing=has_missing, split_mode=split_mode)
+
+    tree = dict(split_feature=P(), split_bin=P(), default_left=P(),
+                is_leaf=P(), active=P(), leaf_value=P(), node_sum=P(),
+                gain=P(), is_cat_split=P(), cat_words=P(), base_weight=P())
+    if split_mode == "col":
+        # features sharded over the axis, rows replicated; every
+        # output (positions/delta included) is replicated
+        in_specs = (P(None, DATA_AXIS), P(), P(DATA_AXIS), P(DATA_AXIS),
+                    P(), P(), P(), P())
+        out_specs = GrownTree(positions=P(), delta=P(), **tree)
+    else:
+        in_specs = (P(DATA_AXIS, None), P(DATA_AXIS, None), P(), P(), P(),
+                    P(), P(), P())
+        out_specs = GrownTree(positions=P(DATA_AXIS), delta=P(DATA_AXIS),
+                              **tree)
+    # col mode: outputs ARE replicated (every split field passes
+    # through a psum / all_gather), but the static replication
+    # checker cannot prove it through the owner-shard select chain
+    return jax.jit(jax.shard_map(
+        _grow_mesh, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=split_mode != "col"))
+
+
+_COLLECTIVE_PRIMS = ("psum", "pmax", "psum_invariant", "pmax_invariant")
+_mesh_ledgers: dict = {}
+
+
+def _walk_collectives(jaxpr, times: int, out: dict) -> None:
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in _COLLECTIVE_PRIMS:
+            scopes = [p for p in str(eqn.source_info.name_stack).split("/")
+                      if p.startswith("mesh.")]
+            what = scopes[-1][len("mesh."):] if scopes else "unscoped"
+            nbytes = sum(int(np.prod(v.aval.shape)) * v.aval.dtype.itemsize
+                         for v in eqn.invars if hasattr(v, "aval"))
+            n, b = out.get(what, (0, 0))
+            out[what] = (n + times, b + times * nbytes)
+        inner_times = times * int(eqn.params.get("length", 1)) \
+            if eqn.primitive.name == "scan" else times
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _walk_collectives(sub, inner_times, out)
+
+
+def _mesh_collectives(fn, args) -> dict:
+    """``{what: (count, bytes)}`` of the collectives one dispatch of the mesh
+    program ``fn`` runs at these argument shapes, by the innermost ``mesh.``
+    scope on each one's name stack (``obs.trace.MESH_SCOPES``; anything else
+    reads ``unscoped``). Read off the traced program once a shape: the
+    second trace finds ``_grow``'s own in jax's cache. Bytes are one shard's
+    operand: what a chip hands to the exchange."""
+    key = (fn, tuple((tuple(a.shape), str(a.dtype))
+                     for a in jax.tree_util.tree_leaves(args)
+                     if hasattr(a, "shape")))
+    if key not in _mesh_ledgers:
+        out: dict = {}
+        _walk_collectives(fn.trace(*args).jaxpr.jaxpr, 1, out)
+        _mesh_ledgers[key] = out
+    return _mesh_ledgers[key]
+
+
 @TREE_UPDATERS.register("grow_quantile_histmaker", "grow_gpu_hist",
                         "grow_histmaker")
 class TreeGrower:
@@ -769,7 +851,6 @@ class TreeGrower:
                     self.cat = CatInfo(
                         is_cat=jnp.pad(self.cat.is_cat, (0, pad)),
                         is_onehot=jnp.pad(self.cat.is_onehot, (0, pad)))
-        self._sharded_fn = None
 
     def grow(self, bins: jnp.ndarray, gpair: jnp.ndarray,
              n_real_bins: jnp.ndarray, key: jax.Array) -> GrownTree:
@@ -830,55 +911,23 @@ class TreeGrower:
             base_weight=np.where(exists, base_weight, 0.0).astype(np.float32))
 
     def sharded_program(self):
-        """Build (and cache) the jitted shard_map grow program WITHOUT
-        dispatching it — the traceable handle exported through
-        ``xgboost_tpu/tree/programs.py`` for the mesh row/col contract
-        checks; ``_sharded`` below invokes the same cached object."""
-        from ..context import DATA_AXIS
-
-        if self._sharded_fn is None:
-            P = jax.sharding.PartitionSpec
-
-            def inner(b, g, nr, tm, k):
-                return _grow(b, g, nr, tm, k, self.monotone,
-                             self.constraint_sets, self.cat,
-                             param=self.param, max_nbins=self.max_nbins,
-                             hist_method=self.hist_method,
-                             axis_name=DATA_AXIS,
-                             has_missing=self.has_missing,
-                             split_mode=self.split_mode)
-
-            if self.split_mode == "col":
-                # features sharded over the axis, rows replicated; every
-                # output (positions/delta included) is replicated
-                in_specs = (P(None, DATA_AXIS), P(), P(DATA_AXIS),
-                            P(DATA_AXIS), P())
-                out_specs = GrownTree(
-                    split_feature=P(), split_bin=P(), default_left=P(),
-                    is_leaf=P(), active=P(), leaf_value=P(), node_sum=P(),
-                    gain=P(), positions=P(), delta=P(),
-                    is_cat_split=P(), cat_words=P(), base_weight=P())
-            else:
-                in_specs = (P(DATA_AXIS, None), P(DATA_AXIS, None), P(),
-                            P(), P())
-                out_specs = GrownTree(
-                    split_feature=P(), split_bin=P(), default_left=P(),
-                    is_leaf=P(), active=P(), leaf_value=P(), node_sum=P(),
-                    gain=P(), positions=P(DATA_AXIS), delta=P(DATA_AXIS),
-                    is_cat_split=P(), cat_words=P(), base_weight=P())
-            # col mode: outputs ARE replicated (every split field passes
-            # through a psum / all_gather), but the static replication
-            # checker cannot prove it through the owner-shard select chain
-            self._sharded_fn = jax.jit(jax.shard_map(
-                inner, mesh=self.mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                check_vma=self.split_mode != "col"))
-        return self._sharded_fn
+        """The jitted shard_map grow program WITHOUT dispatching it — the
+        traceable handle exported through ``xgboost_tpu/tree/programs.py``
+        for the mesh row/col contract checks; ``_sharded`` below invokes
+        the same object. One program a (mesh, configuration), shared by
+        every grower of the process (``_mesh_program``): a continuation
+        call rebinds its grower and must not trace the round again."""
+        return _mesh_program(self.mesh, self.param, self.max_nbins,
+                             self.hist_method, self.has_missing,
+                             self.split_mode)
 
     def _sharded(self, bins, gpair, n_real_bins, tree_mask, key) -> GrownTree:
-        return self.sharded_program()(bins, gpair, n_real_bins, tree_mask,
-                                      key)
+        fn = self.sharded_program()
+        args = (bins, gpair, n_real_bins, tree_mask, key, self.monotone,
+                self.constraint_sets, self.cat)
+        if self.split_mode == "row":
+            count_mesh_dispatch(_mesh_collectives(fn, args))
+        return fn(*args)
 
     def to_tree_model(self, g: GrownTree) -> TreeModel:
         """Pull device arrays to host, compact the heap, attach raw split

@@ -111,7 +111,8 @@ def _mesh_plan(split_mode: str) -> RoundPlan:
     spec = ProgramSpec(
         name=f"sharded_grow_{split_mode}",
         fn=grower.sharded_program(),
-        args=_grow_args(),
+        # monotone, constraint_sets, cat: none
+        args=_grow_args() + (None, None, None),
         src=_grow)
     return RoundPlan(handle=f"mesh.{split_mode}", unit="tree",
                      dispatches=[spec],
