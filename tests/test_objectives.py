@@ -218,7 +218,7 @@ def test_weighted_training():
 @pytest.mark.parametrize("exp_gain,cap", [(True, None), (False, None),
                                           (True, 4)])
 def test_lambdarank_device_matches_host_loop(obj, exp_gain, cap, monkeypatch):
-    # the padded [G, L, L] device gradient must reproduce the per-group
+    # the padded [C, K, L] device gradient must reproduce the per-group
     # host loop's math, f32 vs f64 tolerance only; ragged groups +
     # per-query weights. ``cap`` None: the topk default (every pair of a
     # group, deterministic); ``cap`` 4: only pairs whose better-ranked doc
@@ -253,6 +253,51 @@ def test_lambdarank_device_matches_host_loop(obj, exp_gain, cap, monkeypatch):
         g_all = np.asarray(get_objective(obj, dict(params))
                            .get_gradient(s, info))
         assert np.abs(g_all - g_dev).max() > 1e-3
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)     # a ClosedJaxpr's own
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+@pytest.mark.parametrize("objective,kpos", [("ndcg", 0), ("pairwise", 0),
+                                            ("map", 0), ("ndcg", 8)])
+@pytest.mark.parametrize("kcap", [8, 0])
+def test_topk_chunk_works_in_rank_order(objective, kpos, kcap):
+    # the chunk loop of the ``topk`` kernel: sorts, a [C, K, L] block and
+    # nothing looked up row by row. K = min(kcap, L) anchors a group (L with
+    # no truncation), so under a truncation no value of L x L a group exists
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from xgboost_tpu.objective import ranking as rk
+
+    n, G, L, C = 600, 24, 40, 4
+    rows = jax.ShapeDtypeStruct((n,), jnp.float32)
+    idx = jax.ShapeDtypeStruct((n,), jnp.int32)
+    per_group = jax.ShapeDtypeStruct((G,), jnp.int32)
+    bias = (jax.ShapeDtypeStruct((kpos,), jnp.float32),) * 2 if kpos else ()
+    closed = jax.make_jaxpr(functools.partial(
+        rk._lambda_grad_device, kcap=kcap, L=L, exp_gain=True,
+        objective=objective, chunk=C, n_groups=G, kpos=kpos))(
+            rows, rows, idx, idx, per_group, per_group, rows, *bias)
+    (loop,) = [e for e in _equations(closed.jaxpr)
+               if e.primitive.name == "scan"]
+    body = list(_equations(loop.params["jaxpr"].jaxpr))
+    names = {e.primitive.name for e in body}
+    assert "sort" in names
+    assert not {p for p in names if "gather" in p or "scatter" in p}, names
+    K = rk._topk_anchors(kcap, L)
+    assert max(v.aval.size for e in body for v in e.outvars) == C * K * L
+    assert loop.params["length"] == G // C
 
 
 @pytest.mark.parametrize("obj", ["rank:ndcg", "rank:map"])

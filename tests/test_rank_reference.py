@@ -40,9 +40,13 @@ class _Info:
 
 
 # a group of one row, a group of equal labels, a group longer than the
-# truncation (8 here, 32 in the cell), and ordinary ragged ones
+# truncation (8 here, 32 in the cell), ordinary ragged ones, and a group of
+# 13 whose rows ranked 7 and 8 tie in score: the tie straddles truncation 8,
+# and the slot decides which of the two is the last anchor
 SIZES = [1, 5, 40, 2, 13, 9]
 EQUAL = 1            # index of the group whose labels are all equal
+LONGEST = max(SIZES)
+STRADDLE = 4         # index of the group whose tie straddles truncation 8
 
 
 def _ragged(seed):
@@ -53,7 +57,15 @@ def _ragged(seed):
     y[ptr[EQUAL]:ptr[EQUAL + 1]] = 2.0
     s = rng.randn(len(y)).astype(np.float32)
     s[ptr[2] + 3] = s[ptr[2] + 7]            # a tie in score: stable ranks
+    pair = _ranked_7_and_8(s, ptr)
+    s[pair] = s[pair[0]]                     # they tie now,
+    y[pair] = [0.0, 3.0]                     # on labels that make a pair
     return s, y, ptr
+
+
+def _ranked_7_and_8(s, ptr):
+    lo, hi = ptr[STRADDLE], ptr[STRADDLE + 1]
+    return lo + np.argsort(-s[lo:hi], kind="stable")[7:9]
 
 
 def _program_grad(s, y, ptr, truncation, chunk=None):
@@ -61,7 +73,7 @@ def _program_grad(s, y, ptr, truncation, chunk=None):
         TOPK, lambdarank_num_pair_per_sample=truncation))
     info = _Info(y, ptr)
     if chunk is not None:
-        obj._device_layout(info)["chunk"] = chunk
+        obj._chunk_of = lambda layout, block: chunk
     return np.asarray(obj.get_gradient(s, info), np.float64)[:, 0, :]
 
 
@@ -69,8 +81,11 @@ def _program_grad(s, y, ptr, truncation, chunk=None):
 # terms, each exp and divide in f32 (relative 1e-6 to 1e-5), with
 # cancellation between a row's wins and losses; 2e-4 of the value or 1e-6
 # absolute is what the program's own device-against-host test allows
+# Truncations: inside the longest group (K < L: the block is [C, K, L]), one
+# under, equal to and one over it (K = L - 1, L, L), and none (K = L)
 @pytest.mark.parametrize("seed", [0, 1, 2])
-@pytest.mark.parametrize("truncation", [8, 0])
+@pytest.mark.parametrize("truncation", [8, 0, LONGEST - 1, LONGEST,
+                                        LONGEST + 1])
 def test_gradient_matches_reference(seed, truncation):
     rr = _reference()
     s, y, ptr = _ragged(seed)
@@ -94,6 +109,27 @@ def test_pair_inside_truncation_counts_once():
     np.testing.assert_allclose(got[:, 0], [p * delta, -p * delta], rtol=1e-5)
     np.testing.assert_allclose(got[:, 1], [p * (1 - p) * delta] * 2,
                                rtol=1e-5)
+
+
+# the tie across the truncation boundary goes to the lower slot, as the
+# stable argsort of the host loop and of the reference has it: of the two
+# tied rows only the one at rank 7 pairs with the rows ranked below both.
+# With their scores nudged apart either way, the order the slots give is
+# the one whose gradient the program states
+def test_tie_across_the_truncation_goes_to_the_lower_slot():
+    rr = _reference()
+    s, y, ptr = _ragged(5)
+    a, b = _ranked_7_and_8(s, ptr)
+    assert s[a] == s[b] and a < b and y[a] != y[b]
+    rows = slice(ptr[STRADDLE], ptr[STRADDLE + 1])
+    got = _program_grad(s, y, ptr, 8)[rows]
+    for first in (a, b):
+        nudged = s.astype(np.float64)
+        nudged[first] += 1e-9               # ``first`` takes rank 7
+        g, h = rr.lambda_gradients(nudged, y, ptr, truncation=8)
+        close = np.allclose(got[:, 0], g[rows], rtol=2e-4, atol=1e-6) \
+            and np.allclose(got[:, 1], h[rows], rtol=2e-4, atol=1e-6)
+        assert close == (first == a)
 
 
 # (b) the parts add up to the whole

@@ -9,15 +9,18 @@ label_i > label_j get the RankNet lambda scaled by the metric delta
 better-ranked doc is inside the truncation, each pair once).
 
 All three objectives run ON DEVICE in both pair modes: groups pad into a
-``[G, L]`` matrix (L = longest group), per-group ranks come from two
-stable argsorts, and the pair interaction is a ``[G, L, L]`` VPU tensor
-for ``topk`` (top-k docs × the docs ranked below them, deterministic) or a
-sampled ``[G, L, k]``
-tensor for ``mean`` (the default, matching the reference: k uniform
-out-of-label-bucket rivals per doc, ``lambdarank_obj.h:231-275``), chunked
-over groups by ``lax.map`` to bound memory — the TPU answer to the
-reference's per-pair CUDA kernels. MAP's |ΔAP| rides the same kernels via
-rank-ordered prefix statistics (``_map_prefix``/``_map_delta_dev``). The
+``[G, L]`` matrix (L = longest group), chunked over groups by ``lax.map``
+to bound memory — the TPU answer to the reference's per-pair CUDA kernels.
+``topk`` (top-k docs × the docs ranked below them, deterministic) works in
+RANK order: one sort a chunk puts scores, labels and slots by (score,
+slot), the pair interaction is a ``[C, K, L]`` VPU tensor over the sorted
+rows (K = min(truncation, L) anchors a group; ``[C, L, L]`` with no
+truncation), and one sort by slot returns the sums: no per-row gather
+inside the loop. ``mean`` (the default, matching the reference: k uniform
+out-of-label-bucket rivals per doc, ``lambdarank_obj.h:231-275``) keeps
+slot order, per-group ranks from two stable argsorts and a sampled
+``[C, L, k]`` tensor. MAP's |ΔAP| rides the same kernels via rank-ordered
+prefix statistics (``_map_prefix_ranked``/``_map_swap_delta``). The
 per-group numpy loop remains as the oracle/fallback, forced with
 XTPU_RANK_HOST=1.
 
@@ -83,12 +86,17 @@ def _bucket_stats(y: np.ndarray):
 
 
 def _map_prefix(yp, vp, order, L):
-    """Per-group MAP prefix statistics in current rank order: C_k (relevant
-    count in top k+1), T0 (shifted cumsum of rel/(rank+1); T0[k] == T[k-1],
-    T0[0] == 0) and R (total relevant, floored at 1) — the device mirror of
-    the host ``LambdaRankMAP._delta`` precomputation."""
+    """Per-group MAP prefix statistics in current rank order, from slot-order
+    labels and the rank order's slots (the ``mean`` kernel's way in)."""
     yb = ((yp > 0) & vp).astype(jnp.float32)
-    rel_rank = jnp.take_along_axis(yb, order, axis=1)          # [C, L]
+    return _map_prefix_ranked(jnp.take_along_axis(yb, order, axis=1), L)
+
+
+def _map_prefix_ranked(rel_rank, L):
+    """C_k (relevant count in top k+1), T0 (shifted cumsum of rel/(rank+1);
+    T0[k] == T[k-1], T0[0] == 0) and R (total relevant, floored at 1) of the
+    ``[C, L]`` relevance in rank order — the device mirror of the host
+    ``LambdaRankMAP._delta`` precomputation."""
     Ck = jnp.cumsum(rel_rank, axis=1)
     T = jnp.cumsum(rel_rank / (jnp.arange(L, dtype=jnp.float32) + 1.0),
                    axis=1)
@@ -140,14 +148,22 @@ def _debias_dev(lam, hes, p, delta, mask, a_is_i, i_pos, j_pos, ti, tj,
     that drive the post-iteration bias update. Positions >= kpos (or with
     a zero bias estimate — the reference's Eps64 gate) pass through
     unscaled and unaccumulated. Returns (lam, hes, cost/tmj, cost/tpi,
-    ok) with the cost terms zeroed outside ``ok``. The gate threshold is
-    the HOST loop's float64 eps (not f32 tiny): a bias estimate below it
-    must be EXCLUDED, not divided by — dividing by ~1e-20 in f32
-    overflows the lambdas where the reference trains normally."""
-    eps = jnp.float32(np.finfo(np.float64).eps)
+    ok) with the cost terms zeroed outside ``ok``."""
     tpi = ti[jnp.minimum(i_pos, kpos - 1)]
     tmj = tj[jnp.minimum(j_pos, kpos - 1)]
-    ok = mask & (i_pos < kpos) & (j_pos < kpos) & (tpi >= eps) & (tmj >= eps)
+    return _debias_scaled(lam, hes, p, delta,
+                          mask & (i_pos < kpos) & (j_pos < kpos), tpi, tmj)
+
+
+def _debias_scaled(lam, hes, p, delta, mask, tpi, tmj):
+    """``_debias_dev`` past its lookups: ``tpi`` / ``tmj`` are each pair's
+    ti+[pos_i] / tj-[pos_j], ``mask`` the pairs with both positions tracked.
+    The gate threshold is the HOST loop's float64 eps (not f32 tiny): a
+    bias estimate below it must be EXCLUDED, not divided by — dividing by
+    ~1e-20 in f32 overflows the lambdas where the reference trains
+    normally."""
+    eps = jnp.float32(np.finfo(np.float64).eps)
+    ok = mask & (tpi >= eps) & (tmj >= eps)
     scale = jnp.where(ok, tpi * tmj, 1.0)
     lam = lam / scale
     hes = hes / scale
@@ -158,9 +174,10 @@ def _debias_dev(lam, hes, p, delta, mask, a_is_i, i_pos, j_pos, ti, tj,
 
 def _delta_dev(objective, *, yp, vp, order, L, gv, dv, inv_idcg,
                gj, dj, rank_i, rank_j, a_is_i):
-    """Metric delta for a gathered pair tensor — shared 3-way dispatch
-    (|ΔNDCG| / |ΔMAP| / 1) for both device kernels; ``gj``/``dj``/
-    ``rank_j`` arrive already gathered/broadcast to the pair shape."""
+    """Metric delta for a gathered pair tensor in slot order — the ``mean``
+    kernel's 3-way dispatch (|ΔNDCG| / |ΔMAP| / 1; ``topk`` states its own
+    in rank coordinates, where nothing is gathered); ``gj``/``dj``/
+    ``rank_j`` arrive already gathered to the pair shape."""
     if objective == "pairwise":
         return jnp.float32(1.0)
     if objective == "map":
@@ -171,8 +188,8 @@ def _delta_dev(objective, *, yp, vp, order, L, gv, dv, inv_idcg,
 
 
 def _map_delta_dev(rank_i, rank_j, a_is_i, Ck, T0, R):
-    """|ΔAP| for swapping the (oriented-relevant) doc i with doc j — the
-    device mirror of the host formula (binary relevance)."""
+    """|ΔAP| for swapping the (oriented-relevant) doc i with doc j, the
+    prefix statistics gathered at the pair's two ranks."""
     r_rel = jnp.where(a_is_i, rank_i, rank_j)
     r_irr = jnp.where(a_is_i, rank_j, rank_i)
     u = jnp.minimum(r_rel, r_irr)
@@ -184,19 +201,27 @@ def _map_delta_dev(rank_i, rank_j, a_is_i, Ck, T0, R):
         return jnp.take_along_axis(A, idx.reshape(Cc, -1),
                                    axis=1).reshape(shape)
 
-    Cu = g2(Ck, u)
-    Cv = g2(Ck, v)
-    Tv1 = g2(T0, v)        # T[v-1]
-    Tu = g2(T0, u + 1)     # T[u]
-    Tu1 = g2(T0, u)        # T[u-1]
+    return _map_swap_delta(
+        u, v, r_rel < r_irr, Cu=g2(Ck, u), Cv=g2(Ck, v), Tv1=g2(T0, v),
+        Tu=g2(T0, u + 1), Tu1=g2(T0, u), R=R)
+
+
+def _map_swap_delta(u, v, rel_above, *, Cu, Cv, Tv1, Tu, Tu1, R):
+    """|ΔAP| of swapping the docs at ranks u < v, the relevant one above
+    where ``rel_above`` — the device mirror of the host formula (binary
+    relevance). C* = C_k at u / v; Tv1, Tu, Tu1 = T[v-1], T[u], T[u-1];
+    everything broadcastable to the ``[C, ., .]`` pair shape."""
     uf = u.astype(jnp.float32)
     vf = v.astype(jnp.float32)
     d_down = Cv / (vf + 1.0) - Cu / (uf + 1.0) - (Tv1 - Tu)
     d_up = (Cu + 1.0) / (uf + 1.0) - Cv / (vf + 1.0) + (Tv1 - Tu1)
-    rel_above = r_rel < r_irr
-    extra = (1,) * (len(shape) - 1)
-    return jnp.abs(jnp.where(rel_above, d_down, d_up)) \
-        / R.reshape((Cc,) + extra)
+    return jnp.abs(jnp.where(rel_above, d_down, d_up)) / R[:, None, None]
+
+
+def _topk_anchors(kcap: int, L: int) -> int:
+    """K, the rows of a group that can be a pair's better-ranked row under
+    ``topk``: the truncation, or all L of the padded group without one."""
+    return L if kcap == 0 else min(kcap, L)
 
 
 @functools.partial(
@@ -216,69 +241,114 @@ def _lambda_grad_device(s, y, qidx, slot, starts, sizes, w_row, ti=None,
     currently ranked < ``kcap`` (the reference's ``MakePairs`` rule for
     ``topk``: ``for i < k: for j > i``); ``kcap`` = 0 means no truncation.
     The same pairs as ``_pairs``.
+
+    A chunk works in RANK coordinates: one sort puts its rows by score
+    (ties by slot, as the host's stable argsort), so a row's rank is its
+    position, its discount a column of the ``[L]`` table, the anchors the
+    first K (``_topk_anchors``) columns and the pair block ``[C, K, L]``;
+    one sort by slot returns the sums. No gather inside the chunk loop.
     """
+    K = _topk_anchors(kcap, L)
     with rank_scope("layout"):
-        Gp, s_pad, y_pad, valid, sz = _padded_layout(
+        Gp, s_pad, y_pad, _valid, sz = _padded_layout(
             s, y, starts, sizes, n_groups=n_groups, chunk=chunk, L=L)
         kc = sz if kcap == 0 else jnp.minimum(kcap, sz)
-        disc = 1.0 / jnp.log2(jnp.arange(L, dtype=jnp.float32) + 2.0)
+        pos = jnp.arange(L, dtype=jnp.int32)
+        disc = 1.0 / jnp.log2(pos.astype(jnp.float32) + 2.0)
+        if kpos > 0:  # unbiased LambdaMART: slots ARE input positions
+            ti_slot = ti[jnp.minimum(pos, kpos - 1)]
+            tj_slot = tj[jnp.minimum(pos, kpos - 1)]
 
     def gains_j(v):
         return (jnp.exp2(v) - 1.0) if exp_gain else v
 
-    def order_of(sp, yp):
+    # ``lax.slice`` by name: ``a[:, :K, None]`` traces as a gather
+    first_k = lambda a: jax.lax.slice_in_dim(a, 0, K, axis=1)
+    rows = lambda a: jnp.broadcast_to(a[None, :], (chunk, L))
+    anchor = lambda a: first_k(a)[..., None]   # [C, L] -> the block's i axis
+    partner = lambda a: a[:, None, :]          # [C, L] -> the block's j axis
+
+    def by_rank(sp, yp):
+        """Scores, labels, slots (and the slots' bias estimates) in rank
+        order: padded slots hold -inf and sort last."""
         with rank_scope("order"):
-            order = jnp.argsort(-sp, axis=1, stable=True)
-            rank_of = jnp.argsort(order, axis=1, stable=True)  # inverse perm
+            bias = (rows(ti_slot), rows(tj_slot)) if kpos > 0 else ()
+            # keys (-score, slot): the order of a stable sort by score,
+            # less the iota operand XLA gives a stable sort of its own
+            neg, slots, ys, *bias = jax.lax.sort(
+                (-sp, rows(pos), yp) + bias, dimension=1, is_stable=False,
+                num_keys=2)
             y_desc = -jnp.sort(-yp, axis=1)
             idcg = jnp.sum(gains_j(y_desc) * disc[None, :], axis=1)
             inv_idcg = jnp.where(idcg > 0, 1.0 / idcg, 0.0)
-            gv = gains_j(yp)                              # [C, L]
-            dv = disc[rank_of]                            # [C, L]
-        return order, rank_of, inv_idcg, gv, dv
+        return -neg, ys, slots, bias, inv_idcg
+
+    def by_slot(slots, *sums):
+        """``[C, L]`` sums of the sorted positions back in slot order (a
+        group's slots are distinct: no stability to pay an operand for)."""
+        with rank_scope("order"):
+            return jax.lax.sort((slots,) + sums, dimension=1,
+                                is_stable=False, num_keys=1)[1:]
+
+    def both_axes(a_side, b_side):
+        """A sorted position's sum over the block: what it collects as a
+        partner plus, in the first K positions, as an anchor."""
+        return b_side.sum(axis=1) + jnp.pad(a_side.sum(axis=2),
+                                            ((0, 0), (0, L - K)))
 
     def one_chunk(args):
-        sp, yp, vp, kcc = args                       # [C, L] / [C]
-        order, rank_of, inv_idcg, gv, dv = order_of(sp, yp)
-        yi, yj = yp[:, :, None], yp[:, None, :]
-        ri, rj = rank_of[:, :, None], rank_of[:, None, :]
+        sp, yp, szc, kcc = args                      # [C, L] / [C]
+        ss, ys, slots, bias, inv_idcg = by_rank(sp, yp)
+        pi, pj = anchor(pos[None, :]), pos[None, None, :]
+        yi, yj = anchor(ys), partner(ys)
         # each pair once: i is its better-ranked row, inside the truncation
-        mask = (vp[:, :, None] & vp[:, None, :] & (yi != yj)
-                & (ri < kcc[:, None, None]) & (ri < rj))
+        mask = ((pi < kcc[:, None, None]) & (pi < pj)
+                & (pj < szc[:, None, None]) & (yi != yj))
         a_is_i = yi > yj
-        Cn = rank_of.shape[0]
-        delta = _delta_dev(
-            objective, yp=yp, vp=vp, order=order, L=L, gv=gv, dv=dv,
-            inv_idcg=inv_idcg, gj=gv[:, None, :], dj=dv[:, None, :],
-            rank_i=jnp.broadcast_to(ri, (Cn, L, L)),
-            rank_j=jnp.broadcast_to(rj, (Cn, L, L)),
-            a_is_i=a_is_i)
-        lam, hes, p = _ranknet_dev(sp[:, :, None], sp[:, None, :], a_is_i,
-                                   delta, mask)
-        if kpos > 0:  # unbiased LambdaMART: slots ARE input positions
-            pos = jnp.arange(L, dtype=jnp.int32)
-            i_pos = jnp.where(a_is_i, pos[None, :, None], pos[None, None, :])
-            j_pos = jnp.where(a_is_i, pos[None, None, :], pos[None, :, None])
-            lam, hes, ci, cj, ok = _debias_dev(
-                lam, hes, p, delta, mask, a_is_i, i_pos, j_pos, ti, tj,
-                kpos)
-            # per-position pair-cost sums: i_pos is the anchor slot where
-            # a_is_i, else the partner slot (and symmetrically for j_pos)
-            li_c = (jnp.where(a_is_i, ci, 0.0).sum(axis=2).sum(axis=0)
-                    + jnp.where(~a_is_i, ci, 0.0).sum(axis=1).sum(axis=0))
-            lj_c = (jnp.where(~a_is_i, cj, 0.0).sum(axis=2).sum(axis=0)
-                    + jnp.where(a_is_i, cj, 0.0).sum(axis=1).sum(axis=0))
+        if objective == "pairwise":
+            delta = jnp.float32(1.0)
+        elif objective == "map":    # ranks u = pi < v = pj: columns, no gather
+            Ck, T0, R = _map_prefix_ranked(
+                ((ys > 0) & (pos[None, :] < szc[:, None])).astype(
+                    jnp.float32), L)
+            delta = _map_swap_delta(
+                pi, pj, a_is_i, Cu=anchor(Ck), Cv=partner(Ck),
+                Tv1=partner(T0[:, :L]), Tu=anchor(T0[:, 1:]),
+                Tu1=anchor(T0), R=R)
+        else:
+            gv = gains_j(ys)
+            delta = jnp.abs((anchor(gv) - partner(gv))
+                            * (anchor(disc[None, :]) - disc[None, None, :])) \
+                * inv_idcg[:, None, None]
+        lam, hes, p = _ranknet_dev(anchor(ss), partner(ss), a_is_i, delta,
+                                   mask)
+        sums = ()
+        if kpos > 0:
+            ti_s, tj_s = bias
+            tracked = anchor(slots < kpos) & partner(slots < kpos)
+            lam, hes, ci, cj, _ok = _debias_scaled(
+                lam, hes, p, delta, mask & tracked,
+                jnp.where(a_is_i, anchor(ti_s), partner(ti_s)),
+                jnp.where(a_is_i, partner(tj_s), anchor(tj_s)))
+            # per-position pair-cost sums: i_pos is the anchor's slot where
+            # a_is_i, else the partner's (and symmetrically for j_pos)
+            sums = (both_axes(jnp.where(a_is_i, ci, 0.0),
+                              jnp.where(a_is_i, 0.0, ci)),
+                    both_axes(jnp.where(a_is_i, 0.0, cj),
+                              jnp.where(a_is_i, cj, 0.0)))
+        lam_i = jnp.where(a_is_i, lam, -lam)
+        g, h, *costs = by_slot(slots, both_axes(lam_i, -lam_i),
+                               both_axes(hes, hes), *sums)
+        if kpos > 0:
+            li_c, lj_c = (c.sum(axis=0) for c in costs)
         else:
             li_c = lj_c = jnp.zeros((L,), jnp.float32)
-        g = (jnp.where(a_is_i, lam, -lam).sum(axis=2)
-             + jnp.where(a_is_i, -lam, lam).sum(axis=1))
-        h = hes.sum(axis=2) + hes.sum(axis=1)
         return g, h, li_c, lj_c
 
     cs = lambda a: a.reshape(Gp // chunk, chunk, *a.shape[1:])
     with rank_scope("pairs"):       # the chunk loop's own glue included
         g_pad, h_pad, li_s, lj_s = jax.lax.map(
-            one_chunk, (cs(s_pad), cs(y_pad), cs(valid), cs(kc)))
+            one_chunk, (cs(s_pad), cs(y_pad), cs(sz), cs(kc)))
     return _rows_of(g_pad, h_pad, li_s, lj_s, qidx, slot, w_row, Gp, L, kpos)
 
 
@@ -481,11 +551,15 @@ class _LambdaRankBase(Objective):
                 sizes=jnp.asarray(sizes, jnp.int32),
                 w_row=jnp.asarray(w_row),
                 y=jnp.asarray(y_np),
-                fill=float(ptr[-1]) / max(G * L, 1),
-                # chunk groups so one [C, L, L] pair block stays ~64 MB
-                chunk=max(1, min(G, (1 << 24) // max(L * L, 1))))
+                fill=float(ptr[-1]) / max(G * L, 1))
         self._dev_layout = (key, layout)
         return layout
+
+    @staticmethod
+    def _chunk_of(layout, block: int) -> int:
+        """Groups a ``lax.map`` step of the device kernels: as many as keep
+        one pair block, ``block`` slots a group, at ~64 MB of float32."""
+        return max(1, min(layout["G"], (1 << 24) // max(block, 1)))
 
     @staticmethod
     def _pairs_kept(layout, kcap: int) -> int:
@@ -589,11 +663,8 @@ class _LambdaRankBase(Objective):
                 key = jax.random.fold_in(
                     jax.random.key(int(self.params.get("seed", 0))),
                     iteration)
-                # the sampled-pair tensor is [C, L, k] — rechunk by its
-                # own footprint, not the all-pairs [C, L, L] budget
-                chunk = max(1, min(lay["G"],
-                                   (1 << 24) // max(lay["L"] * k, 1)))
-                slots, kept = lay["L"] * k, n * k
+                slots, kept = lay["L"] * k, n * k   # the [C, L, k] block
+                chunk = self._chunk_of(lay, slots)
                 gpair, li, lj = _lambda_grad_device_mean(
                     s, lay["y"], lay["qidx"], lay["slot"], lay["starts"],
                     lay["sizes"], lay["w_row"], key, lay["y_order"], lay["n_lefts"],
@@ -603,8 +674,10 @@ class _LambdaRankBase(Objective):
             else:
                 kcap = int(self.params.get(
                     "lambdarank_num_pair_per_sample", 0))
-                chunk = lay["chunk"]
-                slots, kept = lay["L"] ** 2, self._pairs_kept(lay, kcap)
+                # the [C, K, L] block in rank order: K anchors a group
+                slots = _topk_anchors(kcap, lay["L"]) * lay["L"]
+                kept = self._pairs_kept(lay, kcap)
+                chunk = self._chunk_of(lay, slots)
                 gpair, li, lj = _lambda_grad_device(
                     s, lay["y"], lay["qidx"], lay["slot"], lay["starts"],
                     lay["sizes"], lay["w_row"], ti_d, tj_d, kcap=kcap, L=lay["L"],

@@ -393,8 +393,10 @@ _RANK_FILL = "xtpu_rank_layout_fill_ratio"
 def count_rank_gradient(method: str, pair_slots: int, pairs_kept: int,
                         fill_ratio: float) -> None:
     """One device dispatch of the ranking gradient (``objective/ranking.py``).
-    ``pair_slots``: slots of the pair blocks it sweeps (steps x C x L x L
-    under ``topk``, steps x C x L x k under ``mean``). ``pairs_kept``: pairs
+    ``pair_slots``: slots of the pair blocks it sweeps (steps x C x K x L
+    under ``topk``: the ``[C, K, L]`` block in rank order, K = the truncation
+    or L, whichever is less; steps x C x L x k under ``mean``).
+    ``pairs_kept``: pairs
     the truncation admits inside the groups' real rows, from the group sizes
     alone: an upper bound on the pairs that carry a lambda (label ties are
     not counted out). Both are host arithmetic on the cached layout: no

@@ -112,8 +112,10 @@ def opened_stages() -> frozenset:
 # ``xtpu.<stage>`` on its path still books the whole gradient to
 # ``gradient``, and a reader of the parts takes the innermost ``rank.``
 # scope. ``layout``: rows gathered into the padded ``[G, L]`` buffers;
-# ``order``: the per-group sorts, ranks, discounts and ideal DCG;
-# ``pairs``: the ``[C, L, L]`` pair block and the chunk loop around it;
+# ``order``: the per-group sorts (by score into rank order and back by
+# slot under ``topk``), the label sort and the ideal DCG;
+# ``pairs``: the pair block (``[C, K, L]`` in rank order under ``topk``,
+# K anchors a group) and the chunk loop around it;
 # ``reduce``: the padded sums gathered back to rows.
 RANK_SCOPES = ("layout", "order", "pairs", "reduce")
 
