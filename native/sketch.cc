@@ -234,12 +234,12 @@ void xtpu_sketch_cuts(const float* X, int64_t n, int64_t nf,
   }
 }
 
-// 1 if any element of X[0:count] is NaN.
-int32_t xtpu_has_nan(const float* X, int64_t count) {
-  int32_t found = 0;
-#pragma omp parallel for schedule(static) reduction(| : found)
+// How many elements of X[0:count] are NaN.
+int64_t xtpu_count_nan(const float* X, int64_t count) {
+  int64_t found = 0;
+#pragma omp parallel for schedule(static) reduction(+ : found)
   for (int64_t i = 0; i < count; ++i) {
-    if (std::isnan(X[i])) found = 1;
+    found += std::isnan(X[i]) ? 1 : 0;
   }
   return found;
 }

@@ -53,9 +53,11 @@ def test_native_search_bin_matches_python(with_missing):
     if with_missing:
         X[rng.random((n, nf)) < 0.1] = np.nan
     cuts = _python_cuts(X, 32, None, None)
-    out = bn._search_bin_native(np.ascontiguousarray(X), cuts)
-    assert out is not None
-    arr, has_missing, max_nbins = out
+    X = np.ascontiguousarray(X)
+    has_missing, max_nbins, dtype, miss, n_nan = bn._matrix_layout(X, cuts)
+    assert n_nan == int(np.isnan(X).sum())
+    arr = np.empty(X.shape, dtype)
+    bn.search_bin_into(X, cuts, miss, arr)
     local = cuts.search_bin(X)
     ref_missing = bool((local < 0).any())
     assert has_missing == ref_missing == with_missing

@@ -128,3 +128,62 @@ def test_heap_walk_program_compiles_for_v5e(mesh, rows, features, bins_dtype,
     row_gathers = [line for line in text.splitlines()
                    if " gather(" in line and str(rows) in line]
     assert not row_gathers
+
+
+# the production-line job's width (benchmark cell bosch-line.sparse-train):
+# 968 columns of two-byte ids with the missing slot at 256
+@pytest.mark.parametrize("kernel,nodes,width", [
+    # the widest boundary fused_advance_coarse's gate admits at this width
+    # (F * n_level <= 52,428): the whole-F tile, its int32 copy, the coarse
+    # accumulator and the group loop's operands, past the default 16 MiB of
+    # scoped VMEM (the wrapper states its own limit)
+    ("fused_advance_coarse", 32, 20),
+    ("fused_advance_coarse", 2, 20),
+    # the root's coarse build and the widest level's refine build, in
+    # feature blocks on the grid
+    ("build_hist_int8", 1, 20),
+    ("build_hist_int8", 128, 36),
+    # the last level's advance below 128 nodes
+    ("advance_leaf", 128, 0),
+])
+def test_wide_missing_kernels_compile_for_v5e(mesh, kernel, nodes, width):
+    """Mosaic takes ``_grow``'s kernels at F = 968 with ``uint16`` bins, in
+    feature groups: no VMEM refusal at the widest tiles the default path
+    hands them, and no body longer than ``FEATURE_GROUP`` features."""
+    from jax.sharding import SingleDeviceSharding
+
+    from xgboost_tpu.obs.metrics import get_registry, hist_body_features
+    from xgboost_tpu.ops.pallas import histogram as ph
+
+    one = SingleDeviceSharding(mesh.devices.flat[0])
+    n, F = 4096, 968
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    get_registry().set_gauge("xtpu_hist_body_features", 0)
+    if kernel == "fused_advance_coarse":
+        prev = nodes // 2
+        fn = jax.jit(lambda b, g, p, f, t, d, c: ph.fused_advance_coarse_pallas(
+            b, g, p, f, t, d, c, lo_prev=prev - 1, n_prev=prev, lo=nodes - 1,
+            n_level=nodes, missing_bin=256))
+        args = (arg((F, n), jnp.uint16), arg((n, 2), jnp.float32),
+                arg((n,), jnp.int32), arg((prev,), jnp.int32),
+                arg((prev,), jnp.int32), arg((prev,), jnp.bool_),
+                arg((prev,), jnp.bool_))
+    elif kernel == "build_hist_int8":
+        fn = jax.jit(lambda b, g, p: ph.build_hist_pallas(b, g, p, nodes,
+                                                          width))
+        args = (arg((F, n), jnp.uint8), arg((n, 2), jnp.float32),
+                arg((n,), jnp.int32))
+    else:
+        fn = jax.jit(lambda b, p, f, t, d, c, v: ph.advance_leaf_pallas(
+            b, p, f, t, d, c, v, n_prev=nodes, missing_bin=256))
+        args = (arg((F, n), jnp.uint16), arg((n,), jnp.int32),
+                arg((nodes,), jnp.int32), arg((nodes,), jnp.int32),
+                arg((nodes,), jnp.bool_), arg((nodes,), jnp.bool_),
+                arg((4 * nodes - 1,), jnp.float32))
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and f"xtpu.kernel.{kernel}" in text
+    if kernel != "advance_leaf":
+        assert 0 < hist_body_features() <= ph.FEATURE_GROUP

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import memory as _mem
-from ..obs.metrics import count_degrade
+from ..obs import trace as obs_trace
+from ..obs.metrics import count_degrade, set_binned_layout
 from .quantile import HistogramCuts
 
 
@@ -61,45 +62,32 @@ def _dtype_for(max_local_bins: int):
     return np.int32
 
 
-def _matrix_layout(X: np.ndarray, cuts: HistogramCuts, lib):
-    """(has_missing, max_nbins, dtype, missing_bin) for a dense matrix —
-    single source of the bin-layout policy, shared by the one-shot and
-    pipelined native binning paths so they can never drift."""
-    import ctypes
-
-    n, nf = X.shape
-    has_missing = bool(lib.xtpu_has_nan(
-        X.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-        ctypes.c_int64(n * nf)))
-    max_nbins = int(cuts.n_real_bins().max(initial=0)) + int(has_missing)
-    dtype = _dtype_for(max(max_nbins - 1, 0))
-    return has_missing, max_nbins, dtype, max(max_nbins - 1, 0)
-
-
-def _search_bin_native(X: np.ndarray, cuts: HistogramCuts):
-    """Threaded bin assignment (native/sketch.cc); None -> pure-Python path."""
+def count_nan(X: np.ndarray) -> int:
+    """NaN entries of an array: the native sweep over a C-contiguous
+    float32 one where the library is built, numpy otherwise."""
     import ctypes
 
     from .. import native
 
     lib = native.load()
-    n, nf = X.shape
-    if lib is None or n == 0 or nf == 0:
-        return None
-    fptr = ctypes.POINTER(ctypes.c_float)
-    has_missing, max_nbins, dtype, _ = _matrix_layout(X, cuts, lib)
-    dcode = {np.uint8: 0, np.uint16: 1, np.int32: 2}[dtype]
-    out = np.empty((n, nf), dtype)
-    values = np.ascontiguousarray(cuts.values, np.float32)
-    ptrs = np.ascontiguousarray(cuts.ptrs, np.int32)
-    fn = lib.xtpu_search_bin
-    fn.restype = None
-    fn(X.ctypes.data_as(fptr), ctypes.c_int64(n), ctypes.c_int64(nf),
-       values.ctypes.data_as(fptr),
-       ptrs.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-       ctypes.c_int32(max_nbins - 1), ctypes.c_int32(dcode),
-       out.ctypes.data_as(ctypes.c_void_p))
-    return out, has_missing, max_nbins
+    if (lib is None or not X.size or X.dtype != np.float32
+            or not X.flags.c_contiguous):
+        return int(np.isnan(X).sum())
+    fn = lib.xtpu_count_nan
+    fn.argtypes = [ctypes.POINTER(ctypes.c_float), ctypes.c_int64]
+    fn.restype = ctypes.c_int64
+    return int(fn(X.ctypes.data_as(ctypes.POINTER(ctypes.c_float)), X.size))
+
+
+def _matrix_layout(X: np.ndarray, cuts: HistogramCuts):
+    """(has_missing, max_nbins, dtype, missing_bin, n_nan) for a dense matrix —
+    single source of the bin-layout policy, shared by the one-shot and
+    pipelined native binning paths so they can never drift."""
+    n_nan = count_nan(X)
+    has_missing = n_nan > 0
+    max_nbins = int(cuts.n_real_bins().max(initial=0)) + int(has_missing)
+    dtype = _dtype_for(max(max_nbins - 1, 0))
+    return has_missing, max_nbins, dtype, max(max_nbins - 1, 0), n_nan
 
 
 def search_bin_into(X: np.ndarray, cuts: HistogramCuts, missing_bin: int,
@@ -274,66 +262,79 @@ class BinnedMatrix:
         from .. import native
 
         X = np.ascontiguousarray(X, dtype=np.float32)
-        n, nf = X.shape
-        lib = native.load()
-        if lib is not None and n >= BinnedMatrix._PIPELINE_MIN_ROWS and nf:
-            has_missing, max_nbins, dtype, miss = _matrix_layout(X, cuts, lib)
-            chunk = BinnedMatrix._PIPELINE_CHUNK
-            # producer/consumer: the native binning (ctypes, GIL released)
-            # of chunk k runs concurrently with the upload of chunk k-1 on
-            # a worker thread, in case device_put blocks the calling thread
-            # (unverified on the attached chip)
-            import queue
-            import threading
 
-            q: "queue.Queue" = queue.Queue(maxsize=2)
-            parts = []
-            err = []
+        def put(arr):
+            return (jax.device_put(arr, device) if device is not None
+                    else jnp.asarray(arr))
 
-            def uploader():
-                try:
-                    while True:
-                        item = q.get()
-                        if item is None:
-                            return
-                        parts.append(jax.device_put(item, device))
-                except Exception as e:
-                    err.append(e)
-                    while True:  # keep draining so the producer never blocks
-                        if q.get() is None:
-                            return
-
-            # daemon: if the producer raises, interpreter exit must not hang
-            # on a parked uploader
-            t = threading.Thread(target=uploader, daemon=True)
-            t.start()
-            try:
-                for s in range(0, n, chunk):
-                    out = np.empty((min(chunk, n - s), nf), dtype)
-                    search_bin_into(X[s:s + chunk], cuts, miss, out)
-                    q.put(out)
-            finally:
-                q.put(None)
-                t.join()
-            if err:
-                raise err[0]
-            bins = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-            return BinnedMatrix(bins=bins, cuts=cuts, max_nbins=max_nbins,
-                                has_missing=has_missing)
-        arr = _search_bin_native(X, cuts)
-        if arr is not None:
-            arr, has_missing, max_nbins = arr
-        else:
+        if native.load() is None or not X.size:
             local = cuts.search_bin(X)
-            has_missing = bool((local < 0).any())
+            n_nan = int((local < 0).sum())
+            has_missing = n_nan > 0
             max_nbins = int(cuts.n_real_bins().max(initial=0)) + int(has_missing)
             if has_missing:
                 local = np.where(local < 0, max_nbins - 1, local)
-            arr = local.astype(_dtype_for(max_nbins - 1))
-        bins = (jax.device_put(arr, device) if device is not None
-                else jnp.asarray(arr))
+            bins = put(local.astype(_dtype_for(max_nbins - 1)))
+        else:
+            has_missing, max_nbins, dtype, miss, n_nan = _matrix_layout(X, cuts)
+            with obs_trace.span("ingest/bin", "ingest",
+                                {"rows": X.shape[0], "batches": 1,
+                                 "nan": n_nan, "dtype": np.dtype(dtype).name}):
+                if X.shape[0] >= BinnedMatrix._PIPELINE_MIN_ROWS:
+                    bins = BinnedMatrix._bin_pipelined(X, cuts, dtype, miss,
+                                                       device)
+                else:
+                    arr = np.empty(X.shape, dtype)
+                    search_bin_into(X, cuts, miss, arr)
+                    bins = put(arr)
+        set_binned_layout(n_nan, X.size, bins.dtype.itemsize)
         return BinnedMatrix(bins=bins, cuts=cuts, max_nbins=max_nbins,
                             has_missing=has_missing)
+
+    @staticmethod
+    def _bin_pipelined(X: np.ndarray, cuts: HistogramCuts, dtype, miss: int,
+                       device):
+        """Producer/consumer: the native binning (ctypes, GIL released) of
+        chunk k runs concurrently with the upload of chunk k-1 on a worker
+        thread, in case device_put blocks the calling thread (unverified on
+        the attached chip)."""
+        import queue
+        import threading
+
+        n, nf = X.shape
+        chunk = BinnedMatrix._PIPELINE_CHUNK
+        q: "queue.Queue" = queue.Queue(maxsize=2)
+        parts = []
+        err = []
+
+        def uploader():
+            try:
+                while True:
+                    item = q.get()
+                    if item is None:
+                        return
+                    parts.append(jax.device_put(item, device))
+            except Exception as e:
+                err.append(e)
+                while True:  # keep draining so the producer never blocks
+                    if q.get() is None:
+                        return
+
+        # daemon: if the producer raises, interpreter exit must not hang
+        # on a parked uploader
+        t = threading.Thread(target=uploader, daemon=True)
+        t.start()
+        try:
+            for s in range(0, n, chunk):
+                out = np.empty((min(chunk, n - s), nf), dtype)
+                search_bin_into(X[s:s + chunk], cuts, miss, out)
+                q.put(out)
+        finally:
+            q.put(None)
+            t.join()
+        if err:
+            raise err[0]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
     is_paged = False
 

@@ -434,7 +434,8 @@ class DMatrix:
         with ``cache_prefix`` the bin matrix itself is a disk-backed
         memmap (the SparsePageDMatrix disk-spill tier,
         ``src/data/sparse_page_dmatrix.h``)."""
-        from .binned import _dtype_for
+        from ..obs.metrics import set_binned_layout
+        from .binned import _dtype_for, count_nan
 
         # pass 1: metadata + per-batch summaries (or copy ref cuts)
         labels, weights, margins, qids = [], [], [], []
@@ -443,7 +444,7 @@ class DMatrix:
         n_rows = 0
         n_feat = 0
         n_batches = 0
-        has_missing = False
+        n_nan = 0              # this process's missing entries, all batches
         need_sketch = ref is None
         feature_names: Optional[List[str]] = None
         feature_types: Optional[List[str]] = None
@@ -469,8 +470,7 @@ class DMatrix:
                     feature_names = list(bn)
                 if bt is not None:
                     feature_types = list(bt)
-                # every column's TRUE maximum over every batch, NaN ignored (a
-                # column minimum of NaN says the batch has a missing value).
+                # every column's TRUE maximum over every batch, NaN ignored.
                 # The sketch's strided subsample may skip it: a category's top
                 # code would fold rows into the wrong bin (reference:
                 # categories bypass the sketch entirely, src/common/
@@ -479,15 +479,13 @@ class DMatrix:
                 # Tracked for ALL columns: feature_types may be announced on
                 # any batch, and codes seen before the announcement count too.
                 step = max(1, -(-X.shape[0] // 16))
-                parts = list(pool.map(
-                    lambda lo: (np.fmax.reduce(X[lo:lo + step], axis=0,
-                                               initial=-np.inf),
-                                bool(np.isnan(X[lo:lo + step].min(initial=0.0)))),
-                    range(0, X.shape[0], step)))
-                has_missing = has_missing or any(nan for _, nan in parts)
-                for part_max, _ in parts:
+                for part_max in pool.map(
+                        lambda lo: np.fmax.reduce(X[lo:lo + step], axis=0,
+                                                  initial=-np.inf),
+                        range(0, X.shape[0], step)):
                     col_max = (part_max if col_max is None
                                else np.fmax(col_max, part_max))
+                n_nan += count_nan(X)
                 for key, dest in (("label", labels), ("weight", weights),
                                   ("base_margin", margins),
                                   ("label_lower_bound", lbound),
@@ -541,6 +539,7 @@ class DMatrix:
             self.info.set_group(counts)
         from ..parallel import collective as _collective
 
+        has_missing = n_nan > 0
         if (_collective.is_distributed()
                 and self._data_split_mode == "row"):
             # multi-host external memory: every process streams ITS row
@@ -587,8 +586,10 @@ class DMatrix:
         from .binned import search_bin_into
 
         row = 0
+        set_binned_layout(n_nan, n_rows * n_feat, np.dtype(dtype).itemsize)
         with obs_trace.span("ingest/bin", "ingest",
-                            {"rows": n_rows, "batches": n_batches}):
+                            {"rows": n_rows, "batches": n_batches,
+                             "nan": n_nan, "dtype": np.dtype(dtype).name}):
             for batch in it.collect():
                 X, _, _ = to_dense(batch["data"], missing)
                 search_bin_into(X, cuts, max_nbins - 1,

@@ -366,6 +366,92 @@ def grow_epilogue_counts() -> Dict[str, int]:
     return {k: int(v) for k, v in _by_label(_GROW_EPILOGUE, "kind").items()}
 
 
+_FUSED_BOUNDARY = "xtpu_fused_boundary_total"
+FUSED_BOUNDARY_BODIES = ("kernel", "xla")
+
+
+def count_fused_boundary(body: str) -> None:
+    """One level boundary of a traced grow program under the ``fused``
+    schedule, by what ``ops/histogram.py fused_advance_coarse`` took for
+    it: ``kernel`` (the Mosaic sweep: advance and the new level's coarse
+    histogram from one read of the bin tile) or ``xla`` (the XLA body: the
+    advance, then ``coarse_bin_ids`` over the whole matrix and an unfused
+    coarse build). Counted while jax traces, beside ``count_grow_schedule``."""
+    if body not in FUSED_BOUNDARY_BODIES:
+        raise ValueError(f"unknown boundary body {body!r}")
+    _registry.inc(_FUSED_BOUNDARY, labels=(("body", body),),
+                  help="level boundaries of traced fused grow programs, by "
+                       "what advanced the rows and built the coarse "
+                       "histogram")
+
+
+def fused_boundary_counts() -> Dict[str, int]:
+    return {k: int(v) for k, v in _by_label(_FUSED_BOUNDARY, "body").items()}
+
+
+_HIST_ONEHOT = "xtpu_hist_onehot_total"
+HIST_ONEHOT_BUILDS = ("swar", "compare")
+
+
+def count_hist_onehot(build: str) -> None:
+    """One trace of a Pallas histogram kernel's wrapper (``ops/pallas/
+    histogram.py build_hist_pallas``, ``fused_advance_coarse_pallas``), by
+    how its kernel builds the bin one-hot: ``swar`` (four bins a uint32 word,
+    a zero-byte detect: the width is a multiple of 4 and at most 256) or
+    ``compare`` (a ``[B, R]`` int32 compare: every other width, 257 slots
+    among them, and the f32/bf16 kernels). The wrappers are jitted, so a
+    width and node count traced before in the process is not counted
+    again."""
+    if build not in HIST_ONEHOT_BUILDS:
+        raise ValueError(f"unknown one-hot build {build!r}")
+    _registry.inc(_HIST_ONEHOT, labels=(("build", build),),
+                  help="Pallas histogram kernels traced, by one-hot build")
+
+
+def hist_onehot_counts() -> Dict[str, int]:
+    return {k: int(v) for k, v in _by_label(_HIST_ONEHOT, "build").items()}
+
+
+_HIST_BODY = "xtpu_hist_body_features"
+
+
+def note_hist_body_features(features: int) -> None:
+    """The features one traced Pallas histogram kernel body unrolls
+    (``ops/pallas/histogram.py``: a feature block of ``build_hist_pallas``,
+    a group of ``fused_advance_coarse_pallas``). The gauge keeps the largest
+    seen in the process: tracing, lowering and Mosaic's compile are linear
+    in it, and ``FEATURE_GROUP`` is its ceiling."""
+    if features > _registry.get(_HIST_BODY):
+        _registry.set_gauge(_HIST_BODY, features,
+                            help="largest number of features a traced "
+                                 "histogram kernel body unrolls")
+
+
+def hist_body_features() -> int:
+    return int(_registry.get(_HIST_BODY))
+
+
+_BINNED_MISSING = "xtpu_binned_missing_ratio"
+_BINNED_BYTES = "xtpu_binned_bin_bytes"
+
+
+def set_binned_layout(missing: int, entries: int, bin_bytes: int) -> None:
+    """The bin matrix the last binning pass made (``data/binned.py
+    BinnedMatrix.from_dense``, ``DMatrix._init_from_iter``): the missing
+    entries the pass counted on the host over all entries, and the bytes a
+    value takes in the matrix. Host arithmetic, no device pull."""
+    _registry.set_gauge(_BINNED_MISSING, missing / entries if entries else 0.0,
+                        help="missing entries / entries of the last matrix "
+                             "binned")
+    _registry.set_gauge(_BINNED_BYTES, bin_bytes,
+                        help="bytes a value takes in the last matrix binned")
+
+
+def binned_layout() -> Dict[str, float]:
+    return {"missing_ratio": _registry.get(_BINNED_MISSING),
+            "bin_bytes": int(_registry.get(_BINNED_BYTES))}
+
+
 _EVAL_WALK = "xtpu_eval_walk_total"
 
 

@@ -26,6 +26,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ..obs.metrics import count_fused_boundary
 from ..obs.trace import stage
 
 
@@ -273,6 +274,7 @@ def fused_advance_coarse(bins: jnp.ndarray, gpair: jnp.ndarray,
                   and decision_axis is None and kind == "dense"
                   and nl_prev <= 64 and n_level <= 128
                   and F * COARSE_B * 2 * n_level * 4 <= 8 * 2 ** 20)
+    count_fused_boundary("kernel" if use_pallas or interpret else "xla")
     if use_pallas or interpret:
         from .pallas.histogram import fused_advance_coarse_pallas
 

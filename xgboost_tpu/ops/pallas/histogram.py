@@ -15,7 +15,11 @@ everything XLA would materialise:
 - the node-scatter matrix ``P^T [2N, R]`` (rows scattered to their tree node,
   times (g, h)) is built once per row block and shared by every feature;
 - the accumulator ``[Fb, B, 2N]`` lives in VMEM across the row-block grid axis
-  and only hits HBM once per feature block.
+  and only hits HBM once per feature block;
+- a body runs its features in groups of at most ``FEATURE_GROUP`` (G): the
+  per-feature steps of a group are unrolled in Python, and a matrix wider than
+  G takes more groups (feature blocks on the grid, or a loop inside the
+  kernel), not a longer body.
 
 All vector inputs are lane-major (``[2, n]`` gpair, ``[1, n]`` positions) so no
 VMEM is wasted padding 1- or 2-wide lanes to 128.
@@ -52,11 +56,46 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...obs.metrics import count_hist_onehot, note_hist_body_features
 from ...obs.trace import mesh_scope, stage
+
+# The most features a kernel body unrolls. A body's per-feature steps are
+# unrolled in Python so that Mosaic overlaps the one-hot of feature f+1 with
+# the dot of feature f, and tracing, lowering and Mosaic's compile are all
+# linear in what is unrolled: a depth-8 round program holds 17 such kernels,
+# and at F = 968 with whole-F bodies it traced and lowered for 215 s and
+# compiled for 152 s more (PERF.md section 6, PR 36). Wider matrices run in
+# groups of at most this many features: ``build_hist_pallas`` caps its
+# feature block on the grid, ``_make_fused_kernel``, which needs the whole-F
+# tile for its advance, loops over groups inside the kernel.
+FEATURE_GROUP = 256
 
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+def _feature_groups(F: int) -> tuple:
+    """(groups, features a group): the fewest groups of at most
+    ``FEATURE_GROUP`` features, evenly sized, so that the padding past F is
+    under one feature a group. One group of F features while F fits."""
+    groups = max(-(-F // FEATURE_GROUP), 1)
+    return groups, -(-F // groups)
+
+
+def _feature_block(F: int, cap: int, step: int = 8) -> int:
+    """Feature block of a grid over F features, at most ``cap`` a block: the
+    whole F when it fits (no padding features burn one-hot builds, and a
+    block spec allows any first dimension equal to the array's); otherwise
+    the multiple of ``step``, no smaller than a quarter of the cap, that
+    pads F least (every padded feature costs a one-hot build, and a
+    cap-sized block can pad F nearly 2x), the largest such."""
+    if F <= cap:
+        return F
+    cap = max(cap // step * step, step)
+    least = max(cap // 4 // step * step, step)
+    return min(range(least, cap + 1, step),
+               key=lambda b: (_round_up(F, b), -b))
 
 
 _CONTRACT_LAST = (((1,), (1,)), ((), ()))  # oh [M, R] . P^T [K, R] -> [M, K]
@@ -76,12 +115,13 @@ def _packed_hist(row, K4, PT4):
     one-hot of ``row`` ([1, R] u32 bin ids; ``_make_int8_kernel`` explains
     the detect) against ``PT4`` ([4N, R] int8 byte planes) on the int8 MXU,
     recombined to [B, 2N] f32. Jitted so that a kernel body traces it ONCE
-    and binds one equation per feature afterwards: the bodies are unrolled
-    in Python (28 features, up to 64 previous nodes), and binding their
-    primitives one by one cost the depth-8 batched round program 38.8 s of
-    tracing and lowering under ``fused`` at HIGGS's shape against 14.9 s
-    this way (one v5e host, PERF.md section 6, PR 28). Mosaic inlines the
-    call when it lowers: the kernel and its device time are the same."""
+    and binds one equation per feature afterwards: the bodies run in groups
+    of G features (``FEATURE_GROUP``; a group's features and up to 64
+    previous nodes are unrolled in Python), and binding their primitives one
+    by one cost the depth-8 batched round program 38.8 s of tracing and
+    lowering under ``fused`` at HIGGS's shape against 14.9 s this way (one
+    v5e host, PERF.md section 6, PR 28). Mosaic inlines the call when it
+    lowers: the kernel and its device time are the same."""
     M7F = jnp.uint32(0x7F7F7F7F)
     x = K4 ^ (row * jnp.uint32(0x01010101))                # [B/4, R]
     y = (~(((x & M7F) + M7F) | x | M7F)) >> jnp.uint32(7)
@@ -264,11 +304,24 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
     Histogram math is IDENTICAL to ``_make_int8_kernel(packed=True)`` at
     ``B = coarse_b``: same per-feature loop, same PT4 node-scatter, same
     per-row-block f32 accumulation order — the fused coarse histogram is
-    bit-identical to the unfused one."""
-    B, N, R, F = coarse_b, n_nodes, block_rows, n_feat
+    bit-identical to the unfused one.
 
-    # the two unrolled loop bodies, jitted for the reason ``_packed_hist``
-    # gives: traced once a kernel, one equation an iteration afterwards
+    The advance reads an arbitrary split feature, so the tile stays whole-F
+    and cannot be cut on the grid as ``build_hist_pallas`` cuts it. Past
+    ``FEATURE_GROUP`` features the histogram runs as a ``fori_loop`` over
+    even groups (``_feature_groups``), a group's features unrolled exactly
+    as the one group of a narrow matrix is: the rows of the int32 scratch
+    and the accumulator's leading axis take a dynamic index as they are.
+    The scratch and the accumulator are ``groups x group`` features long;
+    the rows past F (fewer than ``groups``) are zero ids, and the wrapper
+    drops their histograms."""
+    B, N, R, F = coarse_b, n_nodes, block_rows, n_feat
+    groups, group = _feature_groups(F)
+    F_pad = groups * group
+
+    # the two loop bodies (a previous node, a feature of a group), jitted for
+    # the reason ``_packed_hist`` gives: traced once a kernel, one equation an
+    # iteration afterwards
     @jax.jit
     def advance_below(bj, tj, dj, cj, j, pos_row, rel_prev, new_pos):
         # the SMEM scalars enter as int32 operands only: a scalar bool
@@ -292,7 +345,14 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
         def _():
             hist_ref[:] = jnp.zeros_like(hist_ref)
 
-        bins32[:] = bins_ref[:].astype(jnp.int32)          # [F, R] i32
+        if F_pad == F:
+            bins32[:] = bins_ref[:].astype(jnp.int32)      # [F, R] i32
+        else:
+            @pl.when(i == 0)
+            def _():
+                bins32[F:, :] = jnp.zeros((F_pad - F, R), jnp.int32)
+
+            bins32[0:F, :] = bins_ref[:].astype(jnp.int32)
 
         # ---- advance: route rows below the previous level's splits ----
         pos_row = pos_ref[:]                               # [1, R] i32
@@ -328,8 +388,18 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
         w_iota = jax.lax.broadcasted_iota(jnp.uint32, (B // 4, R), 0)
         K4 = (w_iota * jnp.uint32(4) * jnp.uint32(0x01010101)
               + jnp.uint32(0x03020100))
-        for f in range(F):
-            hist_ref[f] += coarse_hist(bins32[f:f + 1, :], K4, PT4)
+        if groups == 1:
+            for f in range(F):
+                hist_ref[f] += coarse_hist(bins32[f:f + 1, :], K4, PT4)
+        else:
+            def one_group(g, carry):
+                for f in range(group):
+                    at = g * group + f
+                    hist_ref[at] += coarse_hist(bins32[pl.ds(at, 1), :],
+                                                K4, PT4)
+                return carry
+
+            jax.lax.fori_loop(0, groups, one_group, 0)
 
     return kernel
 
@@ -384,11 +454,24 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                         can_split.astype(jnp.int32)])    # [4, n_prev]
 
     grid = (n_pad // R,)
+    groups, group = _feature_groups(F)
+    F_pad = groups * group
+    # The whole-F tile, double-buffered, and its int32 copy are 15.9 MB at
+    # F = 968 with two-byte ids: all but the default 16 MiB of scoped VMEM
+    # before the accumulator and the loop's operands (Mosaic asked 16.83M
+    # for the grouped body at 32 nodes). A grouped kernel states what its
+    # tile takes, with the default's room beside it; a narrow one keeps the
+    # default.
+    params = None if groups == 1 else pltpu.CompilerParams(
+        vmem_limit_bytes=F_pad * R * (4 + 2 * bins_t.dtype.itemsize)
+        + 16 * 2 ** 20)
+    count_hist_onehot("swar")
+    note_hist_body_features(group)
     with stage("kernel.fused_advance_coarse"):
         hist, pos_out = pl.pallas_call(
             _make_fused_kernel(F, n_prev, N, R, lo_prev, lo, missing_bin, B,
                                shift),
-            out_shape=[_out_struct((F, B, 2 * N), jnp.float32, bins_t, q),
+            out_shape=[_out_struct((F_pad, B, 2 * N), jnp.float32, bins_t, q),
                        _out_struct((1, n_pad), jnp.int32, bins_t, pos_t)],
             grid=grid,
             in_specs=[pl.BlockSpec((4, n_prev), lambda i: (0, 0),
@@ -399,17 +482,18 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                                    memory_space=pltpu.VMEM),
                       pl.BlockSpec((1, R), lambda i: (0, i),
                                    memory_space=pltpu.VMEM)],
-            out_specs=[pl.BlockSpec((F, B, 2 * N), lambda i: (0, 0, 0),
+            out_specs=[pl.BlockSpec((F_pad, B, 2 * N), lambda i: (0, 0, 0),
                                     memory_space=pltpu.VMEM),
                        pl.BlockSpec((1, R), lambda i: (0, i),
                                     memory_space=pltpu.VMEM)],
-            scratch_shapes=[pltpu.VMEM((F, R), jnp.int32)],
+            scratch_shapes=[pltpu.VMEM((F_pad, R), jnp.int32)],
+            compiler_params=params,
             interpret=interpret,
             name="fused_advance_coarse",
         )(splits, bins_t, q, pos_t)
     with stage("fold"):
         inv = jnp.repeat(1.0 / scale, N)[None, None, :]  # [1, 1, 2N]
-        hist = hist * inv
+        hist = (hist if F_pad == F else hist[:F]) * inv
         gh = hist.reshape(F, B, 2, N)
         return pos_out[0, :n], gh.transpose(3, 0, 1, 2)  # [N, F, B, 2]
 
@@ -585,10 +669,6 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     u4 = bool(packed_u4)
     if u4:
         F, n = packed_u4, bins_t.shape[1]
-        # packed transport exists for max_nbins <= 16, so the whole-F
-        # accumulator [F, B, 2N] is far inside the VMEM budget — one
-        # feature block, no F padding, nibble rows addressed in-kernel
-        feat_block = F
     else:
         F, n = bins_t.shape
     B, N = max_nbins, n_nodes
@@ -601,37 +681,31 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     R = min(block_rows, max(_round_up(n, 128), 128))
     n_pad = _round_up(max(n, R), R)
     if feat_block is None:
-        if precision == "int8x2":
+        if u4 or precision == "int8x2":
             # whole-F feature block when the [F, B, 2N] f32 accumulator
             # fits the VMEM budget: no padding features burn one-hot
             # builds (F=28 pads to 32 at feat_block=8 — a 12.5% tax) and
             # the node-scatter PT4 is built once per ROW block instead of
-            # once per (feature block, row block). Pallas block specs
-            # allow any first-dim size equal to the full array dim;
-            # otherwise fall back to a multiple of 8. Budget: the 16M
+            # once per (feature block, row block). Budget: the 16M
             # scoped-VMEM limit must also hold the one-hot plane, PT4,
             # double-buffered input blocks and SWAR temporaries — 8M for
             # the accumulator leaves that headroom (a 12M budget OOMed
             # the Mosaic stack at F=136, B=256, N=32: 17.53M > 16M).
-            budget = 8 * 2 ** 20
-            if F * B * 2 * N * 4 <= budget:
-                feat_block = F
-            else:
-                # split F into the fewest VMEM-fitting blocks, sized to
-                # MINIMIZE feature padding (a cap-sized block can pad F
-                # nearly 2x — every padded feature costs a one-hot build)
-                per_feat = B * 2 * N * 4
-                cap = max(8, (budget // per_feat) // 8 * 8)
-                n_blocks = -(-F // cap)
-                feat_block = min(cap, _round_up(-(-F // n_blocks), 8))
+            # (The packed transport exists for max_nbins <= 16, so its
+            # accumulator is far inside the budget.)
+            feat_block = max((8 * 2 ** 20) // (B * 2 * N * 4), 8)
         else:
             # f32/bf16 variants stage a [Fb*B, R] scratch — keep it small
             feat_block = 8
-    F_blk = min(feat_block, F)
+    # no body unrolls more than FEATURE_GROUP features (see the constant);
+    # a u4 block is whole (32, 128) tiles of bytes
+    F_blk = _feature_block(F, min(feat_block, FEATURE_GROUP),
+                           step=64 if u4 else 8)
     F_pad = _round_up(F, F_blk)
+    note_hist_body_features(F_blk)
     if n_pad != n or F_pad != F:
-        bins_t = jnp.pad(bins_t, ((0, 0 if u4 else F_pad - F),
-                                  (0, n_pad - n)))
+        rows_pad = (-(-F_pad // 2) - bins_t.shape[0]) if u4 else F_pad - F
+        bins_t = jnp.pad(bins_t, ((0, rows_pad), (0, n_pad - n)))
         gpair = jnp.pad(gpair, ((0, n_pad - n), (0, 0)))
         rel_pos = jnp.pad(rel_pos, (0, n_pad - n),
                           constant_values=n_nodes)  # padded rows inactive
@@ -640,8 +714,8 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     pos_t = rel_pos.astype(jnp.int32)[None, :]       # [1, n]
     grid = (F_pad // F_blk, n_pad // R)
 
-    bins_rows = bins_t.shape[0]                      # ceil(F/2) when u4
-    bins_spec = pl.BlockSpec((bins_rows if u4 else F_blk, R),
+    # a u4 block's nibble rows are addressed in-kernel: ceil(F_blk/2) bytes
+    bins_spec = pl.BlockSpec((-(-F_blk // 2) if u4 else F_blk, R),
                              lambda j, i: (j, i),
                              memory_space=pltpu.VMEM)
     vec2_spec = pl.BlockSpec((2, R), lambda j, i: (0, i),
@@ -667,6 +741,7 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         # matrices with a missing slot (B = 257) or tiny max_bin fall back
         # to the compare build
         packed = B % 4 == 0 and B <= 256
+        count_hist_onehot("swar" if packed else "compare")
         with stage("kernel.build_hist_int8"):
             out = pl.pallas_call(
                 _make_int8_kernel(F_blk, B, N, R, packed=packed, u4=u4),
@@ -683,6 +758,7 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
             inv = jnp.repeat(1.0 / scale, N)[None, None, :]  # [1, 1, 2N]
             out = out * inv
     else:
+        count_hist_onehot("compare")
         with stage("kernel.build_hist"):
             out = pl.pallas_call(
                 _make_kernel(F_blk, B, N, R, precision, u4=u4),

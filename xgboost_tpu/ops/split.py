@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 from ..tree.param import TrainParam, calc_gain
@@ -317,6 +318,9 @@ COARSE_SPAN = 16   # fine bins per coarse bin
 COARSE_B = 20      # coarse hist slots: 16 real + 3 pad + missing at 19
 WINDOW = 32        # refined fine bins: the 2 spans around the boundary
 SYN_B = 46         # synthetic slots: 14 lower + 32 fine + (upper folded)
+# features past which ``assemble_two_level`` takes its slots by selects and
+# not by a gather (its docstring)
+SELECT_TAKE_FEATURES = 256
 
 
 def coarse_bin_ids(bins_i32: jnp.ndarray, missing_bin: int) -> jnp.ndarray:
@@ -409,7 +413,16 @@ def assemble_two_level(hist_c: jnp.ndarray, hist_r: jnp.ndarray,
     window's fine bins, slots [w+32, 46) the coarse bins above it, and
     the last slot the missing mass. Cumulative sums over this layout are
     exact, so ``evaluate_splits`` scores every coarse boundary and every
-    in-window fine boundary exactly."""
+    in-window fine boundary exactly.
+
+    A wide matrix takes its slots by a select-and-sum over the source's
+    slots, in integers over the entries' bit patterns (one hit a slot, so
+    the sum IS the entry, bit for bit, a signed zero too): the TPU
+    compiler's time for the gather grows with nodes x features, 21.8 s for
+    this function alone at 64 nodes x 968 features against 1.1 s for the
+    selects, 47 s over a depth-8 tree's levels (PERF.md section 6, PR 36).
+    Up to ``SELECT_TAKE_FEATURES`` features the gather stays, and with it
+    the programs those matrices compile to."""
     s = jnp.arange(SYN_B, dtype=jnp.int32)[None, None, :]
     w = window[:, :, None]
     in_fine = (s >= w) & (s < w + WINDOW)
@@ -417,7 +430,13 @@ def assemble_two_level(hist_c: jnp.ndarray, hist_r: jnp.ndarray,
     f_idx = jnp.clip(s - w, 0, WINDOW - 1)
 
     def take(h, idx):
-        return jnp.take_along_axis(h, idx[..., None], axis=2)
+        if h.shape[1] <= SELECT_TAKE_FEATURES:
+            return jnp.take_along_axis(h, idx[..., None], axis=2)
+        hit = idx[..., None] == jnp.arange(h.shape[2], dtype=jnp.int32)
+        bits = jax.lax.bitcast_convert_type(h, jnp.int32)[:, :, None, :, :]
+        return jax.lax.bitcast_convert_type(
+            jnp.sum(jnp.where(hit[..., None], bits, 0), axis=3),
+            h.dtype)                                   # [N, F, slots, 2]
 
     syn = jnp.where(in_fine[..., None], take(hist_r, f_idx),
                     take(hist_c, c_idx))
