@@ -283,7 +283,8 @@ def phase_train(ctx) -> dict:
     from xgboost_tpu import core
     from xgboost_tpu.metric.auc import binary_roc_auc
     from xgboost_tpu.obs.metrics import (degrade_counts, eval_walk_counts,
-                                         grow_schedule_counts)
+                                         grow_schedule_counts,
+                                         hist_dot_counts, hist_dot_rows)
     from xgboost_tpu.tree.grow import resolve_schedule
 
     sz = ctx["sizes"]
@@ -388,10 +389,15 @@ def phase_train(ctx) -> dict:
             [f'xtpu_grow_schedule_total{{schedule="{k}"}} {v}'
              for k, v in traced.items()]
             + [f'xtpu_eval_walk_total{{kind="{k}"}} {v}'
-               for k, v in walks.items()]))
+               for k, v in walks.items()]
+            + [f'xtpu_hist_dot_total{{form="{k}"}} {v}'
+               for k, v in hist_dot_counts().items()]
+            + [f"xtpu_hist_dot_rows {hist_dot_rows()}"]))
     ctx.update(bst=bst, Xh=Xh)
     return {"rows": sz.rows, "schedule": sched.name,
             "grow_schedule_total": traced, "eval_walk_total": walks,
+            "hist_dot_total": hist_dot_counts(),
+            "hist_dot_rows": hist_dot_rows(),
             "auc": round(auc, 4),
             "auc_floor": sz.auc_floor, "dispatches": got,
             "tpu_custom_call": custom_calls,

@@ -116,6 +116,94 @@ def test_fused_advance_coarse_in_feature_groups_is_bit_identical(
     assert float(np.abs(np.asarray(hist_g)[:, F - 1, COARSE_B - 1]).sum()) > 0
 
 
+def boundary_args(F, dtype, n_prev, n=300):
+    """A level boundary under ``n_prev`` splitting nodes: every previous
+    node splits, the first on the last feature; rows above the previous
+    level (stopped: on no node of either level) and a padded row tail."""
+    missing = MISSING[dtype]
+    rng = np.random.RandomState(F + n_prev)
+    bins = rng.randint(0, missing, (F, n)).astype(dtype)
+    bins[rng.rand(F, n) < 0.3] = missing
+    gpair = rng.randn(n, 2).astype(np.float32)
+    gpair[:, 1] = np.abs(gpair[:, 1])
+    lo_prev = n_prev - 1
+    positions = rng.randint(lo_prev, lo_prev + n_prev, n).astype(np.int32)
+    if lo_prev:
+        positions[rng.rand(n) < 0.1] = 0
+    feat = np.r_[F - 1, rng.randint(0, F, n_prev - 1)].astype(np.int32)
+    thr = rng.randint(0, missing, n_prev).astype(np.int32)
+    args = tuple(jnp.asarray(a) for a in (
+        bins, gpair, positions, feat, thr, rng.rand(n_prev) < 0.5,
+        np.ones(n_prev, bool)))
+    return args, dict(lo_prev=lo_prev, n_prev=n_prev, lo=2 * n_prev - 1,
+                      n_level=2 * n_prev, missing_bin=missing,
+                      block_rows=128)
+
+
+# the boundary sweep from the first boundary to the widest its gate admits
+# (64 previous nodes), at widths that are no multiple of a dot's 8 features
+@pytest.mark.parametrize("dtype", [np.uint8, np.uint16])
+@pytest.mark.parametrize("n_prev", [1, 4, 16, 64])
+@pytest.mark.parametrize("F", [5, 28, 67])
+def test_fused_sweep_stacked_dot_equals_the_dot_a_feature(monkeypatch, F,
+                                                          n_prev, dtype):
+    args, kw = boundary_args(F, dtype, n_prev)
+    assert ph._dot_features(COARSE_B, 2 * n_prev) == 8
+    pos_s, hist_s = fresh(ph.fused_advance_coarse_pallas, interpret=True,
+                          **kw)(*args)
+    monkeypatch.setattr(ph, "_dot_features", lambda B, N: 1)
+    pos_f, hist_f = fresh(ph.fused_advance_coarse_pallas, interpret=True,
+                          **kw)(*args)
+    assert hist_s.shape == (2 * n_prev, F, COARSE_B, 2)
+    np.testing.assert_array_equal(np.asarray(pos_s), np.asarray(pos_f))
+    np.testing.assert_array_equal(np.asarray(hist_s), np.asarray(hist_f))
+    assert float(np.abs(np.asarray(hist_s)[:, F - 1, COARSE_B - 1]).sum()) > 0
+
+
+def test_fused_sweep_in_feature_groups_holds_whole_and_short_dots(
+        monkeypatch):
+    """A group of the in-kernel loop that is no multiple of a dot's features
+    ends in a short dot, and holds an even number of features so that its
+    rows of the accumulator start a sublane tile: 21 features are 2 groups
+    of 12 (8 + 4 a group), the last three of them padding."""
+    monkeypatch.setattr(ph, "FEATURE_GROUP", 11)
+    args, kw = boundary_args(21, np.uint16, 2)
+    assert ph._feature_groups(21) == (2, 12)
+    pos_s, hist_s = fresh(ph.fused_advance_coarse_pallas, interpret=True,
+                          **kw)(*args)
+    monkeypatch.setattr(ph, "_dot_features", lambda B, N: 1)
+    pos_f, hist_f = fresh(ph.fused_advance_coarse_pallas, interpret=True,
+                          **kw)(*args)
+    np.testing.assert_array_equal(np.asarray(pos_s), np.asarray(pos_f))
+    np.testing.assert_array_equal(np.asarray(hist_s), np.asarray(hist_f))
+
+
+def test_bench_tool_times_the_candidate_dot_forms(tmp_path):
+    """``tools/bench_hist_groups.py --forms`` rehearsed: the shipped kernels
+    and every candidate form (``tools/hist_dot_forms.py``) against the dot a
+    feature, bit for bit, at a width that ends in a short dot."""
+    import json
+
+    from tools import bench_hist_groups
+
+    out = tmp_path / "forms.json"
+    candidates = ["stacked:dense:8", "stacked:pad:128", "held:pad:128",
+                  "held:dense:32"]
+    assert bench_hist_groups.main([
+        "--rehearse", "--forms", ",".join(candidates), "--shapes", "11",
+        "--rows", "300", "--nodes", "2", "--widths", "20,36", "--reps", "1",
+        "--out", str(out)]) == 0
+    rows = json.loads(out.read_text())
+    compared = [r for r in rows if "equal" in r]
+    assert all(r["equal"] for r in compared)
+    assert ({(r["kernel"], r["form"]) for r in compared}
+            == {("fused_advance_coarse", "shipped"),
+                ("build_hist_int8", "shipped")}
+            | {("hist_form", c) for c in candidates})
+    assert {r["dot_features"] for r in compared
+            if r["form"] == "shipped"} == {ph.DOT_FEATURES}
+
+
 def test_packed_u4_in_feature_blocks_is_bit_identical(monkeypatch):
     """The u4 page transport past G features: whole-byte blocks on the grid,
     the nibble rows addressed inside each."""
@@ -169,7 +257,9 @@ def test_a_kernel_body_holds_no_more_dots_at_968_than_at_G(kernel):
     at_g, wide = dots(G), dots(968)
     assert 0 < wide <= at_g
     if kernel != "build_hist":         # the f32 body stages 8 features a dot
-        assert at_g == G
+        # a dot contracts eight features' one-hots (_dot_features) at the
+        # refine width of build_args and at the coarse width alike
+        assert at_g == -(-G // ph.DOT_FEATURES)
 
 
 def test_gauge_reads_what_the_bodies_unroll():

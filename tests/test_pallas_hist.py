@@ -15,9 +15,11 @@ import os
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from xgboost_tpu.ops.histogram import build_hist_segment
+from xgboost_tpu.ops.pallas import histogram as ph
 from xgboost_tpu.ops.pallas.histogram import build_hist_pallas
 
 
@@ -125,6 +127,93 @@ def test_int8x2_order_independence_interpret():
         bins[perm].T, gpair[perm], rel[perm], n_nodes, max_nbins,
         precision="int8x2", interpret=True))
     np.testing.assert_array_equal(a, b)
+
+
+def _under_rule(monkeypatch, rule, *arrays, **static):
+    """``build_hist_pallas``'s body under ``_dot_features = rule`` and a jit
+    of its own: nothing jax cached under the module's rule is served."""
+    monkeypatch.setattr(ph, "_dot_features", rule)
+    return np.asarray(jax.jit(
+        lambda *a: ph.build_hist_pallas.__wrapped__(
+            *a, interpret=True, **static))(*arrays))
+
+
+def _narrow(F, B, N, ids, n=300):
+    """A two-level level's input: ids under B with the missing share in the
+    last slot, some rows on no node, and a row tail the block pads."""
+    rng = np.random.RandomState(F * B + N)
+    bins = rng.randint(0, B - 1, (F, n)).astype(ids)
+    bins[rng.rand(F, n) < 0.3] = B - 1
+    gpair = rng.randn(n, 2).astype(np.float32)
+    gpair[:, 1] = np.abs(gpair[:, 1])
+    rel = rng.randint(0, N + 1, n).astype(np.int32)     # N: inactive
+    return jnp.asarray(bins), jnp.asarray(gpair), jnp.asarray(rel)
+
+
+# the two-level search's coarse and refine widths at every width the
+# benchmark runs narrow enough to interpret, from the root to the widest
+# level: 5 features are one short dot, 28 and 67 end in one (of 4 and 3)
+@pytest.mark.parametrize("ids", [np.uint8, np.uint16])
+@pytest.mark.parametrize("N", [1, 8, 32, 128])
+@pytest.mark.parametrize("B", [20, 36])
+@pytest.mark.parametrize("F", [5, 28, 67])
+def test_stacked_dot_equals_the_dot_a_feature_bit_for_bit(monkeypatch, F, B,
+                                                          N, ids):
+    """A group of features' one-hots in one dot (``_dot_features``): a row
+    block's sums are exact int32 whatever the grouping, so the histogram is
+    the dot a feature's, every bit."""
+    arrays = _narrow(F, B, N, ids)
+    assert ph._dot_features(B, N) == ph.DOT_FEATURES == 8 and F % 8
+    static = dict(n_nodes=N, max_nbins=B, block_rows=128)
+    stacked = _under_rule(monkeypatch, ph._dot_features, *arrays, **static)
+    feature = _under_rule(monkeypatch, lambda B, N: 1, *arrays, **static)
+    assert stacked.shape == (N, F, B, 2)
+    np.testing.assert_array_equal(stacked, feature)
+    assert float(np.abs(stacked[:, F - 1, B - 1]).sum()) > 0
+
+
+def test_stacked_dot_over_the_u4_transport_is_bit_identical(monkeypatch):
+    """The packed page's nibble rows feed the stacked dot as the byte rows
+    do (16 slots: two features a vreg of words)."""
+    F, n, B, N = 11, 300, 16, 4
+    bins, gpair, rel = _narrow(F, B, N, np.uint8, n=n)
+    even = np.concatenate([np.asarray(bins), np.zeros((1, n), np.uint8)])
+    packed = jnp.asarray(even[0::2] | even[1::2] << 4)   # [ceil(F/2), n]
+    static = dict(n_nodes=N, max_nbins=B)
+    assert ph._dot_features(B, N) == 8
+    got = _under_rule(monkeypatch, ph._dot_features, packed, gpair, rel,
+                      packed_u4=F, **static)
+    want = _under_rule(monkeypatch, lambda B, N: 1, bins, gpair, rel,
+                       **static)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("B,N,form,rows", [
+    (20, 8, "stacked", 160), (36, 128, "stacked", 288),
+    (16, 1, "stacked", 128), (64, 4, "stacked", 512),
+    # a one-hot wider than 64 slots streams as long as a tile takes to
+    # latch: the one-pass schedules at 256 bins keep the dot a feature
+    (68, 4, "feature", 68), (256, 4, "feature", 256),
+    # 257 slots (a missing slot past a byte) take the compare build
+    (257, 2, "feature", 257),
+])
+def test_dot_counter_and_gauge_read_what_was_traced(B, N, form, rows):
+    from xgboost_tpu.obs import metrics as obs_metrics
+
+    assert (ph._dot_features(B, N) > 1) == (form == "stacked")
+    registry = obs_metrics.get_registry()
+    registry.set_gauge("xtpu_hist_dot_rows", 0)
+    before = obs_metrics.hist_dot_counts()
+    bins, gpair, rel = _narrow(3, B, N, np.uint16, n=64)
+    jax.make_jaxpr(lambda *a: ph.build_hist_pallas.__wrapped__(
+        *a, n_nodes=N, max_nbins=B, interpret=True))(bins, gpair, rel)
+    after = obs_metrics.hist_dot_counts()
+    other = "feature" if form == "stacked" else "stacked"
+    assert after.get(form, 0) == before.get(form, 0) + 1
+    assert after.get(other, 0) == before.get(other, 0)
+    assert obs_metrics.hist_dot_rows() == rows
+    with pytest.raises(ValueError):
+        obs_metrics.count_hist_dot("held", 128)
 
 
 @pytest.mark.skipif(os.environ.get("BENCH_TPU") != "1",

@@ -19,7 +19,8 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from xgboost_tpu.context import DATA_AXIS
-from xgboost_tpu.obs.metrics import grow_epilogue_counts, grow_schedule_counts
+from xgboost_tpu.obs.metrics import (grow_epilogue_counts,
+                                     grow_schedule_counts, hist_dot_counts)
 from xgboost_tpu.tree.grow import AUTO_COARSE_MIN_ROWS, TreeGrower
 from xgboost_tpu.tree.param import TrainParam
 from xgboost_tpu.tree.programs import _NumericCuts
@@ -59,6 +60,10 @@ def mesh():
     # the click-log job's width (benchmark cell criteo-ctr.mesh-train): 67
     # features, no multiple of the sublane's 8, at the published depth
     ("auto", "fused", 8, 2 * 8 + 1, "kernel", 67),
+    # the ranking job's width and depth (istella-letor.train): 55 stacked
+    # dots of four features a coarse body, a (feature block, row block)
+    # grid for the refine build, every level inside DENSE_LEVEL_MAX
+    ("auto", "fused", 6, 2 * 6, "dense", 220),
 ])
 def test_row_split_grow_program_compiles_for_v5e(monkeypatch, mesh, method,
                                                  schedule, depth, kernels,
@@ -75,6 +80,7 @@ def test_row_split_grow_program_compiles_for_v5e(monkeypatch, mesh, method,
 
     before = grow_schedule_counts().get(schedule, 0)
     before_epilogue = grow_epilogue_counts().get(epilogue, 0)
+    dots = hist_dot_counts()
     compiled = grower.sharded_program().lower(
         arg((rows, FEATURES), jnp.uint8, P(DATA_AXIS, None)),
         arg((rows, 2), jnp.float32, P(DATA_AXIS, None)),
@@ -83,6 +89,11 @@ def test_row_split_grow_program_compiles_for_v5e(monkeypatch, mesh, method,
         None, None, None).compile()     # monotone, constraint sets, cat
     assert grow_schedule_counts().get(schedule, 0) == before + 1
     assert grow_epilogue_counts().get(epilogue, 0) == before_epilogue + 1
+    # every histogram kernel of the two-level search (20 and 36 slots)
+    # contracts a group of features a dot, none a feature
+    assert hist_dot_counts().get("feature", 0) == dots.get("feature", 0)
+    if (depth, FEATURES) in ((3, 28), (8, 67), (6, 220)):   # new shapes
+        assert hist_dot_counts()["stacked"] > dots.get("stacked", 0)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= kernels
     assert ("xtpu.kernel.advance_leaf" in text) == (epilogue == "kernel")
@@ -162,6 +173,7 @@ def test_wide_missing_kernels_compile_for_v5e(mesh, kernel, nodes, width):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     get_registry().set_gauge("xtpu_hist_body_features", 0)
+    dots = hist_dot_counts()
     if kernel == "fused_advance_coarse":
         prev = nodes // 2
         fn = jax.jit(lambda b, g, p, f, t, d, c: ph.fused_advance_coarse_pallas(
@@ -187,3 +199,5 @@ def test_wide_missing_kernels_compile_for_v5e(mesh, kernel, nodes, width):
     assert "tpu_custom_call" in text and f"xtpu.kernel.{kernel}" in text
     if kernel != "advance_leaf":
         assert 0 < hist_body_features() <= ph.FEATURE_GROUP
+        assert hist_dot_counts() == {**dots,
+                                     "stacked": dots.get("stacked", 0) + 1}

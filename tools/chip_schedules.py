@@ -58,7 +58,8 @@ def main() -> int:
     from xgboost_tpu.metric.auc import binary_roc_auc
     from xgboost_tpu.obs.metrics import (eval_walk_counts, get_registry,
                                          grow_epilogue_counts,
-                                         grow_schedule_counts)
+                                         grow_schedule_counts,
+                                         hist_dot_counts, hist_dot_rows)
     from xgboost_tpu.tree.grow import resolve_schedule
 
     dev = jax.devices()[0]
@@ -80,7 +81,7 @@ def main() -> int:
         runs_as = method if lossguide else resolve_schedule(
             method, args.rows, binned.max_nbins, binned.has_missing,
             numeric=True).name
-        walls = []
+        walls, dots = [], hist_dot_counts()
         for _ in ("cold", "warm"):
             t0 = time.perf_counter()
             bst = xgb.train(params, dtrain, args.rounds, evals=evals,
@@ -97,13 +98,18 @@ def main() -> int:
                      "depth": args.depth, "rounds": args.rounds,
                      "auc": round(auc, 4),
                      "cold_s": round(walls[0], 2),
-                     "warm_s": round(walls[1], 2)})
+                     "warm_s": round(walls[1], 2),
+                     # the histogram kernels this method traced, by dot form
+                     "hist_dot_total": {
+                         k: v - dots.get(k, 0)
+                         for k, v in hist_dot_counts().items()}})
         print(f"[chip_schedules] {rows[-1]}", flush=True)
     print(json.dumps({
         "ok": True, "smoke_timings": rows,
         "grow_schedule_total": grow_schedule_counts(),
         "grow_epilogue_total": grow_epilogue_counts(),
         "eval_walk_total": eval_walk_counts(),
+        "hist_dot_rows": hist_dot_rows(),
         "tree_flushes_total": int(get_registry().get(
             "xtpu_tree_flushes_total", ())),
         "device": {"platform": dev.platform, "kind": dev.device_kind,

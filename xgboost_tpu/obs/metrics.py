@@ -412,6 +412,38 @@ def hist_onehot_counts() -> Dict[str, int]:
     return {k: int(v) for k, v in _by_label(_HIST_ONEHOT, "build").items()}
 
 
+_HIST_DOT = "xtpu_hist_dot_total"
+_HIST_DOT_ROWS = "xtpu_hist_dot_rows"
+HIST_DOT_FORMS = ("feature", "stacked")
+
+
+def count_hist_dot(form: str, rows: int) -> None:
+    """One trace of a Pallas histogram kernel's wrapper, by the form of its
+    histogram dot (``ops/pallas/histogram.py _dot_features``): ``feature``
+    (one feature's one-hot a dot) or ``stacked`` (eight features' SWAR
+    one-hots, unpadded, in one dot: every width up to 64 slots, the
+    two-level search's 20 and 36 among them). ``rows`` is the one-hot rows the
+    kernel's dot contracts; the gauge keeps the most seen in the process.
+    Counted as ``count_hist_onehot`` counts: a shape traced before is not
+    counted again."""
+    if form not in HIST_DOT_FORMS:
+        raise ValueError(f"unknown histogram dot form {form!r}")
+    _registry.inc(_HIST_DOT, labels=(("form", form),),
+                  help="Pallas histogram kernels traced, by dot form")
+    if rows > _registry.get(_HIST_DOT_ROWS):
+        _registry.set_gauge(_HIST_DOT_ROWS, rows,
+                            help="most one-hot rows one traced histogram "
+                                 "dot contracts")
+
+
+def hist_dot_counts() -> Dict[str, int]:
+    return {k: int(v) for k, v in _by_label(_HIST_DOT, "form").items()}
+
+
+def hist_dot_rows() -> int:
+    return int(_registry.get(_HIST_DOT_ROWS))
+
+
 _HIST_BODY = "xtpu_hist_body_features"
 
 

@@ -5,17 +5,24 @@ atomics, ``src/tree/gpu_hist/histogram.cu:129-311``). TPUs have no fast
 scatter, so the kernel keeps the histogram-as-matmul formulation but fuses
 everything XLA would materialise:
 
-- the per-feature bin one-hot is built directly in its transposed (MXU-ready)
-  ``[B, R]`` layout in VMEM from a ``[F, n]`` bin matrix and never touches
-  HBM. The default int8x2 kernel interleaves build and contraction
-  per-feature so Mosaic pipelines the VPU one-hot of feature f+1 against
-  the MXU dot of feature f (staging a whole ``[Fb*B, R]`` block for one
-  big matmul — still used by the f32/bf16 variants — serialises the two
-  units and measured 1.7x slower);
+- the bin one-hot is built directly in its transposed (MXU-ready) ``[B, R]``
+  layout in VMEM from a ``[F, n]`` bin matrix and never touches HBM. The
+  default int8x2 kernel interleaves build and contraction a DOT at a time,
+  so Mosaic pipelines the VPU one-hot of dot d+1 against the MXU dot d
+  (staging a whole ``[Fb*B, R]`` block for one big matmul, still used by
+  the f32/bf16 variants, serialises the two units and measured 1.7x slower
+  at B = 256). How many features a dot contracts follows from the width
+  alone (``_dot_features``): at B = 256 (the one-pass schedules) one
+  feature's one-hot fills the MXU's passes and a dot is a feature, as
+  measured at 1M x 28 x 256; at the two-level search's 20 and 36 slots a
+  feature's rows leave most of each latched ``PT4`` tile unused, and eight
+  features' one-hots, stacked without padding, share it (PERF.md section 6,
+  PR 37: 0.39 to 0.81 of the dot a feature's time, the same bits);
 - the node-scatter matrix ``P^T [2N, R]`` (rows scattered to their tree node,
   times (g, h)) is built once per row block and shared by every feature;
-- the accumulator ``[Fb, B, 2N]`` lives in VMEM across the row-block grid axis
-  and only hits HBM once per feature block;
+- the accumulator (``[Fb, B, 2N]``; ``[Fb * B, 2N]`` under a stacked dot,
+  whose features are one slice of rows) lives in VMEM across the row-block
+  grid axis and only hits HBM once per feature block;
 - a body runs its features in groups of at most ``FEATURE_GROUP`` (G): the
   per-feature steps of a group are unrolled in Python, and a matrix wider than
   G takes more groups (feature blocks on the grid, or a loop inside the
@@ -56,7 +63,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...obs.metrics import count_hist_onehot, note_hist_body_features
+from ...obs.metrics import (count_hist_dot, count_hist_onehot,
+                            note_hist_body_features)
 from ...obs.trace import mesh_scope, stage
 
 # The most features a kernel body unrolls. A body's per-feature steps are
@@ -78,9 +86,13 @@ def _round_up(x: int, m: int) -> int:
 def _feature_groups(F: int) -> tuple:
     """(groups, features a group): the fewest groups of at most
     ``FEATURE_GROUP`` features, evenly sized, so that the padding past F is
-    under one feature a group. One group of F features while F fits."""
+    under two features a group. One group of F features while F fits; more
+    groups hold an even number each, so that a group's first row in a
+    ``[F * B, 2N]`` accumulator (B a multiple of 4) is a whole sublane
+    tile's."""
     groups = max(-(-F // FEATURE_GROUP), 1)
-    return groups, -(-F // groups)
+    group = -(-F // groups)
+    return groups, group if groups == 1 else _round_up(group, 2)
 
 
 def _feature_block(F: int, cap: int, step: int = 8) -> int:
@@ -109,29 +121,134 @@ def _out_struct(shape, dtype, *inputs) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-@jax.jit
-def _packed_hist(row, K4, PT4):
-    """One feature's histogram inside a fixed-point kernel: the packed-SWAR
-    one-hot of ``row`` ([1, R] u32 bin ids; ``_make_int8_kernel`` explains
-    the detect) against ``PT4`` ([4N, R] int8 byte planes) on the int8 MXU,
-    recombined to [B, 2N] f32. Jitted so that a kernel body traces it ONCE
-    and binds one equation per feature afterwards: the bodies run in groups
-    of G features (``FEATURE_GROUP``; a group's features and up to 64
+# Features whose one-hots a stacked dot contracts: eight features of W words
+# each are W whole (8, 128) uint32 vregs, whatever W is.
+DOT_FEATURES = 8
+
+
+def _dot_features(B: int, N: int) -> int:
+    """Features whose SWAR one-hots one fixed-point dot contracts, from the
+    kernel's static shapes alone; 1 is the dot a feature.
+
+    ``PT4``, the node-scatter operand, is the same for every feature of a row
+    block, and the MXU holds it a 128 x 128 tile at a time. Measured alone
+    on a v5e (PERF.md section 6, PR 37): a latched tile costs what streaming
+    about 64 int8 rows through it costs, and a feature's dot streams its B
+    slots padded to whole 32-row int8 vregs, so at the two-level search's
+    widths a dot a feature pays 64 rows a tile for 20 slots, or for 36 slots
+    padded to 64. Stacked WITHOUT padding (``_packed_hist``), eight
+    features' one-hots stream 8 B rows through each tile they latch and the
+    time follows the slots: 0.39 of the dot a feature's at 20 slots and 128
+    nodes, 0.60 at 36, 0.80 / 0.68 at the root. Measured at 20 and 36
+    slots on the chip (16, the u4 page's, compiles and equals). A one-hot
+    wider than 64 slots streams as long as a tile takes to latch by itself
+    and keeps the dot a feature (the one-pass schedules at 256 bins:
+    stacking two to four features there gained nothing,
+    docs/performance.md)."""
+    return DOT_FEATURES if B <= 64 else 1
+
+
+def _note_dot(B: int, N: int, packed: bool = True) -> None:
+    """Count a traced kernel's dot form and the one-hot rows of its dot."""
+    G = _dot_features(B, N) if packed else 1
+    count_hist_dot("stacked" if G > 1 else "feature", G * B)
+
+
+def _swar_words(B: int, G: int, R: int):
+    """The constants of ``_packed_hist``'s one-hot for a dot of G features of
+    B slots, a tuple of uint32 arrays each: ``K4``, word w's four slot ids a
+    byte each (the words of a feature follow each other, the features follow
+    each other), and ``owner``, the feature of the dot that word w belongs
+    to. The dot a feature takes one ``[B/4, R]`` array of words and no
+    owners; a stacked dot takes them a vreg of eight words at a time (each
+    from its own iota: Mosaic does not slice one)."""
+    W = B // 4
+    if G == 1:
+        at = jax.lax.broadcasted_iota(jnp.uint32, (W, R), 0)
+        return (at * jnp.uint32(4 * 0x01010101) + jnp.uint32(0x03020100),), ()
+    sub = jax.lax.broadcasted_iota(jnp.uint32, (8, R), 0)
+    K4, owner = [], []
+    for v in range(G * W // 8):
+        at = sub + jnp.uint32(8 * v)
+        K4.append((at % jnp.uint32(W)) * jnp.uint32(4 * 0x01010101)
+                  + jnp.uint32(0x03020100))
+        owner.append(at // jnp.uint32(W))
+    return tuple(K4), tuple(owner)
+
+
+@functools.partial(jax.jit, static_argnames=("B",))
+def _packed_hist(rows, K4, owner, PT4, *, B):
+    """The histograms of ``len(rows)`` features inside a fixed-point kernel:
+    the packed-SWAR one-hots of ``rows`` (each [1, R] u32 bin ids under B;
+    ``_make_int8_kernel`` explains the detect), stacked without padding,
+    against ``PT4`` ([4N, R] int8 byte planes) in ONE dot on the int8 MXU,
+    recombined to [len(rows) * B, 2N] f32, feature k's slots at rows
+    k*B .. (k+1)*B-1. A row block's sums are exact int32 however the
+    features are grouped, so every grouping gives the same bits.
+
+    A feature's B/4 one-hot words are no whole vreg (5 sublanes of 8 at 20
+    slots, 9 of 16 at 36), and concatenating them would shift sublanes. So
+    the stack is built a vreg at a time: vreg v holds words 8v .. 8v+7 of
+    the stack, which belong to two or three features; their ids are
+    broadcast over the sublanes and selected by ``owner``, and ONE detect
+    chain runs on the dense vreg. The stack is then whole vregs and its
+    concatenation is free.
+
+    Jitted so that a kernel body traces it ONCE (twice where a last dot is
+    short) and binds one equation a dot afterwards: the bodies run in
+    groups of G features (``FEATURE_GROUP``; a group's features and up to 64
     previous nodes are unrolled in Python), and binding their primitives one
     by one cost the depth-8 batched round program 38.8 s of tracing and
     lowering under ``fused`` at HIGGS's shape against 14.9 s this way (one
     v5e host, PERF.md section 6, PR 28). Mosaic inlines the call when it
     lowers: the kernel and its device time are the same."""
     M7F = jnp.uint32(0x7F7F7F7F)
-    x = K4 ^ (row * jnp.uint32(0x01010101))                # [B/4, R]
+    W, g, R = B // 4, len(rows), K4[0].shape[1]
+    spread = [row * jnp.uint32(0x01010101) for row in rows]    # [1, R]
+    if not owner:                                  # the dot a feature
+        x = K4[0] ^ spread[0]                      # [B/4, R]
+    else:
+        vregs = []
+        for v in range(-(-g * W // 8)):
+            first, last = 8 * v // W, min((8 * v + 7) // W, g - 1)
+            ids = jnp.broadcast_to(spread[last], (8, R))
+            for f in range(last - 1, first - 1, -1):
+                ids = jnp.where(owner[v] == jnp.uint32(f),
+                                jnp.broadcast_to(spread[f], (8, R)), ids)
+            vregs.append(K4[v] ^ ids)
+        x = vregs[0] if len(vregs) == 1 else jnp.concatenate(vregs, axis=0)
     y = (~(((x & M7F) + M7F) | x | M7F)) >> jnp.uint32(7)
-    oh = pltpu.bitcast(y, jnp.int8)                        # [B, R]
+    oh = pltpu.bitcast(y, jnp.int8)                # [>= g*B, R]
     acc4 = jax.lax.dot_general(
         oh, PT4, _CONTRACT_LAST,
-        preferred_element_type=jnp.int32)                  # [B, 4N]
+        preferred_element_type=jnp.int32)          # [>= g*B, 4N]
     n2 = PT4.shape[0] // 2
-    return (acc4[:, :n2].astype(jnp.float32) * 256.0
-            + acc4[:, n2:].astype(jnp.float32))
+    return (acc4[:g * B, :n2].astype(jnp.float32) * 256.0
+            + acc4[:g * B, n2:].astype(jnp.float32))
+
+
+def _acc_shapes(F_blk: int, F_pad: int, B: int, N: int) -> tuple:
+    """(block, whole) shapes of a fixed-point kernel's SWAR accumulator over
+    F_pad features in blocks of F_blk: ``[F, B, 2N]`` for the dot a feature,
+    ``[F * B, 2N]`` for the stacked dot (``_accumulate``)."""
+    if _dot_features(B, N) > 1:
+        return (F_blk * B, 2 * N), (F_pad * B, 2 * N)
+    return (F_blk, B, 2 * N), (F_pad, B, 2 * N)
+
+
+def _accumulate(out_ref, at, rows, B, K4, owner, PT4):
+    """Add the histograms of the features ``at .. at + len(rows) - 1``, one
+    dot: into ``out_ref[at]`` of a ``[F, B, 2N]`` accumulator (the dot a
+    feature), or into rows ``at * B ..`` of a ``[F * B, 2N]`` one (a stacked
+    dot's features follow each other, so their rows are ONE aligned slice;
+    the wrapper reshapes outside the kernel)."""
+    val = _packed_hist(tuple(rows), K4, owner, PT4, B=B)
+    if len(out_ref.shape) == 3:
+        out_ref[at] += val
+    else:
+        # eight features' rows, or an even group's, start a sublane tile
+        first = at * B if isinstance(at, int) else pl.multiple_of(at * B, 8)
+        out_ref[pl.ds(first, len(rows) * B), :] += val
 
 
 def _u4_row(bins_ref, f):
@@ -251,22 +368,24 @@ def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
         # feed dominates)
         PT4 = jnp.concatenate([g_hi, h_hi, g_lo, h_lo], axis=0)  # [4N, R] i8
 
-        # Per-FEATURE one-hot + dot (not one big [Fb*B, R] staged matmul):
-        # Mosaic pipelines the VPU one-hot build of feature f+1 against the
-        # MXU dot of feature f, overlapping the kernel's two bound units —
-        # measured 8.3 -> ~4.8 ms/level at 1M x 28 x 256 on v5e.
+        # One-hot + dot, ``_dot_features`` features at a time (not one big
+        # [Fb*B, R] staged matmul): the dots are unrolled, so Mosaic
+        # pipelines the VPU one-hot build of dot d+1 against the MXU dot d,
+        # overlapping the kernel's two bound units. At B = 256 a dot is one
+        # feature: measured 8.3 -> ~4.8 ms/level at 1M x 28 x 256 on v5e
+        # against the staged matmul. At the two-level search's 20 and 36
+        # slots it is eight (``_dot_features`` has that measurement).
         if packed:
-            w_iota = jax.lax.broadcasted_iota(jnp.uint32, (B // 4, R), 0)
-            K4 = (w_iota * jnp.uint32(4) * jnp.uint32(0x01010101)
-                  + jnp.uint32(0x03020100))
+            G = _dot_features(B, N)
+            K4, owner = _swar_words(B, G, R)
+            for f0 in range(0, Fb, G):
+                _accumulate(out_ref, f0, [
+                    (_u4_row(bins_ref, f).astype(jnp.uint32) if u4
+                     else bins_ref[f:f + 1, :].astype(jnp.uint32))
+                    for f in range(f0, min(f0 + G, Fb))], B, K4, owner, PT4)
         else:
             bin_iota = jax.lax.broadcasted_iota(jnp.int32, (B, R), 0)
-        for f in range(Fb):
-            if packed:
-                row = (_u4_row(bins_ref, f).astype(jnp.uint32) if u4
-                       else bins_ref[f:f + 1, :].astype(jnp.uint32))
-                out_ref[f] += _packed_hist(row, K4, PT4)
-            else:
+            for f in range(Fb):
                 row = (_u4_row(bins_ref, f) if u4
                        else bins_ref[f:f + 1, :].astype(jnp.int32))
                 oh = (bin_iota == row).astype(jnp.int8)        # [B, R]
@@ -302,9 +421,9 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
     have to be provably aligned), and accepts it on 32-bit rows.
 
     Histogram math is IDENTICAL to ``_make_int8_kernel(packed=True)`` at
-    ``B = coarse_b``: same per-feature loop, same PT4 node-scatter, same
-    per-row-block f32 accumulation order — the fused coarse histogram is
-    bit-identical to the unfused one.
+    ``B = coarse_b``: same loop over dots of ``_dot_features`` features,
+    same PT4 node-scatter, same per-row-block f32 accumulation order: the
+    fused coarse histogram is bit-identical to the unfused one.
 
     The advance reads an arbitrary split feature, so the tile stays whole-F
     and cannot be cut on the grid as ``build_hist_pallas`` cuts it. Past
@@ -319,9 +438,9 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
     groups, group = _feature_groups(F)
     F_pad = groups * group
 
-    # the two loop bodies (a previous node, a feature of a group), jitted for
-    # the reason ``_packed_hist`` gives: traced once a kernel, one equation an
-    # iteration afterwards
+    # the two loop bodies (a previous node, a feature's coarse ids), jitted
+    # for the reason ``_packed_hist`` gives: traced once a kernel, one
+    # equation an iteration afterwards
     @jax.jit
     def advance_below(bj, tj, dj, cj, j, pos_row, rel_prev, new_pos):
         # the SMEM scalars enter as int32 operands only: a scalar bool
@@ -333,9 +452,9 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
         return jnp.where(take > 0, child, new_pos)
 
     @jax.jit
-    def coarse_hist(row, K4, PT4):
+    def coarse_ids(row):
         cb = jnp.where(row == missing_bin, B - 1, row >> shift)
-        return _packed_hist(cb.astype(jnp.uint32), K4, PT4)
+        return cb.astype(jnp.uint32)
 
     def kernel(split_ref, bins_ref, q_ref, pos_ref, hist_ref, pos_out_ref,
                bins32):
@@ -385,20 +504,20 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
         h_hi, h_lo = planes(q_ref[1:2, :])
         PT4 = jnp.concatenate([g_hi, h_hi, g_lo, h_lo], axis=0)  # [4N, R]
 
-        w_iota = jax.lax.broadcasted_iota(jnp.uint32, (B // 4, R), 0)
-        K4 = (w_iota * jnp.uint32(4) * jnp.uint32(0x01010101)
-              + jnp.uint32(0x03020100))
-        if groups == 1:
-            for f in range(F):
-                hist_ref[f] += coarse_hist(bins32[f:f + 1, :], K4, PT4)
-        else:
-            def one_group(g, carry):
-                for f in range(group):
-                    at = g * group + f
-                    hist_ref[at] += coarse_hist(bins32[pl.ds(at, 1), :],
-                                                K4, PT4)
-                return carry
+        G = _dot_features(B, N)
+        K4, owner = _swar_words(B, G, R)
 
+        def one_group(g, carry):
+            for f0 in range(0, group, G):
+                at = g * group + f0
+                _accumulate(hist_ref, at, [
+                    coarse_ids(bins32[pl.ds(at + k, 1), :])
+                    for k in range(min(G, group - f0))], B, K4, owner, PT4)
+            return carry
+
+        if groups == 1:
+            one_group(0, 0)
+        else:
             jax.lax.fori_loop(0, groups, one_group, 0)
 
     return kernel
@@ -460,18 +579,21 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     # F = 968 with two-byte ids: all but the default 16 MiB of scoped VMEM
     # before the accumulator and the loop's operands (Mosaic asked 16.83M
     # for the grouped body at 32 nodes). A grouped kernel states what its
-    # tile takes, with the default's room beside it; a narrow one keeps the
-    # default.
+    # tile takes, with the default's room beside it (the accumulator, and a
+    # stacked dot's words, one-hot and int32 product: 0.7 MB at 20 slots and
+    # 32 nodes); a narrow one keeps the default.
     params = None if groups == 1 else pltpu.CompilerParams(
         vmem_limit_bytes=F_pad * R * (4 + 2 * bins_t.dtype.itemsize)
         + 16 * 2 ** 20)
     count_hist_onehot("swar")
+    _note_dot(B, N)
     note_hist_body_features(group)
     with stage("kernel.fused_advance_coarse"):
+        _, acc = _acc_shapes(F_pad, F_pad, B, N)
         hist, pos_out = pl.pallas_call(
             _make_fused_kernel(F, n_prev, N, R, lo_prev, lo, missing_bin, B,
                                shift),
-            out_shape=[_out_struct((F_pad, B, 2 * N), jnp.float32, bins_t, q),
+            out_shape=[_out_struct(acc, jnp.float32, bins_t, q),
                        _out_struct((1, n_pad), jnp.int32, bins_t, pos_t)],
             grid=grid,
             in_specs=[pl.BlockSpec((4, n_prev), lambda i: (0, 0),
@@ -482,7 +604,7 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                                    memory_space=pltpu.VMEM),
                       pl.BlockSpec((1, R), lambda i: (0, i),
                                    memory_space=pltpu.VMEM)],
-            out_specs=[pl.BlockSpec((F_pad, B, 2 * N), lambda i: (0, 0, 0),
+            out_specs=[pl.BlockSpec(acc, lambda i: (0,) * len(acc),
                                     memory_space=pltpu.VMEM),
                        pl.BlockSpec((1, R), lambda i: (0, i),
                                     memory_space=pltpu.VMEM)],
@@ -493,7 +615,7 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         )(splits, bins_t, q, pos_t)
     with stage("fold"):
         inv = jnp.repeat(1.0 / scale, N)[None, None, :]  # [1, 1, 2N]
-        hist = (hist if F_pad == F else hist[:F]) * inv
+        hist = hist.reshape(F_pad, B, 2 * N)[:F] * inv
         gh = hist.reshape(F, B, 2, N)
         return pos_out[0, :n], gh.transpose(3, 0, 1, 2)  # [N, F, B, 2]
 
@@ -722,10 +844,15 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                              memory_space=pltpu.VMEM)
     pos_spec = pl.BlockSpec((1, R), lambda j, i: (0, i),
                             memory_space=pltpu.VMEM)
-    out_spec = pl.BlockSpec((F_blk, B, 2 * N), lambda j, i: (j, 0, 0),
+    # SWAR one-hot needs every bin id to fit a byte and whole words:
+    # matrices with a missing slot (B = 257) or tiny max_bin fall back
+    # to the compare build
+    packed = precision == "int8x2" and B % 4 == 0 and B <= 256
+    block, whole = (_acc_shapes(F_blk, F_pad, B, N) if packed
+                    else ((F_blk, B, 2 * N), (F_pad, B, 2 * N)))
+    out_spec = pl.BlockSpec(block, lambda j, i: (j,) + (0,) * (len(block) - 1),
                             memory_space=pltpu.VMEM)
-    out_shape = _out_struct((F_pad, B, 2 * N), jnp.float32, bins_t,
-                            gpair_t, pos_t)
+    out_shape = _out_struct(whole, jnp.float32, bins_t, gpair_t, pos_t)
 
     if precision == "int8x2":
         # 15-bit fixed-point with a global per-component scale (reference
@@ -737,11 +864,8 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                     max_abs = jax.lax.pmax(max_abs, axis_name)
             scale = 32512.0 / jnp.maximum(max_abs, 1e-30)    # vs 32767
             q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
-        # SWAR one-hot needs every bin id to fit a byte and whole words:
-        # matrices with a missing slot (B = 257) or tiny max_bin fall back
-        # to the compare build
-        packed = B % 4 == 0 and B <= 256
         count_hist_onehot("swar" if packed else "compare")
+        _note_dot(B, N, packed)
         with stage("kernel.build_hist_int8"):
             out = pl.pallas_call(
                 _make_int8_kernel(F_blk, B, N, R, packed=packed, u4=u4),
@@ -756,9 +880,10 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         # columns [0:N] hold g-sums, [N:2N] h-sums -> per-component dequant
         with stage("fold"):
             inv = jnp.repeat(1.0 / scale, N)[None, None, :]  # [1, 1, 2N]
-            out = out * inv
+            out = out.reshape(F_pad, B, 2 * N) * inv
     else:
         count_hist_onehot("compare")
+        _note_dot(B, N, packed=False)
         with stage("kernel.build_hist"):
             out = pl.pallas_call(
                 _make_kernel(F_blk, B, N, R, precision, u4=u4),
