@@ -98,8 +98,10 @@ def test_profiler_session_sees_the_round_driver(tmp_path, evals):
     assert parents["round"] == "train/call"
     assert parents["round/guard"] == "round"
     assert "round/flush" in names
+    # a fresh DMatrix: its state is made inside the first ``round``
+    assert parents["train/state"] in ("round", "round/batch", "round/fused")
     for name, _s, _e, stats in spans:
-        assert "iteration" in stats, (name, stats)
+        assert "iteration" in stats or name == "train/state", (name, stats)
     rounds = [s for s in spans if s[0] == "round"]
     if evals:
         assert [s[3]["step_num"] for s in rounds] == [0, 1, 2, 3]
@@ -544,3 +546,255 @@ def test_compile_counters_by_program():
     text = om.get_registry().render_prometheus()
     assert f'xtpu_program_compiles_total{{program="{prog}"}}' in text
     assert "# TYPE xtpu_program_trace_lower_seconds_total counter" in text
+
+
+# ------------------------------------------- phases and the start-up report
+
+def _phase_delta(fn):
+    """``fn()``, and what it added to the two phase counter families:
+    ({phase: self seconds}, {phase: count})."""
+    def read():
+        return om.phase_seconds(), om.phase_counts()
+    s0, n0 = read()
+    fn()
+    s1, n1 = read()
+    return ({k: v - s0.get(k, 0.0) for k, v in s1.items()
+             if v != s0.get(k, 0.0)},
+            {k: v - n0.get(k, 0) for k, v in n1.items() if v != n0.get(k, 0)})
+
+
+@pytest.fixture
+def unfrozen(monkeypatch):
+    """The next ``train()`` is the process's first again: it freezes a new
+    start-up report (the gauges are written over)."""
+    monkeypatch.setattr(om, "_startup", None)
+
+
+def test_nested_phases_book_self_time():
+    walls = {}
+
+    def run():
+        t0 = time.perf_counter()
+        with tr.phase("test/outer", "test", {"k": 1}):
+            time.sleep(0.02)
+            t1 = time.perf_counter()
+            with tr.phase("test/inner"):
+                time.sleep(0.03)
+            walls["inner"] = time.perf_counter() - t1
+            with tr.phase("test/inner"):
+                pass
+        walls["outer"] = time.perf_counter() - t0
+
+    secs, counts = _phase_delta(run)
+    assert counts == {"test/outer": 1, "test/inner": 2}
+    assert 0.03 <= secs["test/inner"] <= walls["inner"]
+    # the outer phase's self time: its duration less the two inside it
+    assert 0.02 <= secs["test/outer"] <= walls["outer"] - secs["test/inner"]
+    text = om.get_registry().render_prometheus()
+    assert 'xtpu_phase_seconds_total{phase="test/outer"}' in text
+    assert "# TYPE xtpu_phase_total counter" in text
+
+
+def test_import_and_before_import_are_booked_once():
+    secs, counts = _phase_delta(lambda: om.book_import(time.perf_counter()))
+    assert not secs and not counts           # the package's own call stands
+    booked = om.phase_seconds()
+    assert booked["import"] > 0
+    if om._process_age() is not None:        # left out where /proc is not
+        assert 0 < booked["before_import"] < om._process_age()
+
+
+def test_startup_report_parts_add_up_to_total(unfrozen):
+    _train(4)
+    report = om.startup_report()
+    assert {"import", "caller", "unattributed", "round", "train/state",
+            "ingest/sketch", "ingest/bin", "total"} <= set(report)
+    assert not set(om.PHASE_CONTAINERS) & set(report)
+    parts = sum(v for k, v in report.items() if k != "total")
+    assert abs(parts - report["total"]) < 1e-3, (parts, report)
+    assert all(v >= 0 for v in report.values()), report
+    age = om._process_age()
+    if age is not None:
+        assert 0 <= age - report["total"] < 5.0   # frozen moments ago
+
+
+def test_startup_gauges_are_written_once(unfrozen):
+    assert om.startup_report() is None
+    _train(4)
+    first = om.startup_report()
+    reg = om.get_registry()
+
+    def gauges():
+        return {name: reg.get("xtpu_startup_seconds", (("phase", name),))
+                for name in first}
+
+    assert gauges() == first
+    _train(4, max_bin=31)                 # compiles: the counters move on
+    assert om.startup_report() == first and gauges() == first
+    assert om.phase_seconds()["round"] > first["round"]
+    assert 'xtpu_startup_seconds{phase="total"}' in reg.render_prometheus()
+
+
+def test_startup_line_at_verbosity_2(unfrozen, capsys):
+    with xgb.config_context(verbosity=2):
+        _train(2)
+        _train(2)
+    lines = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("start-up: ")]
+    assert len(lines) == 1 and "total " in lines[0] and "round " in lines[0]
+
+
+def test_startup_is_silent_at_the_default_verbosity(unfrozen, capsys):
+    _train(2)
+    assert "start-up" not in capsys.readouterr().out
+    assert om.startup_report() is not None
+
+
+def test_phase_on_a_worker_thread_is_not_booked():
+    import threading
+
+    tr.enable()
+    try:
+        def work():
+            with tr.phase("test/worker", "test"):
+                time.sleep(0.001)
+
+        def run():
+            t = threading.Thread(target=work)
+            t.start()
+            t.join()
+
+        secs, counts = _phase_delta(run)
+        names = [s.name for s in tr.tracer().spans()]
+    finally:
+        tr.disable()
+    assert not secs and not counts
+    assert "test/worker" in names            # a plain span it stays
+
+
+def test_first_train_on_a_worker_thread_freezes_nothing(unfrozen):
+    import threading
+
+    t = threading.Thread(target=_train, args=(2,))
+    t.start()
+    t.join()
+    assert om.startup_report() is None
+
+
+def test_trace_lower_comes_off_the_round_it_ran_in():
+    X, y = _data()
+    dm = xgb.DMatrix(X, label=y)         # its constructor is no round's
+    tr.enable()
+    try:
+        # a shape no other test compiles: traced, lowered and compiled
+        # inside the first ``round``
+        secs, counts = _phase_delta(lambda: xgb.train(
+            {**PARAMS, "max_bin": 23}, dm, 4, verbose_eval=False))
+        spans = tr.tracer().spans()
+    finally:
+        tr.disable()
+    rounds = [s for s in spans if s.name == "round"]
+    assert len(rounds) == 1 and counts["round"] == 1
+    traced = [s for s in spans if s.name == "program/trace_lower"
+              and s.args["program"] == "_fused_multi_round_fn"]
+    assert len(traced) == 2                  # the trace, then the lowering
+    for s in traced:
+        assert rounds[0].t0 <= s.t0 and s.t1 <= rounds[0].t1 + 1e-3
+    compiled = [s for s in spans if s.name == "program/compile"
+                and s.args["program"] == "_fused_multi_round_fn"]
+    assert [s.args["cache_hit"] for s in compiled] == [0]
+    assert secs["program/trace_lower"] >= sum(s.dur for s in traced) - 1e-6
+    # the round's self time is what is left of it
+    inside = sum(v for k, v in secs.items()
+                 if k not in ("round", "train/call"))
+    assert secs["round"] > 0
+    assert secs["round"] <= rounds[0].dur - secs["program/trace_lower"]
+    assert abs(secs["round"] + inside - rounds[0].dur) < 5e-3, (secs,
+                                                               rounds[0].dur)
+    # and the program counters read the same seconds as before
+    assert om.program_compile_counts()["_fused_multi_round_fn"][
+        "trace_lower_s"] > 0
+
+
+def _ingest_spans(build):
+    tr.enable()
+    try:
+        secs, counts = _phase_delta(build)
+        spans = [s for s in tr.tracer().spans()
+                 if s.name.startswith("ingest")]
+    finally:
+        tr.disable()
+    return secs, counts, spans
+
+
+@pytest.mark.parametrize("path", ["memory", "iterator"])
+def test_ingest_paths_open_their_phases(path):
+    from xgboost_tpu.data import quantile
+    from xgboost_tpu.testing import IteratorForTest
+
+    X, y = _data(n=1200)
+
+    def build():
+        if path == "memory":
+            xgb.DMatrix(X, label=y).binned(32)
+        else:
+            xgb.QuantileDMatrix(IteratorForTest(
+                [X[:600], X[600:]], [y[:600], y[600:]]), max_bin=32)._binned
+
+    secs, counts, spans = _ingest_spans(build)
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s.name, []).append(s)
+    sketch, = by_name["ingest/sketch"]
+    assert sketch.args == {"max_bin": 32,
+                           "sample_rows": quantile.SKETCH_SAMPLE_ROWS}
+    binning, = by_name["ingest/bin"]
+    assert binning.args == {"rows": 1200, "nan": 0, "dtype": "uint8",
+                            "batches": 1 if path == "memory" else 2}
+    upload, = by_name["ingest/upload"]
+    assert upload.args == {"rows": 1200, "shards": 1}
+    assert counts["ingest/sketch"] == counts["ingest/bin"] == 1
+    assert counts["ingest/upload"] == 1
+    if path == "memory":
+        assert "ingest/next" not in by_name
+        assert counts["ingest"] == 2         # the constructor, binned()
+    else:
+        # two passes: two batches and the end of the stream in each
+        assert counts["ingest/next"] == len(by_name["ingest/next"]) == 6
+        assert counts["ingest"] == 1
+    # the container holds them all, and books only what they leave (an
+    # iterator's bins go up when first asked for, outside any container)
+    whole = sum(s.dur for s in by_name["ingest"]) + (
+        upload.dur if path == "iterator" else 0.0)
+    assert abs(sum(secs.values()) - whole) < 5e-3, (secs, whole)
+    assert secs["ingest"] < whole
+
+
+def test_native_build_is_a_phase(monkeypatch):
+    from xgboost_tpu import native
+
+    assert native.load() is not None
+    built = []
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_cached_digest", lambda _path: None)
+    monkeypatch.setattr(native, "_build",
+                        lambda path, digest: built.append(path))
+    secs, counts = _phase_delta(native.load)
+    assert len(built) == 1 and counts == {"native/build": 1}
+    secs, counts = _phase_delta(native.load)  # loaded: nothing to build
+    assert not counts
+
+
+def test_phase_site_costs_under_5us():
+    tr.disable()
+    for _ in range(2000):
+        with tr.phase("test/cost", "test", {"iteration": 1}):
+            pass
+    n, best = 5000, float("inf")
+    for _ in range(12):                  # the best of twelve: a shared host
+        t0 = time.perf_counter()
+        for i in range(n):
+            with tr.phase("test/cost", "test", {"iteration": i}):
+                pass
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert best < 5e-6, f"{best * 1e6:.2f} us a phase site"

@@ -7,6 +7,11 @@ row partitioning as static-shape gathers under ``jit``, and the rabit/NCCL
 collective layer replaced by ``jax.lax.psum`` over the ICI/DCN device mesh.
 """
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()   # the ``import`` phase starts here
+
+
 def _place_compile_cache() -> None:
     """Persistent XLA compilation cache. ``JAX_COMPILATION_CACHE_DIR`` set
     from outside is the whole configuration: jax reads it itself and nothing
@@ -83,3 +88,9 @@ __all__ = [
     "CheckpointConfig", "TrainingSnapshot", "NumericalDivergence",
     "__version__",
 ]
+
+# the ``import`` phase ends here (docs/observability.md, "Start-up report");
+# ``obs`` is not importable at the top, so both ends are booked from here
+from .obs import metrics as _obs_metrics  # noqa: E402
+
+_obs_metrics.book_import(_IMPORT_T0)

@@ -454,6 +454,13 @@ class Booster:
     def _configure(self, dtrain: Optional[DMatrix]) -> None:
         if self._configured:
             return
+        if dtrain is None:
+            self._configure_learner(None)
+            return
+        with obs_trace.phase("train/state", "train"):
+            self._configure_learner(dtrain)
+
+    def _configure_learner(self, dtrain: Optional[DMatrix]) -> None:
         tm = self.learner_params.get("tree_method", "auto")
         if tm not in ("auto", "hist", "gpu_hist", "tpu_hist", "approx",
                       "exact"):
@@ -724,60 +731,10 @@ class Booster:
             # first seen as eval-only; rebuild as a training entry
             del self._caches[key]
         if key not in self._caches:
-            if is_train and getattr(dm, "presharded", False):
-                # ShardedDMatrix (parallel/launch.py): the global quantized
-                # matrix was already assembled from per-process shards — no
-                # host-global arrays exist anywhere. Must be checked before
-                # the exact branch: that trains on raw thresholds of the
-                # (local-only) X and would silently fit 1/N of the data.
-                # approx works: it re-sketches through the distributed
-                # merge every iteration (dm.resketch_binned).
-                if tm == "exact":
-                    raise NotImplementedError(
-                        "tree_method=exact is not supported with sharded "
-                        "multi-process ingestion; use hist or approx")
-                base = self._base_np()
-                return self._store_cache(
-                    key, None if tm == "approx" else dm.global_binned(),
-                    dm.make_margin(base, self.n_groups), True, dm,
-                    dm.device_info(), dm.num_row())
-            if is_train and tm in ("approx", "exact"):
-                # approx re-sketches per iteration and exact rank-encodes
-                # losslessly — neither trains against a shared binned matrix,
-                # so margins always walk raw thresholds (binned=None).
-                # approx over an iterator-built PAGED matrix DOES sync under
-                # a communicator (per-iteration sketch merge + the paged
-                # hist driver's per-level allreduce), so it passes the
-                # row-comm check like the hist paged tier; exact still
-                # refuses (it rejects paged matrices outright in do_boost).
-                binned = None
-                self._check_row_comm_sync(paged=(
-                    tm == "approx" and getattr(
-                        getattr(dm, "_binned", None), "is_paged", False)))
-            elif is_train:
-                if self.ctx.mesh is not None:
-                    return self._make_sharded_train_state(key, dm)
-                binned = dm.binned(self.tree_param.max_bin)
-                binned = self._collapse_paged_if_fits(binned)
-                self._check_row_comm_sync(
-                    paged=getattr(binned, "is_paged", False))
-            else:
-                train_cuts = None
-                for st in self._caches.values():
-                    if st.get("is_train") and st["binned"] is not None:
-                        train_cuts = st["binned"].cuts
-                        break
-                # The binned fast path is only valid against the cuts the
-                # trees were grown with; without them (e.g. a loaded model)
-                # fall back to raw-threshold prediction (binned=None).
-                binned = (dm.binned(self.tree_param.max_bin,
-                                    ref_cuts=train_cuts)
-                          if train_cuts is not None else None)
-                if binned is not None:
-                    binned = self._collapse_paged_if_fits(binned)
-            n = dm.num_row()
-            margin = jnp.asarray(self._broadcast_base_margin(dm, n))
-            self._store_cache(key, binned, margin, is_train, dm, dm.info, n)
+            if not is_train:
+                return self._make_state(key, dm, False, tm)
+            with obs_trace.phase("train/state", "train"):
+                return self._make_state(key, dm, True, tm)
         elif is_train and self.ctx.mesh is None and not getattr(
                 dm, "presharded", False):
             # a communicator activated AFTER the entry was built (training
@@ -794,6 +751,66 @@ class Booster:
                 or (tm == "approx" and getattr(
                     getattr(dm, "_binned", None), "is_paged", False))))
         return self._caches[key]
+
+    def _make_state(self, key: int, dm: DMatrix, is_train: bool,
+                    tm: str) -> Dict[str, Any]:
+        """``_state_of``'s miss: quantized matrix, starting margin and the
+        cache entry for ``dm``."""
+        if is_train and getattr(dm, "presharded", False):
+            # ShardedDMatrix (parallel/launch.py): the global quantized
+            # matrix was already assembled from per-process shards — no
+            # host-global arrays exist anywhere. Must be checked before
+            # the exact branch: that trains on raw thresholds of the
+            # (local-only) X and would silently fit 1/N of the data.
+            # approx works: it re-sketches through the distributed
+            # merge every iteration (dm.resketch_binned).
+            if tm == "exact":
+                raise NotImplementedError(
+                    "tree_method=exact is not supported with sharded "
+                    "multi-process ingestion; use hist or approx")
+            base = self._base_np()
+            return self._store_cache(
+                key, None if tm == "approx" else dm.global_binned(),
+                dm.make_margin(base, self.n_groups), True, dm,
+                dm.device_info(), dm.num_row())
+        if is_train and tm in ("approx", "exact"):
+            # approx re-sketches per iteration and exact rank-encodes
+            # losslessly — neither trains against a shared binned matrix,
+            # so margins always walk raw thresholds (binned=None).
+            # approx over an iterator-built PAGED matrix DOES sync under
+            # a communicator (per-iteration sketch merge + the paged
+            # hist driver's per-level allreduce), so it passes the
+            # row-comm check like the hist paged tier; exact still
+            # refuses (it rejects paged matrices outright in do_boost).
+            binned = None
+            self._check_row_comm_sync(paged=(
+                tm == "approx" and getattr(
+                    getattr(dm, "_binned", None), "is_paged", False)))
+        elif is_train:
+            if self.ctx.mesh is not None:
+                return self._make_sharded_train_state(key, dm)
+            binned = dm.binned(self.tree_param.max_bin)
+            binned = self._collapse_paged_if_fits(binned)
+            self._check_row_comm_sync(
+                paged=getattr(binned, "is_paged", False))
+        else:
+            train_cuts = None
+            for st in self._caches.values():
+                if st.get("is_train") and st["binned"] is not None:
+                    train_cuts = st["binned"].cuts
+                    break
+            # The binned fast path is only valid against the cuts the
+            # trees were grown with; without them (e.g. a loaded model)
+            # fall back to raw-threshold prediction (binned=None).
+            binned = (dm.binned(self.tree_param.max_bin,
+                                ref_cuts=train_cuts)
+                      if train_cuts is not None else None)
+            if binned is not None:
+                binned = self._collapse_paged_if_fits(binned)
+        n = dm.num_row()
+        margin = jnp.asarray(self._broadcast_base_margin(dm, n))
+        return self._store_cache(key, binned, margin, is_train, dm, dm.info,
+                                 n)
 
     def _collapse_paged_if_fits(self, binned):
         """External-memory fast path: when a paged matrix fits the HBM
@@ -1270,18 +1287,19 @@ class Booster:
                 self._fused_blocked = True  # non-scalar objective params
                 return None                 # can't be static jit args
             obj_params = tuple(sorted(scalars.items()))
-            grower = gbm._grower_for(binned)
-            info = state["info"]
-            dev = getattr(info, "labels_device", None)
-            wdev = getattr(info, "weights_device", None)
-            self._fused_round = (
-                state, obj_params, grower,
-                dev() if dev is not None
-                else jnp.asarray(info.labels, jnp.float32),
-                ((wdev() if wdev is not None
-                  else jnp.asarray(info.weights, jnp.float32))
-                 if info.weights is not None else None),
-                binned.n_real_bins())
+            with obs_trace.phase("train/state", "train"):
+                grower = gbm._grower_for(binned)
+                info = state["info"]
+                dev = getattr(info, "labels_device", None)
+                wdev = getattr(info, "weights_device", None)
+                self._fused_round = (
+                    state, obj_params, grower,
+                    dev() if dev is not None
+                    else jnp.asarray(info.labels, jnp.float32),
+                    ((wdev() if wdev is not None
+                      else jnp.asarray(info.weights, jnp.float32))
+                     if info.weights is not None else None),
+                    binned.n_real_bins())
         return self._fused_round[1:]
 
     def _insight_binding(self, state: Dict[str, Any],
@@ -2399,6 +2417,7 @@ def train(params: Dict[str, Any], dtrain: DMatrix,
                            EvaluationMonitor)
 
     from .obs import insight as obs_insight
+    from .obs.metrics import freeze_startup
 
     callbacks = list(callbacks) if callbacks else []
     # Round batching: valid when NOTHING consumes per-round output. Decided
@@ -2456,12 +2475,20 @@ def train(params: Dict[str, Any], dtrain: DMatrix,
         # margin update + metric partials into the fused round program
         bst._insight_evals = list(evals)
 
-    with obs_trace.span("train/call", "train",
-                        {"iteration": bst.num_boosted_rounds(),
-                         "rounds": num_boost_round}):
+    with obs_trace.phase("train/call", "train",
+                         {"iteration": bst.num_boosted_rounds(),
+                          "rounds": num_boost_round}):
         bst = _train_rounds(bst, dtrain, num_boost_round, container, evals,
                             obj, batchable, ck, resumed)
     bst._monitor.maybe_print()  # one cumulative table (reference: destructor)
+    startup = freeze_startup()  # the process's first call alone gets one
+    if startup is not None:
+        from .config import get_config
+
+        if get_config().get("verbosity", 1) >= 2:
+            console("start-up: " + ", ".join(
+                f"{name} {secs:.2f}s" for name, secs in sorted(
+                    startup.items(), key=lambda kv: -kv[1])))
 
     if evals_result is not None:
         evals_result.update(container.history)
@@ -2497,8 +2524,8 @@ def _train_rounds(bst: Booster, dtrain: DMatrix, num_boost_round: int,
                 lim = min(lim, ck.rounds_to_boundary(i))
             if batchable and lim >= 2:
                 k = 1 << (lim.bit_length() - 1)
-                with obs_trace.span("round", "train",
-                                    {"iteration": i, "rounds": k}, step=i):
+                with obs_trace.phase("round", "train",
+                                     {"iteration": i, "rounds": k}, step=i):
                     batched = bst.update_batch(dtrain, list(range(i, i + k)))
                 if batched:
                     i += k
@@ -2507,8 +2534,8 @@ def _train_rounds(bst: Booster, dtrain: DMatrix, num_boost_round: int,
                     continue
                 # config needs the per-round path (or a continuation
                 # bootstrap round) — fall through; retried next iteration
-            with obs_trace.span("round", "train",
-                                {"iteration": i, "rounds": 1}, step=i):
+            with obs_trace.phase("round", "train",
+                                 {"iteration": i, "rounds": 1}, step=i):
                 if container.before_iteration(bst, i):
                     break
                 bst.update(dtrain, i, fobj=obj)

@@ -277,15 +277,22 @@ class BinnedMatrix:
             bins = put(local.astype(_dtype_for(max_nbins - 1)))
         else:
             has_missing, max_nbins, dtype, miss, n_nan = _matrix_layout(X, cuts)
-            with obs_trace.span("ingest/bin", "ingest",
-                                {"rows": X.shape[0], "batches": 1,
-                                 "nan": n_nan, "dtype": np.dtype(dtype).name}):
-                if X.shape[0] >= BinnedMatrix._PIPELINE_MIN_ROWS:
+            # pipelined, the uploads run on a thread of their own and this
+            # one's wait for them counts under ``ingest/bin``
+            pipelined = X.shape[0] >= BinnedMatrix._PIPELINE_MIN_ROWS
+            with obs_trace.phase("ingest/bin", "ingest",
+                                 {"rows": X.shape[0], "batches": 1,
+                                  "nan": n_nan,
+                                  "dtype": np.dtype(dtype).name}):
+                if pipelined:
                     bins = BinnedMatrix._bin_pipelined(X, cuts, dtype, miss,
                                                        device)
                 else:
                     arr = np.empty(X.shape, dtype)
                     search_bin_into(X, cuts, miss, arr)
+            if not pipelined:
+                with obs_trace.phase("ingest/upload", "ingest",
+                                     {"rows": X.shape[0], "shards": 1}):
                     bins = put(arr)
         set_binned_layout(n_nan, X.size, bins.dtype.itemsize)
         return BinnedMatrix(bins=bins, cuts=cuts, max_nbins=max_nbins,

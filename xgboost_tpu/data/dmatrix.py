@@ -12,6 +12,7 @@ sharing as in ``GetCutsFromRef``, ``src/data/iterative_dmatrix.cc:54-93``).
 
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass, field
 from typing import Any, Iterator, List, Optional
@@ -23,6 +24,18 @@ from .adapters import to_dense
 from .binned import BinnedMatrix
 from .quantile import (FeatureSummary, HistogramCuts, cover_maxima,
                        cuts_from_summaries, sketch_matrix)
+
+
+def _ingest(fn):
+    """The ``ingest`` container phase around a constructor or a placement:
+    what it holds beyond ``ingest/sketch``, ``ingest/bin``, ``ingest/upload``
+    and ``ingest/next`` is booked to it, and reads as unattributed set-up
+    (docs/observability.md, "Start-up report")."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with obs_trace.phase("ingest", "ingest"):
+            return fn(*args, **kwargs)
+    return wrapped
 
 
 @dataclass
@@ -96,6 +109,7 @@ class DMatrix:
 
     _data_split_mode = "row"  # subclasses with their own __init__ inherit
 
+    @_ingest
     def __init__(self, data: Any, label: Any = None, *, weight: Any = None,
                  base_margin: Any = None, missing: float = np.nan,
                  feature_names: Optional[List[str]] = None,
@@ -184,8 +198,8 @@ class DMatrix:
         if pending is not None:
             self.__dict__["_host_bins"] = None
             local, cuts, max_nbins, has_missing = pending
-            with obs_trace.span("ingest/upload", "ingest",
-                                {"rows": int(local.shape[0]), "shards": 1}):
+            with obs_trace.phase("ingest/upload", "ingest",
+                                 {"rows": int(local.shape[0]), "shards": 1}):
                 self.__dict__["_binned_v"] = BinnedMatrix.from_local_bins(
                     local, cuts, max_nbins=max_nbins,
                     has_missing=has_missing)
@@ -195,6 +209,7 @@ class DMatrix:
     def _binned(self, value) -> None:
         self.__dict__["_binned_v"] = value
 
+    @_ingest
     def place_binned(self, sharding) -> Optional[BinnedMatrix]:
         """An iterator's bin matrix that nobody has asked for yet, placed
         under ``sharding`` (rows over its first axis, padded to a whole
@@ -214,8 +229,8 @@ class DMatrix:
         n = local.shape[0]
         n_pad = -(-n // world) * world
         missing_bin = max_nbins - 1 if has_missing else max_nbins
-        with obs_trace.span("ingest/upload", "ingest",
-                            {"rows": int(n), "shards": world}):
+        with obs_trace.phase("ingest/upload", "ingest",
+                             {"rows": int(n), "shards": world}):
             placed = BinnedMatrix(
                 bins=put_row_shards(local, sharding, n_pad,
                                     min(missing_bin, max_nbins - 1)),
@@ -417,11 +432,20 @@ class DMatrix:
                     "an iterator-built matrix is quantized once at "
                     "construction; rebuild it with the desired max_bin or "
                     "pass ref= to share cuts")
-            cuts = ref_cuts if ref_cuts is not None else sketch_matrix(
-                self.X, max_bin, self.info.weights,
-                self.info.feature_types)
-            self._binned = BinnedMatrix.from_dense(self.X, cuts)
-            self._binned_max_bin = max_bin
+            with obs_trace.phase("ingest", "ingest"):
+                cuts = ref_cuts
+                if cuts is None:
+                    from .quantile import SKETCH_SAMPLE_ROWS
+
+                    with obs_trace.phase(
+                            "ingest/sketch", "ingest",
+                            {"max_bin": max_bin,
+                             "sample_rows": SKETCH_SAMPLE_ROWS}):
+                        cuts = sketch_matrix(self.X, max_bin,
+                                             self.info.weights,
+                                             self.info.feature_types)
+                self._binned = BinnedMatrix.from_dense(self.X, cuts)
+                self._binned_max_bin = max_bin
         return self._binned
 
     def _init_from_iter(self, it: DataIter, max_bin: int,
@@ -456,7 +480,7 @@ class DMatrix:
         # numpy's sorts and reductions release the interpreter lock: a few
         # threads take a batch's columns side by side
         with ThreadPoolExecutor(max(1, min(16, (os.cpu_count() or 2) - 1))) \
-                as pool, obs_trace.span(
+                as pool, obs_trace.phase(
                     "ingest/sketch", "ingest",
                     {"max_bin": max_bin, "sample_rows": SKETCH_SAMPLE_ROWS}):
             for batch in it.collect():
@@ -587,9 +611,9 @@ class DMatrix:
 
         row = 0
         set_binned_layout(n_nan, n_rows * n_feat, np.dtype(dtype).itemsize)
-        with obs_trace.span("ingest/bin", "ingest",
-                            {"rows": n_rows, "batches": n_batches,
-                             "nan": n_nan, "dtype": np.dtype(dtype).name}):
+        with obs_trace.phase("ingest/bin", "ingest",
+                             {"rows": n_rows, "batches": n_batches,
+                              "nan": n_nan, "dtype": np.dtype(dtype).name}):
             for batch in it.collect():
                 X, _, _ = to_dense(batch["data"], missing)
                 search_bin_into(X, cuts, max_nbins - 1,
@@ -773,7 +797,10 @@ class DataIter:
                 batches.clear()
                 return self.next(input_data)
 
-            if not _retry_io(step, "data iterator next()"):
+            # the wait for the caller's data, retries included
+            with obs_trace.phase("ingest/next", "ingest"):
+                more = _retry_io(step, "data iterator next()")
+            if not more:
                 break
             for b in batches:
                 yield b
@@ -785,6 +812,7 @@ class QuantileDMatrix(DMatrix):
     sketches cuts across all batches (or reuses ``ref``'s), pass 2 bins each
     batch; the float matrix is not retained when built from an iterator."""
 
+    @_ingest
     def __init__(self, data: Any, label: Any = None, *, max_bin: int = 256,
                  ref: Optional[DMatrix] = None, missing: float = np.nan,
                  weight: Any = None, base_margin: Any = None,

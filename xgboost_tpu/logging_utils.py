@@ -4,8 +4,8 @@ Mirrors the reference's console logger (``include/xgboost/logging.h:41``).
 The ``common::Monitor`` analogue now lives in
 :mod:`xgboost_tpu.obs.monitor` (this module used to carry a duplicate
 copy); it is re-exported here for compatibility. On TPU the analogue of
-NVTX ranges is ``jax.profiler.TraceAnnotation``; Monitor sections wrap
-both, plus an :mod:`xgboost_tpu.obs.trace` span.
+NVTX ranges is ``jax.profiler.TraceAnnotation``, which
+:mod:`xgboost_tpu.obs.trace` spans open.
 """
 
 from __future__ import annotations

@@ -126,6 +126,9 @@ def load() -> Optional[ctypes.CDLL]:
             if shutil.which("g++") is None:
                 _no_toolchain = True
                 return None
-            _build(lib_path, digest)
+            from .obs import trace as obs_trace
+
+            with obs_trace.phase("native/build", "ingest"):
+                _build(lib_path, digest)
         _lib = ctypes.CDLL(lib_path)
     return _lib

@@ -534,9 +534,9 @@ class _LambdaRankBase(Objective):
             return cached[1]
         sizes = np.diff(ptr)
         G, L = len(sizes), int(sizes.max(initial=1))
-        with obs_trace.span("rank/layout", "train",
-                            {"groups": G, "longest": L,
-                             "rows": int(ptr[-1])}):
+        with obs_trace.phase("rank/layout", "train",
+                             {"groups": G, "longest": L,
+                              "rows": int(ptr[-1])}):
             qidx = np.repeat(np.arange(G, dtype=np.int32), sizes)
             slot = (np.arange(ptr[-1], dtype=np.int32)
                     - np.repeat(ptr[:-1], sizes).astype(np.int32))

@@ -5,7 +5,9 @@ Five instruments, one taxonomy:
 - :mod:`~xgboost_tpu.obs.trace` — program spans as profiler annotations
   and ``xtpu.<stage>`` scopes inside the compiled programs (the switch
   is the ``jax.profiler`` session); ``XTPU_TRACE=1`` also records the
-  spans into a ring exported as Chrome/Perfetto JSON or jsonl.
+  spans into a ring exported as Chrome/Perfetto JSON or jsonl. A
+  ``phase()`` is a span whose self time is also booked when nothing
+  listens: the start-up report (``metrics.startup_report()``).
 - :mod:`~xgboost_tpu.obs.metrics` — the process-wide
   :class:`MetricsRegistry` every counting subsystem registers into;
   rendered as Prometheus text exposition on serve's ``GET /metrics``.

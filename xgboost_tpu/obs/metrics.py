@@ -21,7 +21,9 @@ output; docs/observability.md has the metric glossary.
 from __future__ import annotations
 
 import math
+import os
 import threading
+import time
 import weakref
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -35,7 +37,9 @@ __all__ = ["Sample", "Family", "HistogramData", "MetricsRegistry",
            "count_eval_walk", "eval_walk_counts",
            "count_rank_gradient", "rank_counts",
            "count_mesh_dispatch", "set_mesh_layout", "mesh_counts",
-           "program_compile_counts"]
+           "program_compile_counts", "Phase", "phase_seconds",
+           "phase_counts", "book_import", "freeze_startup",
+           "startup_report"]
 
 LabelSet = Tuple[Tuple[str, str], ...]
 
@@ -585,6 +589,196 @@ def mesh_counts() -> Dict[str, Any]:
             "rows_per_shard": int(_registry.get(_MESH_ROWS))}
 
 
+# ---- phases: spans that are booked when nothing is listening ---------------
+# ``obs.trace.phase(name)`` opens the annotation ``span()`` opens and, on
+# exit, adds its SELF time on ``time.perf_counter`` (its duration less the
+# phases that ran inside it) to ``xtpu_phase_seconds_total{phase}`` and one
+# to ``xtpu_phase_total{phase}``. Phases are coarse by rule: a few dozen a
+# job and one a ``round`` span, never inside a round. Only the thread that
+# entered the library (imported this module) books them; pool and uploader
+# threads keep plain spans, so the stack and the sums below have one writer
+# and no lock (a collector hands the sums to the registry when it is read).
+# The compile listeners further down book ``program/trace_lower`` and
+# ``program/compile`` as intervals inside whatever phase is open, so tracing
+# and compiling come off the ``round`` they happen in.
+
+_PHASE_S = "xtpu_phase_seconds_total"
+_PHASE_N = "xtpu_phase_total"
+_STARTUP = "xtpu_startup_seconds"
+# containers: what they hold beyond their named parts is nobody's
+PHASE_CONTAINERS = ("ingest", "train/call")
+
+
+class Phase:
+    """``obs.trace.phase``'s context manager: the span, and around its
+    inside the booking frame (none off the booking thread)."""
+
+    __slots__ = ("name", "_span", "t0", "inside")
+
+    def __init__(self, name: str, span_cm) -> None:
+        self.name = name
+        self._span = span_cm
+        self.t0 = None
+        self.inside = 0.0     # seconds of the phases that ran inside it
+
+    def __enter__(self):
+        self._span.__enter__()
+        if threading.get_ident() == _phase_thread:
+            _stack.append(self)
+            self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        if self.t0 is not None:
+            dur = time.perf_counter() - self.t0
+            _stack.pop()      # ``with`` blocks close in order: it is on top
+            (_stack[-1] if _stack else _root).inside += dur
+            _book_phase(self.name, dur - self.inside)
+        return self._span.__exit__(*exc)
+
+
+_phase_thread = threading.get_ident()
+_stack: List[Phase] = []
+_root = Phase("", None)       # inside: seconds under any phase since import
+_marks: List[Tuple[Phase, float]] = []    # open compile-path intervals
+_phase_sums: Dict[str, List[float]] = {}  # phase -> [self seconds, count]
+_process_t0: Optional[float] = None       # process start on perf_counter
+_import_end: Optional[float] = None
+_startup: Optional[Dict[str, float]] = None
+
+
+def _book_phase(name: str, secs: float) -> None:
+    sums = _phase_sums.get(name)
+    if sums is None:
+        sums = _phase_sums[name] = [0.0, 0]
+    sums[0] += secs
+    sums[1] += 1
+
+
+def _collect_phases() -> List[Family]:
+    rows = [((("phase", name),), sums[0], sums[1])
+            for name, sums in list(_phase_sums.items())]
+    if not rows:
+        return []
+    return [Family(_PHASE_S, "counter",
+                   "self seconds of the library's phases on the thread that "
+                   "entered it, by phase",
+                   [Sample(secs, labels) for labels, secs, _n in rows]),
+            Family(_PHASE_N, "counter", "phases finished, by phase",
+                   [Sample(n, labels) for labels, _secs, n in rows])]
+
+
+_registry.register(_collect_phases)
+
+
+def _interval_open() -> None:
+    """A compile-path event started (outermost trace or lowering, a backend
+    compile): remember what the open phase held, so that phases finishing
+    inside the interval come off it and are not taken from their parent
+    twice."""
+    if threading.get_ident() == _phase_thread:
+        parent = _stack[-1] if _stack else _root
+        _marks.append((parent, parent.inside))
+
+
+def _interval_close(name: str, secs: float, args: Dict[str, Any]) -> None:
+    """Book the interval ``[now - secs, now)`` as phase ``name`` inside
+    whatever phase is open."""
+    if threading.get_ident() != _phase_thread:
+        return
+    parent = _stack[-1] if _stack else _root
+    nested = 0.0
+    if _marks:
+        marked, inside = _marks.pop()
+        if marked is parent:
+            nested = parent.inside - inside
+    parent.inside += secs - nested
+    _book_phase(name, secs - nested)
+    from . import trace       # imports this module: not at the top
+
+    trace.record_interval(name, secs, args)
+
+
+def phase_seconds() -> Dict[str, float]:
+    """``{phase: self seconds}`` as booked so far."""
+    return {name: sums[0] for name, sums in list(_phase_sums.items())}
+
+
+def phase_counts() -> Dict[str, int]:
+    """``{phase: phases finished}`` as booked so far."""
+    return {name: sums[1] for name, sums in list(_phase_sums.items())}
+
+
+def _process_age() -> Optional[float]:
+    """Seconds since the process started: its start time in
+    ``/proc/self/stat`` (field 22, clock ticks after boot) against
+    ``CLOCK_BOOTTIME``. None where either cannot be read."""
+    try:
+        with open("/proc/self/stat", "rb") as fh:
+            fields = fh.read().rsplit(b")", 1)[1].split()
+        started = int(fields[19]) / os.sysconf("SC_CLK_TCK")
+        return time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+def book_import(t0: float) -> None:
+    """Called once, from the last line of ``xgboost_tpu/__init__.py``, with
+    ``perf_counter`` at its first: books ``import`` (less what was booked
+    while it ran: every phase so far lies inside it) and ``before_import``
+    (process start to that first line; left out where the process's age
+    cannot be read)."""
+    global _process_t0, _import_end
+    if _import_end is not None or threading.get_ident() != _phase_thread:
+        return
+    now = time.perf_counter()
+    _book_phase("import", now - t0 - sum(phase_seconds().values()))
+    age = _process_age()
+    if age is not None and age >= now - t0:
+        _process_t0 = now - age
+        _book_phase("before_import", t0 - _process_t0)
+    else:
+        _process_t0 = t0
+    _root.inside = 0.0
+    _import_end = now
+
+
+def freeze_startup() -> Optional[Dict[str, float]]:
+    """At the end of the process's first ``train()``: write the gauges
+    ``xtpu_startup_seconds{phase}`` once. Each phase's self time so far;
+    ``caller`` (this thread's time after ``import`` under no phase: the
+    caller's own code); ``unattributed`` (self time of the containers
+    ``ingest`` and ``train/call``); ``total`` (the process's age now). The
+    parts add up to ``total``. Returns the report on the call that froze
+    it, None on every other."""
+    global _startup
+    if _startup is not None or _import_end is None \
+            or threading.get_ident() != _phase_thread:
+        return None
+    now = time.perf_counter()
+    report = {"unattributed": 0.0}
+    for name, secs in phase_seconds().items():
+        if name in PHASE_CONTAINERS:
+            report["unattributed"] += secs
+        else:
+            report[name] = secs
+    report["caller"] = now - _import_end - _root.inside
+    report["total"] = now - _process_t0
+    for name, secs in report.items():
+        _registry.set_gauge(_STARTUP, secs, labels=(("phase", name),),
+                            help="where the time went from process start "
+                                 "to the end of the first train(), frozen "
+                                 "there: self seconds by phase")
+    _startup = report
+    return dict(report)
+
+
+def startup_report() -> Optional[Dict[str, float]]:
+    """The frozen start-up report as ``{phase: seconds}``; None until the
+    process's first ``train()`` has returned."""
+    return None if _startup is None else dict(_startup)
+
+
 # ---- compile counters by program -------------------------------------------
 # jax.monitoring listeners, registered once at import. jax 0.9 passes
 # ``fun_name`` with its three compile-path duration events and announces
@@ -596,7 +790,12 @@ def mesh_counts() -> Dict[str, Any]:
 # all counted; a load from the persistent cache counts as a compile, as it
 # does in jax's own event, and is counted again as a cache hit (jax's
 # ``cache_hits`` event names no program: it fires inside the compile event
-# of the program it serves, on the same thread). A cache hit matters to
+# of the program it serves, on the same thread). A compile that jax WRITES
+# to the persistent cache is counted again as a cache miss (jax's
+# ``cache_misses`` event, in the same place): the programs a later process
+# is served. A program that compiles faster than
+# ``jax_persistent_cache_min_compile_time_secs`` (1 s) is neither: it is
+# compiled in every process. A cache hit matters to
 # whoever reads scopes off a device trace: the cache's key leaves metadata
 # out, so the executable carries the scopes of the source that wrote it.
 # The listeners run only when jax traces or compiles: a steady window pays
@@ -606,9 +805,11 @@ _TRACE_LOWER = ("/jax/core/compile/jaxpr_trace_duration",
                 "/jax/core/compile/jaxpr_to_mlir_module_duration")
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
 _COMPILES = "xtpu_program_compiles_total"
 _COMPILE_S = "xtpu_program_compile_seconds_total"
 _CACHE_HITS = "xtpu_program_cache_hits_total"
+_CACHE_MISSES = "xtpu_program_cache_misses_total"
 _TRACE_LOWER_S = "xtpu_program_trace_lower_seconds_total"
 _nesting = threading.local()
 
@@ -625,14 +826,19 @@ def _program_label(fun_name) -> LabelSet:
 
 def _on_compile_start(event: str, _value, **_kw) -> None:
     if event in _TRACE_LOWER:
-        _nesting.depth = getattr(_nesting, "depth", 0) + 1
+        depth = _nesting.depth = getattr(_nesting, "depth", 0) + 1
+        if depth == 1:
+            _interval_open()
     elif event == _BACKEND_COMPILE:
-        _nesting.cache_hit = False
+        _nesting.cache_hit = _nesting.cache_miss = False
+        _interval_open()
 
 
 def _on_event(event: str, **_kw) -> None:
     if event == _CACHE_HIT:
         _nesting.cache_hit = True
+    elif event == _CACHE_MISS:
+        _nesting.cache_miss = True
 
 
 def _on_compile_duration(event: str, secs: float, fun_name=None,
@@ -640,12 +846,17 @@ def _on_compile_duration(event: str, secs: float, fun_name=None,
     if event in _TRACE_LOWER:
         depth = _nesting.depth = max(getattr(_nesting, "depth", 1) - 1, 0)
         if depth == 0:
-            _registry.inc(_TRACE_LOWER_S, by=secs,
-                          labels=_program_label(fun_name),
+            labels = _program_label(fun_name)
+            _registry.inc(_TRACE_LOWER_S, by=secs, labels=labels,
                           help="seconds tracing and lowering, booked to "
                                "the outermost program")
+            _interval_close("program/trace_lower", secs,
+                            {"program": labels[0][1]})
     elif event == _BACKEND_COMPILE:
         labels = _program_label(fun_name)
+        _interval_close("program/compile", secs, {
+            "program": labels[0][1],
+            "cache_hit": int(getattr(_nesting, "cache_hit", False))})
         _registry.inc(_COMPILES, labels=labels,
                       help="backend compiles (or persistent-cache loads), "
                            "by program")
@@ -656,19 +867,25 @@ def _on_compile_duration(event: str, secs: float, fun_name=None,
             _registry.inc(_CACHE_HITS, labels=labels,
                           help="compiles served from the persistent cache: "
                                "the executable carries its writer's scopes")
+        if getattr(_nesting, "cache_miss", False):
+            _nesting.cache_miss = False
+            _registry.inc(_CACHE_MISSES, labels=labels,
+                          help="compiles written to the persistent cache: "
+                               "what a later process is served")
 
 
 def program_compile_counts() -> Dict[str, Dict[str, float]]:
-    """``{program: {"compiles", "compile_s", "cache_hits",
+    """``{program: {"compiles", "compile_s", "cache_hits", "cache_misses",
     "trace_lower_s"}}`` as counted so far in this process."""
     out: Dict[str, Dict[str, float]] = {}
     for key, field in ((_COMPILES, "compiles"), (_COMPILE_S, "compile_s"),
                        (_CACHE_HITS, "cache_hits"),
+                       (_CACHE_MISSES, "cache_misses"),
                        (_TRACE_LOWER_S, "trace_lower_s")):
         for program, value in _by_label(key, "program").items():
             out.setdefault(program, {
                 "compiles": 0, "compile_s": 0.0, "cache_hits": 0,
-                "trace_lower_s": 0.0})[field] = value
+                "cache_misses": 0, "trace_lower_s": 0.0})[field] = value
     return out
 
 

@@ -17,17 +17,15 @@ each section a sentinel to block on::
 
 With ``sync=False`` (the default) the sentinel is ignored and the
 bracket stays free — the historical behavior, fine for host-side phases
-and for spotting dispatch stalls. Sections also emit an
-:mod:`~xgboost_tpu.obs.trace` span of the same name, so enabling
-``XTPU_TRACE`` yields the identical taxonomy on the trace timeline.
+and for spotting dispatch stalls. Sections are the verbosity-3 table's
+alone: the trace timeline names the same intervals ``round/gradient`` and
+``round/general`` (:mod:`~xgboost_tpu.obs.trace`).
 """
 
 from __future__ import annotations
 
 import time
 from typing import Dict, Optional
-
-from . import trace as _trace
 
 
 class Timer:
@@ -70,7 +68,7 @@ class Monitor:
         self.timers[label].stop()
 
     class _Section:
-        __slots__ = ("mon", "label", "_sentinel", "_span")
+        __slots__ = ("mon", "label", "_sentinel")
 
         def __init__(self, mon: "Monitor", label: str) -> None:
             self.mon = mon
@@ -83,21 +81,11 @@ class Monitor:
             self._sentinel = x
 
         def __enter__(self) -> "Monitor._Section":
-            tr = _trace.tracer()
-            if tr is not None:
-                self._span = tr.span(f"{self.mon.name}.{self.label}"
-                                     if self.mon.name else self.label,
-                                     "monitor")
-                self._span.__enter__()
-            else:
-                self._span = None
             self.mon.start(self.label)
             return self
 
         def __exit__(self, *exc):
             self.mon.stop(self.label, sync_on=self._sentinel)
-            if self._span is not None:
-                self._span.__exit__(*exc)
             self._sentinel = None
             return False
 

@@ -45,8 +45,10 @@ from typing import Any, Dict, List, Optional
 import jax
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
+from . import metrics as _metrics
+
 __all__ = ["Span", "Tracer", "enable", "disable", "enabled", "tracer",
-           "span", "instant", "export", "reset", "set_identity",
+           "span", "phase", "instant", "export", "reset", "set_identity",
            "STAGES", "ROUND_ROOTS", "stage", "opened_stages",
            "RANK_SCOPES", "rank_scope", "MESH_SCOPES", "mesh_scope"]
 
@@ -360,6 +362,32 @@ def span(name: str, cat: str = "", args: Optional[Dict[str, Any]] = None,
     if t is None:
         return _annotation(name, args, step)
     return t.span(name, cat, args, step)
+
+
+def phase(name: str, cat: str = "", args: Optional[Dict[str, Any]] = None,
+          step: Optional[int] = None):
+    """A span that is also booked when nothing is listening: the same
+    annotation ``span()`` opens (profiler and ring see it as before), and on
+    exit its self time (its duration less the phases that ran inside it on
+    this thread) goes to ``xtpu_phase_seconds_total{phase}`` and one to
+    ``xtpu_phase_total{phase}``. For the coarse parts of a job's set-up and
+    the ``round`` span, never for anything inside a round: two clock reads
+    and two counter adds a site. Off the thread that entered the library it
+    is a plain span."""
+    return _metrics.Phase(name, span(name, cat, args, step))
+
+
+def record_interval(name: str, secs: float,
+                    args: Optional[Dict[str, Any]] = None) -> None:
+    """A phase that jax timed itself (``program/trace_lower``,
+    ``program/compile``: ``obs/metrics.py``'s listeners), just ended: into
+    the ring as ``[now - secs, now)``, where the ring is on."""
+    t = _tracer
+    if t is not None:
+        now = time.perf_counter()
+        t._record(Span(name, "program", now - secs, now,
+                       getattr(t._tl, "depth", 0), threading.get_ident(),
+                       args))
 
 
 def instant(name: str, cat: str = "",
