@@ -256,14 +256,32 @@ def test_lambdarank_device_matches_host_loop(obj, exp_gain, cap, monkeypatch):
 
 
 def _equations(jaxpr):
-    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    """Every equation of a jaxpr, those of its sub-jaxprs included (a Pallas
+    kernel's body is a program of its own: not descended into)."""
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (tuple, list)) else (v,)):
                 sub = getattr(sub, "jaxpr", sub)     # a ClosedJaxpr's own
                 if hasattr(sub, "eqns"):
                     yield from _equations(sub)
+
+
+def _gradient_shapes(n, G, L, kpos):
+    """The argument shapes of ``_lambda_grad_device``: scores, padded
+    labels, tile tables, weights, bias."""
+    import jax
+    import jax.numpy as jnp
+
+    from xgboost_tpu.objective import ranking as rk
+
+    rows = jax.ShapeDtypeStruct((n,), jnp.float32)
+    tiles = rk._Tiles(*[jax.ShapeDtypeStruct((G,), jnp.int32)] * 3)
+    bias = (jax.ShapeDtypeStruct((kpos,), jnp.float32),) * 2 if kpos else ()
+    return (rows, jax.ShapeDtypeStruct((G, L), jnp.float32), tiles, rows,
+            *bias)
 
 
 @pytest.mark.parametrize("objective,kpos", [("ndcg", 0), ("pairwise", 0),
@@ -281,14 +299,10 @@ def test_topk_chunk_works_in_rank_order(objective, kpos, kcap):
     from xgboost_tpu.objective import ranking as rk
 
     n, G, L, C = 600, 24, 40, 4
-    rows = jax.ShapeDtypeStruct((n,), jnp.float32)
-    idx = jax.ShapeDtypeStruct((n,), jnp.int32)
-    per_group = jax.ShapeDtypeStruct((G,), jnp.int32)
-    bias = (jax.ShapeDtypeStruct((kpos,), jnp.float32),) * 2 if kpos else ()
     closed = jax.make_jaxpr(functools.partial(
         rk._lambda_grad_device, kcap=kcap, L=L, exp_gain=True,
-        objective=objective, chunk=C, n_groups=G, kpos=kpos))(
-            rows, rows, idx, idx, per_group, per_group, rows, *bias)
+        objective=objective, chunk=C, kpos=kpos))(
+            *_gradient_shapes(n, G, L, kpos))
     (loop,) = [e for e in _equations(closed.jaxpr)
                if e.primitive.name == "scan"]
     body = list(_equations(loop.params["jaxpr"].jaxpr))
@@ -463,3 +477,173 @@ def test_lambdarank_unbiased_learns_position_bias(method):
     b3.load_model(bytes(bst.save_raw("json")))
     np.testing.assert_allclose(b3.obj._ti_plus, tp)
     np.testing.assert_allclose(b3.obj._tj_minus, tm)
+
+
+# ---- the padded layout's tile path against the per-group gather forms ----
+#
+# Until the tile path, rows reached the padded [Gp, L] layout by one slice a
+# group (``vmap`` of ``dynamic_slice``) and left it by a gather of single
+# elements. Those forms, kept here as the reference, give the same bits.
+
+def _slices_in(rows, tiles, L, fill):
+    """The way in before the tile path: L rows from each group's start."""
+    import jax
+    import jax.numpy as jnp
+
+    from xgboost_tpu.objective.ranking import LANES
+
+    starts = tiles.first * LANES + tiles.shift
+    ext = jnp.concatenate([rows, jnp.zeros((L,), rows.dtype)])
+    block = jax.vmap(lambda p: jax.lax.dynamic_slice(ext, (p,), (L,)))(starts)
+    return jnp.where(jnp.arange(L)[None, :] < tiles.sizes[:, None], block,
+                     fill)
+
+
+def _elements_out(pad, tiles, n):
+    """The way back before the tile path: each row's (group, slot)."""
+    import jax.numpy as jnp
+
+    from xgboost_tpu.objective.ranking import LANES
+
+    starts = tiles.first * LANES + tiles.shift
+    qidx = jnp.repeat(jnp.arange(pad.shape[0]), tiles.sizes,
+                      total_repeat_length=n)
+    return pad[qidx, jnp.arange(n) - starts[qidx]]
+
+
+@pytest.fixture
+def gather_forms(monkeypatch):
+    """The device gradient with the layout's gather forms in place of the
+    tile path, compiled afresh (no trace of the tile path is reused)."""
+    import jax
+
+    from xgboost_tpu.objective import ranking as rk
+
+    monkeypatch.setattr(rk, "_padded_layout", _slices_in)
+    monkeypatch.setattr(rk, "_rows_of", _elements_out)
+    statics = ("L", "exp_gain", "objective", "chunk", "kpos")
+    for name, own in (("_lambda_grad_device", "kcap"),
+                      ("_lambda_grad_device_mean", "k")):
+        monkeypatch.setattr(rk, name, jax.jit(
+            getattr(rk, name).__wrapped__, static_argnames=statics + (own,)))
+
+
+def _groups(case):
+    """Group sizes of a layout case and the groups padded on."""
+    return {"sizes_1_8_127_128_129_L": ([1, 8, 127, 128, 129, 300, 2], 0),
+            "aligned_starts": ([128, 256, 128, 384], 0),
+            "window_past_n": ([200, 129, 3], 0),
+            "padded_to_chunks": ([5, 130, 77, 1, 64], 3),
+            "L_below_128": ([3, 40, 7, 1, 12, 9], 2),
+            "one_row_groups": ([1] * 200 + [6], 1),
+            "steps_of_groups": ([(7 * i) % 97 + 1 for i in range(150)], 5),
+            # groups longer than a kernel's piece of 4,096 slots: moved as
+            # pieces, the last one partial or empty
+            "longer_than_a_piece": ([4097, 1, 9000, 4096, 130, 8193], 1),
+            }[case]
+
+
+@pytest.mark.parametrize("case", ["sizes_1_8_127_128_129_L",
+                                  "aligned_starts", "window_past_n",
+                                  "padded_to_chunks", "L_below_128",
+                                  "one_row_groups", "steps_of_groups",
+                                  "longer_than_a_piece"])
+def test_tile_layout_matches_the_gather_forms(case):
+    import jax
+    import jax.numpy as jnp
+
+    from xgboost_tpu.objective import ranking as rk
+    from xgboost_tpu.ops.pallas.rank_layout import PIECE, window_tiles
+
+    sizes, pad_groups = _groups(case)
+    ptr = np.concatenate([[0], np.cumsum(sizes)])
+    n, L, Gp = int(ptr[-1]), max(sizes), len(sizes) + pad_groups
+    tiles = jax.tree.map(jnp.asarray, rk._tile_tables(ptr, Gp))
+    if case == "aligned_starts":
+        assert not np.asarray(tiles.shift).any()
+    assert (L > PIECE) == (case == "longer_than_a_piece")
+    if case == "window_past_n":     # the last group's window passes row n
+        last = int(tiles.first[len(sizes) - 1])
+        assert last + window_tiles(L) > -(-n // rk.LANES)
+    rng = np.random.RandomState(len(sizes))
+    rows = jnp.asarray(rng.randn(n).astype(np.float32))
+    for fill in (-np.inf, 0.0):
+        np.testing.assert_array_equal(
+            rk._padded_layout(rows, tiles, L, fill),
+            _slices_in(rows, tiles, L, fill))
+    # what pad slots hold never reaches a row
+    pad = rng.randn(Gp, L).astype(np.float32)
+    pad[rng.rand(Gp, L) < 0.3] = np.nan
+    pad[0, -1] = np.inf
+    pad = jnp.where(jnp.arange(L)[None, :] < tiles.sizes[:, None],
+                    jnp.asarray(rng.randn(Gp, L).astype(np.float32)), pad)
+    np.testing.assert_array_equal(rk._rows_of(pad, tiles, n),
+                                  _elements_out(pad, tiles, n))
+
+
+def _rank_info(weighted, seed=5):
+    rng = np.random.RandomState(seed)
+    sizes = [1, 7, 130, 2, 13, 129, 128, 40]
+    y = np.concatenate([rng.randint(0, 4, s) for s in sizes]).astype(
+        np.float32)
+    ptr = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
+    w = rng.rand(len(sizes)).astype(np.float32) + 0.5 if weighted else None
+    return rng.randn(len(y)).astype(np.float32), _Info(y, group_ptr=ptr,
+                                                       weights=w)
+
+
+@pytest.mark.parametrize("method", ["topk", "mean"])
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("unbiased", [False, True])
+def test_device_gradient_equals_the_gather_forms(method, weighted, unbiased,
+                                                 request, monkeypatch):
+    # kpos > 0 where unbiased: the slots' bias estimates ride the pair block
+    monkeypatch.delenv("XTPU_RANK_HOST", raising=False)
+    s, info = _rank_info(weighted)
+    params = {"lambdarank_pair_method": method, "seed": 2,
+              "lambdarank_num_pair_per_sample": 4 if method == "topk" else 2,
+              "lambdarank_unbiased": unbiased}
+    tile = np.asarray(get_objective("rank:ndcg", dict(params))
+                      .get_gradient(s, info, 3))
+    request.getfixturevalue("gather_forms")
+    gather = np.asarray(get_objective("rank:ndcg", dict(params))
+                        .get_gradient(s, info, 3))
+    assert np.abs(tile).max() > 0
+    np.testing.assert_array_equal(tile, gather)
+
+
+@pytest.mark.parametrize("kpos", [0, 8])
+def test_device_gradient_moves_rows_by_whole_tiles(kpos):
+    # no gather or scatter over the rows, of single elements or slices: the
+    # scores go in by the tile kernel, both sums come out by its mirror
+    import functools
+
+    import jax
+
+    from xgboost_tpu.objective import ranking as rk
+
+    n, G, L = 600, 24, 40
+    closed = jax.make_jaxpr(functools.partial(
+        rk._lambda_grad_device, kcap=8, L=L, exp_gain=True, objective="ndcg",
+        chunk=4, kpos=kpos))(*_gradient_shapes(n, G, L, kpos))
+    eqns = list(_equations(closed.jaxpr))
+    moves = [e for e in eqns
+             if "gather" in e.primitive.name or "scatter" in e.primitive.name]
+    assert not [e for e in moves if e.invars[0].aval.size >= n], moves
+    kernels = [e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"]
+    assert kernels == ["rank_rows_in", "rank_rows_out", "rank_rows_out"]
+
+
+@pytest.mark.parametrize("method", ["topk", "mean"])
+def test_two_rounds_give_the_gather_forms_model(method, request):
+    X, y, qid = _make_ltr(seed=9)
+    dm = xgb.DMatrix(X, label=y, qid=qid)
+    params = {"objective": "rank:ndcg", "lambdarank_pair_method": method,
+              "max_depth": 3, "eta": 0.3}
+    tile = bytes(xgb.train(params, dm, 2, verbose_eval=False)
+                 .save_raw("json"))
+    request.getfixturevalue("gather_forms")
+    gather = bytes(xgb.train(params, dm, 2, verbose_eval=False)
+                   .save_raw("json"))
+    assert tile == gather

@@ -68,13 +68,16 @@ def _ranked_7_and_8(s, ptr):
     return lo + np.argsort(-s[lo:hi], kind="stable")[7:9]
 
 
-def _program_grad(s, y, ptr, truncation, chunk=None):
+def _program_grad(s, y, ptr, truncation):
+    return _program_layout_grad(s, y, ptr, truncation)[1]
+
+
+def _program_layout_grad(s, y, ptr, truncation):
+    """The device layout the objective cached, and its gradient."""
     obj = get_objective("rank:ndcg", dict(
         TOPK, lambdarank_num_pair_per_sample=truncation))
-    info = _Info(y, ptr)
-    if chunk is not None:
-        obj._chunk_of = lambda layout, block: chunk
-    return np.asarray(obj.get_gradient(s, info), np.float64)[:, 0, :]
+    grad = np.asarray(obj.get_gradient(s, _Info(y, ptr)), np.float64)
+    return obj._dev_layout[1], grad[:, 0, :]
 
 
 # (a) f32 on the device against f64: a lambda is a sum of at most 40 pair
@@ -132,13 +135,20 @@ def test_tie_across_the_truncation_goes_to_the_lower_slot():
         assert close == (first == a)
 
 
-# (b) the parts add up to the whole
-def test_gradient_invariant_to_group_order_and_chunk():
+# (b) the parts add up to the whole: one group a ``lax.map`` step, groups
+# padded up to whole steps (6 to 8), all groups in one step
+def test_gradient_invariant_to_group_order_and_chunk(monkeypatch):
+    from xgboost_tpu.objective import ranking as rk
+
     s, y, ptr = _ragged(3)
     whole = _program_grad(s, y, ptr, 8)
-    for chunk in (1, len(SIZES)):
-        np.testing.assert_allclose(_program_grad(s, y, ptr, 8, chunk=chunk),
-                                   whole, rtol=1e-6, atol=1e-9)
+    for chunk, padded in ((1, 6), (4, 8), (len(SIZES), 6)):
+        monkeypatch.setattr(rk, "_chunk", lambda G, slots: chunk)
+        lay, got = _program_layout_grad(s, y, ptr, 8)
+        assert lay["chunk"] == chunk
+        assert lay["y_pad"].shape[0] == lay["tiles"].sizes.shape[0] == padded
+        np.testing.assert_allclose(got, whole, rtol=1e-6, atol=1e-9)
+    monkeypatch.undo()
     perm = np.random.RandomState(0).permutation(len(SIZES))
     rows = np.concatenate([np.arange(ptr[q], ptr[q + 1]) for q in perm])
     ptr_p = np.concatenate([[0], np.cumsum(np.asarray(SIZES)[perm])])

@@ -201,3 +201,47 @@ def test_wide_missing_kernels_compile_for_v5e(mesh, kernel, nodes, width):
         assert 0 < hist_body_features() <= ph.FEATURE_GROUP
         assert hist_dot_counts() == {**dots,
                                      "stacked": dots.get("stacked", 0) + 1}
+
+
+# the ranking job's layout (istella-letor.train: 7,325,625 rows in 23,219
+# groups of at most 1,024, 23,552 padded, truncation 32), a ragged one: a
+# longest group of no whole number of tiles, groups of no whole step, and
+# one whose longest group of 32,768 rows the kernels move as pieces of
+# 4,096 slots (as one window a group a step would hold 17 MB of VMEM)
+@pytest.mark.parametrize("rows,groups,L,kcap,chunk", [
+    (7_325_625, 23_552, 1024, 32, 512),
+    (100_003, 336, 1000, 0, 16),
+    (300_001, 64, 32_768, 32, 16),
+])
+def test_ranking_gradient_compiles_for_v5e(monkeypatch, mesh, rows, groups,
+                                           L, kcap, chunk):
+    """The ranking gradient for one described chip: Mosaic takes the two
+    layout kernels (``ops/pallas/rank_layout.py``), under the gradient's
+    ``rank.layout`` and ``rank.reduce`` scopes, and no op gathers over the
+    rows."""
+    import functools
+
+    from jax.sharding import SingleDeviceSharding
+
+    from xgboost_tpu.objective import ranking as rk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(mesh.devices.flat[0])
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    text = jax.jit(functools.partial(
+        rk._lambda_grad_device, kcap=kcap, L=L, exp_gain=True,
+        objective="ndcg", chunk=chunk)).lower(
+            arg((rows,)), arg((groups, L)),
+            rk._Tiles(*[arg((groups,), jnp.int32)] * 3),
+            arg((rows,))).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    assert sum("rank.layout" in c for c in calls) == 1
+    assert sum("rank.reduce" in c for c in calls) == 2
+    row_gathers = [line for line in text.splitlines()
+                   if " gather(" in line and str(rows) in line]
+    assert not row_gathers

@@ -236,6 +236,9 @@ def test_ranking_rounds_have_gradient_spans_and_counters(tmp_path):
     assert c1["pair_slots"] - c0["pair_slots"] == 3 * G * min(8, L) * L
     assert c1["pairs_kept"] - c0["pairs_kept"] == 3 * kept
     assert c1["fill_ratio"] == pytest.approx(sizes.sum() / (G * L))
+    # the scores in, both sums out: float32 rows and [G, L] slots each way
+    assert c1["layout_bytes"] - c0["layout_bytes"] == \
+        3 * 3 * 4 * (sizes.sum() + G * L)
 
 
 RANK_SCOPE = re.compile(r"rank\.[a-z_]+")
@@ -254,17 +257,14 @@ def test_ranking_gradient_carries_its_root_and_parts(method):
     info.group_ptr = np.concatenate([[0], np.cumsum(sizes)])
     obj = get_objective("rank:ndcg", {"lambdarank_pair_method": method,
                                       "lambdarank_num_pair_per_sample": 2})
-    lay = obj._device_layout(info)
+    lay = obj._device_layout(info, method, 2)
     from xgboost_tpu.objective import ranking as rk
     s = jnp.zeros(len(y), jnp.float32)
-    args = (s, lay["y"], lay["qidx"], lay["slot"], lay["starts"],
-            lay["sizes"], lay["w_row"])
-    kw = dict(L=lay["L"], exp_gain=True, objective="ndcg", chunk=4,
-              n_groups=lay["G"])
+    args = (s, lay["y_pad"], lay["tiles"], lay["w_row"])
+    kw = dict(L=lay["L"], exp_gain=True, objective="ndcg", chunk=4)
     if method == "topk":
         low = rk._lambda_grad_device.lower(*args, kcap=2, **kw)
     else:
-        lay = obj._mean_stats(lay)
         low = rk._lambda_grad_device_mean.lower(
             *args, jax.random.key(0), lay["y_order"], lay["n_lefts"],
             lay["n_geq"], k=2, **kw)

@@ -11,6 +11,13 @@ better-ranked doc is inside the truncation, each pair once).
 All three objectives run ON DEVICE in both pair modes: groups pad into a
 ``[G, L]`` matrix (L = longest group), chunked over groups by ``lax.map``
 to bound memory — the TPU answer to the reference's per-pair CUDA kernels.
+Rows move in and out of that layout by whole 128-lane tiles: a group's
+rows are contiguous, so its L slots are a few tiles of rows moved by the
+lane its first row sits at (``_padded_layout``), and back (``_rows_of``),
+two Pallas kernels that copy a span of tiles a grid step by DMA
+(``ops/pallas/rank_layout.py``). The tables come from the group pointer
+once a dataset (``_tile_tables``), and so do the padded labels: a round
+moves its scores in and its sums out.
 ``topk`` (top-k docs × the docs ranked below them, deterministic) works in
 RANK order: one sort a chunk puts scores, labels and slots by (score,
 slot), the pair interaction is a ``[C, K, L]`` VPU tensor over the sorted
@@ -47,7 +54,7 @@ from __future__ import annotations
 import functools
 import os
 import time
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +63,8 @@ import numpy as np
 from ..obs import trace as obs_trace
 from ..obs.metrics import count_rank_gradient
 from ..obs.trace import rank_scope, stage
+from ..ops.pallas import rank_layout
+from ..ops.pallas.rank_layout import LANES
 from ..registry import OBJECTIVES
 from .base import ObjInfo, Objective
 
@@ -105,26 +114,59 @@ def _map_prefix_ranked(rel_rank, L):
     return Ck, T0, R
 
 
-def _padded_layout(s, y, starts, sizes, *, n_groups, chunk, L):
-    """Scores and labels as ``[Gp, L]`` (Gp: the groups padded up to whole
-    chunks), the mask of real slots and the group sizes ``[Gp]``.
-    ``_device_layout`` lists the rows group by group, so group g's slots are
-    the L rows from ``starts[g]`` on, masked past its size: one gather of
-    whole slices, where a scatter of single rows costs the device a sort of
-    all rows and leaves ops that carry no scope."""
-    Gp = -(-n_groups // chunk) * chunk
-    first = jnp.zeros((Gp,), jnp.int32).at[:n_groups].set(starts)
-    sz = jnp.zeros((Gp,), jnp.int32).at[:n_groups].set(
-        sizes.astype(jnp.int32))
-    valid = jnp.arange(L, dtype=jnp.int32)[None, :] < sz[:, None]
+class _Tiles(NamedTuple):
+    """Where the groups lie in the rows' 128-lane tiles, from the group
+    pointer alone (``_tile_tables``): group g's ``sizes[g]`` rows start at
+    lane ``shift[g]`` of tile ``first[g]``. ``[Gp]`` each: the groups
+    padded up to whole chunks hold no row and start past the last."""
+    first: jax.Array
+    shift: jax.Array
+    sizes: jax.Array
 
-    def padded(rows, fill):
-        ext = jnp.concatenate([rows, jnp.zeros((L,), rows.dtype)])
-        block = jax.vmap(
-            lambda p: jax.lax.dynamic_slice(ext, (p,), (L,)))(first)
-        return jnp.where(valid, block, fill)
 
-    return Gp, padded(s, -jnp.inf), padded(y, 0.0), valid, sz
+def _tile_tables(ptr: np.ndarray, Gp: int) -> _Tiles:
+    """The ``_Tiles`` of a group pointer, on the host."""
+    ptr = np.asarray(ptr, np.int64)
+    G = len(ptr) - 1
+
+    def per_group(a, pad=0):
+        return np.pad(a, (0, Gp - G), constant_values=pad).astype(np.int32)
+
+    return _Tiles(per_group(ptr[:-1] // LANES, -(-ptr[-1] // LANES)),
+                  per_group(ptr[:-1] % LANES), per_group(np.diff(ptr)))
+
+
+def _chunk(G: int, slots: int) -> int:
+    """Groups a ``lax.map`` step of the device kernels: as many as keep one
+    pair block, ``slots`` a group, at ~64 MB of float32."""
+    return max(1, min(G, (1 << 24) // max(slots, 1)))
+
+
+def _interpret() -> bool:
+    """Whether the layout kernels run in the Pallas interpreter: off a TPU,
+    so that every backend runs the one form (a CPU round pays the
+    interpreter: PERF.md §6)."""
+    return jax.default_backend() != "tpu"
+
+
+def _padded_layout(rows, tiles: _Tiles, L: int, fill):
+    """``rows [n]`` as ``[Gp, L]``: group g's rows in its first slots, ``fill``
+    past its size. ``_device_layout`` lists the rows group by group, so
+    group g's L slots are the tiles of 128 rows from ``tiles.first[g]`` on,
+    moved ``tiles.shift[g]`` lanes left: ``rank_layout.rows_in`` copies a
+    span of tiles a grid step into VMEM and moves each group's lanes there,
+    with no index a slot (a gather or scatter of single rows costs the
+    device a hundred times the tiles' moves)."""
+    return rank_layout.rows_in(rows, *tiles, L=L, fill=fill,
+                               interpret=_interpret())
+
+
+def _rows_of(pad, tiles: _Tiles, n: int):
+    """``_padded_layout`` backwards: ``pad [Gp, L]`` to ``[n]`` rows, by
+    ``rank_layout.rows_out``: each group's slots moved ``tiles.shift``
+    lanes right onto the rows' tiles. Slots past a group's size never reach
+    a row."""
+    return rank_layout.rows_out(pad, *tiles, n=n, interpret=_interpret())
 
 
 def _ranknet_dev(s_i, s_j, a_is_i, delta, mask):
@@ -226,13 +268,10 @@ def _topk_anchors(kcap: int, L: int) -> int:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("kcap", "L", "exp_gain", "objective", "chunk",
-                     "n_groups", "kpos"))
+    static_argnames=("kcap", "L", "exp_gain", "objective", "chunk", "kpos"))
 @stage("gradient")
-def _lambda_grad_device(s, y, qidx, slot, starts, sizes, w_row, ti=None,
-                        tj=None, *,
-                        kcap, L, exp_gain, objective, chunk, n_groups,
-                        kpos=0):
+def _lambda_grad_device(s, y_pad, tiles, w_row, ti=None, tj=None, *,
+                        kcap, L, exp_gain, objective, chunk, kpos=0):
     """Truncated all-pairs LambdaRank lambdas over padded [G, L] groups.
 
     Exactly the host loop's math (orientation, RankNet clip, 1e-16 hessian
@@ -249,9 +288,9 @@ def _lambda_grad_device(s, y, qidx, slot, starts, sizes, w_row, ti=None,
     one sort by slot returns the sums. No gather inside the chunk loop.
     """
     K = _topk_anchors(kcap, L)
+    Gp, sz = y_pad.shape[0], tiles.sizes
     with rank_scope("layout"):
-        Gp, s_pad, y_pad, _valid, sz = _padded_layout(
-            s, y, starts, sizes, n_groups=n_groups, chunk=chunk, L=L)
+        s_pad = _padded_layout(s, tiles, L, -jnp.inf)
         kc = sz if kcap == 0 else jnp.minimum(kcap, sz)
         pos = jnp.arange(L, dtype=jnp.int32)
         disc = 1.0 / jnp.log2(pos.astype(jnp.float32) + 2.0)
@@ -349,15 +388,16 @@ def _lambda_grad_device(s, y, qidx, slot, starts, sizes, w_row, ti=None,
     with rank_scope("pairs"):       # the chunk loop's own glue included
         g_pad, h_pad, li_s, lj_s = jax.lax.map(
             one_chunk, (cs(s_pad), cs(y_pad), cs(sz), cs(kc)))
-    return _rows_of(g_pad, h_pad, li_s, lj_s, qidx, slot, w_row, Gp, L, kpos)
+    return _gpairs_of(g_pad, h_pad, li_s, lj_s, tiles, w_row, L, kpos)
 
 
-def _rows_of(g_pad, h_pad, li_s, lj_s, qidx, slot, w_row, Gp, L, kpos):
-    """The padded sums gathered back to rows: ([n, 1, 2] gradient pairs,
-    li, lj); the last two None unless the unbiased path is on."""
+def _gpairs_of(g_pad, h_pad, li_s, lj_s, tiles, w_row, L, kpos):
+    """The padded sums back in rows: ([n, 1, 2] gradient pairs, li, lj); the
+    last two None unless the unbiased path is on."""
     with rank_scope("reduce"):
-        g = g_pad.reshape(Gp, L)[qidx, slot] * w_row
-        h = h_pad.reshape(Gp, L)[qidx, slot] * w_row
+        n = w_row.shape[0]
+        g = _rows_of(g_pad.reshape(-1, L), tiles, n) * w_row
+        h = _rows_of(h_pad.reshape(-1, L), tiles, n) * w_row
         gpair = jnp.stack([g, h], axis=-1)[:, None, :]   # [n, 1, 2] f32
         if kpos > 0:
             m = min(kpos, L)
@@ -371,13 +411,11 @@ def _rows_of(g_pad, h_pad, li_s, lj_s, qidx, slot, w_row, Gp, L, kpos):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k", "L", "exp_gain", "objective", "chunk",
-                     "n_groups", "kpos"))
+    static_argnames=("k", "L", "exp_gain", "objective", "chunk", "kpos"))
 @stage("gradient")
-def _lambda_grad_device_mean(s, y, qidx, slot, starts, sizes, w_row, key,
-                             y_order_g, n_lefts_g, n_geq_g, ti=None,
-                             tj=None, *, k, L, exp_gain, objective, chunk,
-                             n_groups, kpos=0):
+def _lambda_grad_device_mean(s, y_pad, tiles, w_row, key, op, nl_p, ng_p,
+                             ti=None, tj=None, *, k, L, exp_gain, objective,
+                             chunk, kpos=0):
     """Sampled-pair (``mean``) LambdaRank lambdas over padded [G, L] groups.
 
     The reference's distribution (``lambdarank_obj.h:231-275``): each doc
@@ -387,15 +425,13 @@ def _lambda_grad_device_mean(s, y, qidx, slot, starts, sizes, w_row, key,
     than the all-pairs kernel, letting much larger group chunks ride one
     ``lax.map`` step. RNG stream: jax.random.split(key, n_chunks)
     (chunk-size-dependent); the reference seeds per (iter, group), so
-    distributional — not bitwise — parity."""
+    distributional — not bitwise — parity. ``op``, ``nl_p``, ``ng_p``: the
+    bucket statistics at ``[Gp, L]`` (``_LambdaRankBase._mean_stats``)."""
+    Gp, sz = y_pad.shape[0], tiles.sizes
     with rank_scope("layout"):
-        Gp, s_pad, y_pad, valid, sz = _padded_layout(
-            s, y, starts, sizes, n_groups=n_groups, chunk=chunk, L=L)
+        s_pad = _padded_layout(s, tiles, L, -jnp.inf)
+        valid = jnp.arange(L, dtype=jnp.int32)[None, :] < sz[:, None]
         disc = 1.0 / jnp.log2(jnp.arange(L, dtype=jnp.float32) + 2.0)
-        # pad the precomputed per-group bucket statistics to [Gp, L]
-        op = jnp.zeros((Gp, L), jnp.int32).at[:n_groups].set(y_order_g)
-        nl_p = jnp.zeros((Gp, L), jnp.int32).at[:n_groups].set(n_lefts_g)
-        ng_p = jnp.zeros((Gp, L), jnp.int32).at[:n_groups].set(n_geq_g)
 
     def gains_j(v):
         return (jnp.exp2(v) - 1.0) if exp_gain else v
@@ -473,7 +509,7 @@ def _lambda_grad_device_mean(s, y, qidx, slot, starts, sizes, w_row, key,
         g_pad, h_pad, li_s, lj_s = jax.lax.map(
             one_chunk, (cs(s_pad), cs(y_pad), cs(valid), cs(sz), cs(op),
                         cs(nl_p), cs(ng_p), keys))
-    return _rows_of(g_pad, h_pad, li_s, lj_s, qidx, slot, w_row, Gp, L, kpos)
+    return _gpairs_of(g_pad, h_pad, li_s, lj_s, tiles, w_row, L, kpos)
 
 
 class _LambdaRankBase(Objective):
@@ -512,12 +548,19 @@ class _LambdaRankBase(Objective):
     def _delta(self, y, i, j, rank_of, inv_idcg, exp_gain) -> np.ndarray:
         raise NotImplementedError
 
-    def _device_layout(self, info):
-        """Cached padded-group indexing arrays (+ per-row weights). The key
-        hashes the CONTENT of labels/groups/weights, not object identity:
-        a mutated-in-place MetaInfo or a recycled id() must rebuild, or the
-        device gradient would silently use stale y/slots (the host path
-        re-reads them every call). What the key costs a call is kept in
+    def _device_layout(self, info, method: str, k: int):
+        """The cached padded layout of a dataset for the pair method
+        ``method`` (``k``: its truncation or rivals a row): the tile tables
+        (``_Tiles``), the padded labels ``y_pad [Gp, L]`` (0 past a group's
+        size), the per-row weights, and under ``mean`` the bucket statistics
+        (``_mean_stats``); Gp is the groups padded up to whole chunks of
+        ``_chunk(G, slots)`` groups (``slots``: the pair block a group).
+        All of it is static per dataset: a round moves
+        only its scores in and its sums out. The key hashes the CONTENT of
+        labels/groups/weights, not object identity: a mutated-in-place
+        MetaInfo or a recycled id() must rebuild, or the device gradient
+        would silently use stale labels or groups (the host path re-reads
+        them every call). What the key costs a call is kept in
         ``layout_key_ms`` (``core.Booster.update`` puts the last reading on
         its ``round/gradient`` span); the build itself is the ``rank/layout``
         span, once a dataset."""
@@ -527,39 +570,36 @@ class _LambdaRankBase(Objective):
         w_np = (None if info.weights is None
                 else np.asarray(info.weights, np.float32))
         key = (hash(ptr.tobytes()), hash(y_np.tobytes()),
-               None if w_np is None else hash(w_np.tobytes()))
+               None if w_np is None else hash(w_np.tobytes()), method, k)
         self.layout_key_ms = 1e3 * (time.perf_counter() - t0)
         cached = getattr(self, "_dev_layout", None)
         if cached is not None and cached[0] == key:
             return cached[1]
         sizes = np.diff(ptr)
-        G, L = len(sizes), int(sizes.max(initial=1))
+        G, L, n = len(sizes), int(sizes.max(initial=1)), int(ptr[-1])
+        # the pair block a group: [K, L] in rank order, [L, k] sampled
+        slots = (_topk_anchors(k, L) if method == "topk" else k) * L
+        chunk = _chunk(G, slots)
+        Gp = -(-G // chunk) * chunk
         with obs_trace.phase("rank/layout", "train",
-                             {"groups": G, "longest": L,
-                              "rows": int(ptr[-1])}):
-            qidx = np.repeat(np.arange(G, dtype=np.int32), sizes)
-            slot = (np.arange(ptr[-1], dtype=np.int32)
-                    - np.repeat(ptr[:-1], sizes).astype(np.int32))
+                             {"groups": G, "longest": L, "rows": n}):
+            qidx = np.repeat(np.arange(G), sizes)
+            slot = np.arange(n) - np.repeat(ptr[:-1], sizes)
+            y_pad = np.zeros((Gp, L), np.float32)
+            y_pad[qidx, slot] = y_np
             if w_np is not None:
                 w_row = np.repeat(w_np, sizes) if len(w_np) == G else w_np
             else:
-                w_row = np.ones(int(ptr[-1]), np.float32)
+                w_row = np.ones(n, np.float32)
             layout = dict(
-                G=G, L=L, _ptr=ptr, _y_np=y_np,
-                qidx=jnp.asarray(qidx), slot=jnp.asarray(slot),
-                starts=jnp.asarray(ptr[:-1], jnp.int32),
-                sizes=jnp.asarray(sizes, jnp.int32),
-                w_row=jnp.asarray(w_row),
-                y=jnp.asarray(y_np),
-                fill=float(ptr[-1]) / max(G * L, 1))
+                G=G, L=L, n=n, chunk=chunk, slots=slots, _ptr=ptr,
+                tiles=jax.tree.map(jnp.asarray, _tile_tables(ptr, Gp)),
+                y_pad=jnp.asarray(y_pad), w_row=jnp.asarray(w_row),
+                fill=float(n) / max(G * L, 1))
+            if method == "mean":
+                self._mean_stats(layout, y_pad, qidx, slot)
         self._dev_layout = (key, layout)
         return layout
-
-    @staticmethod
-    def _chunk_of(layout, block: int) -> int:
-        """Groups a ``lax.map`` step of the device kernels: as many as keep
-        one pair block, ``block`` slots a group, at ~64 MB of float32."""
-        return max(1, min(layout["G"], (1 << 24) // max(block, 1)))
 
     @staticmethod
     def _pairs_kept(layout, kcap: int) -> int:
@@ -577,41 +617,36 @@ class _LambdaRankBase(Objective):
         return kept[kcap]
 
     @staticmethod
-    def _mean_stats(layout):
-        """Lazily attach the mean-sampling bucket statistics to a cached
-        layout (static per dataset; only mean-mode gradients read them —
-        topk callers never pay the build or the 3 [G, L] device arrays).
-        Same count-based encoding as the host ``_bucket_stats`` (see its
-        invariant note), built vectorized over chunked [c, L, L] counts."""
-        if "y_order" not in layout:
-            ptr, y_np = layout["_ptr"], layout["_y_np"]
-            G, L = layout["G"], layout["L"]
-            # padded [G, L] label matrix; pads sort last / count nowhere
-            sizes = np.diff(ptr)
-            qidx = np.repeat(np.arange(G), sizes)
-            slot = np.arange(int(ptr[-1])) - np.repeat(ptr[:-1], sizes)
-            y_pad = np.zeros((G, L), np.float32)
-            vpad = np.zeros((G, L), bool)
-            y_pad[qidx, slot] = y_np
-            vpad[qidx, slot] = True
-            y_order = np.argsort(
-                np.where(vpad, -y_pad, np.inf), axis=1,
-                kind="stable").astype(np.int32)
-            # vectorized bucket counts, chunked so [c, L, L] stays bounded
-            n_lefts = np.zeros((G, L), np.int32)
-            n_geq = np.zeros((G, L), np.int32)
-            c = max(1, (1 << 24) // max(L * L, 1))
-            for a in range(0, G, c):
-                b = min(G, a + c)
-                yq = y_pad[a:b, None, :]
-                vq = vpad[a:b, None, :]
-                yi = y_pad[a:b, :, None]
-                n_lefts[a:b] = (vq & (yq > yi)).sum(axis=2)
-                n_geq[a:b] = (vq & (yq >= yi)).sum(axis=2)
-            layout["y_order"] = jnp.asarray(y_order)
-            layout["n_lefts"] = jnp.asarray(n_lefts)
-            layout["n_geq"] = jnp.asarray(n_geq)
-        return layout
+    def _mean_stats(layout, y_pad, qidx, slot):
+        """Attach the mean-sampling bucket statistics to a layout, as
+        ``[Gp, L]`` device arrays (only mean-mode gradients read them; the
+        pad groups' rows are zero). Same count-based encoding as the host
+        ``_bucket_stats`` (see its invariant note), built vectorized over
+        chunked [c, L, L] counts from the padded labels and the rows'
+        (group, slot)."""
+        G, L = layout["G"], layout["L"]
+        y_pad = y_pad[:G]
+        # pads sort last / count nowhere
+        vpad = np.zeros((G, L), bool)
+        vpad[qidx, slot] = True
+        y_order = np.argsort(
+            np.where(vpad, -y_pad, np.inf), axis=1,
+            kind="stable").astype(np.int32)
+        # vectorized bucket counts, chunked so [c, L, L] stays bounded
+        n_lefts = np.zeros((G, L), np.int32)
+        n_geq = np.zeros((G, L), np.int32)
+        c = max(1, (1 << 24) // max(L * L, 1))
+        for a in range(0, G, c):
+            b = min(G, a + c)
+            yq = y_pad[a:b, None, :]
+            vq = vpad[a:b, None, :]
+            yi = y_pad[a:b, :, None]
+            n_lefts[a:b] = (vq & (yq > yi)).sum(axis=2)
+            n_geq[a:b] = (vq & (yq >= yi)).sum(axis=2)
+        rows = ((0, layout["tiles"].sizes.shape[0] - G), (0, 0))
+        for name, a in (("y_order", y_order), ("n_lefts", n_lefts),
+                        ("n_geq", n_geq)):
+            layout[name] = jnp.asarray(np.pad(a, rows))
 
     def get_gradient(self, preds, info, iteration=0):
         if info.group_ptr is None:
@@ -638,8 +673,10 @@ class _LambdaRankBase(Objective):
         if (self.name in ("rank:ndcg", "rank:pairwise", "rank:map")
                 and method in ("topk", "mean")
                 and os.environ.get("XTPU_RANK_HOST") != "1"):
-            lay = self._device_layout(info)
-            n = lay["y"].shape[0]
+            k = int(self.params.get("lambdarank_num_pair_per_sample",
+                                    1 if method == "mean" else 0))
+            lay = self._device_layout(info, method, k)
+            n, L = lay["n"], lay["L"]
             s = jnp.asarray(preds, jnp.float32).reshape(-1)[:n]
             kpos, ti_d, tj_d = 0, None, None
             if unbiased:
@@ -652,40 +689,32 @@ class _LambdaRankBase(Objective):
                 # twice per round (numerically identical, the update
                 # still precedes this iteration's gradient; not measured
                 # on the chip: no cell runs the unbiased path, PERF.md 7)
-                kpos = self._position_bias_state(method, int(lay["L"]))
+                kpos = self._position_bias_state(method, L)
                 bias = jnp.asarray(
                     np.stack([self._ti_plus, self._tj_minus]), jnp.float32)
                 ti_d, tj_d = bias[0], bias[1]
+            fixed = (s, lay["y_pad"], lay["tiles"], lay["w_row"])
+            static = dict(L=L, exp_gain=exp_gain, chunk=lay["chunk"],
+                          objective=self.name.split(":")[1], kpos=kpos)
             if method == "mean":
-                lay = self._mean_stats(lay)
-                k = int(self.params.get(
-                    "lambdarank_num_pair_per_sample", 1))
                 key = jax.random.fold_in(
                     jax.random.key(int(self.params.get("seed", 0))),
                     iteration)
-                slots, kept = lay["L"] * k, n * k   # the [C, L, k] block
-                chunk = self._chunk_of(lay, slots)
+                kept = n * k                       # the [C, L, k] block
                 gpair, li, lj = _lambda_grad_device_mean(
-                    s, lay["y"], lay["qidx"], lay["slot"], lay["starts"],
-                    lay["sizes"], lay["w_row"], key, lay["y_order"], lay["n_lefts"],
-                    lay["n_geq"], ti_d, tj_d, k=k, L=lay["L"],
-                    exp_gain=exp_gain, objective=self.name.split(":")[1],
-                    chunk=chunk, n_groups=lay["G"], kpos=kpos)
+                    *fixed, key, lay["y_order"], lay["n_lefts"],
+                    lay["n_geq"], ti_d, tj_d, k=k, **static)
             else:
-                kcap = int(self.params.get(
-                    "lambdarank_num_pair_per_sample", 0))
                 # the [C, K, L] block in rank order: K anchors a group
-                slots = _topk_anchors(kcap, lay["L"]) * lay["L"]
-                kept = self._pairs_kept(lay, kcap)
-                chunk = self._chunk_of(lay, slots)
+                kept = self._pairs_kept(lay, k)
                 gpair, li, lj = _lambda_grad_device(
-                    s, lay["y"], lay["qidx"], lay["slot"], lay["starts"],
-                    lay["sizes"], lay["w_row"], ti_d, tj_d, kcap=kcap, L=lay["L"],
-                    exp_gain=exp_gain, objective=self.name.split(":")[1],
-                    chunk=chunk, n_groups=lay["G"], kpos=kpos)
-            # groups padded up to whole chunks are swept too
-            count_rank_gradient(method, -(-lay["G"] // chunk) * chunk * slots,
-                                kept, lay["fill"])
+                    *fixed, ti_d, tj_d, kcap=k, **static)
+            # groups padded up to whole chunks are swept too; the scores
+            # move in and the two sums out, each read in one layout and
+            # written in the other
+            Gp = lay["y_pad"].shape[0]
+            count_rank_gradient(method, Gp * lay["slots"], kept, lay["fill"],
+                                3 * 4 * (n + Gp * L))
             if unbiased:
                 # ONE packed device array, pulled lazily at the next
                 # gradient call / serialization (see _flush_bias_update)

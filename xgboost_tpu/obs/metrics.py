@@ -510,10 +510,11 @@ _RANK_SLOTS = "xtpu_rank_pair_slots_total"
 _RANK_KEPT = "xtpu_rank_pairs_kept_total"
 _RANK_DISPATCHES = "xtpu_rank_gradient_dispatches_total"
 _RANK_FILL = "xtpu_rank_layout_fill_ratio"
+_RANK_LAYOUT_BYTES = "xtpu_rank_layout_bytes_total"
 
 
 def count_rank_gradient(method: str, pair_slots: int, pairs_kept: int,
-                        fill_ratio: float) -> None:
+                        fill_ratio: float, layout_bytes: int) -> None:
     """One device dispatch of the ranking gradient (``objective/ranking.py``).
     ``pair_slots``: slots of the pair blocks it sweeps (steps x C x K x L
     under ``topk``: the ``[C, K, L]`` block in rank order, K = the truncation
@@ -521,8 +522,14 @@ def count_rank_gradient(method: str, pair_slots: int, pairs_kept: int,
     ``pairs_kept``: pairs
     the truncation admits inside the groups' real rows, from the group sizes
     alone: an upper bound on the pairs that carry a lambda (label ties are
-    not counted out). Both are host arithmetic on the cached layout: no
+    not counted out). ``layout_bytes``: what the dispatch moves between row
+    order and the padded ``[Gp, L]`` layout, both ways (the scores in, the
+    gradient and hessian sums out: each read once in one layout and written
+    once in the other). All are host arithmetic on the cached layout: no
     device pull."""
+    _registry.inc(_RANK_LAYOUT_BYTES, by=float(layout_bytes),
+                  help="bytes the ranking gradient moves between row order "
+                       "and its padded layout")
     _registry.inc(_RANK_DISPATCHES, labels=(("method", method),),
                   help="device dispatches of the ranking gradient, by pair "
                        "method")
@@ -538,9 +545,10 @@ def count_rank_gradient(method: str, pair_slots: int, pairs_kept: int,
 
 def rank_counts() -> Dict[str, Any]:
     """The ranking counters as one dict: ``pair_slots``, ``pairs_kept``,
-    ``fill_ratio`` and ``dispatches`` by method."""
+    ``layout_bytes``, ``fill_ratio`` and ``dispatches`` by method."""
     return {"pair_slots": _registry.get(_RANK_SLOTS),
             "pairs_kept": _registry.get(_RANK_KEPT),
+            "layout_bytes": _registry.get(_RANK_LAYOUT_BYTES),
             "fill_ratio": _registry.get(_RANK_FILL),
             "dispatches": {k: int(v) for k, v in _by_label(
                 _RANK_DISPATCHES, "method").items()}}
