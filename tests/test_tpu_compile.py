@@ -203,6 +203,89 @@ def test_wide_missing_kernels_compile_for_v5e(mesh, kernel, nodes, width):
                                      "stacked": dots.get("stacked", 0) + 1}
 
 
+# the PASCAL epsilon job's width (benchmark cell pascal-epsilon.wide-train):
+# 2,000 dense columns of one-byte ids. The refine build at 128 nodes and 36
+# slots (200 features a block) and the last level's advance over the whole-F
+# tile need more than the default scoped VMEM (20.44 and 24.75 MiB), and
+# state their own limit
+@pytest.mark.parametrize("kernel,nodes,width", [
+    ("build_hist_int8", 128, 36),
+    ("advance_leaf", 128, 0),
+    ("advance_leaf", 256, 0),
+])
+def test_wide_dense_kernels_compile_for_v5e(mesh, kernel, nodes, width):
+    from jax.sharding import SingleDeviceSharding
+
+    from xgboost_tpu.obs.metrics import get_registry, kernel_vmem_bytes
+    from xgboost_tpu.ops.pallas import histogram as ph
+
+    one = SingleDeviceSharding(mesh.devices.flat[0])
+    n, F = 4096, 2000
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    get_registry().set_gauge("xtpu_kernel_vmem_bytes", 0,
+                             (("kernel", kernel),))
+    if kernel == "build_hist_int8":
+        fn = jax.jit(lambda b, g, p: ph.build_hist_pallas(b, g, p, nodes,
+                                                          width))
+        args = (arg((F, n), jnp.uint8), arg((n, 2), jnp.float32),
+                arg((n,), jnp.int32))
+    else:
+        fn = jax.jit(lambda b, p, f, t, d, c, v: ph.advance_leaf_pallas(
+            b, p, f, t, d, c, v, n_prev=nodes, missing_bin=256))
+        args = (arg((F, n), jnp.uint8), arg((n,), jnp.int32),
+                arg((nodes,), jnp.int32), arg((nodes,), jnp.int32),
+                arg((nodes,), jnp.bool_), arg((nodes,), jnp.bool_),
+                arg((4 * nodes - 1,), jnp.float32))
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and f"xtpu.kernel.{kernel}" in text
+    assert kernel_vmem_bytes()[kernel] > ph.SCOPED_VMEM
+
+
+def test_wide_dense_grow_program_compiles_for_v5e(monkeypatch, mesh):
+    """The one-device grow program at the epsilon job's shape (400,000 x
+    2,000 one-byte ids, depth 8) for one described chip: the boundary sweep
+    on the kernel into 2-16 nodes and on its XLA body into 32, 64 and 128
+    (past the coarse accumulator's 8 MiB gate), ``advance_leaf`` below the
+    last level, and every wide kernel under the limit it states."""
+    from jax.sharding import SingleDeviceSharding
+
+    from xgboost_tpu.obs.metrics import (fused_boundary_counts,
+                                         hist_body_features,
+                                         kernel_vmem_bytes)
+    from xgboost_tpu.ops.pallas import histogram as ph
+    from xgboost_tpu.tree.grow import _grow
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(mesh.devices.flat[0])
+    rows, F = 400_000, 2000
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    boundaries = fused_boundary_counts()
+    before_epilogue = grow_epilogue_counts().get("kernel", 0)
+    text = _grow.lower(
+        arg((rows, F), jnp.uint8), arg((rows, 2), jnp.float32),
+        arg((F,), jnp.int32), arg((F,), jnp.bool_), arg((2,), jnp.uint32),
+        param=TrainParam(max_depth=8), max_nbins=MAX_NBINS,
+        has_missing=False).compile().as_text()
+    assert fused_boundary_counts() == {
+        "kernel": boundaries.get("kernel", 0) + 4,
+        "xla": boundaries.get("xla", 0) + 3}
+    assert grow_epilogue_counts().get("kernel", 0) == before_epilogue + 1
+    # the root's coarse build, 8 refine builds, 4 sweeps, the 3 XLA
+    # boundaries' coarse builds and advance_leaf
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2 * 8 + 1
+    assert "xtpu.kernel.advance_leaf" in text
+    assert 0 < hist_body_features() <= ph.FEATURE_GROUP
+    vmem = kernel_vmem_bytes()
+    for kernel in ("build_hist_int8", "fused_advance_coarse", "advance_leaf"):
+        assert vmem[kernel] > ph.SCOPED_VMEM, vmem
+
+
 # the ranking job's layout (istella-letor.train: 7,325,625 rows in 23,219
 # groups of at most 1,024, 23,552 padded, truncation 32), a ragged one: a
 # longest group of no whole number of tiles, groups of no whole step, and

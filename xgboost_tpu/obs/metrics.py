@@ -467,6 +467,32 @@ def hist_body_features() -> int:
     return int(_registry.get(_HIST_BODY))
 
 
+_KERNEL_VMEM = "xtpu_kernel_vmem_bytes"
+
+
+def note_kernel_vmem(kernel: str, nbytes: int) -> None:
+    """The scoped VMEM one traced Mosaic histogram kernel compiles under
+    (``ops/pallas/histogram.py``, by its ``name``): the ``vmem_limit_bytes``
+    its wrapper states, or the compiler's default where it states none.
+    Counted while jax traces, as ``note_hist_body_features``; the gauge keeps
+    the largest seen in the process for each kernel."""
+    labels = (("kernel", kernel),)
+    if nbytes > _registry.get(_KERNEL_VMEM, labels):
+        _registry.set_gauge(_KERNEL_VMEM, nbytes, labels,
+                            help="largest scoped VMEM a traced Mosaic "
+                                 "histogram kernel states or is sized to, "
+                                 "by kernel")
+
+
+def kernel_vmem_bytes() -> Dict[str, int]:
+    """``{kernel: bytes}`` of ``xtpu_kernel_vmem_bytes``."""
+    with _registry._lock:
+        gauges = dict(_registry._gauges)
+    return {dict(labels)["kernel"]: int(value)
+            for (name, labels), value in gauges.items()
+            if name == _KERNEL_VMEM}
+
+
 _BINNED_MISSING = "xtpu_binned_missing_ratio"
 _BINNED_BYTES = "xtpu_binned_bin_bytes"
 

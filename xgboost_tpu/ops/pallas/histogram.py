@@ -64,7 +64,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...obs.metrics import (count_hist_dot, count_hist_onehot,
-                            note_hist_body_features)
+                            note_hist_body_features, note_kernel_vmem)
 from ...obs.trace import mesh_scope, stage
 
 # The most features a kernel body unrolls. A body's per-feature steps are
@@ -78,9 +78,23 @@ from ...obs.trace import mesh_scope, stage
 # tile for its advance, loops over groups inside the kernel.
 FEATURE_GROUP = 256
 
+# The scoped VMEM a Mosaic kernel may use unless it states a limit of its
+# own (``vmem_limit_bytes``): the compiler's default on a v5e, of the core's
+# 128 MiB.
+SCOPED_VMEM = 16 * 2 ** 20
+
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+def _vmem_params(kernel: str, limit: Optional[int]):
+    """The compiler parameters of a kernel that states ``limit`` bytes of
+    scoped VMEM (None where it keeps the default), noted in the gauge
+    ``xtpu_kernel_vmem_bytes``."""
+    note_kernel_vmem(kernel, SCOPED_VMEM if limit is None else limit)
+    return None if limit is None else pltpu.CompilerParams(
+        vmem_limit_bytes=limit)
 
 
 def _feature_groups(F: int) -> tuple:
@@ -234,6 +248,39 @@ def _acc_shapes(F_blk: int, F_pad: int, B: int, N: int) -> tuple:
     if _dot_features(B, N) > 1:
         return (F_blk * B, 2 * N), (F_pad * B, 2 * N)
     return (F_blk, B, 2 * N), (F_pad, B, 2 * N)
+
+
+def _int8_vmem_bytes(F_blk: int, F_pad: int, B: int, N: int, R: int,
+                     tile_rows: int, itemsize: int, packed: bool) -> int:
+    """Scoped VMEM a fixed-point histogram kernel holds, reckoned from what
+    its body keeps: the input tiles twice (the bin block, its rows padded to
+    whole 32-row tiles of bytes, and the gradient pair and positions, 8
+    sublanes each); ``PT4`` and the int32 planes it is made from, five
+    ``[N, R]`` int32 arrays' worth; a dot's one-hot, ``G * B`` rows of words
+    and of bytes (a ``[B, R]`` int32 compare and its bytes without the SWAR
+    build); and, where the whole ``[F_pad * B, 2N]`` output passes
+    ``_OUT_IN_SCOPE``, the accumulator block's two pipeline buffers.
+
+    Measured for a described v5e at 36 slots and 128 nodes (PERF.md section
+    6): 5.9-6.4 MiB at 80-200 features a block while the whole
+    output is at most 49 MiB (reckoned 6.75-7.25), the output block then
+    outside the scoped allocation; 20.44 MiB at 200 features a block of
+    F = 1,600 and 2,000, whose outputs of 56 and 70 MiB bring the block's
+    two buffers in (reckoned 21.31); 3.95 at 64 nodes and 5.92 at 20 slots
+    (reckoned 4.75 and 6.75)."""
+    tiles = (_round_up(tile_rows, 32) * itemsize + 2 * 8 * 4) * R
+    G = _dot_features(B, N) if packed else 1
+    onehot = (2 if packed else 5) * G * B * R
+    need = 2 * tiles + 5 * N * R * 4 + onehot
+    if F_pad * B * 2 * N * 4 > _OUT_IN_SCOPE:
+        need += 2 * F_blk * B * 2 * N * 4
+    return need
+
+
+# the largest whole histogram output measured to keep its accumulator
+# block outside a kernel's scoped VMEM was 49 MiB, the smallest to bring its
+# two buffers in 56 MiB (``_int8_vmem_bytes``)
+_OUT_IN_SCOPE = 48 * 2 ** 20
 
 
 def _accumulate(out_ref, at, rows, B, K4, owner, PT4):
@@ -582,9 +629,9 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     # tile takes, with the default's room beside it (the accumulator, and a
     # stacked dot's words, one-hot and int32 product: 0.7 MB at 20 slots and
     # 32 nodes); a narrow one keeps the default.
-    params = None if groups == 1 else pltpu.CompilerParams(
-        vmem_limit_bytes=F_pad * R * (4 + 2 * bins_t.dtype.itemsize)
-        + 16 * 2 ** 20)
+    params = _vmem_params("fused_advance_coarse", None if groups == 1 else
+                          F_pad * R * (4 + 2 * bins_t.dtype.itemsize)
+                          + SCOPED_VMEM)
     count_hist_onehot("swar")
     _note_dot(B, N)
     note_hist_body_features(group)
@@ -694,6 +741,11 @@ def _make_advance_leaf_kernel(n_feat: int, n_prev: int, block_rows: int,
 ADVANCE_LEAF_MAX_NODES = 512
 # table sublanes a step of the kernel's select: ``[C, R]`` accumulators
 _LEAF_CHUNK = 64
+# the widest tile ``advance_leaf_pallas`` compiles under the default scoped
+# VMEM, measured for a described v5e below a level of 128 nodes: F = 968
+# two-byte ids at R = 2048, whose two buffers and int32 copy are 15.1 of the
+# 16 MiB (the table and the row vectors take the rest)
+_ADVANCE_LEAF_TILE_FITS = 968 * 2048 * (4 + 2 * 2)
 
 
 @functools.partial(
@@ -725,6 +777,15 @@ def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
         bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
         positions = jnp.pad(positions, (0, n_pad - n), constant_values=-1)
     pos_t = positions.astype(jnp.int32)[None, :]           # [1, n]
+    # The whole-F tile, double-buffered, and its int32 copy. Past the widest
+    # measured to compile under the default scoped VMEM the kernel states
+    # what they take, with the default's room beside it, as the boundary
+    # sweep does: at F = 2,000 with one-byte ids they are 23.4 MiB, and
+    # Mosaic asked 24.75M (PERF.md section 6).
+    tile = F * R * (4 + 2 * bins_t.dtype.itemsize)
+    params = _vmem_params("advance_leaf",
+                          None if tile <= _ADVANCE_LEAF_TILE_FITS
+                          else tile + SCOPED_VMEM)
 
     bits = jax.lax.bitcast_convert_type(
         leaf_value.astype(jnp.float32), jnp.int32)
@@ -759,6 +820,7 @@ def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
                                     memory_space=pltpu.VMEM),
                        pl.BlockSpec((1, R), lambda i: (0, i),
                                     memory_space=pltpu.VMEM)],
+            compiler_params=params,
             interpret=interpret,
             name="advance_leaf",
         )(tab, bins_t, pos_t)
@@ -814,7 +876,9 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
             # the accumulator leaves that headroom (a 12M budget OOMed
             # the Mosaic stack at F=136, B=256, N=32: 17.53M > 16M).
             # (The packed transport exists for max_nbins <= 16, so its
-            # accumulator is far inside the budget.)
+            # accumulator is far inside the budget.) Where what the body
+            # holds with that block passes the default all the same, the
+            # kernel states its limit (``_int8_vmem_bytes``).
             feat_block = max((8 * 2 ** 20) // (B * 2 * N * 4), 8)
         else:
             # f32/bf16 variants stage a [Fb*B, R] scratch — keep it small
@@ -866,6 +930,14 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
             q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
         count_hist_onehot("swar" if packed else "compare")
         _note_dot(B, N, packed)
+        # the default scoped VMEM holds the body at every shape but the
+        # widest; there the kernel states what it holds, with the default's
+        # room beside it
+        vmem = _int8_vmem_bytes(F_blk, F_pad, B, N, R,
+                                bins_spec.block_shape[0],
+                                bins_t.dtype.itemsize, packed)
+        params = _vmem_params("build_hist_int8", None if vmem <= SCOPED_VMEM
+                              else vmem + SCOPED_VMEM)
         with stage("kernel.build_hist_int8"):
             out = pl.pallas_call(
                 _make_int8_kernel(F_blk, B, N, R, packed=packed, u4=u4),
@@ -874,6 +946,7 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                 in_specs=[bins_spec, vec2_spec, pos_spec],
                 out_specs=out_spec,
                 scratch_shapes=[],
+                compiler_params=params,
                 interpret=interpret,
                 name="build_hist_int8",
             )(bins_t, q, pos_t)
@@ -884,6 +957,7 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     else:
         count_hist_onehot("compare")
         _note_dot(B, N, packed=False)
+        _vmem_params("build_hist", None)
         with stage("kernel.build_hist"):
             out = pl.pallas_call(
                 _make_kernel(F_blk, B, N, R, precision, u4=u4),
