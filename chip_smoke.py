@@ -284,7 +284,8 @@ def phase_train(ctx) -> dict:
     from xgboost_tpu.metric.auc import binary_roc_auc
     from xgboost_tpu.obs.metrics import (degrade_counts, eval_walk_counts,
                                          grow_schedule_counts,
-                                         hist_dot_counts, hist_dot_rows)
+                                         hist_dot_counts, hist_dot_rows,
+                                         refine_remap_counts)
     from xgboost_tpu.tree.grow import resolve_schedule
 
     sz = ctx["sizes"]
@@ -392,12 +393,15 @@ def phase_train(ctx) -> dict:
                for k, v in walks.items()]
             + [f'xtpu_hist_dot_total{{form="{k}"}} {v}'
                for k, v in hist_dot_counts().items()]
-            + [f"xtpu_hist_dot_rows {hist_dot_rows()}"]))
+            + [f"xtpu_hist_dot_rows {hist_dot_rows()}"]
+            + [f'xtpu_refine_remap_total{{where="{k}"}} {v}'
+               for k, v in refine_remap_counts().items()]))
     ctx.update(bst=bst, Xh=Xh)
     return {"rows": sz.rows, "schedule": sched.name,
             "grow_schedule_total": traced, "eval_walk_total": walks,
             "hist_dot_total": hist_dot_counts(),
             "hist_dot_rows": hist_dot_rows(),
+            "refine_remap_total": refine_remap_counts(),
             "auc": round(auc, 4),
             "auc_floor": sz.auc_floor, "dispatches": got,
             "tpu_custom_call": custom_calls,

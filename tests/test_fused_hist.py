@@ -251,6 +251,50 @@ def test_advance_leaf_pallas_interpret_matches_walk(n_prev, n, has_missing,
     assert (~moved).any()        # rows that stopped at a leaf earlier
 
 
+def test_kernels_take_the_tile_padded_to_the_row_blocks_once():
+    """A round program pads its ``[F, n]`` tile to the kernels' row blocks
+    once (``tree/grow.py``, ``fit_rows``); each kernel takes that tile as
+    it is, and one padded for a larger block cut to its own: the boundary
+    sweep, the refine build and the last level's advance give what they
+    give over the unpadded tile, every bit."""
+    from xgboost_tpu.ops.pallas.histogram import fit_rows, row_blocks
+    from xgboost_tpu.ops.split import WINDOW
+
+    n, F, block = 700, 6, 256
+    n_pad = row_blocks(n, block)[1]
+    assert n_pad == 768
+    data = _level_data(n, F, 255, 1, 2, seed=3)
+    bins_t = data[0].T
+    padded, wide = fit_rows(bins_t, n_pad), fit_rows(bins_t, 1024)
+    assert padded.shape == (F, n_pad)
+    np.testing.assert_array_equal(np.asarray(fit_rows(wide, n_pad)),
+                                  np.asarray(padded))
+
+    def sweep(tile):
+        return fused_advance_coarse_pallas(
+            tile, *data[1:], lo_prev=1, n_prev=2, lo=3, n_level=4,
+            missing_bin=254, block_rows=block, interpret=True)
+
+    def refine(tile):
+        rel = jnp.asarray(np.arange(n, dtype=np.int32) % 5)   # 4: outside
+        window = jnp.asarray(np.arange(4 * F, dtype=np.int32).reshape(
+            4, F) % 15)
+        return build_hist_pallas(tile, data[1], rel, 4, WINDOW + 4,
+                                 block_rows=block, window=window,
+                                 missing_bin=254, interpret=True)
+
+    def leaf(tile):
+        return advance_leaf_pallas(
+            tile, data[2], *data[3:], jnp.arange(8, dtype=jnp.float32),
+            n_prev=2, missing_bin=254, block_rows=block, interpret=True)
+
+    for kernel in (sweep, refine, leaf):
+        want = jax.tree.leaves(kernel(bins_t))
+        for tile in (padded, wide):
+            for a, b in zip(jax.tree.leaves(kernel(tile)), want):
+                np.testing.assert_array_equal(_bits(a), _bits(b))
+
+
 def test_advance_leaf_op_kinds(monkeypatch):
     """What ``advance_leaf`` reports: the kernel for a walk-kind level on
     a TPU, the XLA advance off it, for a dense level and wherever a

@@ -322,3 +322,65 @@ def test_assemble_two_level_by_selects_equals_the_gather(monkeypatch):
     assert gathered[0].shape == (N, F, split.SYN_B + 1, 2)
     for a, b in zip(gathered, selected):
         np.testing.assert_array_equal(a.view(np.int32), b.view(np.int32))
+
+
+def kernel_body(fn, args, kw):
+    """The jaxpr of the one ``pallas_call`` body that ``fn`` traces (a
+    ``window`` in ``kw`` is passed as an array)."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["jaxpr"])
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (tuple, list))
+                            else (value,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    if "window" in kw:
+        kw = dict(kw)
+        window = kw.pop("window")
+        traced = jax.jit(lambda *a: fn.__wrapped__(*a[:-1], window=a[-1],
+                                                   **kw))
+        args = args + (window,)
+    else:
+        traced = fresh(fn, **kw)
+    walk(jax.make_jaxpr(traced)(*args).jaxpr)
+    assert len(found) == 1
+    return found[0]
+
+
+def packed_hist_traces(body) -> int:
+    """Distinct traces of ``_packed_hist`` the body binds: a jit binds the
+    same jaxpr for every call of one signature."""
+    return len({id(eqn.params["jaxpr"]) for eqn in body.eqns
+                if eqn.primitive.name == "pjit"
+                and eqn.params["name"] == "_packed_hist"})
+
+
+@pytest.mark.parametrize("N", [1, 32])
+@pytest.mark.parametrize("F", [56, 224])
+def test_refine_window_adds_a_fixed_number_of_equations(F, N):
+    """The refine build's in-VMEM remap (``_remap_window``) is one step on
+    the whole ``[F_blk, R]`` block: its body holds the same number of
+    equations more than the body without the window table at every F and
+    N, and traces ``_packed_hist`` no more often. A remap a feature (or a
+    feature and node) would grow with both: the tracing and lowering of a
+    round program is linear in what a body unrolls."""
+    from xgboost_tpu.ops.split import WINDOW
+
+    args, kw = build_args(F, np.uint8, n_nodes=N, width=WINDOW + 4)
+    window = jnp.asarray(
+        np.random.RandomState(F).randint(0, 15, (N, F)).astype(np.int32))
+    plain = kernel_body(ph.build_hist_pallas, args, kw)
+    refine = kernel_body(ph.build_hist_pallas, args,
+                         dict(kw, window=window, missing_bin=WINDOW + 3))
+    assert len(refine.eqns) - len(plain.eqns) == REMAP_EQUATIONS
+    assert packed_hist_traces(refine) == packed_hist_traces(plain) <= 2
+
+
+# the equations ``_remap_window`` adds to a kernel body, whatever F and N
+REMAP_EQUATIONS = 15

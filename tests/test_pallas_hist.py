@@ -130,9 +130,11 @@ def test_int8x2_order_independence_interpret():
 
 
 def _under_rule(monkeypatch, rule, *arrays, **static):
-    """``build_hist_pallas``'s body under ``_dot_features = rule`` and a jit
-    of its own: nothing jax cached under the module's rule is served."""
-    monkeypatch.setattr(ph, "_dot_features", rule)
+    """``build_hist_pallas``'s body under ``_dot_features = rule`` (the
+    module's own where no ``monkeypatch`` is given) and a jit of its own:
+    nothing jax cached under the module's rule is served."""
+    if monkeypatch is not None:
+        monkeypatch.setattr(ph, "_dot_features", rule)
     return np.asarray(jax.jit(
         lambda *a: ph.build_hist_pallas.__wrapped__(
             *a, interpret=True, **static))(*arrays))
@@ -214,6 +216,78 @@ def test_dot_counter_and_gauge_read_what_was_traced(B, N, form, rows):
     assert obs_metrics.hist_dot_rows() == rows
     with pytest.raises(ValueError):
         obs_metrics.count_hist_dot("held", 128)
+
+
+MISSING = {np.uint8: 255, np.uint16: 256}
+
+
+def _refine_level(F, N, ids, n=700):
+    """A refine build's input: raw ids with the missing slot set, a row
+    tail no row block divides, rows outside the level (rel == N), and each
+    node's window start per feature; plus the remapped ids the build
+    replaces, each row's window looked up by numpy (``refine_bin_ids``)."""
+    from xgboost_tpu.ops.split import refine_bin_ids
+
+    missing = MISSING[ids]
+    rng = np.random.RandomState(F + N)
+    bins = rng.randint(0, missing, (n, F)).astype(ids)
+    bins[rng.rand(n, F) < 0.2] = missing
+    gpair = rng.randn(n, 2).astype(np.float32)
+    gpair[:, 1] = np.abs(gpair[:, 1])
+    rel = rng.randint(0, N + 1, n).astype(np.int32)
+    window = rng.randint(0, 15, (N, F)).astype(np.int32)
+    c_row = np.concatenate([window, np.zeros((1, F), np.int32)])[rel]
+    remapped = refine_bin_ids(jnp.asarray(bins, jnp.int32),
+                              jnp.asarray(c_row), missing)
+    return ((jnp.asarray(bins), jnp.asarray(gpair), jnp.asarray(rel),
+             jnp.asarray(window)), remapped, missing)
+
+
+REFINE_CASES = ([("kernel", ids, F, N) for ids in (np.uint8, np.uint16)
+                 for F in (28, 220, 968) for N in (1, 32, 128)]
+                + [("xla", ids, F, N) for ids in (np.uint8, np.uint16)
+                   for F in (28, 220) for N in (1, 32)])
+
+
+@pytest.mark.parametrize("where,ids,F,N", REFINE_CASES)
+def test_refine_window_build_equals_the_remapped_copy(where, ids, F, N):
+    """The refine build over the RAW ids and the window table equals the
+    build over ``refine_bin_ids``' remapped copy, bit for bit: in the
+    int8x2 kernel, which remaps in VMEM (``kernel``: 28 and 220 features
+    whole-F bodies, 968 in feature blocks), and in ``build_hist``'s XLA
+    build, which remaps first (``xla``)."""
+    from xgboost_tpu.obs.metrics import refine_remap_counts
+    from xgboost_tpu.ops.histogram import build_hist
+    from xgboost_tpu.ops.split import WINDOW
+
+    (bins, gpair, rel, window), remapped, missing = _refine_level(F, N, ids)
+    B = WINDOW + 4
+    if where == "kernel":
+        static = dict(n_nodes=N, max_nbins=B, block_rows=256)
+        got = _under_rule(None, None, bins.T, gpair, rel, window=window,
+                          missing_bin=missing, **static)
+        want = _under_rule(None, None, remapped.T, gpair, rel, **static)
+    else:
+        before = refine_remap_counts().get("xla", 0)
+        got = np.asarray(build_hist(bins, gpair, rel, N, B, window=window,
+                                    missing_bin=missing))
+        assert refine_remap_counts()["xla"] == before + 1
+        want = _reference(remapped, gpair, rel, N, B)
+    assert got.shape == (N, F, B, 2)
+    np.testing.assert_array_equal(got, want)
+    # in-window mass lands in the window's slots, the rest in the pad slot
+    assert float(np.abs(got[..., :WINDOW, :]).sum()) > 0
+    assert float(np.abs(got[..., WINDOW + 3, :]).sum()) > 0
+
+
+def test_refine_window_outside_the_int8_kernel_refused():
+    bins, gpair, rel = _data(300, 3, 16, 2)
+    window = jnp.zeros((2, 3), jnp.int32)
+    for static in (dict(precision="f32", missing_bin=15),
+                   dict(missing_bin=None)):
+        with pytest.raises(ValueError):
+            build_hist_pallas(bins.T, gpair, rel, 2, 36, window=window,
+                              interpret=True, **static)
 
 
 @pytest.mark.skipif(os.environ.get("BENCH_TPU") != "1",

@@ -328,3 +328,73 @@ def test_ranking_gradient_compiles_for_v5e(monkeypatch, mesh, rows, groups,
     row_gathers = [line for line in text.splitlines()
                    if " gather(" in line and str(rows) in line]
     assert not row_gathers
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs it calls."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+# the ranking job's width and depth and the HIGGS job's: few rows (tracing
+# does not depend on them), no multiple of a row block
+@pytest.mark.parametrize("features,depth", [(220, 6), (28, 8)])
+def test_refine_builds_remap_in_the_kernel_for_v5e(monkeypatch, mesh,
+                                                   features, depth):
+    """The one-device grow program's refine builds read the raw tile for
+    one described chip: a kernel remap a refined level and none in XLA
+    (``xtpu_refine_remap_total``), the ``[F, n]`` tile padded to the row
+    blocks once in the program, and the refine kernel under the scoped
+    VMEM ``_int8_vmem_bytes`` states for it."""
+    from jax.sharding import SingleDeviceSharding
+
+    from xgboost_tpu.obs.metrics import (get_registry, kernel_vmem_bytes,
+                                         refine_remap_counts)
+    from xgboost_tpu.ops.pallas import histogram as ph
+    from xgboost_tpu.ops.split import WINDOW
+    from xgboost_tpu.tree.grow import _grow
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(mesh.devices.flat[0])
+    rows = AUTO_COARSE_MIN_ROWS + 5 + features   # a shape traced afresh
+    R, n_pad = ph.row_blocks(rows)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    get_registry().set_gauge("xtpu_kernel_vmem_bytes", 0,
+                             (("kernel", "build_hist_int8"),))
+    before = refine_remap_counts()
+    traced = _grow.trace(
+        arg((rows, features), jnp.uint8), arg((rows, 2), jnp.float32),
+        arg((features,), jnp.int32), arg((features,), jnp.bool_),
+        arg((2,), jnp.uint32), param=TrainParam(max_depth=depth),
+        max_nbins=MAX_NBINS, has_missing=False)
+    after = refine_remap_counts()
+    assert after.get("kernel", 0) == before.get("kernel", 0) + depth
+    assert after.get("xla", 0) == before.get("xla", 0)
+    tile_pads = [eqn for eqn in _eqns(traced.jaxpr.jaxpr)
+                 if eqn.primitive.name == "pad"
+                 and eqn.outvars[0].aval.shape == (features, n_pad)]
+    assert len(tile_pads) == 1
+    text = traced.lower().compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2 * depth
+
+    def stated(B, N, refine):
+        # what build_hist_pallas states for a build of N nodes and B slots
+        cap = min(max((8 * 2 ** 20) // (B * 2 * N * 4), 8), ph.FEATURE_GROUP)
+        F_blk = ph._feature_block(features, cap)
+        vmem = ph._int8_vmem_bytes(F_blk, ph._round_up(features, F_blk), B,
+                                   N, R, F_blk, 1, True, refine)
+        return ph.SCOPED_VMEM if vmem <= ph.SCOPED_VMEM else (
+            vmem + ph.SCOPED_VMEM)
+
+    # the root's coarse build and every level's refine build
+    assert kernel_vmem_bytes()["build_hist_int8"] == max(
+        [stated(20, 1, False)]
+        + [stated(WINDOW + 4, 2 ** d, True) for d in range(depth)])

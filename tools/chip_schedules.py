@@ -59,7 +59,8 @@ def main() -> int:
     from xgboost_tpu.obs.metrics import (eval_walk_counts, get_registry,
                                          grow_epilogue_counts,
                                          grow_schedule_counts,
-                                         hist_dot_counts, hist_dot_rows)
+                                         hist_dot_counts, hist_dot_rows,
+                                         refine_remap_counts)
     from xgboost_tpu.tree.grow import resolve_schedule
 
     dev = jax.devices()[0]
@@ -110,6 +111,7 @@ def main() -> int:
         "grow_epilogue_total": grow_epilogue_counts(),
         "eval_walk_total": eval_walk_counts(),
         "hist_dot_rows": hist_dot_rows(),
+        "refine_remap_total": refine_remap_counts(),
         "tree_flushes_total": int(get_registry().get(
             "xtpu_tree_flushes_total", ())),
         "device": {"platform": dev.platform, "kind": dev.device_kind,

@@ -96,7 +96,7 @@ def main():
     if "coarse" in PHASES:
         from xgboost_tpu.ops.split import (WINDOW, assemble_two_level,
                                            choose_refine_window,
-                                           coarse_bin_ids, refine_bin_ids)
+                                           coarse_bin_ids)
         from xgboost_tpu.ops.split import COARSE_B
 
         has_missing = binned.has_missing
@@ -114,20 +114,9 @@ def main():
                 parent = jnp.sum(hist_c[:, 0], axis=1)
                 span = choose_refine_window(hist_c, parent, n_real, param,
                                             has_missing)
-                span_pad = jnp.concatenate(
-                    [span.astype(jnp.float32),
-                     jnp.zeros((1, COLS), jnp.float32)]).T
-                oh_rel = (rel[None, :] == jnp.arange(
-                    N + 1, dtype=jnp.int32)[:, None]).astype(jnp.float32)
-                c_row_t = jax.lax.dot_general(
-                    span_pad, oh_rel, (((1,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST)
-                rb_t = refine_bin_ids(bt.astype(jnp.int32),
-                                      c_row_t.astype(jnp.int32),
-                                      missing_bin)
-                hist_r = build_hist(rb_t.T, gp, rel, N, WINDOW + 4,
-                                    method="auto",
-                                    bins_t=rb_t)[:, :, :WINDOW, :]
+                hist_r = build_hist(bt.T, gp, rel, N, WINDOW + 4,
+                                    method="auto", bins_t=bt, window=span,
+                                    missing_bin=missing_bin)[:, :, :WINDOW, :]
                 hist, _ = assemble_two_level(hist_c, hist_r, span, n_real,
                                              has_missing)
                 g = g + jnp.sum(hist).astype(jnp.float32)

@@ -393,6 +393,30 @@ def fused_boundary_counts() -> Dict[str, int]:
     return {k: int(v) for k, v in _by_label(_FUSED_BOUNDARY, "body").items()}
 
 
+_REFINE_REMAP = "xtpu_refine_remap_total"
+REFINE_REMAP_WHERE = ("kernel", "xla")
+
+
+def count_refine_remap(where: str) -> None:
+    """One refine build of the two-level search (``ops/histogram.py
+    build_hist(window=...)``), by where the raw bin ids were remapped into
+    the node's window: ``kernel`` (in VMEM inside the int8x2 Pallas
+    kernel, from the resident tile: no remapped copy in HBM) or ``xla``
+    (``refine_bin_ids`` over the whole matrix ahead of the build: the CPU,
+    levels past 128 nodes, the f32/bf16 kernels). Counted while jax
+    traces, as ``count_fused_boundary``: one a refined level of a traced
+    grow program."""
+    if where not in REFINE_REMAP_WHERE:
+        raise ValueError(f"unknown refine remap {where!r}")
+    _registry.inc(_REFINE_REMAP, labels=(("where", where),),
+                  help="refine builds of traced programs, by where the "
+                       "bin ids were remapped into the window")
+
+
+def refine_remap_counts() -> Dict[str, int]:
+    return {k: int(v) for k, v in _by_label(_REFINE_REMAP, "where").items()}
+
+
 _HIST_ONEHOT = "xtpu_hist_onehot_total"
 HIST_ONEHOT_BUILDS = ("swar", "compare")
 

@@ -26,7 +26,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..obs.metrics import count_fused_boundary
+from ..obs.metrics import count_fused_boundary, count_refine_remap
 from ..obs.trace import stage
 
 
@@ -115,13 +115,82 @@ def build_hist_onehot(bins: jnp.ndarray, gpair: jnp.ndarray, rel_pos: jnp.ndarra
     return acc.reshape(F, max_nbins, n_nodes, 2).transpose(2, 0, 1, 3)
 
 
-@partial(jax.jit, static_argnames=("n_nodes", "max_nbins", "method",
-                                   "block_rows", "axis_name", "packed_u4"))
+def _resolve_method(method: str, n_nodes: int) -> str:
+    """The build ``method`` runs as here: "auto" is the XLA ``segment``
+    build on the CPU, the fused Pallas kernel on a TPU up to 128 nodes (it
+    accumulates ``[F_blk, max_nbins, 2 * n_nodes]`` blocks in VMEM; past
+    that, at depth > 7, the XLA formulation rather than shrinking blocks),
+    and the XLA ``onehot`` build elsewhere (Pallas specs here are
+    TPU-only)."""
+    if method != "auto":
+        return method
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return "segment"
+    return "pallas" if backend == "tpu" and n_nodes <= 128 else "onehot"
+
+
+def refine_remap_where(method: str, n_nodes: int) -> str:
+    """Where a refine build over ``n_nodes`` remaps the raw ids into the
+    window (``build_hist(window=...)``): ``kernel``, in VMEM inside the
+    int8x2 Pallas kernel, or ``xla``, by ``_refine_ids`` ahead of any other
+    build."""
+    resolved = _resolve_method(method, n_nodes)
+    return "kernel" if resolved in ("pallas", "pallas:int8x2") else "xla"
+
+
+def _refine_ids(bins: jnp.ndarray, rel_pos: jnp.ndarray,
+                window: jnp.ndarray, missing_bin: int) -> jnp.ndarray:
+    """``[n, F]`` refine slots (``ops/split.py refine_bin_ids``) of every
+    row at its node's window: the per-row window by one
+    ``[n, N+1] @ [N+1, F]`` MXU matmul (rows outside the level meet the
+    zero pad row; their build contribution is dropped by rel == N)."""
+    from .split import refine_bin_ids
+
+    N, F = window.shape
+    span_pad = jnp.concatenate([window.astype(jnp.float32),
+                                jnp.zeros((1, F), jnp.float32)])  # [N+1, F]
+    oh_rel = (rel_pos[:, None] == jnp.arange(
+        N + 1, dtype=jnp.int32)[None, :]).astype(jnp.float32)    # [n, N+1]
+    c_row = jax.lax.dot_general(oh_rel, span_pad, (((1,), (0,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST)
+    return refine_bin_ids(bins.astype(jnp.int32), c_row.astype(jnp.int32),
+                          missing_bin)
+
+
 def build_hist(bins: jnp.ndarray, gpair: jnp.ndarray, rel_pos: jnp.ndarray,
                n_nodes: int, max_nbins: int, method: str = "auto",
                block_rows: int = 1 << 16,
                bins_t: jnp.ndarray = None, axis_name=None,
-               packed_u4: int = 0) -> jnp.ndarray:
+               packed_u4: int = 0, window: jnp.ndarray = None,
+               missing_bin: int = None) -> jnp.ndarray:
+    """``[n_nodes, F, max_nbins, 2]`` (g, h) sums of ``bins`` ([n, F]; its
+    transpose ``bins_t``, where the caller holds one, feeds the Pallas
+    kernels, padded to their row blocks or not) by ``method``.
+
+    ``window`` ([n_nodes, F] window starts, ``ops/split.py
+    choose_refine_window``) and ``missing_bin`` make it the refine build of
+    the two-level search over the RAW ids: the histogram of ``refine_bin_ids``'
+    slots at each row's node's window, ``max_nbins`` (WINDOW + 4) of them.
+    The int8x2 Pallas kernel remaps in VMEM; every other build remaps by
+    ``_refine_ids`` first. ``xtpu_refine_remap_total{where}`` counts which
+    (``refine_remap_where``), once a call: a call inside a traced program
+    is one a trace."""
+    if window is not None:
+        if packed_u4:
+            raise ValueError("the u4 page transport never refines")
+        count_refine_remap(refine_remap_where(method, n_nodes))
+    return _build_hist(bins, gpair, rel_pos, n_nodes, max_nbins, method,
+                       block_rows, bins_t, axis_name, packed_u4, window,
+                       missing_bin)
+
+
+@partial(jax.jit, static_argnames=("n_nodes", "max_nbins", "method",
+                                   "block_rows", "axis_name", "packed_u4",
+                                   "missing_bin"))
+def _build_hist(bins, gpair, rel_pos, n_nodes, max_nbins, method,
+                block_rows, bins_t, axis_name, packed_u4, window,
+                missing_bin):
     if packed_u4:
         # ``bins`` is a u4-packed [n, ceil(F/2)] page (packed_u4 = logical
         # F). The Pallas path decodes nibbles in-VMEM inside the kernel's
@@ -145,19 +214,17 @@ def build_hist(bins: jnp.ndarray, gpair: jnp.ndarray, rel_pos: jnp.ndarray,
             "growers only (tree/grow.py resident, tree/paged.py external "
             "memory); this code path (lossguide / vector-leaf / vertical) "
             "does not support it")
-    if method == "auto":
-        backend = jax.default_backend()
-        # The fused Pallas kernel accumulates [F_blk, max_nbins, 2*n_nodes]
-        # blocks in VMEM; past ~128 nodes per level (depth > 7) fall back to
-        # the XLA formulation rather than shrinking blocks. Non-TPU
-        # accelerators get the XLA onehot path (Pallas specs here are
-        # TPU-only).
-        if backend == "cpu":
-            method = "segment"
-        elif backend == "tpu" and n_nodes <= 128:
-            method = "pallas"
-        else:
-            method = "onehot"
+    if window is not None:
+        if refine_remap_where(method, n_nodes) == "kernel":
+            from .pallas.histogram import build_hist_pallas
+
+            return build_hist_pallas(
+                bins.T if bins_t is None else bins_t, gpair, rel_pos,
+                n_nodes, max_nbins, axis_name=axis_name, window=window,
+                missing_bin=missing_bin)
+        bins = _refine_ids(bins, rel_pos, window, missing_bin)
+        bins_t = None
+    method = _resolve_method(method, n_nodes)
     if method.startswith("pallas"):
         from .pallas.histogram import build_hist_pallas
 

@@ -88,6 +88,29 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+# rows a grid step of the kernels over a bin tile
+ROW_BLOCK = 2048
+
+
+def row_blocks(n: int, block_rows: int = ROW_BLOCK) -> tuple:
+    """(R, n_pad): the row block of a kernel over ``n`` rows (whole 128-row
+    tiles, at most ``block_rows``) and the rows its grid covers."""
+    R = min(block_rows, max(_round_up(n, 128), 128))
+    return R, _round_up(max(n, R), R)
+
+
+def fit_rows(x_t: jnp.ndarray, width: int) -> jnp.ndarray:
+    """``x_t`` ([rows, m]) padded with zeros, or cut, to ``width``
+    columns: nothing where it has them. A round program pads its bin tile
+    to the row blocks once (``tree/grow.py``), and every kernel of the
+    program takes that tile as it is; a kernel pads a narrower tile
+    itself, per call."""
+    have = x_t.shape[1]
+    if have < width:
+        return jnp.pad(x_t, ((0, 0), (0, width - have)))
+    return x_t[:, :width] if have > width else x_t
+
+
 def _vmem_params(kernel: str, limit: Optional[int]):
     """The compiler parameters of a kernel that states ``limit`` bytes of
     scoped VMEM (None where it keeps the default), noted in the gauge
@@ -251,7 +274,8 @@ def _acc_shapes(F_blk: int, F_pad: int, B: int, N: int) -> tuple:
 
 
 def _int8_vmem_bytes(F_blk: int, F_pad: int, B: int, N: int, R: int,
-                     tile_rows: int, itemsize: int, packed: bool) -> int:
+                     tile_rows: int, itemsize: int, packed: bool,
+                     refine: bool = False) -> int:
     """Scoped VMEM a fixed-point histogram kernel holds, reckoned from what
     its body keeps: the input tiles twice (the bin block, its rows padded to
     whole 32-row tiles of bytes, and the gradient pair and positions, 8
@@ -259,7 +283,11 @@ def _int8_vmem_bytes(F_blk: int, F_pad: int, B: int, N: int, R: int,
     ``[N, R]`` int32 arrays' worth; a dot's one-hot, ``G * B`` rows of words
     and of bytes (a ``[B, R]`` int32 compare and its bytes without the SWAR
     build); and, where the whole ``[F_pad * B, 2N]`` output passes
-    ``_OUT_IN_SCOPE``, the accumulator block's two pipeline buffers.
+    ``_OUT_IN_SCOPE``, the accumulator block's two pipeline buffers. The
+    refine build that remaps in VMEM (``refine``) adds its window table
+    twice (``[F_blk, N]`` f32, lanes padded to 128), the node one-hot it
+    contracts (``[N, R]`` f32), and two ``[F_blk, R]`` 32-bit arrays: the
+    remapped block's scratch and the window product it is made from.
 
     Measured for a described v5e at 36 slots and 128 nodes (PERF.md section
     6): 5.9-6.4 MiB at 80-200 features a block while the whole
@@ -272,6 +300,10 @@ def _int8_vmem_bytes(F_blk: int, F_pad: int, B: int, N: int, R: int,
     G = _dot_features(B, N) if packed else 1
     onehot = (2 if packed else 5) * G * B * R
     need = 2 * tiles + 5 * N * R * 4 + onehot
+    if refine:
+        rows = _round_up(F_blk, 8)
+        need += (2 * rows * _round_up(N, 128) * 4 + N * R * 4
+                 + 2 * rows * R * 4)
     if F_pad * B * 2 * N * 4 > _OUT_IN_SCOPE:
         need += 2 * F_blk * B * 2 * N * 4
     return need
@@ -354,13 +386,40 @@ def _make_kernel(n_feat_block: int, n_bins: int, n_nodes: int, block_rows: int,
     return kernel
 
 
+def _remap_window(tab_ref, bins_ref, on_node, rb_ref, missing_bin: int):
+    """The refine build's remap of one ``[F_blk, R]`` block of raw bin ids,
+    in VMEM: each row's window start is its node's entry of the window
+    table (``tab_ref``, ``[F_blk, N]`` in coarse units), taken by ONE dot
+    against the node one-hot the body already holds (exact: one non-zero
+    term a sum, window starts below 16); ``refine_slots`` (``ops/split.py``,
+    the XLA build's definition) maps the ids into ``rb_ref``, the int32
+    scratch the feature loop then reads as it reads a bin block. A fixed
+    number of equations whatever F_blk and N are. A row of no node of the
+    level (outside it, or padding) meets a zero column and keeps window 0;
+    its slot is inert, for its ``PT4`` column is zero."""
+    from ..split import refine_slots
+
+    win = jax.lax.dot_general(
+        tab_ref[:], on_node.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                # [F_blk, R]
+    rb_ref[:] = refine_slots(bins_ref[:].astype(jnp.int32),
+                             win.astype(jnp.int32), missing_bin)
+
+
 def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
                       block_rows: int, packed: bool = False,
-                      u4: bool = False):
+                      u4: bool = False,
+                      refine_missing: Optional[int] = None):
     """Fixed-point kernel: gradients arrive as two int8 byte planes
     (value = hi * 256 + lo, a 15-bit quantisation done by the caller);
     both planes are contracted with the 0/1 one-hot on the int8 MXU with
     exact int32 accumulation, then recombined into f32.
+
+    ``refine_missing`` (the raw tile's missing slot) makes it the refine
+    build of the two-level search: the kernel takes the window table
+    first and the RAW bin block, remaps the block once a grid step
+    (``_remap_window``) and histograms the refine slots, bit for bit the
+    build over ``ops/split.py refine_bin_ids``' ids.
 
     ``packed=True`` (requires ``n_bins % 4 == 0 and n_bins <= 256``): the
     one-hot is built four bins per uint32 word with a SWAR zero-byte
@@ -387,7 +446,11 @@ def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
     column tile), so multi-target histograms intentionally loop targets."""
     B, N, R, Fb = n_bins, n_nodes, block_rows, n_feat_block
 
-    def kernel(bins_ref, q_ref, pos_ref, out_ref):
+    def kernel(*refs):
+        if refine_missing is None:
+            bins_ref, q_ref, pos_ref, out_ref = refs
+        else:
+            tab_ref, raw_ref, q_ref, pos_ref, out_ref, bins_ref = refs
         i = pl.program_id(1)
 
         @pl.when(i == 0)
@@ -398,6 +461,9 @@ def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
         node_iota = jax.lax.broadcasted_iota(jnp.int32, (N, R), 0)
         on_node = pos_row == node_iota                     # [N, R] bool
         zero = jnp.zeros((N, R), jnp.int32)
+        if refine_missing is not None:
+            _remap_window(tab_ref, raw_ref, on_node, bins_ref,
+                          refine_missing)
 
         # Scatter q to nodes in the i32 layout domain, split into byte
         # planes, and drop to int8 only at the MXU boundary (int8 VPU
@@ -579,25 +645,25 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                                 thr: jnp.ndarray, dleft: jnp.ndarray,
                                 can_split: jnp.ndarray, *, lo_prev: int,
                                 n_prev: int, lo: int, n_level: int,
-                                missing_bin: int, block_rows: int = 2048,
+                                missing_bin: int, block_rows: int = ROW_BLOCK,
                                 axis_name=None, interpret: bool = False):
     """Single-HBM-read advance + coarse build (see ``_make_fused_kernel``).
 
-    bins_t: [F, n] fine bin ids; gpair: [n, 2] f32; positions: [n] heap
-    node ids; feat/thr/dleft/can_split: [n_prev] previous-level split
-    vectors (feat == -1 on non-split slots).
+    bins_t: [F, n] fine bin ids (or the tile padded to the row blocks,
+    ``fit_rows``); gpair: [n, 2] f32; positions: [n] heap node ids;
+    feat/thr/dleft/can_split: [n_prev] previous-level split vectors
+    (feat == -1 on non-split slots).
     -> (new_positions [n] int32, hist [n_level, F, COARSE_B, 2] f32)
     """
     from ..split import COARSE_B, COARSE_SPAN
 
-    F, n = bins_t.shape
+    F, n = bins_t.shape[0], gpair.shape[0]
     B, N = COARSE_B, n_level
     shift = COARSE_SPAN.bit_length() - 1
 
-    R = min(block_rows, max(_round_up(n, 128), 128))
-    n_pad = _round_up(max(n, R), R)
+    R, n_pad = row_blocks(n, block_rows)
+    bins_t = fit_rows(bins_t, n_pad)
     if n_pad != n:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
         gpair = jnp.pad(gpair, ((0, n_pad - n), (0, 0)))
         # pad positions OUTSIDE every level: inactive for both the advance
         # and the new level's histogram (their quantised gpair is 0 anyway)
@@ -755,26 +821,26 @@ def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
                         feat: jnp.ndarray, thr: jnp.ndarray,
                         dleft: jnp.ndarray, can_split: jnp.ndarray,
                         leaf_value: jnp.ndarray, *, n_prev: int,
-                        missing_bin: int, block_rows: int = 2048,
+                        missing_bin: int, block_rows: int = ROW_BLOCK,
                         interpret: bool = False):
     """Advance below the deepest evaluated level and look the leaf up, in
     one sweep (see ``_make_advance_leaf_kernel``).
 
-    bins_t: [F, n] bin ids; positions: [n] heap node ids, none below the
+    bins_t: [F, n] bin ids (or the tile padded to the row blocks,
+    ``fit_rows``); positions: [n] heap node ids, none below the
     level; feat/thr/dleft/can_split: [n_prev] the level's splits, the
     level starting at heap node ``n_prev - 1``; leaf_value: [max_nodes]
     f32 over the whole heap, the level's children included.
     -> (new_positions [n] int32, delta [n] f32 = leaf_value[new_positions])
     """
-    F, n = bins_t.shape
+    F, n = bins_t.shape[0], positions.shape[0]
     N, lo = n_prev, n_prev - 1
     if F > 0xFFFF or missing_bin > 0xFFF:
         raise NotImplementedError("advance_leaf_pallas packs the feature "
                                   "in 16 bits and the bin in 12")
-    R = min(block_rows, max(_round_up(n, 128), 128))
-    n_pad = _round_up(max(n, R), R)
+    R, n_pad = row_blocks(n, block_rows)
+    bins_t = fit_rows(bins_t, n_pad)
     if n_pad != n:
-        bins_t = jnp.pad(bins_t, ((0, 0), (0, n_pad - n)))
         positions = jnp.pad(positions, (0, n_pad - n), constant_values=-1)
     pos_t = positions.astype(jnp.int32)[None, :]           # [1, n]
     # The whole-F tile, double-buffered, and its int32 copy. Past the widest
@@ -830,31 +896,44 @@ def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
 @functools.partial(
     jax.jit,
     static_argnames=("n_nodes", "max_nbins", "precision", "block_rows",
-                     "feat_block", "interpret", "axis_name", "packed_u4"))
+                     "feat_block", "interpret", "axis_name", "packed_u4",
+                     "missing_bin"))
 def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                       rel_pos: jnp.ndarray, n_nodes: int, max_nbins: int,
-                      precision: str = "int8x2", block_rows: int = 2048,
+                      precision: str = "int8x2", block_rows: int = ROW_BLOCK,
                       feat_block: Optional[int] = None,
                       interpret: bool = False,
-                      axis_name=None, packed_u4: int = 0) -> jnp.ndarray:
+                      axis_name=None, packed_u4: int = 0,
+                      window: Optional[jnp.ndarray] = None,
+                      missing_bin: Optional[int] = None) -> jnp.ndarray:
     """Fused histogram kernel.
 
     bins_t: [F, n] local bin ids (any int dtype), missing at max_nbins - 1
         — or, with ``packed_u4 = F``, a u4-packed ``[ceil(F/2), n]`` uint8
         page (compressed page transport): nibbles decode in-VMEM inside
-        the feature loop, so the packed page is the only HBM copy
+        the feature loop, so the packed page is the only HBM copy. A tile
+        padded to the row blocks (``fit_rows``) is taken as it is
     gpair: [n, 2] f32
     rel_pos: [n] int32 in [0, n_nodes]; n_nodes means "inactive row"
     axis_name: mesh axis carrying row shards — the int8x2 quantisation
         scale is pmax'd over it so every shard quantises identically and
         N-chip histograms reproduce the 1-chip run bit-for-bit
+    window, missing_bin: the refine build of the two-level search
+        (int8x2 only). ``window`` is ``[n_nodes, F]`` int32, each node's
+        window start in coarse units (``ops/split.py
+        choose_refine_window``), ``missing_bin`` the raw tile's missing
+        slot; ``bins_t`` holds the RAW ids, and the kernel remaps them in
+        VMEM (``_remap_window``): the histogram of ``refine_bin_ids``'
+        slots, ``max_nbins`` of them, with no remapped copy in HBM
     -> [n_nodes, F, max_nbins, 2] f32
     """
     u4 = bool(packed_u4)
-    if u4:
-        F, n = packed_u4, bins_t.shape[1]
-    else:
-        F, n = bins_t.shape
+    refine = window is not None
+    if refine and (u4 or precision != "int8x2" or missing_bin is None):
+        raise ValueError("the refine window remaps inside the int8x2 "
+                         "kernel only, given the missing slot; the u4 page "
+                         "transport never refines")
+    F, n = (packed_u4 if u4 else bins_t.shape[0]), gpair.shape[0]
     B, N = max_nbins, n_nodes
 
     if precision == "bf16x2":
@@ -862,8 +941,7 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         # 2048-row block busts the 16M scoped-VMEM limit at 256 bins (the
         # feature block can't shrink below 8 — sublane minimum)
         block_rows = min(block_rows, 1024)
-    R = min(block_rows, max(_round_up(n, 128), 128))
-    n_pad = _round_up(max(n, R), R)
+    R, n_pad = row_blocks(n, block_rows)
     if feat_block is None:
         if u4 or precision == "int8x2":
             # whole-F feature block when the [F, B, 2N] f32 accumulator
@@ -889,9 +967,11 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                            step=64 if u4 else 8)
     F_pad = _round_up(F, F_blk)
     note_hist_body_features(F_blk)
-    if n_pad != n or F_pad != F:
-        rows_pad = (-(-F_pad // 2) - bins_t.shape[0]) if u4 else F_pad - F
-        bins_t = jnp.pad(bins_t, ((0, rows_pad), (0, n_pad - n)))
+    rows_pad = (-(-F_pad // 2) - bins_t.shape[0]) if u4 else F_pad - F
+    if rows_pad:
+        bins_t = jnp.pad(bins_t, ((0, rows_pad), (0, 0)))
+    bins_t = fit_rows(bins_t, n_pad)
+    if n_pad != n:
         gpair = jnp.pad(gpair, ((0, n_pad - n), (0, 0)))
         rel_pos = jnp.pad(rel_pos, (0, n_pad - n),
                           constant_values=n_nodes)  # padded rows inactive
@@ -935,21 +1015,33 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         # room beside it
         vmem = _int8_vmem_bytes(F_blk, F_pad, B, N, R,
                                 bins_spec.block_shape[0],
-                                bins_t.dtype.itemsize, packed)
+                                bins_t.dtype.itemsize, packed, refine)
         params = _vmem_params("build_hist_int8", None if vmem <= SCOPED_VMEM
                               else vmem + SCOPED_VMEM)
+        in_specs, operands, scratch = ([bins_spec, vec2_spec, pos_spec],
+                                       [bins_t, q, pos_t], [])
+        if refine:
+            # the window table [F_pad, N] f32, a feature block's rows a step
+            tab = jnp.pad(window.T.astype(jnp.float32),
+                          ((0, F_pad - F), (0, 0)))
+            in_specs.insert(0, pl.BlockSpec((F_blk, N), lambda j, i: (j, 0),
+                                            memory_space=pltpu.VMEM))
+            operands.insert(0, tab)
+            scratch.append(pltpu.VMEM((F_blk, R), jnp.int32))
         with stage("kernel.build_hist_int8"):
             out = pl.pallas_call(
-                _make_int8_kernel(F_blk, B, N, R, packed=packed, u4=u4),
+                _make_int8_kernel(F_blk, B, N, R, packed=packed, u4=u4,
+                                  refine_missing=missing_bin if refine
+                                  else None),
                 out_shape=out_shape,
                 grid=grid,
-                in_specs=[bins_spec, vec2_spec, pos_spec],
+                in_specs=in_specs,
                 out_specs=out_spec,
-                scratch_shapes=[],
+                scratch_shapes=scratch,
                 compiler_params=params,
                 interpret=interpret,
                 name="build_hist_int8",
-            )(bins_t, q, pos_t)
+            )(*operands)
         # columns [0:N] hold g-sums, [N:2N] h-sums -> per-component dequant
         with stage("fold"):
             inv = jnp.repeat(1.0 / scale, N)[None, None, :]  # [1, 1, 2N]

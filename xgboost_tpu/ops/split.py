@@ -334,16 +334,25 @@ def coarse_bin_ids(bins_i32: jnp.ndarray, missing_bin: int) -> jnp.ndarray:
                      bins_i32 >> shift).astype(jnp.uint8)
 
 
-def refine_bin_ids(bins_i32: jnp.ndarray, span_sel_i32: jnp.ndarray,
-                   missing_bin: int) -> jnp.ndarray:
+def refine_slots(bins_i32: jnp.ndarray, span_sel_i32: jnp.ndarray,
+                 missing_bin: int) -> jnp.ndarray:
     """Refine-pass slot per element given each element's window start (in
-    coarse units): in-window elements land on [0, WINDOW); everything else
-    (out of window / missing) on the discarded pad slot WINDOW + 3, which
-    keeps the kernel width WINDOW + 4 a multiple of 4 for the packed SWAR
-    build."""
+    coarse units), int32: in-window elements land on [0, WINDOW);
+    everything else (out of window / missing) on the discarded pad slot
+    WINDOW + 3, which keeps the kernel width WINDOW + 4 a multiple of 4
+    for the packed SWAR build. The one definition of the remap: the XLA
+    build (``refine_bin_ids``) and the refine kernel's in-VMEM remap
+    (``ops/pallas/histogram.py _remap_window``) both apply it."""
     rb = bins_i32 - COARSE_SPAN * span_sel_i32
     ok = (rb >= 0) & (rb < WINDOW) & (bins_i32 != missing_bin)
-    return jnp.where(ok, rb, WINDOW + 3).astype(jnp.uint8)
+    return jnp.where(ok, rb, WINDOW + 3)
+
+
+def refine_bin_ids(bins_i32: jnp.ndarray, span_sel_i32: jnp.ndarray,
+                   missing_bin: int) -> jnp.ndarray:
+    """``refine_slots`` as the uint8 ids a histogram build reads."""
+    return refine_slots(bins_i32, span_sel_i32, missing_bin).astype(
+        jnp.uint8)
 
 
 def refine_from_fine(fine: jnp.ndarray, window: jnp.ndarray,

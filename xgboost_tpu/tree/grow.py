@@ -283,7 +283,14 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
     is_cat_split = jnp.zeros((max_nodes,), bool)
     cat_words = jnp.zeros((max_nodes, n_words), jnp.uint32)
 
-    bins_t = bins.T  # loop-invariant; feeds the fused Pallas hist kernel
+    # loop-invariant [F, n] tile of the Pallas kernels; on a TPU padded to
+    # their row blocks here, once, so that no kernel of the program pads
+    # (copies) it again
+    bins_t = bins.T
+    if jax.default_backend() == "tpu":
+        from ..ops.pallas.histogram import fit_rows, row_blocks
+
+        bins_t = fit_rows(bins_t, row_blocks(n)[1])
     # f32 copy of the bin matrix: the level-wise position advance fetches each
     # node's split-feature column with one [n, F] @ [F, N] MXU matmul (bin ids
     # are < 2^24 so the f32 values are exact).
@@ -338,11 +345,11 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
         # "auto" rule still skips col split — with F/world features per
         # shard the two-pass overhead amortises worse, so coarse there
         # is explicit opt-in.
-        from ..ops.split import (assemble_two_level, choose_refine_window,
-                                 coarse_bin_ids, decode_two_level_bin,
-                                 refine_bin_ids)
+        from ..ops.split import (WINDOW, assemble_two_level,
+                                 choose_refine_window, coarse_bin_ids,
+                                 decode_two_level_bin)
         cb_t = coarse_bin_ids(bins_t.astype(jnp.int32), missing_bin)
-        cb = cb_t.T
+        cb = coarse_bin_ids(bins.astype(jnp.int32), missing_bin)
 
     pending_adv = None  # fused: splits awaiting the next boundary sweep
     for depth in range(max_depth):
@@ -381,32 +388,16 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                                             node_sum[lo:lo + n_level],
                                             n_real_bins, param,
                                             has_missing)          # [N, F]
-            # per-row window of the row's node, via one [F,N+1]@[N+1,n]
-            # MXU matmul (rows outside the level hit the zero pad row;
-            # their kernel contribution is dropped by rel == n_level)
+            # the refine build reads the raw tile and remaps each row into
+            # its node's window: in VMEM inside the kernel where one runs,
+            # by refine_bin_ids ahead of the build elsewhere
+            # (build_hist's docstring). The out-of-window slot WINDOW + 3
+            # is a VALID slot of the build, its pad slots discarded here
             with stage("refine"):
-                span_pad = jnp.concatenate(
-                    [span.astype(jnp.float32),
-                     jnp.zeros((1, F), jnp.float32)]).T  # [F, N+1]
-                oh_rel = (rel[None, :] == jnp.arange(
-                    n_level + 1,
-                    dtype=jnp.int32)[:, None]).astype(jnp.float32)
-                c_row_t = jax.lax.dot_general(
-                    span_pad, oh_rel, (((1,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST)    # [F, n]
-                # out-of-window sentinel (refine_bin_ids) must be a
-                # VALID slot of the kernel — the flat-index segment
-                # path would bleed an out-of-range id into the next
-                # feature's bins; the pad slots of the WINDOW+4-wide
-                # pass are discarded
-                from ..ops.split import WINDOW
-                rb_t = refine_bin_ids(bins_t.astype(jnp.int32),
-                                      c_row_t.astype(jnp.int32),
-                                      missing_bin)
                 hist_r = allreduce(build_hist(
-                    rb_t.T, gpair, rel, n_level, WINDOW + 4,
-                    method="auto", bins_t=rb_t,
-                    axis_name=row_axis))[:, :, :WINDOW, :]
+                    bins, gpair, rel, n_level, WINDOW + 4, method="auto",
+                    bins_t=bins_t, axis_name=row_axis, window=span,
+                    missing_bin=missing_bin))[:, :, :WINDOW, :]
             hist, n_real_eval = assemble_two_level(
                 hist_c, hist_r, span, n_real_bins, has_missing)
         else:

@@ -79,28 +79,21 @@ def _eval2(bins, gpair, positions, id0, id1, parent_sums, fmask,
                                node_upper=node_upper,
                                cat=cat, has_missing=has_missing)
     from ..ops.split import (COARSE_B, WINDOW, assemble_two_level,
-                             choose_refine_window, decode_two_level_bin,
-                             refine_bin_ids)
+                             choose_refine_window, decode_two_level_bin)
 
     missing_bin = max_nbins - 1 if has_missing else max_nbins
-    # cb_t is hoisted per TREE by the grower (loop-invariant, like
-    # bins_t); the int32 view feeding refine_bin_ids stays in-jit so
-    # XLA fuses the upcast into the consumer instead of materialising
-    # [F,n]i32
-    bt_i32 = bins_t.astype(jnp.int32)
+    # cb_t is hoisted per TREE by the grower (loop-invariant, like bins_t)
     hist_c = build_hist(cb_t.T, gpair, rel, 2, COARSE_B, method="auto",
                         bins_t=cb_t)
     if axis_name is not None:
         hist_c = jax.lax.psum(hist_c, axis_name)
     span = choose_refine_window(hist_c, parent_sums, n_real_bins,
                                 param, has_missing)       # [2, F]
-    # per-row window of the row's node (N=2: two selects, no matmul)
-    c_row_t = jnp.where(rel[None, :] == 0, span[0][:, None],
-                        jnp.where(rel[None, :] == 1, span[1][:, None],
-                                  0)).astype(jnp.int32)   # [F, n]
-    rb_t = refine_bin_ids(bt_i32, c_row_t, missing_bin)
-    hist_r = build_hist(rb_t.T, gpair, rel, 2, WINDOW + 4,
-                        method="auto", bins_t=rb_t)[:, :, :WINDOW, :]
+    # the raw ids remapped into each row's node's window inside the build
+    # (build_hist's docstring)
+    hist_r = build_hist(bins, gpair, rel, 2, WINDOW + 4, method="auto",
+                        bins_t=bins_t, window=span,
+                        missing_bin=missing_bin)[:, :, :WINDOW, :]
     if axis_name is not None:
         hist_r = jax.lax.psum(hist_r, axis_name)
     hist, n_real_eval = assemble_two_level(hist_c, hist_r, span,
