@@ -182,6 +182,46 @@ def test_growers_agree_on_auto(monkeypatch, grower, backend, shape):
         assert grower("fused", n, max_nbins, cuts, monkeypatch)
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_depthwise_auto_runs_categorical_columns_two_level(monkeypatch,
+                                                           backend):
+    """The depthwise grower states its categorical columns and its widest
+    numeric one (``CatInfo.cols``, ``numeric_bins``), and ``auto`` on a TPU
+    takes the two-level search with them up to ``CAT_SLOTS_MAX`` slots and
+    ``CAT_COLUMNS_MAX`` columns, on row split, beside at least one numeric
+    column of 128-256 bins. A ``CatInfo`` that states no columns (the
+    lossguide and paged growers') keeps the one-pass kernel."""
+    from xgboost_tpu.ops.split import CatInfo
+    from xgboost_tpu.tree.grow import (CAT_COLUMNS_MAX, CAT_SLOTS_MAX,
+                                       TreeGrower, two_level_numeric_bins)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    grower = TreeGrower(TrainParam(max_depth=4), BINS, _Cuts(28, True))
+    assert grower.cat.cols == (0,) and grower.cat.numeric_bins == BINS - 1
+
+    def auto(cat, max_nbins, col_split=False):
+        nb = two_level_numeric_bins(cat, max_nbins, True, col_split)
+        return resolve_schedule("auto", 1 << 20, max_nbins, True,
+                                numeric=cat is None, col_split=col_split,
+                                numeric_bins=nb).name
+
+    one = jnp.zeros((13,), bool)
+    cat = CatInfo(is_cat=one, is_onehot=one, cols=(6, 9, 10),
+                  numeric_bins=256)
+    on_tpu = "fused" if backend == "tpu" else "auto"
+    assert auto(cat, 352) == on_tpu
+    assert auto(cat, CAT_SLOTS_MAX + 1) == on_tpu
+    assert auto(cat, CAT_SLOTS_MAX + 2) == "auto"        # a category too many
+    assert auto(cat, 352, col_split=True) == "auto"
+    assert auto(CatInfo(is_cat=one, is_onehot=one), 352) == "auto"
+    wide = tuple(range(CAT_COLUMNS_MAX + 1))
+    assert auto(CatInfo(is_cat=one, is_onehot=one, cols=wide,
+                        numeric_bins=256), 352) == "auto"
+    for numeric_bins in (0, grow_mod.AUTO_COARSE_MIN_BINS - 1, 257):
+        assert auto(CatInfo(is_cat=one, is_onehot=one, cols=(6,),
+                            numeric_bins=numeric_bins), 352) == "auto"
+
+
 REMOVED = ["scan", "mega", "prehot", "fused+sub", "auto+nosub", "scan+sub"]
 
 

@@ -373,14 +373,22 @@ def test_coarse_hist_unsupported_configs_raise():
             xgb.train({"objective": "binary:logistic",
                        "hist_method": "coarse", **bad},
                       xgb.DMatrix(X, label=y), 1, verbose_eval=False)
-    # categorical features reject at trace time inside _grow
-    Xc = np.concatenate([X, rng.randint(0, 5, (500, 1)).astype(np.float32)],
-                        axis=1)
-    dmc = xgb.DMatrix(Xc, label=y, feature_types=["q"] * 4 + ["c"],
-                      enable_categorical=True)
-    with pytest.raises(NotImplementedError):
-        xgb.train({"objective": "binary:logistic", "hist_method": "coarse"},
-                  dmc, 1, verbose_eval=False)
+    # categorical features ride beside the two-level search up to
+    # CAT_SLOTS_MAX categories; a wider column rejects at trace time inside
+    # _grow
+    from xgboost_tpu.tree.grow import CAT_SLOTS_MAX
+
+    for n_cat, ok in ((5, True), (CAT_SLOTS_MAX + 88, False)):
+        codes = np.linspace(0, n_cat - 1, 500).round()
+        Xc = np.concatenate([X, codes[:, None].astype(np.float32)], axis=1)
+        dmc = xgb.DMatrix(Xc, label=y, feature_types=["q"] * 4 + ["c"],
+                          enable_categorical=True)
+        params = {"objective": "binary:logistic", "hist_method": "coarse"}
+        if ok:
+            xgb.train(params, dmc, 1, verbose_eval=False)
+            continue
+        with pytest.raises(NotImplementedError):
+            xgb.train(params, dmc, 1, verbose_eval=False)
 
 
 def test_auto_coarse_promotion_rule():

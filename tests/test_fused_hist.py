@@ -219,6 +219,158 @@ def _last_level(n, F, n_prev, has_missing, split_share, seed):
             jnp.asarray(leaf), missing_bin)
 
 
+# ---- categorical routing at the airline job's widths ----------------------
+# 352 slots (351 categories and the missing slot) make left sets of 11
+# words; three categorical columns among 13, uint16 ids.
+CAT_SLOTS, CAT_COLS = 352, (2, 6, 9)
+
+
+def _cat_level(n, n_prev, lo_prev, seed, split_share=0.85):
+    """Rows on a level of ``n_prev`` nodes (strays above it), each node
+    splitting on a numeric column by threshold or on a categorical one by
+    a random left set of 11 words; a share of every column missing."""
+    rng = np.random.RandomState(seed)
+    F, missing_bin = 13, CAT_SLOTS - 1
+    bins = rng.randint(0, 256, (n, F))
+    for c in CAT_COLS:
+        bins[:, c] = rng.randint(0, CAT_SLOTS - 1, n)
+    bins[rng.rand(n, F) < 0.05] = missing_bin
+    positions = rng.randint(lo_prev, lo_prev + n_prev, n).astype(np.int32)
+    positions[rng.rand(n) < 0.1] = max(lo_prev - 1, 0)
+    can_split = rng.rand(n_prev) < split_share
+    is_cat = can_split & (rng.rand(n_prev) < 0.6)
+    feat = np.where(is_cat, rng.choice(CAT_COLS, n_prev),
+                    rng.choice([f for f in range(F) if f not in CAT_COLS],
+                               n_prev))
+    feat = np.where(can_split, feat, -1).astype(np.int32)
+    thr = np.where(can_split & ~is_cat, rng.randint(0, 255, n_prev),
+                   0).astype(np.int32)
+    dleft = can_split & (rng.rand(n_prev) < 0.5)
+    words = rng.randint(0, 2 ** 32, (n_prev, 11), dtype=np.uint64)
+    words = np.where(is_cat[:, None], words, 0).astype(np.uint32)
+    return (jnp.asarray(bins.astype(np.uint16)), jnp.asarray(positions),
+            tuple(jnp.asarray(a) for a in (feat, thr, dleft, can_split)),
+            (jnp.asarray(is_cat), jnp.asarray(words)), missing_bin)
+
+
+@pytest.mark.parametrize("n,n_prev", [(1500, 4), (2049, 64)])
+def test_fused_pallas_interpret_routes_categorical_splits(n, n_prev):
+    """The boundary sweep's bitmask advance sends every row where
+    ``advance_positions_level`` sends it at 352 slots, 11 words: a
+    categorical node by its left set, a missing value by default_left;
+    its coarse histogram is the unfused build's, bit for bit."""
+    lo_prev, lo, n_level = n_prev - 1, 2 * n_prev - 1, 2 * n_prev
+    bins, positions, arrs, cat, missing_bin = _cat_level(
+        n, n_prev, lo_prev, seed=n)
+    gpair = jnp.asarray(np.random.RandomState(1).randn(n, 2)
+                        .astype(np.float32))
+    pos_k, hist_k = fused_advance_coarse_pallas(
+        bins.T, gpair, positions, *arrs, cat, lo_prev=lo_prev,
+        n_prev=n_prev, lo=lo, n_level=n_level, missing_bin=missing_bin,
+        block_rows=256, interpret=True, cat_cols=CAT_COLS)
+    rel_prev = jnp.where(
+        (positions >= lo_prev) & (positions < lo_prev + n_prev),
+        positions - lo_prev, n_prev).astype(jnp.int32)
+    pos_ref = advance_positions_level(
+        bins.astype(jnp.float32), positions, rel_prev, *arrs, missing_bin,
+        is_cat=cat[0], cat_words=cat[1])
+    np.testing.assert_array_equal(np.asarray(pos_k), np.asarray(pos_ref))
+    moved = np.asarray(pos_ref) != np.asarray(positions)
+    at_cat = np.asarray(cat[0])[np.clip(np.asarray(positions) - lo_prev, 0,
+                                        n_prev - 1)]
+    assert (moved & at_cat).any()
+    rel = jnp.where((pos_ref >= lo) & (pos_ref < lo + n_level),
+                    pos_ref - lo, n_level).astype(jnp.int32)
+    cb = coarse_bin_ids(bins.astype(jnp.int32), missing_bin)
+    hist_q = build_hist_pallas(cb.T, gpair, rel, n_level, COARSE_B,
+                               block_rows=256, interpret=True)
+    np.testing.assert_array_equal(np.asarray(hist_k), np.asarray(hist_q))
+
+
+@pytest.mark.parametrize("n_prev", [128, 256])
+def test_advance_leaf_pallas_interpret_routes_categorical_splits(n_prev):
+    """The last level's kernel routes categorical nodes by their left
+    sets: positions and delta bit for bit what ``update_positions`` and
+    the leaf gather give at 352 slots, 11 words."""
+    n = 2049
+    lo = n_prev - 1
+    bins, positions, arrs, cat, missing_bin = _cat_level(
+        n, n_prev, lo, seed=n_prev)
+    max_nodes = 4 * n_prev - 1
+    heap = [np.zeros(max_nodes, np.asarray(a).dtype) for a in arrs]
+    heap[0][:] = -1
+    for h, a in zip(heap, arrs):
+        h[lo:lo + n_prev] = np.asarray(a)
+    is_cat_h = np.zeros(max_nodes, bool)
+    is_cat_h[lo:lo + n_prev] = np.asarray(cat[0])
+    words_h = np.zeros((max_nodes, 11), np.uint32)
+    words_h[lo:lo + n_prev] = np.asarray(cat[1])
+    leaf = jnp.asarray(np.random.RandomState(2).randn(max_nodes)
+                       .astype(np.float32))
+    pos_k, delta_k = advance_leaf_pallas(
+        bins.T, positions, *arrs, leaf, cat, n_prev=n_prev,
+        missing_bin=missing_bin, block_rows=256, interpret=True,
+        cat_cols=CAT_COLS)
+    pos_ref = update_positions(
+        bins, positions, *(jnp.asarray(h) for h in heap), missing_bin,
+        is_cat_split=jnp.asarray(is_cat_h), cat_words=jnp.asarray(words_h))
+    np.testing.assert_array_equal(np.asarray(pos_k), np.asarray(pos_ref))
+    np.testing.assert_array_equal(np.asarray(delta_k),
+                                  np.asarray(leaf)[np.asarray(pos_ref)])
+
+
+@pytest.mark.parametrize("feat_block", [None, 4])
+def test_refine_build_sums_the_categorical_columns_in_its_sweep(feat_block):
+    """The refine kernel's categorical columns (in one feature block, or in
+    three of four): their full histograms are the compare build's over
+    those columns alone, bit for bit, and the refine histogram is the one
+    it gives without them."""
+    rng = np.random.RandomState(4)
+    n, N = 3000, 8
+    bins, _, _, _, missing_bin = _cat_level(n, 1, 0, seed=9)
+    gpair = jnp.asarray(rng.randn(n, 2).astype(np.float32))
+    rel = jnp.asarray(rng.randint(0, N + 1, n).astype(np.int32))
+    window = jnp.asarray(rng.randint(0, 15, (N, 13)).astype(np.int32))
+    common = dict(window=window, missing_bin=missing_bin, block_rows=256,
+                  interpret=True, feat_block=feat_block)
+    hist_r, hist_cat = build_hist_pallas(
+        bins.T, gpair, rel, N, 36, cat_rows=CAT_COLS, cat_slots=CAT_SLOTS,
+        **common)
+    np.testing.assert_array_equal(
+        np.asarray(hist_r),
+        np.asarray(build_hist_pallas(bins.T, gpair, rel, N, 36, **common)))
+    alone = build_hist_pallas(bins.T[np.asarray(CAT_COLS)], gpair, rel, N,
+                              CAT_SLOTS, block_rows=256, interpret=True)
+    np.testing.assert_array_equal(np.asarray(hist_cat), np.asarray(alone))
+    assert hist_cat.shape == (N, len(CAT_COLS), CAT_SLOTS, 2)
+
+
+def test_cat_advance_loop_matches_the_gather_walk():
+    """``advance_positions_level``'s word loop (11 words, not unrolled)
+    routes as ``update_positions``' per-row gather of the same words."""
+    n, n_prev = 3000, 8
+    lo = n_prev - 1
+    bins, positions, arrs, cat, missing_bin = _cat_level(n, n_prev, lo, 5)
+    rel = jnp.where((positions >= lo) & (positions < lo + n_prev),
+                    positions - lo, n_prev).astype(jnp.int32)
+    dense = advance_positions_level(
+        bins.astype(jnp.float32), positions, rel, *arrs, missing_bin,
+        is_cat=cat[0], cat_words=cat[1])
+    max_nodes = 2 * n_prev + lo
+    full = [np.zeros(max_nodes, np.asarray(a).dtype) for a in arrs]
+    for h, a in zip(full, arrs):
+        h[lo:lo + n_prev] = np.asarray(a)
+    ic = np.zeros(max_nodes, bool)
+    ic[lo:lo + n_prev] = np.asarray(cat[0])
+    cw = np.zeros((max_nodes, 11), np.uint32)
+    cw[lo:lo + n_prev] = np.asarray(cat[1])
+    walk = update_positions(bins, positions,
+                            *(jnp.asarray(h) for h in full), missing_bin,
+                            is_cat_split=jnp.asarray(ic),
+                            cat_words=jnp.asarray(cw))
+    np.testing.assert_array_equal(np.asarray(dense), np.asarray(walk))
+
+
 def _bits(x):
     return np.asarray(x).view(np.int32)
 
@@ -418,6 +570,25 @@ TIERS = {
 CASES = [(tier, missing, 4) for tier in TIERS for missing in (False, True)]
 CASES += [(tier, missing, 8) for tier in ("resident", "paged", "mesh-row")
           for missing in (False, True)]
+# categorical columns beside the numeric ones: full histograms in the refine
+# sweep, the categorical arm of the split search, the bitmask advance
+TIERS["resident-cat"] = ({}, 4000)
+CASES += [("resident-cat", missing, depth) for depth in (4, 8)
+          for missing in (False, True)]
+
+
+def _with_categories(X, y, seed):
+    """Columns 1 and 4 of ``X`` as category codes (40 and 300 of them,
+    Zipf-drawn, missing where X was), the label moved by their effects."""
+    rng = np.random.RandomState(seed)
+    X = X.copy()
+    types = ["q"] * X.shape[1]
+    for col, n_cat in ((1, 40), (4, 300)):
+        codes = (rng.zipf(1.3, len(X)) - 1) % n_cat
+        y = np.where(rng.rand(n_cat)[codes] < 0.3, 1.0 - y, y)
+        X[:, col] = np.where(np.isnan(X[:, col]), np.nan, codes)
+        types[col] = "c"
+    return X, y.astype(np.float32), types
 
 
 def _paged_dmatrix(X, y, tmp_path, monkeypatch):
@@ -468,9 +639,15 @@ def test_fused_matches_coarse(tier, missing, depth, tmp_path, monkeypatch,
     if tier.startswith("mesh"):
         params["mesh"] = request.getfixturevalue("mesh")
 
+    types = None
+    if tier.endswith("-cat"):
+        X, y, types = _with_categories(X, y, seed=depth)
+
     def train(method):
         dm = (_paged_dmatrix(X, y, tmp_path, monkeypatch)
-              if tier == "paged" else xgb.DMatrix(X, label=y))
+              if tier == "paged" else xgb.DMatrix(
+                  X, label=y, feature_types=types,
+                  enable_categorical=types is not None))
         return xgb.train({**params, "hist_method": method}, dm,
                          2 if depth == 8 else 3, verbose_eval=False)
 
@@ -478,6 +655,8 @@ def test_fused_matches_coarse(tier, missing, depth, tmp_path, monkeypatch,
     assert b_f.get_dump(with_stats=True) == b_c.get_dump(with_stats=True)
     if depth == 8 and "grow_policy" not in extra:
         assert max(t.max_depth() for t in b_f.gbm.trees) == 8
+    if types is not None:
+        assert any(t.is_cat_split.any() for t in b_f.gbm.trees)
 
 
 @pytest.mark.parametrize("extra", [

@@ -286,6 +286,57 @@ def test_wide_dense_grow_program_compiles_for_v5e(monkeypatch, mesh):
         assert vmem[kernel] > ph.SCOPED_VMEM, vmem
 
 
+# the numeric twin of the airline shape needs 889,671,680 B of temporaries
+# for this program; with three categorical columns it may take half again
+CAT_TEMPS_MAX = 1_334_507_520
+
+
+def test_categorical_grow_program_compiles_for_v5e(monkeypatch, mesh):
+    """The one-device grow program at the airline on-time job's shape
+    (20,000,000 x 13 two-byte ids, Origin and Dest as 351 categories and
+    UniqueCarrier as 30, 352 slots with the missing one, depth 8) for one
+    described chip: ``auto`` resolves to ``fused``, the categorical
+    histograms ride the refine sweep, the bitmask advance runs in the
+    boundary sweeps and in ``advance_leaf``, the temporaries stay within
+    half again the numeric twin's, and every kernel compiles under the
+    VMEM limit it states."""
+    from jax.sharding import SingleDeviceSharding
+
+    from xgboost_tpu.obs.metrics import (fused_boundary_counts,
+                                         kernel_vmem_bytes)
+    from xgboost_tpu.ops.split import CatInfo
+    from xgboost_tpu.tree.grow import _grow
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(mesh.devices.flat[0])
+    rows, F, cols = 20_000_000, 13, (6, 9, 10)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cat = CatInfo(is_cat=arg((F,), jnp.bool_), is_onehot=arg((F,), jnp.bool_),
+                  cols=cols, numeric_bins=256)
+    before = grow_schedule_counts().get("fused", 0)
+    boundaries = fused_boundary_counts().get("kernel", 0)
+    before_epilogue = grow_epilogue_counts().get("kernel", 0)
+    compiled = _grow.lower(
+        arg((rows, F), jnp.uint16), arg((rows, 2), jnp.float32),
+        arg((F,), jnp.int32), arg((F,), jnp.bool_), arg((2,), jnp.uint32),
+        None, None, cat, param=TrainParam(max_depth=8), max_nbins=352,
+        has_missing=True).compile()
+    assert grow_schedule_counts().get("fused", 0) == before + 1
+    assert fused_boundary_counts().get("kernel", 0) == boundaries + 7
+    assert grow_epilogue_counts().get("kernel", 0) == before_epilogue + 1
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps <= CAT_TEMPS_MAX, temps
+    text = compiled.as_text()
+    # the root's coarse build, 8 refine builds, 7 sweeps and advance_leaf
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2 * 8 + 1
+    vmem = kernel_vmem_bytes()
+    for kernel in ("build_hist_int8", "fused_advance_coarse", "advance_leaf"):
+        assert vmem[kernel] <= 64 * 2 ** 20, vmem
+
+
 # the ranking job's layout (istella-letor.train: 7,325,625 rows in 23,219
 # groups of at most 1,024, 23,552 padded, truncation 32), a ragged one: a
 # longest group of no whole number of tiles, groups of no whole step, and

@@ -370,6 +370,27 @@ def grow_epilogue_counts() -> Dict[str, int]:
     return {k: int(v) for k, v in _by_label(_GROW_EPILOGUE, "kind").items()}
 
 
+_CAT_SPLITS = "xtpu_cat_splits_total"
+CAT_SPLIT_KINDS = ("partition", "onehot", "numeric")
+
+
+def count_cat_splits(partition: int, onehot: int, numeric: int) -> None:
+    """The splits of grown depthwise trees, by kind: ``partition`` (a
+    categorical column's sorted-partition left set), ``onehot`` (one
+    category against the rest) and ``numeric`` (a threshold). Counted on
+    the host where a tree is pulled from the device
+    (``tree/grow.py TreeGrower.to_tree_model``), from the arrays pulled: no
+    device pass of its own."""
+    for kind, n in zip(CAT_SPLIT_KINDS, (partition, onehot, numeric)):
+        if n:
+            _registry.inc(_CAT_SPLITS, by=n, labels=(("kind", kind),),
+                          help="splits of grown trees, by kind")
+
+
+def cat_split_counts() -> Dict[str, int]:
+    return {k: int(v) for k, v in _by_label(_CAT_SPLITS, "kind").items()}
+
+
 _FUSED_BOUNDARY = "xtpu_fused_boundary_total"
 FUSED_BOUNDARY_BODIES = ("kernel", "xla")
 

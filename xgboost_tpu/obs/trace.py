@@ -59,6 +59,8 @@ __all__ = ["Span", "Tracer", "enable", "disable", "enabled", "tracer",
 #     grow >  advance_hist | hist            one data sweep of a level
 #               > advance | quantise | kernel.<name> | fold
 #             exchange | window | refine | eval | delta
+#             cat_split > eval                 the categorical columns' arm
+#                                              of the two-level search
 #             advance > kernel.advance_leaf     below the last level
 #
 # ``sort``, ``count_sort``, ``permute`` and ``kernel.scan_hist``: no
@@ -70,7 +72,7 @@ STAGES = (
     "gradient", "grow", "leaf", "margin",
     "sort", "advance_hist", "hist",
     "advance", "count_sort", "permute", "quantise", "fold",
-    "exchange", "window", "refine", "eval", "delta",
+    "exchange", "window", "refine", "eval", "delta", "cat_split",
 ) + tuple("kernel." + k for k in KERNELS)
 _STAGE_SET = frozenset(STAGES)
 

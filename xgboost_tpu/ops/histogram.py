@@ -25,6 +25,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..obs.metrics import count_fused_boundary, count_refine_remap
 from ..obs.trace import stage
@@ -163,7 +164,8 @@ def build_hist(bins: jnp.ndarray, gpair: jnp.ndarray, rel_pos: jnp.ndarray,
                block_rows: int = 1 << 16,
                bins_t: jnp.ndarray = None, axis_name=None,
                packed_u4: int = 0, window: jnp.ndarray = None,
-               missing_bin: int = None) -> jnp.ndarray:
+               missing_bin: int = None, cat_cols: tuple = None,
+               cat_slots: int = 0):
     """``[n_nodes, F, max_nbins, 2]`` (g, h) sums of ``bins`` ([n, F]; its
     transpose ``bins_t``, where the caller holds one, feeds the Pallas
     kernels, padded to their row blocks or not) by ``method``.
@@ -175,22 +177,28 @@ def build_hist(bins: jnp.ndarray, gpair: jnp.ndarray, rel_pos: jnp.ndarray,
     The int8x2 Pallas kernel remaps in VMEM; every other build remaps by
     ``_refine_ids`` first. ``xtpu_refine_remap_total{where}`` counts which
     (``refine_remap_where``), once a call: a call inside a traced program
-    is one a trace."""
+    is one a trace.
+
+    ``cat_cols`` (static column indices) and ``cat_slots`` make the refine
+    build also return those columns' full ``[n_nodes, len(cat_cols),
+    cat_slots, 2]`` histograms, one slot a category: in the same sweep of
+    the tile where the int8x2 kernel runs (``build_hist_pallas(cat_rows=
+    ...)``), by a build of their own elsewhere."""
     if window is not None:
         if packed_u4:
             raise ValueError("the u4 page transport never refines")
         count_refine_remap(refine_remap_where(method, n_nodes))
     return _build_hist(bins, gpair, rel_pos, n_nodes, max_nbins, method,
                        block_rows, bins_t, axis_name, packed_u4, window,
-                       missing_bin)
+                       missing_bin, cat_cols, cat_slots)
 
 
 @partial(jax.jit, static_argnames=("n_nodes", "max_nbins", "method",
                                    "block_rows", "axis_name", "packed_u4",
-                                   "missing_bin"))
+                                   "missing_bin", "cat_cols", "cat_slots"))
 def _build_hist(bins, gpair, rel_pos, n_nodes, max_nbins, method,
                 block_rows, bins_t, axis_name, packed_u4, window,
-                missing_bin):
+                missing_bin, cat_cols=None, cat_slots=0):
     if packed_u4:
         # ``bins`` is a u4-packed [n, ceil(F/2)] page (packed_u4 = logical
         # F). The Pallas path decodes nibbles in-VMEM inside the kernel's
@@ -221,7 +229,17 @@ def _build_hist(bins, gpair, rel_pos, n_nodes, max_nbins, method,
             return build_hist_pallas(
                 bins.T if bins_t is None else bins_t, gpair, rel_pos,
                 n_nodes, max_nbins, axis_name=axis_name, window=window,
-                missing_bin=missing_bin)
+                missing_bin=missing_bin, cat_rows=cat_cols or (),
+                cat_slots=cat_slots)
+        if cat_cols:
+            return (_build_hist(
+                _refine_ids(bins, rel_pos, window, missing_bin), gpair,
+                rel_pos, n_nodes, max_nbins, method, block_rows, None,
+                axis_name, 0, None, None),
+                _build_hist(
+                    bins[:, np.asarray(cat_cols)], gpair, rel_pos, n_nodes,
+                    cat_slots, method, block_rows, None, axis_name, 0, None,
+                    None))
         bins = _refine_ids(bins, rel_pos, window, missing_bin)
         bins_t = None
     method = _resolve_method(method, n_nodes)
@@ -284,6 +302,7 @@ def _advance_below(bins: jnp.ndarray, positions: jnp.ndarray, prev: dict,
     from .partition import advance_positions_level, update_positions
 
     lo_prev, nl_prev = prev["lo"], prev["n_level"]
+    is_cat, words = prev.get("cat") or (None, None)
     if prev["kind"] == "dense":
         feat, thr, dleft, cs = prev["arrs"]
         rel_prev = jnp.where(
@@ -293,10 +312,12 @@ def _advance_below(bins: jnp.ndarray, positions: jnp.ndarray, prev: dict,
         # matmul read — no materialised [n, F] f32 copy
         return advance_positions_level(
             bins.astype(jnp.float32), positions, rel_prev, feat, thr,
-            dleft, cs, missing_bin, decision_axis=decision_axis)
+            dleft, cs, missing_bin, is_cat=is_cat, cat_words=words,
+            decision_axis=decision_axis)
     sf, sb, dl, isf = prev["arrs"]
     return update_positions(
         bins, positions, sf, sb, dl, isf, missing_bin,
+        is_cat_split=is_cat, cat_words=words,
         decision_axis=decision_axis,
         feat_offset=prev.get("feat_offset"))
 
@@ -314,8 +335,11 @@ def fused_advance_coarse(bins: jnp.ndarray, gpair: jnp.ndarray,
     ``prev``: the previous level's split payload — ``kind`` ("dense" for
     the matmul advance over per-level vectors, "walk" for the deep-level
     per-row gather walk over full tree arrays), ``lo``, ``n_level``,
-    ``arrs``, and optionally ``feat_offset`` (column split walk) — the
-    same convention as ``tree/paged.py``. Returns
+    ``arrs``, and optionally ``feat_offset`` (column split walk) and, on a
+    matrix with categorical columns, ``cat`` (the categorical flags and
+    left-set words, over the same nodes as ``arrs``) and ``cat_cols``
+    (those columns, static) — the same convention as ``tree/paged.py``.
+    Returns
     ``(new_positions, coarse_hist [n_level, F, COARSE_B, 2])``.
 
     Bit-exactness with the two-pass coarse path: the advance is pure
@@ -349,10 +373,10 @@ def fused_advance_coarse(bins: jnp.ndarray, gpair: jnp.ndarray,
         if bins_t is None:
             bins_t = bins.T
         return fused_advance_coarse_pallas(
-            bins_t, gpair, positions, feat, thr, dleft, cs,
+            bins_t, gpair, positions, feat, thr, dleft, cs, prev.get("cat"),
             lo_prev=lo_prev, n_prev=nl_prev, lo=lo, n_level=n_level,
             missing_bin=missing_bin, axis_name=axis_name,
-            interpret=interpret)
+            interpret=interpret, cat_cols=prev.get("cat_cols", ()))
     positions = _advance_below(bins, positions, prev, missing_bin,
                                decision_axis)
     rel = jnp.where((positions >= lo) & (positions < lo + n_level),
@@ -395,10 +419,14 @@ def advance_leaf(bins: jnp.ndarray, positions: jnp.ndarray, prev: dict,
     with stage("advance"):
         # the level's rows of the walk's whole-heap arrays
         feat, thr, dleft, cs = (a[lo:lo + n_level] for a in prev["arrs"])
+        cat = prev.get("cat")
+        if cat is not None:
+            cat = tuple(a[lo:lo + n_level] for a in cat)
         new_positions, delta = advance_leaf_pallas(
             bins.T if bins_t is None else bins_t, positions, feat, thr,
-            dleft, cs, leaf_value, n_prev=n_level, missing_bin=missing_bin,
-            interpret=interpret)
+            dleft, cs, leaf_value, cat, n_prev=n_level,
+            missing_bin=missing_bin, interpret=interpret,
+            cat_cols=prev.get("cat_cols", ()))
     return new_positions, delta, "kernel"
 
 

@@ -275,7 +275,8 @@ def _acc_shapes(F_blk: int, F_pad: int, B: int, N: int) -> tuple:
 
 def _int8_vmem_bytes(F_blk: int, F_pad: int, B: int, N: int, R: int,
                      tile_rows: int, itemsize: int, packed: bool,
-                     refine: bool = False) -> int:
+                     refine: bool = False, cat_cols: int = 0,
+                     cat_slots: int = 0) -> int:
     """Scoped VMEM a fixed-point histogram kernel holds, reckoned from what
     its body keeps: the input tiles twice (the bin block, its rows padded to
     whole 32-row tiles of bytes, and the gradient pair and positions, 8
@@ -287,7 +288,10 @@ def _int8_vmem_bytes(F_blk: int, F_pad: int, B: int, N: int, R: int,
     refine build that remaps in VMEM (``refine``) adds its window table
     twice (``[F_blk, N]`` f32, lanes padded to 128), the node one-hot it
     contracts (``[N, R]`` f32), and two ``[F_blk, R]`` 32-bit arrays: the
-    remapped block's scratch and the window product it is made from.
+    remapped block's scratch and the window product it is made from. Its
+    categorical columns (``cat_cols`` of ``cat_slots`` slots) add their
+    output's two buffers, a column's compare one-hot (int32 and int8) and
+    its dot's int32 product.
 
     Measured for a described v5e at 36 slots and 128 nodes (PERF.md section
     6): 5.9-6.4 MiB at 80-200 features a block while the whole
@@ -306,6 +310,9 @@ def _int8_vmem_bytes(F_blk: int, F_pad: int, B: int, N: int, R: int,
                  + 2 * rows * R * 4)
     if F_pad * B * 2 * N * 4 > _OUT_IN_SCOPE:
         need += 2 * F_blk * B * 2 * N * 4
+    if cat_cols:
+        need += (2 * cat_cols * cat_slots * _round_up(2 * N, 128) * 4
+                 + 5 * cat_slots * R + cat_slots * _round_up(4 * N, 128) * 4)
     return need
 
 
@@ -409,7 +416,8 @@ def _remap_window(tab_ref, bins_ref, on_node, rb_ref, missing_bin: int):
 def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
                       block_rows: int, packed: bool = False,
                       u4: bool = False,
-                      refine_missing: Optional[int] = None):
+                      refine_missing: Optional[int] = None,
+                      cat_rows: tuple = (), cat_slots: int = 0):
     """Fixed-point kernel: gradients arrive as two int8 byte planes
     (value = hi * 256 + lo, a 15-bit quantisation done by the caller);
     both planes are contracted with the 0/1 one-hot on the int8 MXU with
@@ -420,6 +428,13 @@ def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
     first and the RAW bin block, remaps the block once a grid step
     (``_remap_window``) and histograms the refine slots, bit for bit the
     build over ``ops/split.py refine_bin_ids``' ids.
+
+    ``cat_rows`` (a refine build's categorical columns, as ``(feature
+    block, row in it)``): the same sweep sums their full histograms, one
+    slot a category (``cat_slots``), into a second output ``[len(cat_rows),
+    cat_slots, 2N]`` that stays in VMEM over the whole grid, from the RAW
+    block and the ``PT4`` the refine dots take: a ``[cat_slots, R]``
+    compare one-hot and one dot a column, in the block that holds it.
 
     ``packed=True`` (requires ``n_bins % 4 == 0 and n_bins <= 256``): the
     one-hot is built four bins per uint32 word with a SWAR zero-byte
@@ -449,6 +464,9 @@ def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
     def kernel(*refs):
         if refine_missing is None:
             bins_ref, q_ref, pos_ref, out_ref = refs
+        elif cat_rows:
+            (tab_ref, raw_ref, q_ref, pos_ref, out_ref, cat_ref,
+             bins_ref) = refs
         else:
             tab_ref, raw_ref, q_ref, pos_ref, out_ref, bins_ref = refs
         i = pl.program_id(1)
@@ -456,6 +474,11 @@ def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
         @pl.when(i == 0)
         def _():
             out_ref[:] = jnp.zeros_like(out_ref)
+
+        if cat_rows:
+            @pl.when((i == 0) & (pl.program_id(0) == 0))
+            def _():
+                cat_ref[:] = jnp.zeros_like(cat_ref)
 
         pos_row = pos_ref[:]                               # [1, R] int32
         node_iota = jax.lax.broadcasted_iota(jnp.int32, (N, R), 0)
@@ -480,6 +503,20 @@ def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
         # MXU pass over the one-hot instead of two (the one-hot operand
         # feed dominates)
         PT4 = jnp.concatenate([g_hi, h_hi, g_lo, h_lo], axis=0)  # [4N, R] i8
+
+        if cat_rows:
+            cat_iota = jax.lax.broadcasted_iota(jnp.int32, (cat_slots, R), 0)
+            for k, (blk, r) in enumerate(cat_rows):
+                @pl.when(pl.program_id(0) == blk)
+                def _(k=k, r=r):
+                    oh = (cat_iota == raw_ref[r:r + 1, :].astype(jnp.int32)
+                          ).astype(jnp.int8)                 # [C, R]
+                    acc4 = jax.lax.dot_general(
+                        oh, PT4, _CONTRACT_LAST,
+                        preferred_element_type=jnp.int32)    # [C, 4N]
+                    cat_ref[k] += (acc4[:, : 2 * N].astype(jnp.float32)
+                                   * 256.0
+                                   + acc4[:, 2 * N:].astype(jnp.float32))
 
         # One-hot + dot, ``_dot_features`` features at a time (not one big
         # [Fb*B, R] staged matmul): the dots are unrolled, so Mosaic
@@ -511,9 +548,78 @@ def _make_int8_kernel(n_feat_block: int, n_bins: int, n_nodes: int,
     return kernel
 
 
+# ---- categorical routing inside the advance kernels -------------------------
+# A categorical split sends a row left when its category's bit is set in the
+# node's left set (``ops/split.py SplitResult.cat_words``, W uint32 words).
+# The kernels look the bit up with no gather: the words of every node of the
+# level arrive as one table of bytes, ``[Fc * 4 * Wp, N]`` f32 (row ``(c,
+# byte, w)`` holds byte ``byte`` of word ``w`` of node ``j``'s set where node
+# ``j`` splits on the c-th categorical column, 0 elsewhere), and ONE dot
+# against the rows' node one-hot ``[N, R]`` hands each row its own node's
+# words, exactly (one non-zero term a sum, bytes below 256). A select over
+# the Wp word sublanes then takes the word the row's category falls in.
+
+
+def cat_word_rows(n_words: int) -> int:
+    """Sublanes a column's words take in the routing table: W rounded up to
+    whole sublane tiles."""
+    return _round_up(max(n_words, 1), 8)
+
+
+def cat_route_table(feat: jnp.ndarray, is_cat: jnp.ndarray,
+                    words: jnp.ndarray, cat_cols: tuple) -> jnp.ndarray:
+    """The routing table (above) of one level's ``N`` nodes: ``feat`` [N]
+    split features, ``is_cat`` [N] categorical splits, ``words`` [N, W]
+    their left sets; ``cat_cols`` the matrix's categorical columns.
+    -> ``[len(cat_cols) * 4 * Wp, N]`` f32."""
+    N, W = words.shape
+    Wp = cat_word_rows(W)
+    w = jnp.pad(words.astype(jnp.uint32), ((0, 0), (0, Wp - W)))
+    shifts = jnp.uint32(8) * jnp.arange(4, dtype=jnp.uint32)
+    by = (w[:, None, :] >> shifts[None, :, None]) & jnp.uint32(0xFF)
+    on = is_cat.astype(bool)[:, None] & (
+        feat[:, None] == jnp.asarray(cat_cols, jnp.int32)[None, :])
+    tab = jnp.where(on[:, :, None, None], by[:, None], jnp.uint32(0))
+    return tab.reshape(N, -1).T.astype(jnp.float32)
+
+
+def _cat_route_vmem(n_cat: int, n_words: int, N: int, R: int) -> int:
+    """Scoped VMEM the routing adds to an advance kernel: the table twice,
+    the node one-hot it contracts and a column's product with its select."""
+    rows = n_cat * 4 * cat_word_rows(n_words)
+    return (2 * _round_up(rows, 8) * _round_up(N, 128) * 4 + N * R * 4
+            + 2 * 4 * cat_word_rows(n_words) * R * 4)
+
+
+def _cat_goes_left(tab_ref, on_prev, cat_rows):
+    """``[1, R]`` int32, 1 where a row's category (in the column its node
+    splits on) is in its node's left set; 0 for rows at a numeric node or
+    outside the level. ``on_prev``: ``[N, R]`` f32 one-hot of each row's
+    node; ``cat_rows``: the categorical columns' ``[1, R]`` int32 ids, in
+    the table's order. A fixed number of equations a categorical column."""
+    Wp = tab_ref.shape[0] // (4 * len(cat_rows))
+    R = on_prev.shape[1]
+    at = jax.lax.broadcasted_iota(jnp.int32, (Wp, R), 0)
+    left = jnp.zeros((1, R), jnp.int32)
+    for c, b in enumerate(cat_rows):
+        per = jax.lax.dot_general(
+            tab_ref[c * 4 * Wp:(c + 1) * 4 * Wp, :], on_prev,
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # [4 Wp, R]
+        hit = at == (b >> 5)
+        word = jnp.zeros((1, R), jnp.int32)
+        for k in range(4):
+            v = jnp.sum(jnp.where(hit, per[k * Wp:(k + 1) * Wp], 0.0),
+                        axis=0, keepdims=True).astype(jnp.int32)
+            word = word | (v << (8 * k))
+        left = left | (jax.lax.shift_right_logical(word, b & 31) & 1)
+    return left
+
+
 def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
                        block_rows: int, lo_prev: int, lo: int,
-                       missing_bin: int, coarse_b: int, shift: int):
+                       missing_bin: int, coarse_b: int, shift: int,
+                       cat_cols: tuple = ()):
     """Cross-level fused sweep (hist_method="fused"): ONE read of the
     ``[F, R]`` bin tile per row block drives (a) the row-position advance
     below the previous level's decoded splits, (b) the coarse-id remap
@@ -546,7 +652,16 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
     and the accumulator's leading axis take a dynamic index as they are.
     The scratch and the accumulator are ``groups x group`` features long;
     the rows past F (fewer than ``groups``) are zero ids, and the wrapper
-    drops their histograms."""
+    drops their histograms.
+
+    ``cat_cols`` (the matrix's categorical columns): the payload gains a
+    fifth row, the node's categorical flag, and the kernel the routing
+    table (``cat_route_table``) as its fifth input; a categorical node
+    routes its rows by their category's bit in its left set
+    (``_cat_goes_left``, once a row block for every node of the level),
+    a missing one by ``default_left``. Their coarse ids are never read."""
+    from ..split import COARSE_NUMERIC_IDS, COARSE_PAD_SLOT
+
     B, N, R, F = coarse_b, n_nodes, block_rows, n_feat
     groups, group = _feature_groups(F)
     F_pad = groups * group
@@ -565,12 +680,31 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
         return jnp.where(take > 0, child, new_pos)
 
     @jax.jit
+    def advance_below_cat(bj, tj, dj, cj, kj, j, pos_row, rel_prev, new_pos,
+                          cat_right):
+        gr = jnp.where(bj == missing_bin, 1 - dj,
+                       jnp.where(kj > 0, cat_right,
+                                 (bj > tj).astype(jnp.int32)))
+        child = 2 * pos_row + 1 + gr
+        take = jnp.where(rel_prev == j, cj, 0)
+        return jnp.where(take > 0, child, new_pos)
+
+    @jax.jit
     def coarse_ids(row):
-        cb = jnp.where(row == missing_bin, B - 1, row >> shift)
+        if missing_bin > COARSE_NUMERIC_IDS:
+            # ids past the numeric range: a categorical column's, on a pad
+            # slot (``ops/split.py coarse_bin_ids``)
+            cb = jnp.where(row == missing_bin, B - 1,
+                           jnp.minimum(row >> shift, COARSE_PAD_SLOT))
+        else:
+            cb = jnp.where(row == missing_bin, B - 1, row >> shift)
         return cb.astype(jnp.uint32)
 
-    def kernel(split_ref, bins_ref, q_ref, pos_ref, hist_ref, pos_out_ref,
-               bins32):
+    def kernel(split_ref, bins_ref, q_ref, pos_ref, *refs):
+        if cat_cols:
+            cat_ref, hist_ref, pos_out_ref, bins32 = refs
+        else:
+            hist_ref, pos_out_ref, bins32 = refs
         i = pl.program_id(0)
 
         @pl.when(i == 0)
@@ -592,12 +726,23 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
             (pos_row >= lo_prev) & (pos_row < lo_prev + n_prev),
             pos_row - lo_prev, n_prev)
         new_pos = pos_row
+        if cat_cols:
+            on_prev = (jax.lax.broadcasted_iota(jnp.int32, (n_prev, R), 0)
+                       == rel_prev).astype(jnp.float32)    # [n_prev, R]
+            cat_right = 1 - _cat_goes_left(
+                cat_ref, on_prev, [bins32[c:c + 1, :] for c in cat_cols])
         for j in range(n_prev):
             fj = split_ref[0, j]
             bj = bins32[pl.ds(fj, 1), :]                   # [1, R]
-            new_pos = advance_below(
-                bj, split_ref[1, j], split_ref[2, j], split_ref[3, j],
-                np.int32(j), pos_row, rel_prev, new_pos)
+            if cat_cols:
+                new_pos = advance_below_cat(
+                    bj, split_ref[1, j], split_ref[2, j], split_ref[3, j],
+                    split_ref[4, j], np.int32(j), pos_row, rel_prev, new_pos,
+                    cat_right)
+            else:
+                new_pos = advance_below(
+                    bj, split_ref[1, j], split_ref[2, j], split_ref[3, j],
+                    np.int32(j), pos_row, rel_prev, new_pos)
         pos_out_ref[:] = new_pos
         rel = jnp.where((new_pos >= lo) & (new_pos < lo + N),
                         new_pos - lo, N)                   # [1, R]
@@ -639,20 +784,23 @@ def _make_fused_kernel(n_feat: int, n_prev: int, n_nodes: int,
 @functools.partial(
     jax.jit,
     static_argnames=("lo_prev", "n_prev", "lo", "n_level", "missing_bin",
-                     "block_rows", "interpret", "axis_name"))
+                     "block_rows", "interpret", "axis_name", "cat_cols"))
 def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                                 positions: jnp.ndarray, feat: jnp.ndarray,
                                 thr: jnp.ndarray, dleft: jnp.ndarray,
-                                can_split: jnp.ndarray, *, lo_prev: int,
-                                n_prev: int, lo: int, n_level: int,
-                                missing_bin: int, block_rows: int = ROW_BLOCK,
-                                axis_name=None, interpret: bool = False):
+                                can_split: jnp.ndarray, cat=None, *,
+                                lo_prev: int, n_prev: int, lo: int,
+                                n_level: int, missing_bin: int,
+                                block_rows: int = ROW_BLOCK,
+                                axis_name=None, interpret: bool = False,
+                                cat_cols: tuple = ()):
     """Single-HBM-read advance + coarse build (see ``_make_fused_kernel``).
 
     bins_t: [F, n] fine bin ids (or the tile padded to the row blocks,
     ``fit_rows``); gpair: [n, 2] f32; positions: [n] heap node ids;
     feat/thr/dleft/can_split: [n_prev] previous-level split vectors
-    (feat == -1 on non-split slots).
+    (feat == -1 on non-split slots); cat: (is_cat [n_prev], words
+    [n_prev, W]) of a matrix with the categorical columns ``cat_cols``.
     -> (new_positions [n] int32, hist [n_level, F, COARSE_B, 2] f32)
     """
     from ..split import COARSE_B, COARSE_SPAN
@@ -680,10 +828,11 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         scale = 32512.0 / jnp.maximum(max_abs, 1e-30)
         q = jnp.round(gpair_t * scale[:, None]).astype(jnp.int32)
     pos_t = positions.astype(jnp.int32)[None, :]         # [1, n]
-    splits = jnp.stack([jnp.maximum(feat, 0).astype(jnp.int32),
-                        thr.astype(jnp.int32),
-                        dleft.astype(jnp.int32),
-                        can_split.astype(jnp.int32)])    # [4, n_prev]
+    rows = [jnp.maximum(feat, 0).astype(jnp.int32), thr.astype(jnp.int32),
+            dleft.astype(jnp.int32), can_split.astype(jnp.int32)]
+    if cat_cols:
+        rows.append(cat[0].astype(jnp.int32))
+    splits = jnp.stack(rows)                             # [4 or 5, n_prev]
 
     grid = (n_pad // R,)
     groups, group = _feature_groups(F)
@@ -695,28 +844,39 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     # tile takes, with the default's room beside it (the accumulator, and a
     # stacked dot's words, one-hot and int32 product: 0.7 MB at 20 slots and
     # 32 nodes); a narrow one keeps the default.
-    params = _vmem_params("fused_advance_coarse", None if groups == 1 else
-                          F_pad * R * (4 + 2 * bins_t.dtype.itemsize)
-                          + SCOPED_VMEM)
+    extra = (F_pad * R * (4 + 2 * bins_t.dtype.itemsize)
+             if groups > 1 else 0)
+    if cat_cols:
+        # a categorical matrix's kernel states what its routing adds
+        extra += _cat_route_vmem(len(cat_cols), cat[1].shape[1], n_prev, R)
+    params = _vmem_params("fused_advance_coarse", None if not extra
+                          else extra + SCOPED_VMEM)
     count_hist_onehot("swar")
     _note_dot(B, N)
     note_hist_body_features(group)
+    in_specs = [pl.BlockSpec((splits.shape[0], n_prev), lambda i: (0, 0),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((F, R), lambda i: (0, i),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((2, R), lambda i: (0, i),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, R), lambda i: (0, i),
+                             memory_space=pltpu.VMEM)]
+    operands = [splits, bins_t, q, pos_t]
+    if cat_cols:
+        tab = cat_route_table(feat, cat[0], cat[1], cat_cols)
+        in_specs.append(pl.BlockSpec(tab.shape, lambda i: (0, 0),
+                                     memory_space=pltpu.VMEM))
+        operands.append(tab)
     with stage("kernel.fused_advance_coarse"):
         _, acc = _acc_shapes(F_pad, F_pad, B, N)
         hist, pos_out = pl.pallas_call(
             _make_fused_kernel(F, n_prev, N, R, lo_prev, lo, missing_bin, B,
-                               shift),
+                               shift, cat_cols),
             out_shape=[_out_struct(acc, jnp.float32, bins_t, q),
                        _out_struct((1, n_pad), jnp.int32, bins_t, pos_t)],
             grid=grid,
-            in_specs=[pl.BlockSpec((4, n_prev), lambda i: (0, 0),
-                                   memory_space=pltpu.SMEM),
-                      pl.BlockSpec((F, R), lambda i: (0, i),
-                                   memory_space=pltpu.VMEM),
-                      pl.BlockSpec((2, R), lambda i: (0, i),
-                                   memory_space=pltpu.VMEM),
-                      pl.BlockSpec((1, R), lambda i: (0, i),
-                                   memory_space=pltpu.VMEM)],
+            in_specs=in_specs,
             out_specs=[pl.BlockSpec(acc, lambda i: (0,) * len(acc),
                                     memory_space=pltpu.VMEM),
                        pl.BlockSpec((1, R), lambda i: (0, i),
@@ -725,7 +885,7 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
             compiler_params=params,
             interpret=interpret,
             name="fused_advance_coarse",
-        )(splits, bins_t, q, pos_t)
+        )(*operands)
     with stage("fold"):
         inv = jnp.repeat(1.0 / scale, N)[None, None, :]  # [1, 1, 2N]
         hist = hist.reshape(F_pad, B, 2 * N)[:F] * inv
@@ -734,7 +894,7 @@ def fused_advance_coarse_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
 
 
 def _make_advance_leaf_kernel(n_feat: int, n_prev: int, block_rows: int,
-                              missing_bin: int):
+                              missing_bin: int, cat_cols: tuple = ()):
     """The LAST level's advance with the leaf delta behind it: one sweep of
     the ``[F, R]`` tile routes the rows below the deepest evaluated level
     and writes each row's leaf value, where the XLA epilogue paid six
@@ -759,14 +919,24 @@ def _make_advance_leaf_kernel(n_feat: int, n_prev: int, block_rows: int,
     A select-and-sum over the one-hot has one non-zero term a row, so it
     IS the table entry, bit for bit (a signed zero too: the leaf values
     never pass through float arithmetic). The row's bin of its node's
-    split feature is the same select over the tile's feature sublanes."""
+    split feature is the same select over the tile's feature sublanes.
+
+    ``cat_cols`` (the matrix's categorical columns): the split word's bit
+    30 marks a categorical split, and the routing table
+    (``cat_route_table``) arrives as a fourth input; such a node routes a
+    row by its category's bit in the node's left set
+    (``_cat_goes_left``), a missing one by ``default_left``."""
     F, N, R = n_feat, n_prev, block_rows
     C = min(N, _LEAF_CHUNK)
 
     def take(acc, hit, tab_ref, plane, c):
         return acc + jnp.where(hit, tab_ref[plane, c:c + C, :], 0)
 
-    def kernel(tab_ref, bins_ref, pos_ref, pos_out_ref, delta_ref):
+    def kernel(tab_ref, bins_ref, pos_ref, *refs):
+        if cat_cols:
+            cat_ref, pos_out_ref, delta_ref = refs
+        else:
+            pos_out_ref, delta_ref = refs
         pos = pos_ref[:]                                   # [1, R] i32
         idx = pos + 1
         iota = jax.lax.broadcasted_iota(jnp.int32, (C, R), 0)
@@ -786,15 +956,29 @@ def _make_advance_leaf_kernel(n_feat: int, n_prev: int, block_rows: int,
         feat = split & 0xFFFF
         thr = (split >> 16) & 0xFFF
         dleft = (split >> 28) & 1
-        can_split = split >> 29
+        if cat_cols:
+            can_split = (split >> 29) & 1
+            is_cat = split >> 30
+        else:
+            can_split = split >> 29
 
         fiota = jax.lax.broadcasted_iota(jnp.int32, (F, R), 0)
         b = jnp.sum(jnp.where(fiota == feat,
                               bins_ref[:].astype(jnp.int32), 0),
                     axis=0, keepdims=True)                 # [1, R]
 
-        go_right = jnp.where(b == missing_bin, 1 - dleft,
-                             (b > thr).astype(jnp.int32))
+        if cat_cols:
+            on_prev = (jax.lax.broadcasted_iota(jnp.int32, (N, R), 0)
+                       == pos - (N - 1)).astype(jnp.float32)   # [N, R]
+            cat_left = _cat_goes_left(
+                cat_ref, on_prev,
+                [bins_ref[c:c + 1, :].astype(jnp.int32) for c in cat_cols])
+            go_right = jnp.where(b == missing_bin, 1 - dleft,
+                                 jnp.where(is_cat > 0, 1 - cat_left,
+                                           (b > thr).astype(jnp.int32)))
+        else:
+            go_right = jnp.where(b == missing_bin, 1 - dleft,
+                                 (b > thr).astype(jnp.int32))
         pos_out_ref[:] = jnp.where(can_split > 0, 2 * pos + 1 + go_right,
                                    pos)
         bits = own + jnp.where(go_right > 0, right, left)
@@ -816,13 +1000,14 @@ _ADVANCE_LEAF_TILE_FITS = 968 * 2048 * (4 + 2 * 2)
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_prev", "missing_bin", "block_rows", "interpret"))
+    static_argnames=("n_prev", "missing_bin", "block_rows", "interpret",
+                     "cat_cols"))
 def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
                         feat: jnp.ndarray, thr: jnp.ndarray,
                         dleft: jnp.ndarray, can_split: jnp.ndarray,
-                        leaf_value: jnp.ndarray, *, n_prev: int,
+                        leaf_value: jnp.ndarray, cat=None, *, n_prev: int,
                         missing_bin: int, block_rows: int = ROW_BLOCK,
-                        interpret: bool = False):
+                        interpret: bool = False, cat_cols: tuple = ()):
     """Advance below the deepest evaluated level and look the leaf up, in
     one sweep (see ``_make_advance_leaf_kernel``).
 
@@ -830,7 +1015,9 @@ def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
     ``fit_rows``); positions: [n] heap node ids, none below the
     level; feat/thr/dleft/can_split: [n_prev] the level's splits, the
     level starting at heap node ``n_prev - 1``; leaf_value: [max_nodes]
-    f32 over the whole heap, the level's children included.
+    f32 over the whole heap, the level's children included; cat: (is_cat
+    [n_prev], words [n_prev, W]) of a matrix with the categorical columns
+    ``cat_cols``.
     -> (new_positions [n] int32, delta [n] f32 = leaf_value[new_positions])
     """
     F, n = bins_t.shape[0], positions.shape[0]
@@ -849,9 +1036,12 @@ def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
     # sweep does: at F = 2,000 with one-byte ids they are 23.4 MiB, and
     # Mosaic asked 24.75M (PERF.md section 6).
     tile = F * R * (4 + 2 * bins_t.dtype.itemsize)
+    if cat_cols:
+        # a categorical matrix's kernel states what its routing adds
+        tile += _cat_route_vmem(len(cat_cols), cat[1].shape[1], N, R)
     params = _vmem_params("advance_leaf",
                           None if tile <= _ADVANCE_LEAF_TILE_FITS
-                          else tile + SCOPED_VMEM)
+                          and not cat_cols else tile + SCOPED_VMEM)
 
     bits = jax.lax.bitcast_convert_type(
         leaf_value.astype(jnp.float32), jnp.int32)
@@ -861,6 +1051,8 @@ def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
               | thr.astype(jnp.int32) << 16
               | dleft.astype(jnp.int32) << 28
               | cs.astype(jnp.int32) << 29)
+    if cat_cols:
+        packed = packed | (cat[0].astype(bool) & cs).astype(jnp.int32) << 30
     zeros = jnp.zeros((N,), jnp.int32)
     tab = jnp.stack([
         jnp.concatenate([zeros[:1], bits[:lo], packed]),
@@ -870,18 +1062,25 @@ def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
                                           bits[node])]),
     ])[:, :, None]                                         # [3, 2N, 1]
 
+    in_specs = [pl.BlockSpec((3, 2 * N, 1), lambda i: (0, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((F, R), lambda i: (0, i),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, R), lambda i: (0, i),
+                             memory_space=pltpu.VMEM)]
+    operands = [tab, bins_t, pos_t]
+    if cat_cols:
+        route = cat_route_table(feat, cat[0], cat[1], cat_cols)
+        in_specs.append(pl.BlockSpec(route.shape, lambda i: (0, 0),
+                                     memory_space=pltpu.VMEM))
+        operands.append(route)
     with stage("kernel.advance_leaf"):
         pos_out, delta = pl.pallas_call(
-            _make_advance_leaf_kernel(F, N, R, missing_bin),
+            _make_advance_leaf_kernel(F, N, R, missing_bin, cat_cols),
             out_shape=[_out_struct((1, n_pad), jnp.int32, bins_t, pos_t),
                        _out_struct((1, n_pad), jnp.float32, bins_t, pos_t)],
             grid=(n_pad // R,),
-            in_specs=[pl.BlockSpec((3, 2 * N, 1), lambda i: (0, 0, 0),
-                                   memory_space=pltpu.VMEM),
-                      pl.BlockSpec((F, R), lambda i: (0, i),
-                                   memory_space=pltpu.VMEM),
-                      pl.BlockSpec((1, R), lambda i: (0, i),
-                                   memory_space=pltpu.VMEM)],
+            in_specs=in_specs,
             out_specs=[pl.BlockSpec((1, R), lambda i: (0, i),
                                     memory_space=pltpu.VMEM),
                        pl.BlockSpec((1, R), lambda i: (0, i),
@@ -889,7 +1088,7 @@ def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
             compiler_params=params,
             interpret=interpret,
             name="advance_leaf",
-        )(tab, bins_t, pos_t)
+        )(*operands)
     return pos_out[0, :n], delta[0, :n]
 
 
@@ -897,7 +1096,7 @@ def advance_leaf_pallas(bins_t: jnp.ndarray, positions: jnp.ndarray,
     jax.jit,
     static_argnames=("n_nodes", "max_nbins", "precision", "block_rows",
                      "feat_block", "interpret", "axis_name", "packed_u4",
-                     "missing_bin"))
+                     "missing_bin", "cat_rows", "cat_slots"))
 def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                       rel_pos: jnp.ndarray, n_nodes: int, max_nbins: int,
                       precision: str = "int8x2", block_rows: int = ROW_BLOCK,
@@ -905,7 +1104,8 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                       interpret: bool = False,
                       axis_name=None, packed_u4: int = 0,
                       window: Optional[jnp.ndarray] = None,
-                      missing_bin: Optional[int] = None) -> jnp.ndarray:
+                      missing_bin: Optional[int] = None,
+                      cat_rows: tuple = (), cat_slots: int = 0):
     """Fused histogram kernel.
 
     bins_t: [F, n] local bin ids (any int dtype), missing at max_nbins - 1
@@ -925,7 +1125,12 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         slot; ``bins_t`` holds the RAW ids, and the kernel remaps them in
         VMEM (``_remap_window``): the histogram of ``refine_bin_ids``'
         slots, ``max_nbins`` of them, with no remapped copy in HBM
-    -> [n_nodes, F, max_nbins, 2] f32
+    cat_rows, cat_slots: the refine build of a matrix with categorical
+        columns (``tree/grow.py``): the same sweep also sums the full
+        histograms of the tile's rows ``cat_rows``, one slot a category,
+        ``cat_slots`` of them (``_make_int8_kernel``)
+    -> [n_nodes, F, max_nbins, 2] f32; with ``cat_rows``, that and the
+       categorical columns' ``[n_nodes, len(cat_rows), cat_slots, 2]``
     """
     u4 = bool(packed_u4)
     refine = window is not None
@@ -933,6 +1138,9 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         raise ValueError("the refine window remaps inside the int8x2 "
                          "kernel only, given the missing slot; the u4 page "
                          "transport never refines")
+    if cat_rows and not (refine and cat_slots % 32 == 0):
+        raise ValueError("categorical columns ride the refine build only, "
+                         "in whole 32-slot tiles")
     F, n = (packed_u4 if u4 else bins_t.shape[0]), gpair.shape[0]
     B, N = max_nbins, n_nodes
 
@@ -1015,8 +1223,10 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         # room beside it
         vmem = _int8_vmem_bytes(F_blk, F_pad, B, N, R,
                                 bins_spec.block_shape[0],
-                                bins_t.dtype.itemsize, packed, refine)
-        params = _vmem_params("build_hist_int8", None if vmem <= SCOPED_VMEM
+                                bins_t.dtype.itemsize, packed, refine,
+                                len(cat_rows), cat_slots)
+        params = _vmem_params("build_hist_int8",
+                              None if vmem <= SCOPED_VMEM
                               else vmem + SCOPED_VMEM)
         in_specs, operands, scratch = ([bins_spec, vec2_spec, pos_spec],
                                        [bins_t, q, pos_t], [])
@@ -1028,15 +1238,26 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
                                             memory_space=pltpu.VMEM))
             operands.insert(0, tab)
             scratch.append(pltpu.VMEM((F_blk, R), jnp.int32))
+        out_shapes, out_specs = out_shape, out_spec
+        if cat_rows:
+            # a categorical column's (feature block, row in it)
+            where = tuple((c // F_blk, c % F_blk) for c in cat_rows)
+            cat_shape = (len(cat_rows), cat_slots, 2 * N)
+            out_shapes = [out_shape, _out_struct(cat_shape, jnp.float32,
+                                                 bins_t, gpair_t, pos_t)]
+            out_specs = [out_spec, pl.BlockSpec(
+                cat_shape, lambda j, i: (0, 0, 0), memory_space=pltpu.VMEM)]
         with stage("kernel.build_hist_int8"):
             out = pl.pallas_call(
                 _make_int8_kernel(F_blk, B, N, R, packed=packed, u4=u4,
                                   refine_missing=missing_bin if refine
-                                  else None),
-                out_shape=out_shape,
+                                  else None,
+                                  cat_rows=where if cat_rows else (),
+                                  cat_slots=cat_slots),
+                out_shape=out_shapes,
                 grid=grid,
                 in_specs=in_specs,
-                out_specs=out_spec,
+                out_specs=out_specs,
                 scratch_shapes=scratch,
                 compiler_params=params,
                 interpret=interpret,
@@ -1045,6 +1266,10 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
         # columns [0:N] hold g-sums, [N:2N] h-sums -> per-component dequant
         with stage("fold"):
             inv = jnp.repeat(1.0 / scale, N)[None, None, :]  # [1, 1, 2N]
+            if cat_rows:
+                out, cat_out = out
+                cat_out = (cat_out * inv).reshape(
+                    len(cat_rows), cat_slots, 2, N).transpose(3, 0, 1, 2)
             out = out.reshape(F_pad, B, 2 * N) * inv
     else:
         count_hist_onehot("compare")
@@ -1067,4 +1292,6 @@ def build_hist_pallas(bins_t: jnp.ndarray, gpair: jnp.ndarray,
     with stage("fold"):
         out = out[:F]                                # [F, B, 2N]
         gh = out.reshape(F, B, 2, N)                 # split g-part / h-part
+        if cat_rows:
+            return gh.transpose(3, 0, 1, 2), cat_out
         return gh.transpose(3, 0, 1, 2)              # [N, F, B, 2]

@@ -65,11 +65,20 @@ def advance_positions_level(bins_f32: jnp.ndarray, positions: jnp.ndarray,
     missing = sel_i == missing_bin
     go_right = sel_i > thr[None, :]                        # [n, N]
     if is_cat is not None:
+        # a row's word of its node's left set: one select a word, in a
+        # loop, not unrolled (W is 11 at 352 slots, 16 at the two-level
+        # schedules' 512: tree/grow.py CAT_SLOTS_MAX, and the one-pass
+        # schedules take any count)
         W = cat_words.shape[1]
         widx = jnp.clip(sel_i // 32, 0, W - 1)             # [n, N]
-        word = jnp.zeros(sel_i.shape, jnp.uint32)
-        for w in range(W):                                 # W is tiny (<=8)
-            word = jnp.where(widx == w, cat_words[None, :, w], word)
+
+        def one_word(w, word):
+            return jnp.where(widx == w, cat_words[None, :, w], word)
+
+        # the first word starts the carry: it varies over a mesh axis as
+        # the rows do
+        word = jax.lax.fori_loop(1, W, one_word,
+                                 one_word(0, jnp.uint32(0)))
         bit = (word >> (sel_i % 32).astype(jnp.uint32)) & jnp.uint32(1)
         go_right = jnp.where(is_cat[None, :], bit == 0, go_right)
     go_right = jnp.where(missing, ~dleft[None, :], go_right)

@@ -17,6 +17,8 @@ Everything ends in a flat argmax per node: pure VPU work that XLA fuses.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -27,12 +29,24 @@ from ..tree.param import TrainParam, calc_gain
 _EPS = 1e-6  # reference kRtEps
 
 
-class CatInfo(NamedTuple):
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["is_cat", "is_onehot"],
+                   meta_fields=["cols", "numeric_bins"])
+@dataclasses.dataclass(frozen=True, eq=False)
+class CatInfo:
     """Categorical feature descriptors (bitmask word count is derived from the
-    bin count where needed, keeping this a plain array pytree)."""
+    bin count where needed). ``is_cat`` and ``is_onehot`` are the pytree's
+    arrays; ``cols`` and ``numeric_bins`` are static, part of the traced
+    program's key: the categorical columns' indices and the most real bins
+    a numeric column has, set by a grower that knows them from its cuts
+    (``tree/grow.py TreeGrower``) and read by the two-level schedules,
+    which build the categorical columns' histograms apart. None where
+    the grower does not say."""
 
     is_cat: jnp.ndarray     # [F] bool
     is_onehot: jnp.ndarray  # [F] bool — cat with n_real <= max_cat_to_onehot
+    cols: Optional[tuple] = None
+    numeric_bins: Optional[int] = None
 
 
 class SplitResult(NamedTuple):
@@ -88,7 +102,6 @@ def evaluate_splits(hist: jnp.ndarray, parent_sum: jnp.ndarray,
     else:
         miss = jnp.zeros(hist.shape[:2] + (2,), hist.dtype)
     cum = jnp.cumsum(present, axis=3)                     # left sums, missing->right
-    parent5 = parent_sum[:, None, None, :, None]          # [N,1,1,2,1]
     bins_idx = jnp.arange(nb, dtype=jnp.int32)
 
     # dir 0 = missing right (default_left=False), dir 1 = missing left;
@@ -109,21 +122,26 @@ def evaluate_splits(hist: jnp.ndarray, parent_sum: jnp.ndarray,
         ratio = present[:, :, 0] / (present[:, :, 1] + param.reg_lambda + 1e-10)
         empty = present[:, :, 1] <= 0.0
         ratio = jnp.where(empty, jnp.inf, ratio)  # empty cats sort last
-        order = jnp.argsort(ratio, axis=2)                       # [N,F,nb]
-        ranks = jnp.argsort(order, axis=2).astype(jnp.int32)
-        sorted_hist = jnp.take_along_axis(present, order[:, :, None, :],
-                                          axis=3)
+        # the (g, h) planes ride the stable sort of their keys, and the
+        # ranks are the order's inverse by a second sort: no gather by the
+        # order, which XLA:TPU lowers as a one-hot of every slot against
+        # every slot (380 MB at 128 nodes x 3 columns x 351 categories)
+        at = jax.lax.broadcasted_iota(jnp.int32, ratio.shape, 2)
+        _, sg, sh, order = jax.lax.sort(
+            (ratio, present[:, :, 0], present[:, :, 1], at), dimension=2,
+            is_stable=True, num_keys=1)                    # [N,F,nb] each
+        _, ranks = jax.lax.sort((order, at), dimension=2, is_stable=True,
+                                num_keys=1)
+        sorted_hist = jnp.stack([sg, sh], axis=2)         # [N,F,2,nb]
         cums = jnp.cumsum(sorted_hist, axis=3)
         left_sorted = jnp.stack(
             [cums, cums + miss[:, :, :, None]][:n_dirs], axis=2)
         # one-hot: right child = {category c}; missing follows the default
-        # direction: dir 0 -> left = parent - hist[c] - miss (missing right),
-        # dir 1 -> left = parent - hist[c] (missing left)
-        present5 = present[:, :, None, :, :]              # [N,F,1,2,nb]
-        miss5 = miss[:, :, None, :, None]                 # [N,F,1,2,1]
-        left_oh = jnp.concatenate(
-            [parent5 - miss5 - present5,
-             parent5 - present5][:n_dirs], axis=2)
+        # direction: dir 0 -> right = hist[c] + miss (missing right),
+        # dir 1 -> right = hist[c] (missing left); left is the rest
+        rest_oh = cum[..., -1:] - present                 # [N,F,2,nb]
+        left_oh = jnp.stack(
+            [rest_oh, rest_oh + miss[:, :, :, None]][:n_dirs], axis=2)
         left = jnp.where(ic5, jnp.where(oh5, left_oh, left_sorted), left)
         # validity: sorted prefixes capped by max_cat_threshold
         cat_valid = jnp.where(
@@ -140,12 +158,21 @@ def evaluate_splits(hist: jnp.ndarray, parent_sum: jnp.ndarray,
     # (a leaf stated at 2.26 where its 215 rows' sums give 0.04, and a
     # spurious gain for the split that isolates it: PERF.md section 6,
     # PR 33). Prefix and total of one histogram round together.
+    # The categorical arms take theirs the same way (below).
     rest = cum[..., -1:] - cum                            # real bins right
     right = jnp.stack([rest + miss[:, :, :, None], rest][:n_dirs],
                       axis=2)                             # [N,F,dirs,2,nb]
     if cat is not None:
-        # one-hot and sorted-partition left sets are not bin prefixes
-        right = jnp.where(ic5, parent5 - left, right)
+        # the categorical arms' right children from the same histogram, as
+        # the numeric ones': the rest of the sorted order, or the one
+        # category (with the missing mass where it goes right)
+        rest_s = cums[..., -1:] - cums
+        right_sorted = jnp.stack(
+            [rest_s + miss[:, :, :, None], rest_s][:n_dirs], axis=2)
+        right_oh = jnp.stack(
+            [present + miss[:, :, :, None], present][:n_dirs], axis=2)
+        right = jnp.where(ic5, jnp.where(oh5, right_oh, right_sorted),
+                          right)
 
     lg, lh = left[:, :, :, 0, :], left[:, :, :, 1, :]     # [N,F,dirs,nb]
     rg, rh = right[:, :, :, 0, :], right[:, :, :, 1, :]
@@ -318,6 +345,10 @@ COARSE_SPAN = 16   # fine bins per coarse bin
 COARSE_B = 20      # coarse hist slots: 16 real + 3 pad + missing at 19
 WINDOW = 32        # refined fine bins: the 2 spans around the boundary
 SYN_B = 46         # synthetic slots: 14 lower + 32 fine + (upper folded)
+# the ids a numeric column's bins take under the two-level search (16 coarse
+# spans of 16), and the coarse pad slot that ids past them land on
+COARSE_NUMERIC_IDS = 16 * COARSE_SPAN
+COARSE_PAD_SLOT = 16
 # features past which ``assemble_two_level`` takes its slots by selects and
 # not by a gather (its docstring)
 SELECT_TAKE_FEATURES = 256
@@ -330,6 +361,13 @@ def coarse_bin_ids(bins_i32: jnp.ndarray, missing_bin: int) -> jnp.ndarray:
     has exactly one definition. When the matrix has no missing slot,
     ``missing_bin`` is an out-of-range sentinel and the remap never fires."""
     shift = COARSE_SPAN.bit_length() - 1
+    if missing_bin > COARSE_NUMERIC_IDS:
+        # ids past the numeric range (a categorical column's, which the
+        # two-level search does not read) land on a pad slot, never on
+        # the missing one or past the last
+        return jnp.where(bins_i32 == missing_bin, COARSE_B - 1,
+                         jnp.minimum(bins_i32 >> shift, COARSE_PAD_SLOT)
+                         ).astype(jnp.uint8)
     return jnp.where(bins_i32 == missing_bin, COARSE_B - 1,
                      bins_i32 >> shift).astype(jnp.uint8)
 
@@ -467,3 +505,32 @@ def decode_two_level_bin(slot: jnp.ndarray,
     return jnp.where(slot < window_sel, lower,
                      jnp.where(slot < window_sel + WINDOW, fine,
                                upper)).astype(jnp.int32)
+
+
+def merge_splits(res_num: SplitResult, res_cat: SplitResult, num_cols,
+                 cat_cols) -> SplitResult:
+    """A level's best split a node from the two arms of a two-level search
+    over a matrix with categorical columns: ``res_num`` from the numeric
+    columns' synthetic histogram, ``res_cat`` from the categorical columns'
+    full one, each with features numbered within its arm (``num_cols`` and
+    ``cat_cols``, static tuples, give their matrix columns). The larger
+    gain wins; a tie goes to the lower matrix column, the order the
+    one-pass search's flat argmax prefers."""
+    f_num = jnp.asarray(num_cols, jnp.int32)[res_num.feature]
+    f_cat = jnp.asarray(cat_cols, jnp.int32)[res_cat.feature]
+    take = (res_cat.gain > res_num.gain) | (
+        (res_cat.gain == res_num.gain) & (f_cat < f_num))
+
+    def pick(c, n):
+        t = take.reshape(take.shape + (1,) * (c.ndim - 1))
+        return jnp.where(t, c, n)
+
+    return SplitResult(
+        gain=pick(res_cat.gain, res_num.gain),
+        feature=pick(f_cat, f_num),
+        bin=pick(res_cat.bin, res_num.bin),
+        default_left=pick(res_cat.default_left, res_num.default_left),
+        left_sum=pick(res_cat.left_sum, res_num.left_sum),
+        right_sum=pick(res_cat.right_sum, res_num.right_sum),
+        is_cat=take & res_cat.is_cat,
+        cat_words=jnp.where(take[:, None], res_cat.cat_words, jnp.uint32(0)))

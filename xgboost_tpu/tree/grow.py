@@ -25,12 +25,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs.metrics import (count_grow_epilogue, count_grow_schedule,
-                           count_mesh_dispatch)
+from ..obs.metrics import (count_cat_splits, count_grow_epilogue,
+                           count_grow_schedule, count_mesh_dispatch)
 from ..obs.trace import mesh_scope, stage
 from ..ops.histogram import advance_leaf, build_hist, fused_advance_coarse
 from ..ops.partition import advance_positions_level, update_positions
-from ..ops.split import CatInfo, evaluate_splits
+from ..ops.split import CatInfo, evaluate_splits, merge_splits
 from ..registry import TREE_UPDATERS
 from .param import TrainParam, calc_weight
 from .tree import TreeModel
@@ -98,17 +98,55 @@ HIST_METHODS = ("auto",) + TWO_LEVEL_METHODS + (
 # only among near-tie fine splits inside unrefined windows.
 AUTO_COARSE_MIN_ROWS = 1 << 16
 AUTO_COARSE_MIN_BINS = 128
+# The two-level schedules of the depthwise resident grower run categorical
+# columns beside the numeric ones: a numeric column keeps its 256 bins (and
+# the missing slot) in the coarse->refine search, a categorical column takes
+# one slot a category in a full histogram every level, summed in the refine
+# build's sweep (``ops/pallas/histogram.py build_hist_pallas(cat_rows=...)``),
+# its splits by ``evaluate_splits``' categorical arms. CAT_SLOTS_MAX bounds a
+# categorical column's slots (16 words of its left set, the routing table's
+# rows in the advance kernels), CAT_COLUMNS_MAX the categorical columns
+# (the advance kernels unroll their routing a column at a time).
+NUMERIC_SLOTS_MAX = 256
+CAT_SLOTS_MAX = 512
+CAT_COLUMNS_MAX = 64
+
+
+def two_level_numeric_bins(cat: Optional[CatInfo], max_nbins: int,
+                           has_missing: bool,
+                           col_split: bool = False) -> Optional[int]:
+    """The most real bins a numeric column has, where the two-level
+    schedules can hold this matrix's categorical columns beside it (every
+    numeric column when ``cat`` is None); None where they cannot: a
+    ``CatInfo`` that does not state its columns (the lossguide and paged
+    growers'), column split, no numeric column, or more categorical
+    columns or slots than the constants above."""
+    if cat is None:
+        return max_nbins - int(has_missing)
+    if (cat.cols is None or col_split or not cat.numeric_bins
+            or len(cat.cols) > CAT_COLUMNS_MAX
+            or max_nbins - int(has_missing) > CAT_SLOTS_MAX):
+        return None
+    return cat.numeric_bins
+
 
 def auto_selects_coarse(n_rows: int, max_nbins: int, has_missing: bool, *,
                         numeric: bool, col_split: bool,
-                        backend: Optional[str] = None) -> bool:
+                        backend: Optional[str] = None,
+                        numeric_bins: Optional[int] = None) -> bool:
     """True when ``hist_method='auto'`` should route to the two-level
-    coarse->refine histogram (depthwise scalar resident/paged growers)."""
+    coarse->refine histogram (depthwise scalar resident/paged growers).
+    ``numeric``: no categorical column, and ``numeric_bins`` is the bins
+    a column has; otherwise ``numeric_bins`` is what
+    ``two_level_numeric_bins`` says (None: a grower that does not run
+    categorical columns beside the two-level search)."""
     if backend is None:
         backend = jax.default_backend()
-    return (backend == "tpu" and numeric and not col_split
-            and max_nbins <= 256 + int(has_missing)
-            and max_nbins - int(has_missing) >= AUTO_COARSE_MIN_BINS
+    if numeric:
+        numeric_bins = max_nbins - int(has_missing)
+    return (backend == "tpu" and numeric_bins is not None and not col_split
+            and AUTO_COARSE_MIN_BINS <= numeric_bins <= NUMERIC_SLOTS_MAX
+            and max_nbins - int(has_missing) <= CAT_SLOTS_MAX
             and n_rows >= AUTO_COARSE_MIN_ROWS)
 
 
@@ -192,10 +230,12 @@ class Schedule(NamedTuple):
 
 def resolve_schedule(hist_method: str, n: int, max_nbins: int,
                      has_missing: bool, *, numeric: bool,
-                     col_split: bool = False) -> Schedule:
+                     col_split: bool = False,
+                     numeric_bins: Optional[int] = None) -> Schedule:
     """The histogram schedule every grower runs for ``hist_method`` at
-    this shape: ``n`` local rows, ``numeric`` = no categorical feature.
-    Reads the backend (``auto_selects_coarse``), nothing else.
+    this shape: ``n`` local rows, ``numeric`` = no categorical feature,
+    ``numeric_bins`` as ``auto_selects_coarse`` reads it. Reads the
+    backend (``auto_selects_coarse``), nothing else.
 
     Two-level coarse->refine search ("coarse"): a 20-slot pass over
     bins >> 4, a span choice per (node, feature) from the coarse boundary
@@ -217,7 +257,7 @@ def resolve_schedule(hist_method: str, n: int, max_nbins: int,
     if hist_method == "auto":
         use_coarse = auto_selects_coarse(
             n, max_nbins, has_missing, numeric=numeric,
-            col_split=col_split)
+            col_split=col_split, numeric_bins=numeric_bins)
     use_fused = hist_method == "fused" or (hist_method == "auto"
                                            and use_coarse)
     return Schedule(kernel=hist_method, coarse=use_coarse, fused=use_fused)
@@ -329,15 +369,26 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                          node_upper[lo:lo + n_level])
         return w * param.eta
 
+    numeric_bins = two_level_numeric_bins(cat, max_nbins, has_missing,
+                                          col_split)
     sched = resolve_schedule(hist_method, n, max_nbins, has_missing,
-                             numeric=cat is None, col_split=col_split)
+                             numeric=cat is None, col_split=col_split,
+                             numeric_bins=numeric_bins)
     count_grow_schedule(sched.name)
     hist_kernel, use_coarse, use_fused = sched
+    # the categorical columns a two-level schedule builds apart, and the
+    # numeric ones it searches in two levels (static column indices)
+    cat_cols = num_cols = None
     if use_coarse:
-        if cat is not None or max_nbins > 256 + int(has_missing):
+        if numeric_bins is None or numeric_bins > NUMERIC_SLOTS_MAX:
             raise NotImplementedError(
-                f"hist_method='{hist_kernel}' supports numeric features "
-                "and max_bin <= 256")
+                f"hist_method='{hist_kernel}' supports numeric features of "
+                f"at most {NUMERIC_SLOTS_MAX} bins beside at most "
+                f"{CAT_COLUMNS_MAX} categorical ones of at most "
+                f"{CAT_SLOTS_MAX} categories, on row split")
+        if cat is not None:
+            cat_cols = tuple(cat.cols)
+            num_cols = tuple(f for f in range(F) if f not in cat_cols)
         # col split composes: the scheme is feature-local end to end
         # (coarse hist, window choice, refine, assembly all run on this
         # shard's features over replicated rows; the existing best-split
@@ -350,6 +401,18 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                                  decode_two_level_bin)
         cb_t = coarse_bin_ids(bins_t.astype(jnp.int32), missing_bin)
         cb = coarse_bin_ids(bins.astype(jnp.int32), missing_bin)
+        if cat_cols:
+            # the categorical columns' full histograms ride the refine
+            # build, their slots padded to whole 32-row tiles of its
+            # one-hot; the numeric columns' rows of the level-invariant
+            # operands
+            ci, ni = np.asarray(cat_cols), np.asarray(num_cols)
+            cat_slots = -(-max_nbins // 32) * 32
+            cat_loc = CatInfo(is_cat=jnp.ones((len(ci),), bool),
+                              is_onehot=cat.is_onehot[ci])
+            n_real_num, n_real_cat = n_real_bins[ni], n_real_bins[ci]
+            mono_num = None if monotone is None else monotone[ni]
+            mono_cat = None if monotone is None else monotone[ci]
 
     pending_adv = None  # fused: splits awaiting the next boundary sweep
     for depth in range(max_depth):
@@ -383,23 +446,39 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                     hist_c = allreduce(build_hist(
                         cb, gpair, rel, n_level, 20, method="auto",
                         bins_t=cb_t, axis_name=row_axis))
+            if cat_cols:
+                # the two-level search reads the numeric columns alone
+                hist_c = hist_c[:, ni]
             with stage("window"):
-                span = choose_refine_window(hist_c,
-                                            node_sum[lo:lo + n_level],
-                                            n_real_bins, param,
-                                            has_missing)          # [N, F]
+                span = choose_refine_window(
+                    hist_c, node_sum[lo:lo + n_level],
+                    n_real_num if cat_cols else n_real_bins, param,
+                    has_missing)                              # [N, F]
             # the refine build reads the raw tile and remaps each row into
             # its node's window: in VMEM inside the kernel where one runs,
             # by refine_bin_ids ahead of the build elsewhere
             # (build_hist's docstring). The out-of-window slot WINDOW + 3
-            # is a VALID slot of the build, its pad slots discarded here
+            # is a VALID slot of the build, its pad slots discarded here.
+            # A categorical column's rows sit in window 0 and its refine
+            # slots are never read: the same sweep sums its full
+            # histogram, one slot a category, every level
+            window = (span if not cat_cols else jnp.zeros(
+                (n_level, F), jnp.int32).at[:, ni].set(span))
             with stage("refine"):
-                hist_r = allreduce(build_hist(
+                hist_r = build_hist(
                     bins, gpair, rel, n_level, WINDOW + 4, method="auto",
-                    bins_t=bins_t, axis_name=row_axis, window=span,
-                    missing_bin=missing_bin))[:, :, :WINDOW, :]
+                    bins_t=bins_t, axis_name=row_axis, window=window,
+                    missing_bin=missing_bin, cat_cols=cat_cols,
+                    cat_slots=cat_slots if cat_cols else 0)
+                if cat_cols:
+                    hist_r, hist_cat = hist_r
+                    hist_cat = allreduce(hist_cat)[:, :, :max_nbins, :]
+                hist_r = allreduce(hist_r)[:, :, :WINDOW, :]
+            if cat_cols:
+                hist_r = hist_r[:, ni]
             hist, n_real_eval = assemble_two_level(
-                hist_c, hist_r, span, n_real_bins, has_missing)
+                hist_c, hist_r, span,
+                n_real_num if cat_cols else n_real_bins, has_missing)
         else:
             with stage("hist"):
                 hist = build_hist(
@@ -434,22 +513,35 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
             fmask = fmask & allowed
 
         parent_sum = node_sum[lo:lo + n_level]
+        bounds = dict(
+            node_lower=node_lower[lo:lo + n_level]
+            if monotone is not None else None,
+            node_upper=node_upper[lo:lo + n_level]
+            if monotone is not None else None)
         with stage("eval"):
             res = evaluate_splits(
                 hist, parent_sum,
                 n_real_eval if use_coarse else n_real_bins, param,
-                feature_mask=fmask, monotone=mono_loc,
-                node_lower=node_lower[lo:lo + n_level]
-                if monotone is not None else None,
-                node_upper=node_upper[lo:lo + n_level]
-                if monotone is not None else None,
-                cat=cat_loc, has_missing=has_missing)
+                feature_mask=fmask[:, ni] if cat_cols else fmask,
+                monotone=mono_num if cat_cols else mono_loc,
+                cat=None if cat_cols else cat_loc, has_missing=has_missing,
+                **bounds)
         if use_coarse:
             # synthetic slot -> fine bin, per node's span for its feature
             span_sel = jnp.take_along_axis(
                 span, jnp.maximum(res.feature, 0)[:, None], axis=1)[:, 0]
             res = res._replace(
                 bin=decode_two_level_bin(res.bin, span_sel))
+        if cat_cols:
+            # the categorical arm: evaluate_splits' one-hot and sorted-
+            # partition search over the full histograms; the better of the
+            # two arms a node wins
+            with stage("cat_split"), stage("eval"):
+                res_cat = evaluate_splits(
+                    hist_cat, parent_sum, n_real_cat, param,
+                    feature_mask=fmask[:, ci], monotone=mono_cat,
+                    cat=cat_loc, has_missing=has_missing, **bounds)
+                res = merge_splits(res, res_cat, num_cols, cat_cols)
 
         if col_split:
             local_feat, local_bin = res.feature, res.bin
@@ -525,7 +617,7 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
 
         if use_fused:
             # defer this level's advance to the NEXT boundary's fused
-            # sweep; categorical args never arise (coarse is numeric-only)
+            # sweep (column split is numeric-only here: cat_cols is None)
             if col_split and n_level <= DENSE_LEVEL_MAX:
                 pending_adv = {
                     "kind": "dense", "lo": lo, "n_level": n_level,
@@ -546,6 +638,14 @@ def _grow(bins: jnp.ndarray, gpair: jnp.ndarray, n_real_bins: jnp.ndarray,
                     "arrs": (split_feature, split_bin, default_left,
                              is_split_full),
                     "feat_offset": feat_off}
+            if cat_cols:
+                # categorical nodes route by their left sets, over the
+                # same nodes as "arrs"
+                pending_adv["cat_cols"] = cat_cols
+                pending_adv["cat"] = (
+                    (can_split & res.is_cat, res.cat_words)
+                    if pending_adv["kind"] == "dense"
+                    else (is_cat_split, cat_words))
         elif col_split and n_level <= DENSE_LEVEL_MAX:
             # only the owning shard can route rows at each node; its local
             # decisions reach every shard through one boolean psum (the
@@ -812,12 +912,15 @@ class TreeGrower:
         self.constraint_sets = (None if constraint_sets is None
                                 else jnp.asarray(constraint_sets, bool))
         is_cat = cuts.is_cat()
+        n_real = cuts.n_real_bins()
+        # per feature on the host, for the split counter (to_tree_model)
+        self._is_onehot = is_cat & (n_real <= param.max_cat_to_onehot)
         if is_cat.any():
-            n_real = cuts.n_real_bins()
             self.cat = CatInfo(
                 is_cat=jnp.asarray(is_cat),
-                is_onehot=jnp.asarray(
-                    is_cat & (n_real <= param.max_cat_to_onehot)))
+                is_onehot=jnp.asarray(self._is_onehot),
+                cols=tuple(int(f) for f in np.flatnonzero(is_cat)),
+                numeric_bins=int(n_real[~is_cat].max(initial=0)))
         else:
             self.cat = None
         if split_mode == "col":
@@ -841,7 +944,9 @@ class TreeGrower:
                 if self.cat is not None:
                     self.cat = CatInfo(
                         is_cat=jnp.pad(self.cat.is_cat, (0, pad)),
-                        is_onehot=jnp.pad(self.cat.is_onehot, (0, pad)))
+                        is_onehot=jnp.pad(self.cat.is_onehot, (0, pad)),
+                        cols=self.cat.cols,
+                        numeric_bins=self.cat.numeric_bins)
 
     def grow(self, bins: jnp.ndarray, gpair: jnp.ndarray,
              n_real_bins: jnp.ndarray, key: jax.Array) -> GrownTree:
@@ -926,6 +1031,15 @@ class TreeGrower:
         sf = np.asarray(g.split_feature)
         sb = np.asarray(g.split_bin)
         split_value = self.cuts.split_values(sf, sb)
+        is_cat_split = np.asarray(g.is_cat_split)
+        splits = np.asarray(g.active) & ~np.asarray(g.is_leaf) & (sf >= 0)
+        cat_split = splits & is_cat_split
+        onehot = cat_split & self._is_onehot[np.clip(
+            sf, 0, len(self._is_onehot) - 1)]
+        n_cat = int(cat_split.sum())
+        n_onehot = int(onehot.sum())
+        count_cat_splits(n_cat - n_onehot, n_onehot,
+                         int(splits.sum()) - n_cat)
         return TreeModel.from_heap(
             split_feature=sf, split_bin=sb, split_value=split_value,
             default_left=np.asarray(g.default_left),
@@ -933,7 +1047,7 @@ class TreeGrower:
             leaf_value=np.asarray(g.leaf_value),
             sum_hess=np.asarray(g.node_sum[:, 1]),
             gain=np.asarray(g.gain),
-            is_cat_split=np.asarray(g.is_cat_split),
+            is_cat_split=is_cat_split,
             cat_words=np.asarray(g.cat_words),
             base_weight=None if g.base_weight is None
             else np.asarray(g.base_weight),

@@ -31,7 +31,7 @@ class _NumericCuts:
     def is_cat(self) -> np.ndarray:
         return np.zeros(self._F, bool)
 
-    def n_real_bins(self) -> np.ndarray:  # pragma: no cover - cat-only path
+    def n_real_bins(self) -> np.ndarray:
         return np.full(self._F, _B - 1, np.int32)
 
 
